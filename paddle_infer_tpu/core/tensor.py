@@ -42,7 +42,10 @@ class Tensor:
     def __init__(self, data, stop_gradient: bool = True, name: Optional[str] = None):
         if isinstance(data, Tensor):
             data = data._data
-        if not isinstance(data, jax.Array):
+        # a ShapeDtypeStruct is an abstract payload (shape and dtype, no
+        # buffer): nn.layer.abstract_parameters builds layers from them
+        # so a loader can bind checkpoint arrays without an init copy
+        if not isinstance(data, (jax.Array, jax.ShapeDtypeStruct)):
             data = jnp.asarray(data)
         self._data = data
         self.stop_gradient = stop_gradient

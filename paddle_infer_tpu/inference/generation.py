@@ -20,6 +20,7 @@ reference predictor's shape-keyed TRT engine cache).
 from __future__ import annotations
 
 import logging
+import re
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -107,6 +108,37 @@ class _MeshContext:
         topology.set_current_mesh(self._prev)
         topology.set_quantized_allreduce(self._prev_quant)
         return False
+
+
+def _key_tag(key) -> str:
+    """A program key's leading tag (``"serve-step"``), its name in logs."""
+    return str(key[0]) if isinstance(key, tuple) and key else str(key)
+
+
+def _device_ids(arrays) -> list:
+    return sorted({d.id for a in arrays for d in a.sharding.device_set})
+
+
+def _abstract_like(a):
+    """Shape, dtype AND placement of a live array: an AOT lowering from
+    these compiles the program the dispatch path runs, sharded where it
+    is sharded — not a single-device stand-in for it."""
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+                "collective-permute")
+
+
+def _count_collectives(hlo_text: str) -> dict:
+    """{collective: count} over a compiled module's text (async pairs
+    count once, at their -start)."""
+    out = {}
+    for name in _COLLECTIVES:
+        n = len(re.findall(rf"= [^=\n]*\b{name}(?:-start)?\(", hlo_text))
+        if n:
+            out[name] = n
+    return out
 
 
 @dataclass
@@ -236,6 +268,23 @@ class GenerationEngine:
             out[n] = placed
         return out
 
+    def adopt_placement(self):
+        """Rebind the model's parameters to the arrays this engine placed
+        over its mesh, so the single-device copies they were placed FROM
+        can be freed.  For a caller that owns the model and serves it
+        through this engine only (tools/serve.py): without it the default
+        device keeps the whole checkpoint beside its shard.  Engines
+        without a mesh have nothing to adopt."""
+        if self._mesh is None:
+            return
+        named = dict(self._model.named_parameters())
+        named.update(self._weight_only_buffers())
+        for n, (src, placed) in self._placed.items():
+            t = named.get(n)
+            if t is not None and t._data is src:
+                t._data = placed
+                self._placed[n] = (placed, placed)
+
     def _mesh_ctx(self):
         return _MeshContext(self._mesh, self._quant_allreduce)
 
@@ -256,6 +305,16 @@ class GenerationEngine:
             "replicated_params": len(fallbacks),
             "replicated_names": fallbacks[:8],
             "quantized_allreduce": self._quant_allreduce or "",
+            # where the bytes actually are, as the runtime reports it:
+            # a placement rule that silently left everything on device 0
+            # shows here as a one-element list
+            "param_devices": _device_ids(self._params.values()),
+            "kv_pool_devices": _device_ids(
+                jax.tree_util.tree_leaves(
+                    getattr(self, "_k_pages", None) or [])),
+            "step_collectives": {
+                _key_tag(k): v for k, v in getattr(
+                    self, "_program_collectives", {}).items()},
         }
 
     def _replicated(self, arr):
@@ -665,6 +724,9 @@ class PagedGenerationEngine(GenerationEngine):
         # (observability.steplog's analytic bytes/FLOPs source)
         self._program_shapes = {}
         self._program_costs = {}
+        # per-program-key {collective op: count} read off the compiled
+        # text alongside the cost (shard_report's step_collectives)
+        self._program_collectives = {}
         # persistent per-layer device pools [P, h, page, d]; donated into
         # every compiled call and rebound from its outputs, so the arrays
         # genuinely stay put in HBM across requests
@@ -691,32 +753,34 @@ class PagedGenerationEngine(GenerationEngine):
         if self._k_pages is None or shape_of(self._k_pages[0]) != pshape:
             from ..ops.pallas.paged_attention import KV_SCALE_EPS
 
+            # under a mesh the pool is head-sharded: each mp shard owns
+            # its heads' pages, replicated over every other serving
+            # axis.  Allocated IN that placement — a zeros on the default
+            # device that is then moved would stage each layer's whole
+            # pool on device 0
+            payload_at = scales_at = None
+            if self._mesh is not None:
+                from jax.sharding import NamedSharding
+                from jax.sharding import PartitionSpec as P
+
+                from ..parallel.topology import axis_if_divides
+
+                hax = axis_if_divides(self._mesh, "mp", self._num_heads)
+                payload_at = NamedSharding(self._mesh,
+                                           P(None, hax, None, None))
+                scales_at = NamedSharding(self._mesh, P(None, hax))
+
             def alloc():
                 quant = self._kv_dtype == "int8"
                 z = jnp.zeros(pshape, jnp.int8 if quant
-                              else self._cache_dtype)
+                              else self._cache_dtype, device=payload_at)
+                if not quant:
+                    return z
                 # scales start at the eps floor (never zero): dequant of
                 # a zeroed pool is zero and the scale > 0 invariant the
                 # masked-max writer relies on holds from the first step
-                sc = jnp.full(pshape[:2], KV_SCALE_EPS, jnp.float32) \
-                    if quant else None
-                if self._mesh is not None:
-                    # head-sharded pool: each mp shard owns its heads'
-                    # pages; replicated over every other serving axis
-                    from jax.sharding import NamedSharding
-                    from jax.sharding import PartitionSpec as P
-
-                    from ..parallel.topology import axis_if_divides
-
-                    hax = axis_if_divides(self._mesh, "mp",
-                                          self._num_heads)
-                    z = jax.device_put(
-                        z, NamedSharding(self._mesh,
-                                         P(None, hax, None, None)))
-                    if sc is not None:
-                        sc = jax.device_put(
-                            sc, NamedSharding(self._mesh, P(None, hax)))
-                return (z, sc) if quant else z
+                return z, jnp.full(pshape[:2], KV_SCALE_EPS, jnp.float32,
+                                   device=scales_at)
 
             self._k_pages = [alloc() for _ in range(self._num_layers)]
             self._v_pages = [alloc() for _ in range(self._num_layers)]
@@ -774,8 +838,7 @@ class PagedGenerationEngine(GenerationEngine):
             # before donation consumes the pools, costing only a
             # tree_map on the first call per key
             abstract = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                (args, k_pages, v_pages))
+                _abstract_like, (args, k_pages, v_pages))
             self._program_shapes[key] = abstract
         self._k_pages = self._v_pages = None
         t0 = time.perf_counter() if is_compile else 0.0
@@ -783,8 +846,7 @@ class PagedGenerationEngine(GenerationEngine):
             out = fn(self._params, *args, k_pages, v_pages)
         if is_compile:
             sigs.add(sig)
-            tag = str(key[0]) if isinstance(key, tuple) and key else \
-                str(key)
+            tag = _key_tag(key)
             site = ("serving-decode" if tag in ("serve-step",)
                     else "serving-prefill"
                     if tag in ("serve-prefill", "serve-prefill-px")
@@ -815,14 +877,15 @@ class PagedGenerationEngine(GenerationEngine):
         if fn is None or shapes is None:
             return None
         args_s, k_s, v_s = shapes
-        params_s = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self._params)
+        params_s = jax.tree_util.tree_map(_abstract_like, self._params)
         cost = None
         try:
             with self._mesh_ctx():
                 lowered = fn.lower(params_s, *args_s, k_s, v_s)
-                analysis = lowered.compile().cost_analysis()
+                compiled = lowered.compile()
+                analysis = compiled.cost_analysis()
+            self._program_collectives[key] = _count_collectives(
+                compiled.as_text())
             if isinstance(analysis, (list, tuple)):
                 analysis = analysis[0] if analysis else {}
             if analysis:

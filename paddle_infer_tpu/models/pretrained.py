@@ -92,9 +92,11 @@ class PretrainedMixin:
 
     @classmethod
     def from_pretrained(cls, save_dir: str):
+        import jax.numpy as jnp
+
         from .. import load as pit_load
         from .. import native
-        from ..core.tensor import Tensor
+        from ..nn.initializer import abstract_parameters
 
         with open(os.path.join(save_dir, _CONFIG)) as f:
             cfg = json.load(f)
@@ -104,13 +106,30 @@ class PretrainedMixin:
                 f"{save_dir} holds a {arch}, not a {cls.__name__} — "
                 f"load it with {arch}.from_pretrained")
         config = cls.config_class(**cfg)
-        model = cls(config)
+        # parameters stay abstract until the checkpoint binds them: the
+        # model is served in the checkpoint's dtype, and the device never
+        # holds a default-initialised fp32 copy beside the loaded one
+        with abstract_parameters():
+            model = cls(config)
         pits = os.path.join(save_dir, _WEIGHTS_PITS)
         if os.path.exists(pits):
             tensors = native.load_tensors(pits)
         else:
             tensors = pit_load(os.path.join(save_dir, _WEIGHTS_PKL))
-        model.set_state_dict({n: Tensor(np.asarray(v))
-                              for n, v in tensors.items()})
+        missing = []
+        for name, p in model.named_parameters():
+            if name not in tensors:
+                missing.append(name)
+                continue
+            value = np.asarray(tensors[name])
+            if tuple(value.shape) != tuple(p._data.shape):
+                raise ValueError(
+                    f"{save_dir}: {name} has shape {value.shape}, the "
+                    f"config builds {tuple(p._data.shape)}")
+            p._data = jnp.asarray(value)
+        if missing:
+            raise ValueError(
+                f"{save_dir} lacks {len(missing)} parameter(s) the "
+                f"config builds: {missing[:8]}")
         model.eval()
         return model

@@ -10,15 +10,22 @@ Python loop cannot serve fast enough:
   - TensorStore — mmap'd raw-tensor checkpoint format (reference
     .pdiparams raw serialization, inference/io.cc), zero-copy reads.
 
-The library is built on demand with ``make -C native`` (g++ only — no
-external deps).  ``available()`` reports whether the native path is up;
-callers fall back to the pure-Python implementations when it is not.
+The library is built on first use from the files git tracks (g++ and
+make only — no external deps): ``ensure_built`` keys staleness on a hash
+of the sources and serializes concurrent first users on a file lock.
+``available()`` reports whether the native path is up; a failed build
+says why once, with make's stderr.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
+import shutil
 import subprocess
+import tempfile
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -26,11 +33,6 @@ import numpy as np
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libpitnative.so")
-
-_lib = None
-_load_error: Optional[str] = None
-_build_attempted = False
 
 # numpy dtype <-> stable wire codes for TensorStore
 _DTYPE_CODES = {
@@ -48,48 +50,86 @@ def _np_dtype(name: str) -> np.dtype:
     return np.dtype(name)
 
 
-def _build() -> bool:
-    if not os.path.isdir(_NATIVE_DIR):
-        return False
-    try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-j4"], check=True,
-                       capture_output=True, timeout=300)
-        return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+_lib = None
+_load_error: Optional[str] = None
+_build_status: Optional[str] = None     # "found" | "built" once resolved
 
 
-def _stale() -> bool:
-    """True when any .cc/.h/Makefile is newer than the built library."""
-    if not os.path.exists(_LIB_PATH):
-        return False
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    for name in os.listdir(_NATIVE_DIR):
+def _source_digest() -> str:
+    """sha256 over the Makefile and every .cc/.h, by name order — what
+    the built libraries are a function of.  Recorded beside each library
+    so staleness never depends on mtimes (a fresh checkout or copy has
+    them equal or shuffled)."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(_NATIVE_DIR)):
         if name.endswith((".cc", ".h")) or name == "Makefile":
-            if os.path.getmtime(os.path.join(_NATIVE_DIR, name)) > lib_mtime:
-                return True
-    return False
+            h.update(name.encode() + b"\0")
+            with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def _up_to_date(lib_path: str, digest: str) -> bool:
+    try:
+        with open(lib_path + ".sha256") as f:
+            return f.read().strip() == digest and os.path.exists(lib_path)
+    except OSError:
+        return False
+
+
+def ensure_built(lib_name: str = "libpitnative.so") -> Tuple[str, str]:
+    """Path of ``native/<lib_name>`` built from the sources as they are
+    now, and whether it was ``"found"`` or ``"built"``.  Concurrent first
+    users (xdist workers, a parent's children) serialize on an exclusive
+    file lock: one builds — into a private directory, then ``os.replace``
+    into place — and the rest find it.  Raises RuntimeError carrying
+    make's stderr when the build fails."""
+    lib_path = os.path.join(_NATIVE_DIR, lib_name)
+    digest = _source_digest()
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _up_to_date(lib_path, digest):
+            return lib_path, "found"
+        out = tempfile.mkdtemp(prefix=".build-", dir=_NATIVE_DIR)
+        try:
+            proc = subprocess.run(
+                ["make", "-C", _NATIVE_DIR, "-j4", f"OUT={out}",
+                 os.path.join(out, lib_name)],
+                capture_output=True, text=True, timeout=300)
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    f"native build of {lib_name} failed "
+                    f"(rc={proc.returncode}):\n{proc.stderr[-2000:]}")
+            os.replace(os.path.join(out, lib_name), lib_path)
+            stamp = os.path.join(out, "stamp")
+            with open(stamp, "w") as f:
+                f.write(digest)
+            os.replace(stamp, lib_path + ".sha256")
+        finally:
+            shutil.rmtree(out, ignore_errors=True)
+    return lib_path, "built"
+
+
+def build_status() -> Optional[str]:
+    """``"found"`` / ``"built"`` once the library is loaded, else None."""
+    return _build_status
 
 
 def _load():
-    global _lib, _load_error, _build_attempted
+    global _lib, _load_error, _build_status
     if _lib is not None:
         return _lib
     if _load_error is not None:
         return None            # failure latched: don't re-spawn make
-    if not os.path.exists(_LIB_PATH) or _stale():
-        if _build_attempted or not _build():
-            _build_attempted = True
-            if not os.path.exists(_LIB_PATH):
-                _load_error = (
-                    f"native library missing and build failed ({_LIB_PATH})")
-                return None
-            # stale but rebuild failed: fall through and use what exists
-        _build_attempted = True
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError as e:  # pragma: no cover
+        lib_path, status = ensure_built()
+        lib = ctypes.CDLL(lib_path)
+    except (OSError, RuntimeError, subprocess.TimeoutExpired) as e:
         _load_error = str(e)
+        # said once, with make's own words: callers that only ask
+        # available() would otherwise never learn why it is False
+        warnings.warn(f"native runtime unavailable: {_load_error}",
+                      RuntimeWarning, stacklevel=2)
         return None
     c = ctypes
     sigs = {
@@ -146,30 +186,11 @@ def _load():
         "tstore_entry_data": ([c.c_void_p, c.c_int32], c.c_void_p),
         "tstore_last_error": ([], c.c_int32),
     }
-    try:
-        for name, (argtypes, restype) in sigs.items():
-            fn = getattr(lib, name)
-            fn.argtypes = argtypes
-            fn.restype = restype
-    except AttributeError:
-        # stale prebuilt .so missing a newer symbol: rebuild once, else
-        # latch the failure so available() keeps its returns-bool contract
-        if not _build_attempted and _build():
-            _build_attempted = True
-            try:
-                lib = ctypes.CDLL(_LIB_PATH)
-                for name, (argtypes, restype) in sigs.items():
-                    fn = getattr(lib, name)
-                    fn.argtypes = argtypes
-                    fn.restype = restype
-            except (OSError, AttributeError) as e:
-                _load_error = f"stale native library: {e}"
-                return None
-        else:
-            _build_attempted = True
-            _load_error = ("native library is stale (missing symbol) and "
-                           "rebuild failed")
-            return None
+    for name, (argtypes, restype) in sigs.items():
+        fn = getattr(lib, name)
+        fn.argtypes = argtypes
+        fn.restype = restype
+    _build_status = status
     _lib = lib
     return lib
 

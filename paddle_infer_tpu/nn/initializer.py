@@ -2,6 +2,8 @@
 fluid/initializer.py). Each initializer maps (shape, dtype) -> jax array."""
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 
 import numpy as np
@@ -23,7 +25,44 @@ def _fans(shape):
     return shape[1] * receptive, shape[0] * receptive
 
 
+_abstract = False
+
+
+@contextlib.contextmanager
+def abstract_parameters():
+    """While active, every initializer returns a ``jax.ShapeDtypeStruct``
+    instead of an array: layers are built with their parameters' shapes
+    and dtypes, no random bits are drawn and nothing lands on the device.
+    For loaders that bind every parameter from a checkpoint right after
+    construction — a default-initialised fp32 copy of a model that is
+    about to be overwritten can be more than the device holds."""
+    global _abstract
+    prev, _abstract = _abstract, True
+    try:
+        yield
+    finally:
+        _abstract = prev
+
+
 class Initializer:
+    def __init_subclass__(cls, **kw):
+        # layers call initializers from several places (create_parameter,
+        # the mp layers, MoE stacks): the abstract switch sits on the one
+        # thing they all go through
+        super().__init_subclass__(**kw)
+        call = cls.__dict__.get("__call__")
+        if call is None:
+            return
+
+        @functools.wraps(call)
+        def guarded(self, shape, dtype="float32"):
+            if _abstract:
+                return jax.ShapeDtypeStruct(tuple(shape),
+                                            dtypes.convert_dtype(dtype))
+            return call(self, shape, dtype)
+
+        cls.__call__ = guarded
+
     def __call__(self, shape, dtype="float32"):
         raise NotImplementedError
 

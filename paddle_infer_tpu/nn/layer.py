@@ -15,7 +15,6 @@ import numpy as np
 
 from ..core.tensor import Parameter, Tensor
 
-
 class Layer:
     def __init__(self, name_scope=None, dtype="float32"):
         object.__setattr__(self, "_parameters", OrderedDict())

@@ -24,26 +24,21 @@ import jax
 import jax.numpy as jnp
 
 from ..core.dispatch import register_op, register_vjp_grad
+from . import pallas
 
 _FALLBACK_WARNED: set = set()
 
 
 def _warn_once(reason: str, detail: str):
-    """One-time warning per fallback reason (VERDICT r2 weak #7: the silent
-    fast-path cliffs), mirroring the Pallas-failure warning below."""
+    """One-time warning per documented shape gate that keeps a long
+    sequence on the XLA path (VERDICT r2 weak #7: the silent fast-path
+    cliffs)."""
     if reason in _FALLBACK_WARNED:
         return
     _FALLBACK_WARNED.add(reason)
     warnings.warn(
         f"sdpa falling back to the O(s^2) XLA attention path: {detail}",
         RuntimeWarning, stacklevel=3)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def _attn_impl_choice(q, k, mask, quiet=False):
@@ -55,14 +50,16 @@ def _attn_impl_choice(q, k, mask, quiet=False):
     transpose, and beyond ~4k the pure-Pallas kernel must take over
     because the XLA forward's O(s^2) logits dominate HBM.
 
-      "xla"    — short seqs / arbitrary dense masks / non-TPU
+      "xla"    — short seqs / arbitrary dense masks / the CPU backend
       "hybrid" — XLA fwd + Pallas bwd (training sweet spot, >= 512)
       "flash"  — pure Pallas fwd+bwd (long seqs, >= 4096)
 
     Segment-id masks and dropout do NOT force the XLA path: the kernels
     handle both (segment masking + hash dropout in-tile).
     """
-    if not _on_tpu():
+    if pallas.interpret():
+        # the CPU backend would run the kernels in the interpreter; the
+        # fused XLA composition is the better CPU program
         return "xla"
     b, s, h, d = q.shape
     sk = k.shape[1]
@@ -208,9 +205,6 @@ def _xla_sdpa(q, k, v, mask, seed, dropout_p, is_causal, scale,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision=prec)
 
 
-_pallas_fallback_warned = False
-
-
 @register_op("sdpa")
 def _sdpa(q, k, v, mask=None, key=None, q_segment_ids=None,
           kv_segment_ids=None, dropout_p=0.0, is_causal=False, scale=None,
@@ -221,20 +215,14 @@ def _sdpa(q, k, v, mask=None, key=None, q_segment_ids=None,
         from .pallas.flash_attention import (flash_attention,
                                              hybrid_attention)
 
+        # no rescue around the kernel: the shape gates above chose it, so
+        # a kernel that cannot trace or lower is an error (Pallas names
+        # the kernel, block and array shapes in it), not an XLA run
         fn = flash_attention if impl == "flash" else hybrid_attention
-        try:
-            return _mesh_sharded_attn(
-                fn, q, k, v, q_segment_ids=q_segment_ids,
-                kv_segment_ids=kv_segment_ids, dropout_p=dropout_p,
-                dropout_seed=seed, is_causal=is_causal, scale=scale)
-        except Exception as e:   # pragma: no cover - TPU-only path
-            global _pallas_fallback_warned
-            if not _pallas_fallback_warned:
-                _pallas_fallback_warned = True
-                warnings.warn(
-                    f"pallas attention ({impl}) failed ({e!r}); falling "
-                    "back to the O(s^2) XLA path — perf/memory cliff at "
-                    "long seq", RuntimeWarning)
+        return _mesh_sharded_attn(
+            fn, q, k, v, q_segment_ids=q_segment_ids,
+            kv_segment_ids=kv_segment_ids, dropout_p=dropout_p,
+            dropout_seed=seed, is_causal=is_causal, scale=scale)
     return _xla_sdpa(q, k, v, mask, seed, dropout_p, is_causal, scale,
                      q_segment_ids=q_segment_ids,
                      kv_segment_ids=kv_segment_ids)

@@ -9,33 +9,42 @@ switch (``EnableAutoTune``).
 TPU redesign: the tunables are Pallas grid block sizes, not cuDNN algo
 enums.  Tuning happens at *trace time* with concrete dummy operands (the
 live values are tracers), so one benchmark per (kernel, shape) services
-every retrace; winners persist to ``FLAGS_autotune_cache_file`` so a
-serving restart pays nothing.  The incumbent default must lose by >3% to
-be replaced — noisy timings never regress the shipped configuration.
+every retrace; winners persist to ``FLAGS_autotune_cache_file`` — by
+default a fixed file in the checkout, so a serving restart (or the next
+process of the same run) pays nothing.  The incumbent default must lose
+by >3% to be replaced — noisy timings never regress the shipped
+configuration.
 """
 from __future__ import annotations
 
 import json
+import logging
 import os
 import time
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Sequence
 
 from ...framework.flags import define_flag, flags
+from ...utils.compile_cache import CHECKOUT
+from . import interpret
 
 define_flag("use_autotune", True,
             "measure Pallas kernel block-size candidates per shape and "
             "cache the winner (reference phi/kernels/autotune)")
 define_flag("autotune_cache_file", "",
-            "JSON file persisting autotune winners across processes")
+            "JSON file persisting autotune winners across processes "
+            "(empty: .autotune_cache.json at the checkout root)")
 
+_LOG = logging.getLogger(__name__)
+_DEFAULT_CACHE_FILE = os.path.join(CHECKOUT, ".autotune_cache.json")
 _CACHE: Dict[str, list] = {}
 _LOADED = False
 _MIN_GAIN = 0.97     # challenger must beat the incumbent by >3%
 
 
-def _cache_path() -> Optional[str]:
-    p = flags("autotune_cache_file")
-    return p or os.environ.get("FLAGS_autotune_cache_file") or None
+def _cache_path() -> str:
+    # a fixed path, never a temp name: the processes of one run (and the
+    # next run in the same checkout) must find each other's winners
+    return flags("autotune_cache_file") or _DEFAULT_CACHE_FILE
 
 
 def _load():
@@ -44,36 +53,22 @@ def _load():
         return
     _LOADED = True
     p = _cache_path()
-    if p and os.path.exists(p):
-        try:
-            with open(p) as f:
-                _CACHE.update(json.load(f))
-        except (OSError, json.JSONDecodeError):   # pragma: no cover
-            pass
+    if os.path.exists(p):
+        with open(p) as f:
+            _CACHE.update(json.load(f))
 
 
 def _persist():
     p = _cache_path()
-    if not p:
-        return
-    tmp = p + ".tmp"
-    try:
-        with open(tmp, "w") as f:
-            json.dump(_CACHE, f)
-        os.replace(tmp, p)
-    except OSError:                               # pragma: no cover
-        pass
+    tmp = f"{p}.tmp{os.getpid()}"
+    with open(tmp, "w") as f:
+        json.dump(_CACHE, f)
+    os.replace(tmp, p)
 
 
 def enabled() -> bool:
-    import jax
-
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:                             # pragma: no cover
-        return False
-    return bool(flags("use_autotune"))
+    """Compiled kernels only: timing the interpreter tunes nothing."""
+    return not interpret() and bool(flags("use_autotune"))
 
 
 def clear():
@@ -88,24 +83,29 @@ def autotune(key: str, default, candidates: Sequence,
     so only steady-state time is compared."""
     if not enabled():
         return default
+    import jax
+
     _load()
+    # winners are per chip generation: a file carried to another device
+    # must miss, not answer
+    key = f"{jax.devices()[0].device_kind}|{key}"
     hit = _CACHE.get(key)
     if hit is not None:
         return tuple(hit) if isinstance(hit, list) else hit
-    best, best_t = default, None
-    try:
-        best_t = measure(default)
-        for cand in candidates:
-            if cand == default:
-                continue
-            try:
-                t = measure(cand)
-            except Exception:       # candidate invalid for this shape
-                continue
-            if best_t is None or t < best_t * _MIN_GAIN:
-                best, best_t = cand, t
-    except Exception:               # pragma: no cover - measurement failed
-        return default
+    # the incumbent is what ships: if IT cannot compile or run, that is
+    # the caller's error to see, not something to tune around
+    best, best_t = default, measure(default)
+    for cand in candidates:
+        if cand == default:
+            continue
+        try:
+            t = measure(cand)
+        except Exception as e:      # challenger invalid for this shape
+            _LOG.warning("autotune %s: candidate %r skipped: %r",
+                         key, cand, e)
+            continue
+        if t < best_t * _MIN_GAIN:
+            best, best_t = cand, t
     _CACHE[key] = list(best) if isinstance(best, tuple) else best
     _persist()
     return best

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
+
 LANES = 128
 # HBM-stored per-row stats (lse, delta, q-segment ids) only need a narrow
 # lane tile; 128 lanes would write/read 16x the bytes for the same info
@@ -53,13 +55,6 @@ STAT_LANES = 8
 # sublane-broadcast the same way the q-side stats are lane-broadcast
 SEG_SUBLANES = 8
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
 
 
 def _fit_block(requested: int, seq: int) -> int:

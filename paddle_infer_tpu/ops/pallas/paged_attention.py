@@ -17,8 +17,8 @@ walk; heads are the row dimension of the in-kernel matmuls.  Decode is
 HBM-bandwidth-bound, so the win is reading only ceil(len/page) pages per
 sequence instead of max_seq rows.
 
-CPU fallback/interpret mode runs the same kernel through the Pallas
-interpreter for tests.
+On the CPU backend the tests run the same kernel through the Pallas
+interpreter (``ops.pallas.interpret``).
 """
 from __future__ import annotations
 
@@ -30,20 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
+
 NEG_INF = -1e30
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernel (and the serving engine above it) runs on either side of the
-# rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
-
-def _interpret() -> bool:
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
 
 
 # ------------------------------------------------------------ quantized KV
@@ -137,6 +126,29 @@ def _quantized_scatter(pages, page_idx, slot, kv):
 
 # ------------------------------------------------------------------ kernel
 
+def _page_scales(ks_ref, vs_ref, j):
+    """Column ``j`` of the row's gathered ``[1, H, max_pages]`` K/V scale
+    blocks as ``[H, 1]``.  A masked lane reduction, not a dynamic lane
+    slice: one live term plus zeros, so the pick is exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
+    pick = lambda ref: jnp.sum(jnp.where(lane == j, ref[0], 0.0),
+                               axis=1, keepdims=True)
+    return pick(ks_ref), pick(vs_ref)
+
+
+def _scale_operands(k_scales, v_scales, block_tables):
+    """(in_specs, operands) for a quantized launch: ``[P, H]`` pool scales
+    gathered to the ``[B, H, max_pages]`` rows the launch walks.  A block
+    of one page over the pool-wide array has a second-minor dim of 1,
+    which the TPU lowering refuses; the gathered copy is a few KB and its
+    per-row block is the full trailing dims, resident across the walk."""
+    h, max_pages = k_scales.shape[1], block_tables.shape[1]
+    spec = pl.BlockSpec((1, h, max_pages),
+                        lambda b_, j_, *prefetch: (b_, 0, 0))
+    rows = lambda scales: jnp.transpose(scales[block_tables], (0, 2, 1))
+    return [spec, spec], [rows(k_scales), rows(v_scales)]
+
+
 def _decode_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                    q_ref, k_ref, v_ref,          # blocks (VMEM)
                    *rest,                        # [ks, vs,] o + scratch
@@ -165,13 +177,15 @@ def _decode_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
         q = q_ref[0].astype(jnp.float32)            # [H, D]
         k = k_ref[0].astype(jnp.float32)            # [H, page, D]
         v = v_ref[0].astype(jnp.float32)            # [H, page, D]
-        if quantized:
-            # per-(page, head) dequant rides the VPU feed — the int8
-            # payload is what the DMA streamed, halving page bytes
-            k = k * ks_ref[0][:, None, None]
-            v = v * vs_ref[0][:, None, None]
         # scores over this page's slots: [H, page]
         s = jnp.sum(q[:, None, :] * k, axis=2) * scale
+        if quantized:
+            # per-(page, head) dequant: the int8 payload is what the DMA
+            # streamed, and a page's scale is constant over its slots and
+            # D, so it factors out of both reductions — [H, 1] against
+            # [H, page] / [H, D], heads on sublanes throughout
+            ks, vs = _page_scales(ks_ref, vs_ref, j)
+            s = s * ks
         # mask slots beyond the sequence length
         slot = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -186,6 +200,8 @@ def _decode_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         # weighted values: [H, D]
         pv = jnp.sum(p[:, :, None] * v, axis=1)
+        if quantized:
+            pv = pv * vs
         acc_ref[:] = acc_ref[:] * alpha + pv
         m_ref[:] = m_new
         l_ref[:] = l_new
@@ -277,9 +293,6 @@ def _decode_local(q, k_pages, v_pages, block_tables, lengths,
     def kv_map(b_, j_, lengths_s, tables_s):
         return (tables_s[b_, j_], 0, 0, 0)
 
-    def sc_map(b_, j_, lengths_s, tables_s):
-        return (tables_s[b_, j_], 0)
-
     kernel = functools.partial(
         _decode_kernel, scale=scale, page_size=page_size,
         max_pages=max_pages, quantized=quantized)
@@ -290,9 +303,9 @@ def _decode_local(q, k_pages, v_pages, block_tables, lengths,
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, h), sc_map),
-                     pl.BlockSpec((1, h), sc_map)]
-        operands += [k_scales, v_scales]
+        specs, ops = _scale_operands(k_scales, v_scales, block_tables)
+        in_specs += specs
+        operands += ops
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, max_pages),
@@ -308,7 +321,7 @@ def _decode_local(q, k_pages, v_pages, block_tables, lengths,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )
     return fn(lengths, block_tables, *operands)
@@ -351,34 +364,42 @@ def _verify_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
         q = q_ref[0].astype(jnp.float32)            # [W, H, D]
         k = k_ref[0].astype(jnp.float32)            # [H, page, D]
         v = v_ref[0].astype(jnp.float32)            # [H, page, D]
-        if quantized:
-            # same per-(page, head) dequant as the decode kernel — lane
-            # (b, w) stays bitwise a single-query quantized decode
-            k = k * ks_ref[0][:, None, None]
-            v = v * vs_ref[0][:, None, None]
         # scores over this page's slots, per lane: [W, H, page]
         s = jnp.sum(q[:, :, None, :] * k[None], axis=3) * scale
+        if quantized:
+            # same post-reduction per-(page, head) dequant as the decode
+            # kernel — lane (b, w) stays bitwise a single-query
+            # quantized decode
+            ks, vs = _page_scales(ks_ref, vs_ref, j)
+            s = s * ks[None]
         slot = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        lens = lengths_ref[b]                        # [W]
-        s = jnp.where(slot < lens[:, None, None], s, NEG_INF)
+        # lane lengths are SMEM scalars: one scalar load per lane,
+        # selected onto the lane's major index (SMEM has no vector loads)
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        lens = jnp.zeros(s.shape, jnp.int32)
+        for w in range(window):
+            lens = jnp.where(lane == w, lengths_ref[b, w], lens)
+        s = jnp.where(slot < lens, s, NEG_INF)
 
-        m_prev = m_ref[:][:, :, None]                # [W, H, 1]
-        l_prev = l_ref[:][:, :, None]
+        m_prev = m_ref[:]                            # [W, H, 1]
+        l_prev = l_ref[:]
         m_cur = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)                       # [W, H, page]
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
         pv = jnp.sum(p[:, :, :, None] * v[None], axis=2)   # [W, H, D]
-        acc_ref[:] = acc_ref[:] * alpha[:, :, 0][:, :, None] + pv
-        m_ref[:] = m_new[:, :, 0]
-        l_ref[:] = l_new[:, :, 0]
+        if quantized:
+            pv = pv * vs[None]
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = m_new
+        l_ref[:] = l_new
 
     @pl.when(j == max_pages - 1)
     def _():
-        l = jnp.maximum(l_ref[:], 1e-20)             # [W, H]
-        o_ref[0] = (acc_ref[:] / l[:, :, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:], 1e-20)             # [W, H, 1]
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def paged_attention_verify(q, k_pages, v_pages, block_tables, lengths,
@@ -446,9 +467,6 @@ def _verify_local(q, k_pages, v_pages, block_tables, lengths,
     def kv_map(b_, j_, lengths_s, tables_s):
         return (tables_s[b_, j_], 0, 0, 0)
 
-    def sc_map(b_, j_, lengths_s, tables_s):
-        return (tables_s[b_, j_], 0)
-
     kernel = functools.partial(
         _verify_kernel, scale=scale, page_size=page_size,
         max_pages=max_pages, window=w, quantized=quantized)
@@ -459,17 +477,19 @@ def _verify_local(q, k_pages, v_pages, block_tables, lengths,
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, h), sc_map),
-                     pl.BlockSpec((1, h), sc_map)]
-        operands += [k_scales, v_scales]
+        specs, ops = _scale_operands(k_scales, v_scales, block_tables)
+        in_specs += specs
+        operands += ops
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, max_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, w, h, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((w, h), jnp.float32),
-            pltpu.VMEM((w, h), jnp.float32),
+            # stats keep a unit lane dim so heads stay on sublanes, the
+            # orientation of the [W, H, page] scores they update
+            pltpu.VMEM((w, h, 1), jnp.float32),
+            pltpu.VMEM((w, h, 1), jnp.float32),
             pltpu.VMEM((w, h, d), jnp.float32),
         ],
     )
@@ -477,7 +497,7 @@ def _verify_local(q, k_pages, v_pages, block_tables, lengths,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w, h, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )
     return fn(lengths, block_tables, *operands)
@@ -570,13 +590,19 @@ def prefix_prefill_attention(q, k_pages, v_pages, block_tables, offsets,
     offsets      [B] int32      — tokens already cached per row
     → [B, S, H, D]
 
-    The window width (max_pages × page) is a per-core constant, so the
-    per-query softmax/contraction shape is identical for every prefill
-    bucket — that is what makes warm-path logits bitwise equal to the
-    cold path on CPU (slots past a query's position mask to exactly
-    zero weight, whatever garbage they hold).  A dense gather is fine
-    for prefill (it is compute-bound already); a ragged Pallas variant
-    is the TPU follow-up.
+    Every reduction here has a shape that is a per-core constant, whatever
+    the chunk length ``S``: the window width is ``max_pages × page``, and
+    the queries are walked in blocks of ``page`` positions, so both
+    contractions and the softmax see ``[page, window]`` tiles in every
+    prefill bucket.  That is what makes warm-path logits bitwise equal to
+    the cold path on CPU (slots past a query's position mask to exactly
+    zero weight, whatever garbage they hold).  The query blocking is part
+    of that guarantee, not a tuning choice: one ``[S, window]`` dot lets
+    XLA's CPU backend pick its strategy — and with it the accumulation
+    order over the window — by ``S``, and a 16-token warm suffix then
+    differs from the same positions of a 32-token cold chunk in the last
+    bits.  A dense gather is fine for prefill (it is compute-bound
+    already); a ragged Pallas variant is the TPU follow-up.
     """
     b, s, h, d = q.shape
     max_pages = block_tables.shape[1]
@@ -601,13 +627,25 @@ def prefix_prefill_attention(q, k_pages, v_pages, block_tables, offsets,
             .reshape(b, W, h, d).astype(jnp.float32)
         vw = v_pages[block_tables].transpose(0, 1, 3, 2, 4) \
             .reshape(b, W, h, d).astype(jnp.float32)
-    pos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]  # [b, s]
-    mask = jnp.arange(W, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
-    scores = jnp.einsum("bshd,bwhd->bhsw", q.astype(jnp.float32),
-                        kw) * scale
-    scores = jnp.where(mask[:, None], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhsw,bwhd->bshd", weights, vw)
+    slots = jnp.arange(W, dtype=jnp.int32)[None, None, :]
+
+    def attend(block):
+        qb, pos = block                      # [b, page, h, d], [b, page]
+        scores = jnp.einsum("bshd,bwhd->bhsw", qb.astype(jnp.float32),
+                            kw) * scale
+        scores = jnp.where((slots <= pos[:, :, None])[:, None], scores,
+                           NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhsw,bwhd->bshd", weights, vw)
+
+    n = -(-s // page)
+    pad = n * page - s
+    pos = offsets[:, None] + jnp.arange(n * page, dtype=jnp.int32)[None]
+    qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    out = jax.lax.map(attend, (
+        jnp.moveaxis(qp.reshape(b, n, page, h, d), 1, 0),
+        jnp.moveaxis(pos.reshape(b, n, page), 1, 0)))
+    out = jnp.moveaxis(out, 0, 1).reshape(b, n * page, h, d)[:, :s]
     return out.astype(q.dtype)
 
 

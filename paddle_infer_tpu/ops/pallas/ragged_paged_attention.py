@@ -44,8 +44,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (NEG_INF, _CompilerParams, _interpret,
-                              _quantized_scatter, is_quantized,
+from . import interpret as _interpret
+from .paged_attention import (NEG_INF, _page_scales, _quantized_scatter,
+                              _scale_operands, is_quantized,
                               paged_attention_decode,
                               paged_attention_verify,
                               prefix_prefill_attention)
@@ -154,37 +155,47 @@ def _ragged_kernel(ctx_ref, qlen_ref, tables_ref,    # scalar prefetch
     # ragged win: the DMA walk stops at the row's own length
     @pl.when(jnp.logical_and(qlen > 0, j * page_size < ctx + qlen))
     def _():
-        q = q_ref[0].astype(jnp.float32)             # [C, H, D]
+        # a chunk row has C queries per head, so both contractions are
+        # head-batched MXU matmuls; the broadcast-multiply form the
+        # single-query decode kernel uses would need a [C, H, page, D]
+        # intermediate (16 MB at C=64, H=32, D=128 — over scoped VMEM)
+        q = q_ref[0].astype(jnp.float32)             # [H, C, D]
         k = k_ref[0].astype(jnp.float32)             # [H, page, D]
         v = v_ref[0].astype(jnp.float32)             # [H, page, D]
+        # scores for every (head, query, slot): [H, C, page]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
         if quantized:
-            k = k * ks_ref[0][:, None, None]
-            v = v * vs_ref[0][:, None, None]
-        # scores for every (query, head, slot): [C, H, page]
-        s = jnp.sum(q[:, :, None, :] * k[None], axis=3) * scale
+            ks, vs = _page_scales(ks_ref, vs_ref, j)  # [H, 1]
+            s = s * ks[:, :, None]
         # absolute-position causal mask: slot w visible to query i when
         # w <= ctx + i (the same predicate the reference path uses)
         slot = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
-        qpos = ctx + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        qpos = ctx + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(slot <= qpos, s, NEG_INF)
 
-        m_prev = m_ref[:][:, :, None]                # [C, H, 1]
-        l_prev = l_ref[:][:, :, None]
+        m_prev = m_ref[:]                            # [H, C, 1]
+        l_prev = l_ref[:]
         m_cur = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                       # [C, H, page]
+        p = jnp.exp(s - m_new)                       # [H, C, page]
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
-        pv = jnp.sum(p[:, :, :, None] * v[None], axis=2)   # [C, H, D]
-        acc_ref[:] = acc_ref[:] * alpha[:, :, 0][:, :, None] + pv
-        m_ref[:] = m_new[:, :, 0]
-        l_ref[:] = l_new[:, :, 0]
+        pv = jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)      # [H, C, D]
+        if quantized:
+            pv = pv * vs[:, :, None]
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = m_new
+        l_ref[:] = l_new
 
     @pl.when(j == max_pages - 1)
     def _():
-        l = jnp.maximum(l_ref[:], 1e-20)             # [C, H]
-        o_ref[0] = (acc_ref[:] / l[:, :, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:], 1e-20)             # [H, C, 1]
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
 
 
 def _ragged_kernel_call(q, k_pages, v_pages, block_tables, context_lens,
@@ -209,41 +220,42 @@ def _ragged_kernel_call(q, k_pages, v_pages, block_tables, context_lens,
     def kv_map(b_, j_, ctx_s, qlen_s, tables_s):
         return (tables_s[b_, j_], 0, 0, 0)
 
-    def sc_map(b_, j_, ctx_s, qlen_s, tables_s):
-        return (tables_s[b_, j_], 0)
-
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size,
         max_pages=max_pages, quantized=quantized)
+    # head-major like the pool: the kernel's matmuls batch over heads,
+    # and the [B, C, H, D] <-> [B, H, C, D] swap is one XLA transpose on
+    # each side of the launch instead of a relayout per page inside it
     in_specs = [
-        pl.BlockSpec((1, c, h, d), q_map),
+        pl.BlockSpec((1, h, c, d), q_map),
         pl.BlockSpec((1, h, page_size, d), kv_map),
         pl.BlockSpec((1, h, page_size, d), kv_map),
     ]
-    operands = [q, k_pages, v_pages]
+    operands = [jnp.transpose(q, (0, 2, 1, 3)), k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, h), sc_map),
-                     pl.BlockSpec((1, h), sc_map)]
-        operands += [k_scales, v_scales]
+        specs, ops = _scale_operands(k_scales, v_scales, block_tables)
+        in_specs += specs
+        operands += ops
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, h, d), q_map),
+        out_specs=pl.BlockSpec((1, h, c, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((c, h), jnp.float32),
-            pltpu.VMEM((c, h), jnp.float32),
-            pltpu.VMEM((c, h, d), jnp.float32),
+            pltpu.VMEM((h, c, 1), jnp.float32),
+            pltpu.VMEM((h, c, 1), jnp.float32),
+            pltpu.VMEM((h, c, d), jnp.float32),
         ],
     )
     fn = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, c, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )
-    return fn(context_lens, query_lens, block_tables, *operands)
+    out = fn(context_lens, query_lens, block_tables, *operands)
+    return jnp.transpose(out, (0, 2, 1, 3))
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables,

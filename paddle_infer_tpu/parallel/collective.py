@@ -25,10 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:                       # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.tensor import Tensor
 from . import topology
@@ -245,13 +242,8 @@ def _build(mesh: Mesh, axis, kind: str, **kw):
     rep = P()
 
     def smap(fn, in_spec, out_spec):
-        try:
-            wrapped = shard_map(fn, mesh=mesh, in_specs=in_spec,
-                                out_specs=out_spec, check_vma=False)
-        except TypeError:
-            wrapped = shard_map(fn, mesh=mesh, in_specs=in_spec,
-                                out_specs=out_spec, check_rep=False)
-        return jax.jit(wrapped)
+        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_spec,
+                                 out_specs=out_spec, check_vma=False))
 
     if kind == "allreduce":
         op = kw["op"]

@@ -523,6 +523,12 @@ class FleetTrainStep:
         return self._compiled_executable(batch, static_kwargs) \
             .cost_analysis()
 
+    def compiled_text(self, *batch, **static_kwargs) -> str:
+        """The compiled step's module text — where a bring-up check
+        reads which kernels (``tpu_custom_call``) and collectives the
+        compiler actually put into the step."""
+        return self._compiled_executable(batch, static_kwargs).as_text()
+
     def memory_analysis(self, *batch, **static_kwargs):
         """XLA's compiled-executable memory breakdown (temp/argument/output
         bytes) — the compiler-reported peak-buffer backing for pipeline

@@ -67,18 +67,9 @@ def axis_if_divides(mesh, axis: str, dim: int) -> Optional[str]:
 
 
 def shard_map_norep(fn, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax versions
-    (check_vma in >=0.8, check_rep before)."""
-    try:
-        from jax import shard_map
-    except ImportError:                   # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    """shard_map without replication checking."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 class CommunicateTopology:

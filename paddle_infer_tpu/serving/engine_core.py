@@ -950,9 +950,16 @@ class EngineCore:
         ``wait_s`` for new submissions.  Thread-safe but serialized —
         tests drive it directly on an unstarted core."""
         with self._step_lock:
-            return self._run_once_locked(wait_s)
+            progressed = self._run_once_locked()
+        # idle wait OUTSIDE the step lock: a loop that sleeps holding it
+        # and re-takes it at once starves every other taker (metrics
+        # snapshots, admission probes) for as long as the core is idle —
+        # Python's locks are not fair
+        if not progressed and wait_s > 0:
+            self._queue.wait(wait_s)
+        return progressed
 
-    def _run_once_locked(self, wait_s: float) -> bool:
+    def _run_once_locked(self) -> bool:
         now = time.monotonic()
         progressed = False
 
@@ -1025,8 +1032,6 @@ class EngineCore:
             else:
                 self._decode_step()
             progressed = True
-        elif not progressed and wait_s > 0:
-            self._queue.wait(wait_s)
         return progressed
 
     # --------------------------------------------------------- admission

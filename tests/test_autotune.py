@@ -11,10 +11,13 @@ from paddle_infer_tpu.ops.pallas import autotune as at
 
 
 @pytest.fixture(autouse=True)
-def _reset():
+def _reset(tmp_path):
     at.clear()
     at._LOADED = True      # don't read ambient cache files
+    # winners persist to the checkout by default; tests write elsewhere
+    set_flags({"autotune_cache_file": str(tmp_path / "ambient.json")})
     yield
+    set_flags({"autotune_cache_file": ""})
     at.clear()
 
 
@@ -68,6 +71,26 @@ def test_invalid_candidate_skipped(monkeypatch):
     assert out == (256, 256)
 
 
+def test_failing_incumbent_propagates(monkeypatch):
+    """The default is what ships: if it cannot compile, the caller sees
+    that error instead of silently running an untested configuration."""
+    monkeypatch.setattr(at, "enabled", lambda: True)
+
+    def measure(c):
+        if c == (512, 512):
+            raise RuntimeError("Mosaic refused the default")
+        return 0.5
+
+    with pytest.raises(RuntimeError, match="refused the default"):
+        at.autotune("k", (512, 512), [(256, 256)], measure)
+
+
+def test_default_cache_file_is_fixed_in_checkout():
+    set_flags({"autotune_cache_file": ""})
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert at._cache_path() == os.path.join(root, ".autotune_cache.json")
+
+
 def test_persistence_roundtrip(tmp_path, monkeypatch):
     monkeypatch.setattr(at, "enabled", lambda: True)
     cache_file = str(tmp_path / "tune.json")
@@ -77,7 +100,9 @@ def test_persistence_roundtrip(tmp_path, monkeypatch):
                     lambda c: 0.1 if c == (256, 256) else 1.0)
         with open(cache_file) as f:
             disk = json.load(f)
-        assert disk["persist_k"] == [256, 256]
+        # keyed by chip generation, so a carried file cannot answer for
+        # another device
+        assert disk == {"cpu|persist_k": [256, 256]}
         # a fresh process state loads the winner without measuring
         at.clear()
         at._LOADED = False

@@ -1,0 +1,142 @@
+"""The main path's Pallas kernels, compiled by the TPU's compiler for a
+described (not attached) v5e at the widths ``chip_smoke.py`` runs them:
+``llama-7b`` serving (32 heads of 128, page 16) and ``ernie-3.0-base``
+training (12 heads of 64, batch 32, seq 512).  What Mosaic refuses here it
+refuses on the chip — a block that is not tile-aligned, a vector load from
+SMEM, more scoped VMEM than a kernel may have — and interpret mode shows
+none of it.  A compile that passes is not a chip run; ``chip_smoke.py``
+is.
+
+Everything that touches the topology lives in fixtures and tests of this
+one file: the TPU library loads in the xdist worker that runs it and in
+no other process, and nothing is decided at import time.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these tests silent and
+    the cache clean."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *specs):
+    """Lower + compile for the shardings' device; returns the module text."""
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+# llama-7b serving widths, as tools/serve.py's defaults lay them out
+H, D, PAGE = 32, 128, 16
+B, MAX_PAGES, POOL = 8, 128, 1024
+WINDOW, CHUNK = 4, 64
+
+
+def _pool(spec, quantized):
+    pages = spec((POOL, H, PAGE, D), jnp.int8 if quantized else jnp.bfloat16)
+    return (pages, spec((POOL, H), jnp.float32)) if quantized else pages
+
+
+@pytest.fixture
+def spec(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_decode_compiles(spec, quantized):
+    from paddle_infer_tpu.ops.pallas import paged_attention as PA
+
+    pool = _pool(spec, quantized)
+    _compile(functools.partial(PA._decode_local, interpret=False),
+             spec((B, H, D), jnp.bfloat16), pool, pool,
+             spec((B, MAX_PAGES), jnp.int32), spec((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_verify_compiles(spec, quantized):
+    from paddle_infer_tpu.ops.pallas import paged_attention as PA
+
+    pool = _pool(spec, quantized)
+    _compile(functools.partial(PA._verify_local, interpret=False),
+             spec((B, WINDOW, H, D), jnp.bfloat16), pool, pool,
+             spec((B, MAX_PAGES), jnp.int32),
+             spec((B, WINDOW), jnp.int32))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_ragged_kernel_compiles(spec, quantized):
+    from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
+
+    pool = _pool(spec, quantized)
+    _compile(functools.partial(RPA._ragged_kernel_call, interpret=False),
+             spec((B, CHUNK, H, D), jnp.bfloat16), pool, pool,
+             spec((B, MAX_PAGES), jnp.int32), spec((B,), jnp.int32),
+             spec((B,), jnp.int32))
+
+
+# (batch, seq, heads, head_dim, causal): ernie-3.0-base's training step,
+# and llama-7b heads at the length where the pure-Pallas kernel takes over
+ATTN_SHAPES = {"ernie-3.0-base": (32, 512, 12, 64, False),
+               "llama-7b-s4096": (1, 4096, 32, 128, True)}
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "hybrid_attention"])
+@pytest.mark.parametrize("widths", sorted(ATTN_SHAPES))
+def test_flash_kernels_compile_fwd_bwd(spec, kernel, widths):
+    """Forward and backward with segment ids and dropout, at the default
+    512x512 blocks (the autotuner's incumbent, which must compile)."""
+    from paddle_infer_tpu.ops.pallas import flash_attention as FA
+
+    b, s, h, d, causal = ATTN_SHAPES[widths]
+    fn = getattr(FA, kernel)
+
+    def step(q, k, v, seg, seed):
+        def loss(q_, k_, v_):
+            o = fn(q_, k_, v_, q_segment_ids=seg, kv_segment_ids=seg,
+                   dropout_p=0.1, dropout_seed=seed, is_causal=causal,
+                   block_q=512, block_k=512, interpret=False)
+            return jnp.sum(o.astype(jnp.float32))
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qkv = spec((b, s, h, d), jnp.bfloat16)
+    text = _compile(step, qkv, qkv, qkv, spec((b, s), jnp.int32),
+                    spec((), jnp.uint32))
+    # dK/dV and dQ are Mosaic kernels in both; flash adds the forward
+    assert text.count("tpu_custom_call") >= (3 if kernel ==
+                                             "flash_attention" else 2)

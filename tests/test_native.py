@@ -3,14 +3,71 @@ Python reference), paged-KV block pool (alloc/fork/CoW/OOM), mmap tensor
 store round trip (reference: framework/data_feed.cc, memory/allocation/,
 .pdiparams raw serialization)."""
 import os
+import shutil
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
 from paddle_infer_tpu import native
 
-pytestmark = pytest.mark.skipif(not native.available(),
-                                reason="native library not built")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_FIRST_USER = """
+import sys
+import paddle_infer_tpu.native as n
+n._NATIVE_DIR = sys.argv[1]
+p = n.KVBlockPool(8, 4)
+assert p.reserve(0, 9) == 3 and p.free_blocks == 5
+print(n.build_status())
+"""
+
+
+def test_concurrent_first_use_builds_once(tmp_path):
+    """Six processes meet a ``native/`` that holds sources only (a fresh
+    checkout).  Exactly one builds, the others wait on the lock and find
+    the finished library; nobody dlopens a half-written file."""
+    src = os.path.join(ROOT, "native")
+    ndir = tmp_path / "native"
+    ndir.mkdir()
+    for name in os.listdir(src):
+        if name.endswith((".cc", ".h")) or name == "Makefile":
+            shutil.copy(os.path.join(src, name), ndir / name)
+    env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu")
+    procs = [subprocess.Popen([sys.executable, "-c", _FIRST_USER, str(ndir)],
+                              env=env, stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    outs = [p.communicate(timeout=600) for p in procs]
+    for p, (out, err) in zip(procs, outs):
+        assert p.returncode == 0, err[-2000:]
+    status = sorted(out.strip() for out, _ in outs)
+    assert status == ["built"] + ["found"] * 5, status
+    left = sorted(os.listdir(ndir))
+    assert not [n for n in left if n.startswith(".build-")
+                or n.endswith(".o")], left
+
+
+def test_staleness_is_source_hash_not_mtime(tmp_path, monkeypatch):
+    """Shuffled mtimes (a fresh copy) do not rebuild; a changed source
+    does; a failed build reports make's stderr."""
+    src = os.path.join(ROOT, "native")
+    ndir = tmp_path / "native"
+    shutil.copytree(src, ndir, ignore=shutil.ignore_patterns(
+        "*.so", "*.o", "*.sha256", ".build*"))
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(ndir))
+    assert native.ensure_built()[1] == "built"
+    lib = ndir / "libpitnative.so"
+    os.utime(lib, (1, 1))                  # library "older" than sources
+    assert native.ensure_built()[1] == "found"
+    with open(ndir / "kv_allocator.cc", "a") as f:
+        f.write("\n// touched\n")
+    assert native.ensure_built()[1] == "built"
+    with open(ndir / "kv_allocator.cc", "a") as f:
+        f.write("\n#error broken on purpose\n")
+    with pytest.raises(RuntimeError, match="broken on purpose"):
+        native.ensure_built()
 
 
 @pytest.fixture

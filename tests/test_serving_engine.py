@@ -279,6 +279,25 @@ def test_trace_spans_cover_request_wall_time(make_core):
     assert tr.spans[0].attrs["outcome"] == "deadline-in-queue"
 
 
+def test_idle_scheduler_does_not_starve_readers(make_core):
+    """An idle core's loop waits for work OUTSIDE the step lock.  When it
+    slept holding it and re-took it at once, a /metrics snapshot (which
+    reads slot occupancy under the same lock) waited tens of seconds on
+    an idle server — Python's locks are not fair."""
+    import time
+
+    core = make_core().start()
+    (r,) = core.submit(_prompt(12), GenerationConfig(max_new_tokens=3))
+    r.result(timeout=120)
+    worst = 0.0
+    for _ in range(20):
+        t0 = time.monotonic()
+        snap = core.metrics_snapshot()
+        worst = max(worst, time.monotonic() - t0)
+    assert snap["counters"]["completed"] == 1
+    assert worst < 2.0, f"metrics_snapshot took {worst:.1f}s on an idle core"
+
+
 def test_decode_loop_compile_free_after_warmup(make_core, ref):
     """Acceptance: the fused decode loop performs ZERO XLA compilations
     after warmup.  Three batches with heterogeneous configs (greedy,
@@ -296,8 +315,15 @@ def test_decode_loop_compile_free_after_warmup(make_core, ref):
             core._token_budget if core._ragged else core._decode_chunk,
             core._max_pages, core._pool.num_blocks)
     assert log.is_warm("serving-decode", dkey)
+    # the compile log is process-global and other tests of this worker
+    # (recompile-detector tests among them) write to it: every check
+    # below is a DELTA over this test's own window, and "the warmup
+    # compile was seen" means this engine's own signature record — the
+    # executable may have been compiled by an earlier test that shares
+    # the module-scoped engine
+    assert core._engine._compiled_sigs.get(dkey)
     baseline = log.count("serving-decode")
-    assert baseline >= 1                 # the warmup compile was seen
+    post_warm0 = log.summary()["post_warmup_decode_compiles"]
 
     batches = [
         [GenerationConfig(max_new_tokens=6),
@@ -318,7 +344,7 @@ def test_decode_loop_compile_free_after_warmup(make_core, ref):
         assert all(r.state is RequestState.DONE for r in reqs)
     assert log.count("serving-decode") == baseline, \
         "heterogeneous configs recompiled the fused decode loop"
-    assert log.summary()["post_warmup_decode_compiles"] == 0
+    assert log.summary()["post_warmup_decode_compiles"] == post_warm0
     snap = core.metrics_snapshot()
     assert snap["counters"]["completed"] == 7
     # the StepLog flight recorder observed every step — including its

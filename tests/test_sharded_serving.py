@@ -448,3 +448,36 @@ class TestShardReportAndMetrics:
         assert 'collective_bytes_total{dtype="int8",op="mp_allreduce"}' \
             in text
         assert "collective_bytes_saved_total" in text
+
+
+def test_adopt_placement_frees_the_single_device_copies():
+    """After ``adopt_placement`` the model's own parameters ARE the placed
+    shards (nothing keeps the default device's whole copy alive), the
+    placement cache still hits, and the streams do not change."""
+    import jax
+
+    # a model of its own: adopting rebinds its parameters, and the
+    # module's shared model feeds single-device engines too
+    pit.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, intermediate_size=64,
+        max_position_embeddings=64, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0))
+    model.eval()
+    g = GenerationConfig(max_new_tokens=5)
+    ids = _prompt(3, 9)[None]
+    eng = build_sharded_engine(model, ServingMesh(mp=2), page_size=8)
+    before = np.asarray(eng.generate(ids, g))
+    assert all(len(p._data.sharding.device_set) == 1
+               for p in model.parameters())
+    eng.adopt_placement()
+    placed = eng._params
+    assert eng.refresh_params() is not None
+    assert all(eng._params[n] is placed[n] for n in placed)   # cache hit
+    sharded = [n for n, p in model.named_parameters()
+               if len(p._data.sharding.device_set) == 2]
+    assert len(sharded) == len(list(model.named_parameters()))
+    assert eng.shard_report()["param_devices"] == sorted(
+        d.id for d in jax.devices()[:2])
+    np.testing.assert_array_equal(np.asarray(eng.generate(ids, g)), before)

@@ -54,6 +54,15 @@ def _signature(obj) -> str:
         return "(...)"
 
 
+def _own(obj) -> bool:
+    """Defined by this package?  A namespace that imports ``NamedSharding``
+    or ``typing.Any`` for its own use re-exports it by accident; recording
+    a third-party symbol's signature makes the guard fail whenever THAT
+    library changes, which is not an API break of this one."""
+    mod = getattr(obj, "__module__", None) or ""
+    return mod.split(".")[0] == "paddle_infer_tpu"
+
+
 def collect() -> dict:
     import importlib
 
@@ -72,6 +81,8 @@ def collect() -> dict:
                 obj = getattr(mod, name)
             except AttributeError:
                 spec[f"{ns}.{name}"] = "MISSING (__all__ lists it)"
+                continue
+            if not _own(obj):
                 continue
             if inspect.isclass(obj):
                 spec[f"{ns}.{name}"] = "class" + _signature(obj)

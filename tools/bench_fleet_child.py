@@ -1,10 +1,11 @@
 #!/usr/bin/env python
 """Disaggregated-serving bench child: prefill/decode fleet vs one plane.
 
-Run by bench.py's ``disaggregated`` section in a subprocess (fresh
-backend + fresh process-global compile log — the section builds three
-engines and the parent bench process has already warmed its own).
-Prints ONE JSON line.
+Run by hand, in a process of its own (fresh backend + fresh
+process-global compile log — it builds three engines).  It needs the
+device for itself, so nothing that already holds the chip may start it:
+``bench.py`` is one process and starts no children.  Prints ONE JSON
+line.
 
 The workload is the ``mixed_traffic`` interference scenario: 8 clients
 stream short-prompt decodes while one 192-token prompt lands

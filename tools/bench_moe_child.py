@@ -1,11 +1,10 @@
 #!/usr/bin/env python
 """MoE-serving bench child: ep=2 over virtual CPU devices.
 
-Run by bench.py's ``moe_serving`` section in a subprocess with
+Run by hand with
 ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2``
-(the ``bench_sharded_child`` pattern), because the parent bench process
-has already initialized its backend with a single device.  Prints ONE
-JSON line:
+(the ``bench_sharded_child`` pattern) — never from a process that holds
+the chip: ``bench.py`` starts no children.  Prints ONE JSON line:
 
   - decode tokens/s dense vs MoE (same hidden dims) and MoE ep=1 vs
     ep=2 with bitwise stream parity;

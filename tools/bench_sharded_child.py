@@ -1,11 +1,11 @@
 #!/usr/bin/env python
 """Sharded-serving bench child: mp=2 over virtual CPU devices.
 
-Run by bench.py's ``sharded_serving`` section in a subprocess with
+Run by hand with
 ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2``
-(the same pattern ``__graft_entry__.dryrun_multichip`` uses), because
-the parent bench process has already initialized its backend with a
-single device.  Prints ONE JSON line:
+(the same pattern ``__graft_entry__.dryrun_multichip`` uses) — never
+from a process that holds the chip: ``bench.py`` starts no children.
+Prints ONE JSON line:
 
   - single-device vs mp=2 tokens/s and bitwise stream parity;
   - interconnect bytes per step with exact vs int8-quantized mp

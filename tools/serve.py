@@ -43,8 +43,14 @@ decode steps instead of serializing behind a lock.
                           model-vs-measured summary; ``?limit=N``
                           bounds the ring slice, ``?format=jsonl``
                           streams raw JSONL for offline analysis
-  GET  /health            -> {"status": "ok", "model": ...} (legacy
-                          process-liveness probe; always ok once up)
+  GET  /health            -> {"status": "ok", "model": ..., "runtime":
+                          {...}} (process-liveness probe; always ok
+                          once up).  ``runtime`` says what the process
+                          runs on and serves: device platform / kind /
+                          count as JAX reports them, parameter dtypes,
+                          whether the native library was built or
+                          found, the compile-cache directory, and
+                          bytes in use / peak per device
   GET  /healthz           -> engine health (supervisor state machine):
                           200 while HEALTHY/DEGRADED/DRAINING, 503 +
                           Retry-After when DOWN; includes crash streak
@@ -106,6 +112,31 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 _STATE = {"lock": threading.Lock()}
+
+
+def _runtime_info() -> dict:
+    """What this process runs on and what it serves — the facts a
+    bring-up check needs from the server's own point of view."""
+    import jax
+
+    from paddle_infer_tpu import native
+
+    devs = jax.devices()
+    memory = []
+    for d in devs:
+        stats = d.memory_stats() or {}
+        memory.append({"id": d.id,
+                       "bytes_in_use": stats.get("bytes_in_use"),
+                       "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return {
+        "device": {"platform": devs[0].platform,
+                   "kind": devs[0].device_kind, "count": len(devs)},
+        "param_dtypes": sorted({str(p.dtype) for p in
+                                _STATE["model"].parameters()}),
+        "native": native.build_status(),
+        "compile_cache_dir": _STATE["compile_cache_dir"],
+        "memory": memory,
+    }
 
 
 def _build_fleet(roles):
@@ -195,6 +226,9 @@ def _core():
             engine = build_sharded_engine(
                 _STATE["model"], smesh, page_size=_STATE["page_size"],
                 kv_dtype=_STATE.get("kv_dtype"))
+            # this process serves the model through this engine: drop
+            # the single-device copies the shards were placed from
+            engine.adopt_placement()
             plane = None
             script = _STATE.get("fault_script")
             if script:
@@ -509,7 +543,8 @@ class Handler(BaseHTTPRequestHandler):
         url = urlparse(self.path)
         if url.path == "/health":
             self._json(200, {"status": "ok",
-                             "model": type(_STATE["model"]).__name__})
+                             "model": type(_STATE["model"]).__name__,
+                             "runtime": _runtime_info()})
         elif url.path == "/healthz":
             # liveness: wired to the supervisor's state machine — 503
             # only when the engine is DOWN (crash-looping).  Does not
@@ -1013,6 +1048,11 @@ def main(argv=None):
                          "least-predicted-load dispatch")
     args = ap.parse_args(argv)
 
+    from paddle_infer_tpu.utils.compile_cache import \
+        configure_compile_cache
+
+    _STATE["compile_cache_dir"] = configure_compile_cache()
+
     from paddle_infer_tpu.models import AutoModel
     from paddle_infer_tpu.serving import (ServingMesh, ShardedConfigError,
                                           parse_fleet_roles,
@@ -1255,7 +1295,8 @@ def main(argv=None):
     _STATE["fault_seed"] = args.fault_seed
     server = ThreadingHTTPServer(("127.0.0.1", args.port), Handler)
     print(f"serving {type(_STATE['model']).__name__} on "
-          f"127.0.0.1:{args.port}", flush=True)
+          f"127.0.0.1:{args.port} runtime={json.dumps(_runtime_info())}",
+          flush=True)
     server.serve_forever()
 
 
