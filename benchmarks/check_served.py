@@ -1,0 +1,74 @@
+"""``correct`` for a served model: the widest gap by which a served
+(greedy) token's logit lies below the plain reference's best, over a
+seeded sample of the requests the run served, the longest among them.
+
+The reference runs once over each sampled prompt with its served tokens
+(teacher-forced: under greedy decoding the served stream is its own
+input).  The control reads, at the same positions, the gap of the token a
+lower precision puts first.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from . import weights
+from .reference import mistral
+from .rng import SplitMix
+
+
+def sample(records, seed: int, n: int, max_tokens: int = 256):
+    """The longest served request and n - 1 others drawn from the seed;
+    each as (sequence fed, rows whose next token was served, served)."""
+    served = [r for r in records if r.tokens]
+    if not served:
+        return []
+    served.sort(key=lambda r: (r.prompt_len + len(r.tokens), r.index))
+    picks = [served.pop()]
+    order = SplitMix(seed, 77).permutation(len(served))
+    picks += [served[i] for i in order[:max(0, n - 1)]]
+    out = []
+    for r in picks:
+        toks = r.tokens[:max_tokens]
+        seq = np.concatenate([np.asarray(r.prompt, np.int32),
+                              np.asarray(toks[:-1], np.int32)])
+        rows = np.arange(r.prompt_len - 1, len(seq))
+        out.append((seq, rows, np.asarray(toks, np.int32)))
+    return out
+
+
+def gaps(config: dict, seed: int, cases, precision: str = "float32"):
+    """Per served token: reference's best logit minus the served token's.
+    With a lower ``precision`` the "served" token is replaced by the one
+    that precision puts first (the control)."""
+    dtype = config["torch_dtype"]
+    outer = weights.llama_outer_weights(config, seed, dtype)
+    layer = lambda i: weights.llama_layer_weights(config, seed, i, dtype)
+    out = []
+    width = int(config["check"]["max_tokens_per_request"])
+    for seq, rows, served in cases:
+        # one shape of head for every request: rows padded by repetition
+        n = len(rows)
+        padded = np.concatenate([rows, np.full((width - n,), rows[-1])])
+        ref = np.asarray(mistral.logits_at(config, layer, outer, seq,
+                                           padded))[:n]
+        if precision != "float32":
+            low = np.asarray(mistral.logits_at(config, layer, outer, seq,
+                                               padded, precision))[:n]
+            served = low.argmax(-1)
+        out.append(ref.max(-1) - ref[np.arange(n), served])
+    return np.concatenate(out) if out else np.zeros((0,))
+
+
+def check(config: dict, seed: int, records, say=print) -> bool:
+    spec = config["check"]
+    cases = sample(records, seed, int(spec["sample_requests"]),
+                   int(spec["max_tokens_per_request"]))
+    g = gaps(config, seed, cases)
+    if g.size == 0:
+        say("check: no served token to compare -> not correct")
+        return False
+    widest, limit = float(g.max()), float(spec["limit_logit_gap"])
+    say(f"check: served tokens compared {g.size} over {len(cases)} "
+        f"requests; exact argmax {int((g == 0).sum())}; "
+        f"widest_logit_gap {widest:.6f} (limit {limit})")
+    return bool(np.isfinite(g).all() and widest <= limit)

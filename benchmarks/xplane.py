@@ -1,0 +1,229 @@
+"""From the profiler's trace to numbers: which intervals the device was
+busy, which operations took the time, and what the host was doing in the
+gaps.  The pure functions work on (name, start_s, duration_s) tuples, so
+they are checked on a small recorded trace without a profiler."""
+from __future__ import annotations
+
+import glob
+import os
+import re
+import shutil
+import threading
+import time
+from typing import Dict, Iterable, List, Sequence, Tuple
+
+from . import spans
+
+Interval = Tuple[float, float]
+# operations that only hold others: their bodies are events of their own
+CONTAINERS = ("while", "conditional", "call")
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+# ------------------------------------------------------------ pure parts
+
+def union(intervals: Iterable[Interval]) -> List[Interval]:
+    out: List[Interval] = []
+    for a, b in sorted(intervals):
+        if b <= a:
+            continue
+        if out and a <= out[-1][1]:
+            if b > out[-1][1]:
+                out[-1] = (out[-1][0], b)
+        else:
+            out.append((a, b))
+    return out
+
+
+def total(intervals: Sequence[Interval]) -> float:
+    return sum(b - a for a, b in intervals)
+
+
+def intersect(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:
+    """Intersection of two sorted disjoint interval lists."""
+    out, i, j = [], 0, 0
+    while i < len(xs) and j < len(ys):
+        a, b = max(xs[i][0], ys[j][0]), min(xs[i][1], ys[j][1])
+        if a < b:
+            out.append((a, b))
+        if xs[i][1] < ys[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def complement(xs: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
+    out, at = [], lo
+    for a, b in xs:
+        if a > at:
+            out.append((at, min(a, hi)))
+        at = max(at, b)
+        if at >= hi:
+            break
+    if at < hi:
+        out.append((at, hi))
+    return [(a, b) for a, b in out if b > a]
+
+
+_NAME = re.compile(r"^%?([\w\-.]+?)(?:\.\d+)? = (.*)$", re.S)
+_SHAPE = re.compile(r"\w+\[[\d,]*\]")
+_OPCODE = re.compile(r"(?<![\w\-])([a-z][a-z\-]*)\(")
+
+
+def op_key(name: str) -> str:
+    """Instances of one operation under one name.  The TPU's trace names
+    an event by its whole HLO instruction; kept are the opcode, the
+    instruction's name without its number, and the first output shape:
+    ``fusion fusion bf16[16,64,14336]``.  A bare ``fusion.123`` loses its
+    number."""
+    m = _NAME.match(name)
+    if not m:
+        return re.sub(r"[.:]\d+$", "", name)[:96]
+    base, rest = m.groups()
+    opcode, shape = _OPCODE.search(rest), _SHAPE.search(rest)
+    parts = (opcode.group(1) if opcode else None, base,
+             shape.group(0) if shape else None)
+    return " ".join(x for x in parts if x)[:96]
+
+
+def reduce_events(device_ops: Dict[str, List[tuple]],
+                  host_spans: List[tuple], window_s: float) -> dict:
+    """``device_ops``: per device, (name, start_s, duration_s) of each
+    operation; ``host_spans``: the benchmark's own spans, same clock.
+    Returns busy seconds (mean over devices), the idle share of the
+    window, per-operation and collective seconds, and the idle gaps by
+    what the host was doing."""
+    if not device_ops:
+        return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
+    busy, ops, coll = [], {}, 0.0
+    gaps_by: Dict[str, float] = {}
+    labelled = {}
+    for label in set(spans.GAP_LABELS.values()):
+        labelled[label] = union(
+            (s, s + d) for n, s, d in host_spans
+            if spans.GAP_LABELS.get(n) == label)
+    # innermost first: a dispatch lies inside a run_once
+    order = ["in_dispatch", "scheduler_host"]
+    for dev, events in device_ops.items():
+        iv = union((s, s + d) for _, s, d in events)
+        busy.append(total(iv))
+        for n, s, d in events:
+            key = op_key(n)
+            if key.split(" ", 1)[0] not in CONTAINERS:
+                ops[key] = ops.get(key, 0.0) + d
+            if any(c in n for c in COLLECTIVES):
+                coll += d
+        if not iv:
+            continue
+        rest = complement(iv, iv[0][0], iv[-1][1])
+        for label in order:
+            inside = intersect(rest, labelled.get(label, []))
+            if inside:
+                gaps_by[label] = gaps_by.get(label, 0.0) + total(inside)
+                rest = _subtract(rest, inside)
+        if rest:
+            gaps_by[spans.OUTSIDE] = gaps_by.get(spans.OUTSIDE, 0.0) \
+                + total(rest)
+    n = len(device_ops)
+    # the window on the trace's own clock, first start to last end of the
+    # device's operations: the host's stop call returns a little early, and
+    # its clock is not the trace's
+    marks = [(s, s + d) for ev in device_ops.values() for _, s, d in ev]
+    if marks:
+        window_s = max(b for _, b in marks) - min(a for a, _ in marks)
+    busy_s = sum(busy) / n
+    top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
+    return {
+        "window_s": window_s, "busy_s": busy_s, "devices": n,
+        "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
+        "collective_s": coll / n,
+        "device_ops": [[k, v / n] for k, v in top],
+        "idle_gaps": [[k, v / n] for k, v in
+                      sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
+
+
+def _subtract(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:
+    out = []
+    for a, b in xs:
+        out.extend(complement([y for y in ys if y[1] > a and y[0] < b], a, b))
+    return out
+
+
+# --------------------------------------------------------- the profiler
+
+def load(trace_dir: str, device_prefix: str = "/device:TPU",
+         op_line: str = "XLA Ops"):
+    """(device_ops, host_spans) from the newest ``.xplane.pb`` under
+    ``trace_dir``; times in seconds on the trace's own clock."""
+    import jax
+
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    device_ops, host_spans = {}, []
+    for plane in data.planes:
+        is_device = plane.name.startswith(device_prefix)
+        for line in plane.lines:
+            if is_device and line.name.startswith(op_line):
+                device_ops.setdefault(plane.name, []).extend(
+                    (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                    for e in line.events if e.name not in spans.GAP_LABELS)
+            if plane.name.startswith("/host:"):
+                host_spans.extend(
+                    (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+                    for e in line.events if e.name in spans.GAP_LABELS)
+    return device_ops, host_spans
+
+
+class TraceWindow:
+    """Trace ``span_s`` seconds from ``start()``; ``finish()`` waits for
+    the stop and returns the reduction."""
+
+    def __init__(self, trace_dir: str, span_s: float):
+        self.dir, self.span_s = trace_dir, float(span_s)
+        self._timer = None
+        self._t0 = self._t1 = None
+        self._err = None
+
+    def start(self):
+        import jax
+
+        shutil.rmtree(self.dir, ignore_errors=True)
+        kw = {}
+        try:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0       # our spans only
+            kw["profiler_options"] = opts
+        except AttributeError:
+            pass
+        jax.profiler.start_trace(self.dir, **kw)
+        self._t0 = time.monotonic()
+        self._timer = threading.Timer(self.span_s, self._stop)
+        self._timer.daemon = True
+        self._timer.start()
+
+    def _stop(self):
+        import jax
+
+        self._t1 = time.monotonic()
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:          # reported by finish()
+            self._err = e
+
+    def finish(self) -> dict:
+        self._timer.join(120.0)
+        if self._err is not None:
+            raise self._err
+        if self._t1 is None:
+            raise RuntimeError("the trace was never stopped")
+        device_ops, host_spans = load(self.dir)
+        out = reduce_events(device_ops, host_spans, self._t1 - self._t0)
+        out["t0"], out["t1"] = self._t0, self._t1      # monotonic clock
+        shutil.rmtree(self.dir, ignore_errors=True)
+        return out

@@ -1,0 +1,171 @@
+"""The harness from just after its look for a chip to its result, on the
+CPU at a tiny size: sound runs come out correct, and a timed path broken
+underneath comes out not correct."""
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import run
+from benchmarks.systems import ernie_train, llama_serving
+
+from conftest import ROOT, load_data
+
+PRETRAIN = json.load(open(os.path.join(ROOT, "benchmarks", "traffic",
+                                       "pretrain.json")))
+
+
+def _ctx(config, traffic, cell, seed, seconds, tmp_path, trace=0):
+    return run.Context(config, traffic, cell, 1, seed, seconds, trace,
+                       jax.devices()[:1], time.monotonic(),
+                       say=lambda s: print(s),
+                       trace_dir=str(tmp_path / "trace"))
+
+
+@pytest.fixture(scope="module")
+def chat_result(tmp_path_factory):
+    ctx = _ctx(load_data("tiny-llama.json"), load_data("tiny-chat.json"),
+               {"rate_rps": 4.0}, 2 ** 31 + 77, 2.0,
+               tmp_path_factory.mktemp("chat"))
+    return run.run_cell(ctx)
+
+
+def test_open_loop_cell_runs_and_is_correct(chat_result):
+    res = chat_result
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["attempted"] == 8            # floor(4.0 x 2.0): the whole deck
+    ev = res["evidence"]
+    assert ev.compiles_in_window == 0
+    assert ev.setup_s > 0 and ev.steps and ev.queue_waits
+
+
+def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
+    ev = chat_result["evidence"]
+    e2e = run.read_metrics(benchmark_json["end_to_end"], "e2e_metrics", ev,
+                           "mistral-d12.chat")
+    assert set(e2e) == {"itl_p95_ms", "setup_s"}
+    assert all(v["value"] > 0 for v in e2e.values())
+    layer = run.read_metrics(benchmark_json["per_layer"], "layer_metrics",
+                             ev, "mistral-d12.chat")
+    # not traced: the readers of the device trace found nothing to read
+    assert "device_idle_share.chat" not in layer
+    assert "step_roofline_share.chat" not in layer
+    assert layer["compiles_in_window.chat"]["value"] == 0
+    assert 0 < layer["padded_slot_share.chat"]["value"] < 100
+    # a loaded test machine runs late; the chip run reads 1.6 ms
+    assert 0 <= layer["gen_lateness_p99_ms"]["value"] < 2000
+    assert {"ttft_p50_ms", "ttft_mean_ms", "ttft_p90_ms", "itl_mean_ms",
+            "itl_p99_ms"} <= set(layer)
+
+
+def test_result_line_has_the_contract_keys(chat_result, benchmark_json):
+    line = run.result_line(dict(chat_result), benchmark_json,
+                           "mistral-d12.chat", 0, "cpu", 1)
+    assert set(line) == {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    assert set(line["metrics"]) == {"itl_p95_ms", "setup_s"}
+    assert {"platform", "kind", "count", "memory_peak_bytes",
+            "allocator_peak_bytes", "program_temp_bytes"} == set(
+                line["device"])
+    # the serving engine offers no memory analysis of its step yet
+    assert line["device"]["program_temp_bytes"] is None
+    json.dumps(line)
+
+
+class _AlteredStream:
+    """A request whose tokens are altered where they are produced."""
+
+    def __init__(self, req, vocab):
+        self._req, self._vocab = req, vocab
+
+    def stream(self, timeout=None):
+        for chunk in self._req.stream(timeout=timeout):
+            yield (np.asarray(chunk) + 1) % self._vocab
+
+    def result(self, timeout=None):
+        return self._req.result(timeout)
+
+
+class _BrokenServing(llama_serving.System):
+    def submit(self, ids, max_new):
+        return _AlteredStream(super().submit(ids, max_new),
+                              int(self.config["vocab_size"]))
+
+
+def test_altered_tokens_come_out_not_correct(tmp_path):
+    ctx = _ctx(load_data("tiny-llama.json"), load_data("tiny-chat.json"),
+               {"rate_rps": 4.0}, 9, 1.5, tmp_path)
+    broken = type("M", (), {"System": _BrokenServing})
+    res = run.run_cell(ctx, system_mod=broken)
+    assert res["failed"] == 0 and res["correct"] is False
+
+
+def test_training_cell_runs_and_is_correct(tmp_path, benchmark_json):
+    ctx = _ctx(load_data("tiny-ernie.json"), PRETRAIN, {}, 2 ** 31 + 3, 1.0,
+               tmp_path)
+    res = run.run_cell(ctx)
+    assert res["correct"] is True and res["attempted"] >= 3
+    ev = res["evidence"]
+    assert ev.compiles_in_window == 0
+    e2e = run.read_metrics(benchmark_json["end_to_end"], "e2e_metrics", ev,
+                           "ernie-base.pretrain")
+    assert set(e2e) == {"train_tokens_per_s", "setup_s"}
+    line = run.result_line(res, benchmark_json, "ernie-base.pretrain", 0,
+                           "cpu", 1)
+    # two sources, two fields: the step's temporaries are the program's own
+    # memory analysis, the allocator's peak is the backend's (none on a CPU)
+    assert line["device"]["program_temp_bytes"] > 0
+    assert line["program"]["flags"] == {"use_autotune": True,
+                                        "autotune_cache_file": ""}
+    assert line["program"]["autotune_winners"] == {}
+    json.dumps(line)
+
+
+class _FrozenTraining(ernie_train.System):
+    """A step that returns its state unchanged."""
+
+    def call(self, batch):
+        keep = jax.tree_util.tree_map(jnp.copy, (self.step.params,
+                                                 self.step.opt_state))
+        loss = super().call(batch)
+        self.step.params, _ = keep
+        return loss
+
+
+def test_a_step_that_keeps_its_state_comes_out_not_correct(tmp_path):
+    ctx = _ctx(load_data("tiny-ernie.json"), PRETRAIN, {}, 4, 0.5, tmp_path)
+    frozen = type("M", (), {"System": _FrozenTraining,
+                            "make_batches": staticmethod(
+                                ernie_train.make_batches)})
+    res = run.run_cell(ctx, system_mod=frozen)
+    assert res["correct"] is False
+
+
+def test_four_chip_cell_places_on_four_of_the_eight_devices():
+    """A ``chips: 4`` cell is data: ``deployment.mp`` 4 shards the seeded
+    weights as they are made (never whole on device 0) and serves."""
+    config = load_data("tiny-llama.json")
+    config["deployment"] = dict(config["deployment"], mp=4, chips=4)
+    devices = jax.devices()
+    assert len(devices) >= 8
+    system = llama_serving.System(config, devices[:4], 3, False)
+    system.build()
+    try:
+        used = set()
+        for name, p in system.engine._model.named_parameters():
+            arr = p._data
+            used |= {d.id for d in arr.sharding.device_set}
+            if name.endswith("qkv_proj.weight"):
+                assert len(arr.sharding.device_set) == 4
+                assert arr.addressable_shards[0].data.shape[1] \
+                    == arr.shape[1] // 4
+        assert used == {d.id for d in devices[:4]}
+        toks = system.submit(np.arange(3, 40, dtype=np.int32), 5).result(
+            timeout=300)
+        assert len(toks) == 5
+    finally:
+        system.free()
