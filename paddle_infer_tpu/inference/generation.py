@@ -130,6 +130,20 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
+def _memory_bytes(compiled):
+    """Temp / argument / output bytes of a compiled executable, or None
+    where the backend's ``memory_analysis()`` has nothing to say."""
+    try:
+        m = compiled.memory_analysis()
+    except Exception:
+        return None
+    if m is None:
+        return None
+    return {"temp": int(m.temp_size_in_bytes),
+            "argument": int(m.argument_size_in_bytes),
+            "output": int(m.output_size_in_bytes)}
+
+
 def _count_collectives(hlo_text: str) -> dict:
     """{collective: count} over a compiled module's text (async pairs
     count once, at their -start)."""
@@ -724,6 +738,9 @@ class PagedGenerationEngine(GenerationEngine):
         # (observability.steplog's analytic bytes/FLOPs source)
         self._program_shapes = {}
         self._program_costs = {}
+        # per-program-key memory_analysis() of the same compiled object
+        # (temp / argument / output bytes; see program_memory)
+        self._program_memory = {}
         # per-program-key {collective op: count} read off the compiled
         # text alongside the cost (shard_report's step_collectives)
         self._program_collectives = {}
@@ -886,6 +903,7 @@ class PagedGenerationEngine(GenerationEngine):
                 analysis = compiled.cost_analysis()
             self._program_collectives[key] = _count_collectives(
                 compiled.as_text())
+            self._program_memory[key] = _memory_bytes(compiled)
             if isinstance(analysis, (list, tuple)):
                 analysis = analysis[0] if analysis else {}
             if analysis:
@@ -898,6 +916,14 @@ class PagedGenerationEngine(GenerationEngine):
             cost = None
         self._program_costs[key] = cost
         return cost
+
+    def program_memory(self, key):
+        """``{"temp", "argument", "output"}`` bytes of one serving
+        program, from the ``memory_analysis()`` of the compiled object
+        ``program_cost(key)`` built: a dict look-up, no compile of its
+        own.  None until ``program_cost`` has run for the key, or where
+        the backend offers no analysis."""
+        return self._program_memory.get(key)
 
     def kv_state_lost(self) -> bool:
         """True when the device pools were consumed by a failed donated

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import numpy as np
 
 from ..core.dispatch import dispatch as D
@@ -164,7 +165,8 @@ class ErnieModel(Layer):
             # a dense additive mask would force the O(s^2) XLA path
             segment_ids = D("cast", attention_mask, dtype="int32")
             attention_mask = None
-        x = self.embeddings(input_ids, token_type_ids, position_ids)
+        with jax.named_scope("embed"):
+            x = self.embeddings(input_ids, token_type_ids, position_ids)
         for layer in self.layers:
             x = layer(x, attn_mask=attention_mask, segment_ids=segment_ids)
         pooled = self.pooler(x)
@@ -190,11 +192,13 @@ class ErnieMLMHead(Layer):
         self.decoder_bias.dist_attr = ("mp",)
 
     def forward(self, hidden_states):
-        x = self.layer_norm(self.activation(self.transform(hidden_states)))
-        logits = D("matmul", x, self._tied_weight, transpose_y=True)
-        logits = logits + self.decoder_bias
-        spec = ("data",) + (None,) * (logits.ndim - 2) + ("mp",)
-        return D("sharding_constraint", logits, spec=spec)
+        with jax.named_scope("mlm_head_loss"):
+            x = self.layer_norm(
+                self.activation(self.transform(hidden_states)))
+            logits = D("matmul", x, self._tied_weight, transpose_y=True)
+            logits = logits + self.decoder_bias
+            spec = ("data",) + (None,) * (logits.ndim - 2) + ("mp",)
+            return D("sharding_constraint", logits, spec=spec)
 
 
 class ErnieForMaskedLM(PretrainedMixin, Layer):
@@ -261,7 +265,9 @@ def ernie_pretrain_loss(mlm_logits, nsp_logits, mlm_labels, nsp_labels,
     """Summed MLM + NSP loss with label masking (mean over valid tokens)."""
     from .losses import masked_lm_loss
 
-    mlm_loss = masked_lm_loss(mlm_logits, mlm_labels,
-                              ignore_index=ignore_index)
-    nsp_loss = F.cross_entropy(nsp_logits, nsp_labels, reduction="mean")
-    return mlm_loss + nsp_loss
+    with jax.named_scope("mlm_head_loss"):
+        mlm_loss = masked_lm_loss(mlm_logits, mlm_labels,
+                                  ignore_index=ignore_index)
+        nsp_loss = F.cross_entropy(nsp_logits, nsp_labels,
+                                   reduction="mean")
+        return mlm_loss + nsp_loss

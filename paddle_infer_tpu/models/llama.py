@@ -16,6 +16,8 @@ and paged-KV Pallas decode) and under a serving mesh.
 """
 from __future__ import annotations
 
+import jax
+
 from ..core.dispatch import dispatch as D
 from ..nn import functional as F
 from ..nn.layer import Layer
@@ -115,7 +117,8 @@ class LlamaDecoderLayer(Layer):
         if cache is not None:
             h, new_cache = h
         x = x + h
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        with jax.named_scope("ffn"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         if cache is not None:
             return x, new_cache
         return x
@@ -191,10 +194,11 @@ class LlamaForCausalLM(PretrainedMixin, Layer):
                 caches=None):
         out = self.llama(input_ids, position_ids=position_ids,
                          attention_mask=attention_mask, caches=caches)
-        if caches is not None:
-            x, new_caches = out
-            return self.lm_head(x), new_caches
-        return self.lm_head(out)
+        with jax.named_scope("lm_head_sample"):
+            if caches is not None:
+                x, new_caches = out
+                return self.lm_head(x), new_caches
+            return self.lm_head(out)
 
 
 def llama_lm_loss(logits, labels, ignore_index=-100):

@@ -13,6 +13,8 @@ reference's fused op.
 """
 from __future__ import annotations
 
+import jax
+
 from ..core.dispatch import dispatch as D
 from ..nn import functional as F
 from ..nn.layer import Layer
@@ -87,21 +89,24 @@ class ParallelSelfAttention(Layer):
     def forward(self, x, attn_mask=None, cache=None, segment_ids=None,
                 position_ids=None):
         b, s = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        q, k, v = self._split_qkv(qkv, b, s)
-        if self.rope_theta:
-            if position_ids is None:
-                position_ids = self._rope_positions(cache, s)
-            q = D("rope", q, position_ids, theta=self.rope_theta)
-            k = D("rope", k, position_ids, theta=self.rope_theta)
-        if self.num_kv_heads != self.num_heads:
-            # GQA: expand K/V to the query heads post-RoPE so every
-            # downstream path (caches incl. paged pools, sdpa, kernels)
-            # sees plain MHA.  Cache-side narrow-kv storage is a possible
-            # follow-up optimisation.
-            rep = self.num_heads // self.num_kv_heads
-            k = D("repeat_interleave", k, repeats=rep, axis=2)
-            v = D("repeat_interleave", v, repeats=rep, axis=2)
+        # jax.named_scope: metadata on the operations (a trace names the
+        # part after a refactor), no operation changes
+        with jax.named_scope("qkv_proj"):
+            qkv = self.qkv_proj(x)
+            q, k, v = self._split_qkv(qkv, b, s)
+            if self.rope_theta:
+                if position_ids is None:
+                    position_ids = self._rope_positions(cache, s)
+                q = D("rope", q, position_ids, theta=self.rope_theta)
+                k = D("rope", k, position_ids, theta=self.rope_theta)
+            if self.num_kv_heads != self.num_heads:
+                # GQA: expand K/V to the query heads post-RoPE so every
+                # downstream path (caches incl. paged pools, sdpa,
+                # kernels) sees plain MHA.  Cache-side narrow-kv storage
+                # is a possible follow-up optimisation.
+                rep = self.num_heads // self.num_kv_heads
+                k = D("repeat_interleave", k, repeats=rep, axis=2)
+                v = D("repeat_interleave", v, repeats=rep, axis=2)
         if cache is not None and len(cache) >= 4:
             return self._forward_paged(x, q, k, v, cache, attn_mask)
         static_cache = cache is not None and len(cache) == 3
@@ -150,8 +155,9 @@ class ParallelSelfAttention(Layer):
                 dropout_p=self.dropout if self.training else 0.0,
                 is_causal=self.causal,
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids)
-        out = D("reshape", out, shape=(b, s, self.hidden))
-        out = self.out_proj(out)
+        with jax.named_scope("attn_out"):
+            out = D("reshape", out, shape=(b, s, self.hidden))
+            out = self.out_proj(out)
         if static_cache:
             return out, (k, v, index + s)
         if cache is not None:
@@ -215,17 +221,21 @@ class ParallelSelfAttention(Layer):
             qlens = cache[4]._data
             scratch = cache[5]._data
             verify = cache[6]._data if len(cache) == 7 else None
-            k_pages = RPA.write_ragged_pages(k_pages, tables, k._data,
-                                             positions, qlens, scratch)
-            v_pages = RPA.write_ragged_pages(v_pages, tables, v._data,
-                                             positions, qlens, scratch)
+            with jax.named_scope("kv_write"):
+                k_pages = RPA.write_ragged_pages(k_pages, tables, k._data,
+                                                 positions, qlens, scratch)
+                v_pages = RPA.write_ragged_pages(v_pages, tables, v._data,
+                                                 positions, qlens, scratch)
+            # scoped "paged_attention" inside (it keeps its Pallas calls'
+            # instruction names out of the scope, see there)
             out = Tensor(RPA.ragged_paged_attention(
                 q._data, k_pages, v_pages, tables, positions, qlens,
                 verify_rows=None if verify is None else verify[:, 0],
                 verify_window=None if verify is None
                 else verify.shape[1]))
-            out = D("reshape", out, shape=(b, s, self.hidden))
-            out = self.out_proj(out)
+            with jax.named_scope("attn_out"):
+                out = D("reshape", out, shape=(b, s, self.hidden))
+                out = self.out_proj(out)
             new = (wrap(k_pages), wrap(v_pages), Tensor(tables),
                    Tensor(positions + qlens), cache[4], cache[5])
             return out, (new + (cache[6],) if len(cache) == 7 else new)
@@ -264,8 +274,9 @@ class ParallelSelfAttention(Layer):
                                           tables, positions + 1)
             out = Tensor(o[:, None])         # [b, 1, h, d]
             new_pos = positions + 1
-        out = D("reshape", out, shape=(b, s, self.hidden))
-        out = self.out_proj(out)
+        with jax.named_scope("attn_out"):
+            out = D("reshape", out, shape=(b, s, self.hidden))
+            out = self.out_proj(out)
         return out, (wrap(k_pages), wrap(v_pages), Tensor(tables),
                      Tensor(new_pos))
 
@@ -337,7 +348,8 @@ class ParallelTransformerLayer(Layer):
         residual = x
         if self.normalize_before:
             x = self.norm2(x)
-        x = residual + self.dropout2(self.mlp(x))
+        with jax.named_scope("ffn"):
+            x = residual + self.dropout2(self.mlp(x))
         if not self.normalize_before:
             x = self.norm2(x)
         if cache is not None:

@@ -58,7 +58,35 @@ _SCHEMA = (
     ("kernel", ""),              # ragged | legacy (step-serving records)
     ("wall_s", 0.0),             # whole step event, edge to edge
     ("dispatch_s", 0.0),         # device dispatch + readback sync
-    ("host_s", 0.0),             # wall_s - dispatch_s (host bookkeeping)
+                                 # (== launch_s + wait_s)
+    ("host_s", 0.0),             # wall_s - dispatch_s (host bookkeeping:
+                                 # emission, eviction, cost model, record)
+    # the scheduler iteration's phases, taken once by
+    # observability.stepclock.StepClock (seconds, time.monotonic()) and
+    # mirrored as engine.* spans into the profiler's trace.  For a
+    # serving step: t_begin + admit_s + pack_s + launch_s + wait_s +
+    # host_s is the step's end, and the next step's gap_s reaches its
+    # t_begin.  Page-copy / evict / park / resume records keep 0.
+    ("t_begin", 0.0),            # monotonic time the iteration that ran
+                                 # this step entered _run_once_locked
+    ("step", 0),                 # the engine's step index: the step_num
+                                 # its engine.step profiler span carries
+    ("gap_s", 0.0),              # previous serving step's end -> t_begin
+                                 # (loop turn, step lock, supervisor)
+    ("admit_s", 0.0),            # t_begin -> the step method entered
+                                 # (expiry, exclusive, admission, _admit)
+    ("pack_s", 0.0),             # rows to arrays, planner, drafts, masks
+    ("launch_s", 0.0),           # signature, host-to-device puts, enqueue
+    ("wait_s", 0.0),             # blocked in the read-back: the device's
+                                 # run and the device-to-host copy
+    ("attended_keys", 0),        # query-key pairs the step's attention
+                                 # must compute (sum qlen*ctx + tri(qlen))
+    ("resident_tokens", 0),      # cached tokens the step reads (sum over
+                                 # rows with qlen > 0 of ctx + qlen)
+    ("h2d_bytes", 0),            # bytes of the host arrays handed to the
+                                 # step program this step
+    ("program_temp_bytes", 0),   # the compiled step's temporaries
+                                 # (memory_analysis; 0 where not offered)
     ("active_rows", 0),          # occupied slots at capture
     ("decode_rows", 0),          # rows in this fused decode chunk
     ("prefill_tokens", 0),       # uncached suffix tokens prefetched

@@ -30,6 +30,7 @@ shardings encode the parallelism:
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
@@ -41,6 +42,7 @@ from ..core import random as prandom
 from ..core.tensor import Tensor
 from ..core import dispatch as dispatch_mod
 from ..nn.layer import Layer
+from ..observability.compilelog import get_compile_log, signature_of
 from . import topology
 from .topology import HybridCommunicateGroup
 
@@ -427,8 +429,9 @@ class FleetTrainStep:
                 (loss, buffers), grads = jax.value_and_grad(
                     pure_loss, has_aux=True)(params, buffers, key, batch)
             grads = grad_constraint(grads)
-            new_params, new_state = opt.functional_update(
-                params, grads, opt_state, lr=lr, step=step)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt.functional_update(
+                    params, grads, opt_state, lr=lr, step=step)
             # keep parameter layout stable across steps
             new_params = {
                 n: jax.lax.with_sharding_constraint(
@@ -479,15 +482,28 @@ class FleetTrainStep:
             arrays = self._globalize_batch(arrays)
         sig = batch_signature(arrays, static_kwargs)
         fn = self._cache.get(sig)
-        if fn is None:
+        first_call = fn is None
+        if first_call:
             fn = self._build(arrays, static_kwargs)
             self._cache[sig] = fn
         self._step_count += 1
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         key = prandom.next_key()
-        self.params, self.opt_state, self.buffers, loss = fn(
-            self.params, self.opt_state, self.buffers, key, lr,
-            jnp.asarray(self._step_count, jnp.int32), arrays)
+        # the compiled call as one host span of the profiler's trace,
+        # carrying the step number (a no-op without a profiler session)
+        t0 = time.perf_counter() if first_call else 0.0
+        with jax.profiler.StepTraceAnnotation("fleet.train_step",
+                                              step_num=self._step_count):
+            self.params, self.opt_state, self.buffers, loss = fn(
+                self.params, self.opt_state, self.buffers, key, lr,
+                jnp.asarray(self._step_count, jnp.int32), arrays)
+        if first_call:
+            # a new batch signature's first call traces and compiles:
+            # one CompileLog event, as to_static and the serving
+            # programs leave one
+            get_compile_log().record(
+                "fleet-train-step", sig, signature_of(arrays),
+                time.perf_counter() - t0)
         lr_scheduler_tick(self.optimizer)
         return Tensor(loss)
 

@@ -57,6 +57,7 @@ from ..inference.generation import (GenerationConfig, PagedGenerationEngine,
                                     _round_up)
 from ..observability import Tracer, get_compile_log
 from ..observability.journey import JourneyStore
+from ..observability.stepclock import StepClock
 from ..observability.steplog import StepCostModel, StepLog
 from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
@@ -78,6 +79,12 @@ _log = logging.getLogger(__name__)
 _TRACE_STATE = {RequestState.DONE: "done", RequestState.FAILED: "failed",
                 RequestState.CANCELLED: "cancelled",
                 RequestState.REJECTED: "rejected"}
+
+
+def _host_bytes(args) -> int:
+    """Bytes of the host arrays in ``args`` (a step program's inputs:
+    what the launch puts on the device)."""
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(args))
 
 
 class EngineCore:
@@ -450,8 +457,14 @@ class EngineCore:
         # degradation ladder: memory pressure shrinks the batch the
         # scheduler will actually fill; recovery grows it back
         self._effective_max_batch = self._max_batch
-        self.step_trace: List[dict] = []
+        # per-step active sets (tests prove late admission joins the same
+        # step with it): bounded like the StepLog ring it sits beside
+        self.step_trace: deque = deque(maxlen=self.steplog.capacity)
         self._step_idx = 0
+        # the running iteration's clock (observability/stepclock.py) and
+        # the previous serving step's end, for the next record's gap_s
+        self._clock: Optional[StepClock] = None
+        self._last_step_end: Optional[float] = None
         # chunk-boundary notification (fleet handoff): called with the
         # Request, by the stepping thread under the step lock, the step
         # its prompt finishes prefilling.  Must be fast and reentrant-
@@ -960,7 +973,38 @@ class EngineCore:
         return progressed
 
     def _run_once_locked(self) -> bool:
-        now = time.monotonic()
+        # one clock for the iteration: each phase boundary is read once,
+        # written into the step's StepLog record and mirrored as an
+        # engine.* span into the profiler's trace (no-op without one)
+        clock = self._clock = StepClock("engine", self._step_idx + 1,
+                                        "admit")
+        try:
+            return self._iteration(clock.t_begin)
+        finally:
+            clock.close()
+
+    def _phase_fields(self, clock: StepClock, end: float) -> dict:
+        """The iteration's phases as fields of this step's one StepLog
+        record: ``t_begin`` and the durations tile the iteration up to
+        ``end``, ``gap_s`` reaches back to the previous step's end.  A
+        step that failed in its launch never waited: it records what it
+        reached."""
+        d = clock.durations(end)
+        last, self._last_step_end = self._last_step_end, end
+        return dict(
+            t_begin=clock.t_begin, step=clock.step_num,
+            gap_s=clock.t_begin - last if last is not None else 0.0,
+            admit_s=d.get("admit", 0.0), pack_s=d.get("pack", 0.0),
+            launch_s=d.get("launch", 0.0), wait_s=d.get("wait", 0.0),
+            host_s=d.get("emit", 0.0))
+
+    def _program_temp_bytes(self, key) -> int:
+        """The compiled step's temporaries, from the memory analysis the
+        engine keeps beside the cost analysis (a dict look-up per step)."""
+        mem = self._engine.program_memory(key)
+        return int(mem["temp"]) if mem else 0
+
+    def _iteration(self, now: float) -> bool:
         progressed = False
 
         for r in self._queue.remove_expired(now):
@@ -1628,6 +1672,8 @@ class EngineCore:
         warmup compile every mix of cold chunks, warm-prefix suffixes
         and decode rows reuses it (CompileLog proves it in the
         composition fuzz)."""
+        clock = self._clock
+        clock.phase("pack")
         active = [s for s in self._slots if s is not None]
         b = self._max_batch
         C = self._token_budget
@@ -1810,18 +1856,35 @@ class EngineCore:
         # rows carrying a non-identity adapter this step: each one adds
         # the 2*r*(d_in+d_out) LoRA factor walk the cost model prices
         adapter_rows_step = int(np.count_nonzero(aslots[qlens > 0]))
+        # what the step's attention has to do, from the packer's own
+        # arrays: row i's qlens[i] queries sit at ctx[i].. and each
+        # attends to everything before it and itself
+        ql = qlens.astype(np.int64)
+        cx = np.where(ql > 0, ctx, 0).astype(np.int64)
+        attended_keys_step = int((ql * cx + ql * (ql + 1) // 2).sum())
+        resident_tokens_step = int((cx + ql).sum())
+        h2d_bytes_step = 0
         clog = get_compile_log()
         c0 = clog.count()
-        t0 = time.monotonic()
+        t0 = clock.phase("launch")
         n_emit = None
         try:
             fault = self._fault.fire(
                 "decode.step", rids=[s["req"].rid for s in active])
             moe_out = ()
-            # the optional mask input sits between keys and scratch —
+            # the host arrays handed to the step program, in call order.
+            # The optional mask input sits between keys and scratch —
             # absent entirely on non-grammar deployments, so their
             # executable signatures are byte-identical to before
-            gextra = (gmask,) if grammar_on else ()
+            step_args = (
+                (ids, qlens, ctx, steps0, sample_now, aslots)
+                + ((spec,) if W > 1 else ())
+                + (tables, self._samp_arrays(cfgs), keys)
+                + ((gmask,) if grammar_on else ())
+                # scratch page id is a host int, no device sync
+                # tpulint: disable-next-line=host-sync -- speculative scratch readback at the verification boundary; verification is a host decision
+                + (np.asarray(self._scratch, np.int32),))
+            h2d_bytes_step = _host_bytes(step_args)
             if W > 1:
                 res = eng.run_paged_program(
                     mkey, lambda: build_mixed_step(eng, b, C,
@@ -1830,11 +1893,7 @@ class EngineCore:
                                                    moe_stats=moe
                                                    is not None,
                                                    grammar=grammar_on),
-                    ids, qlens, ctx, steps0, sample_now, aslots, spec,
-                    tables, self._samp_arrays(cfgs), keys, *gextra,
-                    # scratch page id is a host int, no device sync
-                    # tpulint: disable-next-line=host-sync -- speculative scratch readback at the verification boundary; verification is a host decision
-                    np.asarray(self._scratch, np.int32))
+                    *step_args)
                 if moe is not None:
                     tok, n_emit, fin_out, *moe_out = res
                 else:
@@ -1846,11 +1905,7 @@ class EngineCore:
                                                    moe_stats=moe
                                                    is not None,
                                                    grammar=grammar_on),
-                    ids, qlens, ctx, steps0, sample_now, aslots, tables,
-                    self._samp_arrays(cfgs), keys, *gextra,
-                    # scratch page id is a host int, no device sync
-                    # tpulint: disable-next-line=host-sync -- speculative scratch readback at the verification boundary; verification is a host decision
-                    np.asarray(self._scratch, np.int32))
+                    *step_args)
                 if moe is not None:
                     tok, fin_out, *moe_out = res
                 else:
@@ -1860,10 +1915,16 @@ class EngineCore:
             # same contract as the legacy chunk: only a pre-dispatch
             # injection provably leaves the donated pools intact
             injected = isinstance(e, (InjectedFault, InjectedMemoryError))
+            t_fail = clock.phase("emit")    # failed in the launch: no wait
+            end = time.monotonic()
             self.steplog.record(
                 "mixed" if chunk_taken and n_decode else
                 ("prefill" if chunk_taken else "decode"),
-                wall_s=time.monotonic() - t0,
+                wall_s=end - t0, dispatch_s=t_fail - t0,
+                attended_keys=attended_keys_step,
+                resident_tokens=resident_tokens_step,
+                h2d_bytes=h2d_bytes_step,
+                **self._phase_fields(clock, end),
                 active_rows=len(active), decode_rows=n_decode,
                 chunk_steps=1, prefill_tokens=prefill_tokens_step,
                 prefill_chunk_tokens=prefill_tokens_step,
@@ -1886,7 +1947,9 @@ class EngineCore:
                     if s is not None:
                         self._replay_or_fail_slot(s, e, kv_intact=True)
             return
-        wall = time.monotonic() - t0
+        # the launch has returned: from here the host only waits for the
+        # device (JAX dispatch is asynchronous) until the read-back below
+        clock.phase("wait")
         if not self._decode_warm:
             # one executable for EVERY composition: after this, any
             # compile on the serving-decode site is a recompile
@@ -1915,7 +1978,11 @@ class EngineCore:
                           moe_aux_loss=m_aux)
             self._metrics.on_moe([int(x) for x in m_routed],
                                  m_dropped, m_aux)
-        t_sync = time.monotonic()
+        t_sync = clock.phase("emit")
+        # the step as the device ran it, launch to read-back: what the
+        # server's step-time and ITL histograms report (the launch alone
+        # returns in milliseconds, before the device has finished)
+        synced = t_sync - t0
         resident = self._used_pages()
         prefix_hits = sum(len(s["match"].blocks)
                           if s.get("match") is not None else 0
@@ -2029,8 +2096,8 @@ class EngineCore:
                 self._evict(s, RequestState.DONE)
                 evicted.append(req.rid)
         if emitted_decode:
-            self._metrics.on_tokens(emitted_decode, itl_s=wall)
-        self._metrics.on_step(wall * 1e3, len(active), b)
+            self._metrics.on_tokens(emitted_decode, itl_s=synced)
+        self._metrics.on_step(synced * 1e3, len(active), b)
         self.step_trace.append({
             "step": self._step_idx, "batch_steps": 1,
             "active": [s["req"].rid for s in active],
@@ -2053,7 +2120,12 @@ class EngineCore:
         end = time.monotonic()
         self.steplog.record(
             kind, wall_s=end - t0, dispatch_s=t_sync - t0,
-            host_s=end - t_sync, active_rows=len(active),
+            **self._phase_fields(clock, end),
+            attended_keys=attended_keys_step,
+            resident_tokens=resident_tokens_step,
+            h2d_bytes=h2d_bytes_step,
+            program_temp_bytes=self._program_temp_bytes(mkey),
+            active_rows=len(active),
             decode_rows=n_decode, chunk_steps=1,
             prefill_tokens=prefill_tokens_step,
             prefill_chunk_tokens=prefill_tokens_step,
@@ -2102,6 +2174,8 @@ class EngineCore:
 
     # ------------------------------------------------------------ decode
     def _decode_step(self):
+        clock = self._clock
+        clock.phase("pack")
         active = [s for s in self._slots if s is not None]
         # ALWAYS run the full chunk: a variable tail size would compile a
         # fresh program for every distinct min-remaining-budget value
@@ -2132,16 +2206,26 @@ class EngineCore:
             cfgs[i] = s["g"]
         eng = self._engine
         dkey = ("serve-step", b, S, self._max_pages, self._pool.num_blocks)
+        # each of the chunk's S steps feeds one token per live row, which
+        # attends to the row's cache so far and itself
+        live = np.logical_not(fin)
+        cx = np.where(live, pos0, 0).astype(np.int64)
+        n_live = int(live.sum())
+        attended_keys_step = int(S * cx.sum()) + n_live * S * (S + 1) // 2
+        resident_tokens_step = int(cx.sum()) + n_live * S
+        h2d_bytes_step = 0
         clog = get_compile_log()
         c0 = clog.count()
-        t0 = time.monotonic()
+        t0 = clock.phase("launch")
         try:
             fault = self._fault.fire(
                 "decode.step", rids=[s["req"].rid for s in active])
+            step_args = (tok, fin, pos0, steps0, tables,
+                         self._samp_arrays(cfgs), keys)
+            h2d_bytes_step = _host_bytes(step_args)
             toks, fin_out, nvalid = eng.run_paged_program(
                 dkey, lambda: build_decode(eng, b, S, self._max_pages),
-                tok, fin, pos0, steps0, tables,
-                self._samp_arrays(cfgs), keys)
+                *step_args)
         except Exception as e:
             self._metrics.on_failed(0)
             # only a fault-plane injection raised BEFORE dispatch leaves
@@ -2150,8 +2234,15 @@ class EngineCore:
             # every row's KV and every retained cache page — are then
             # garbage), so KV-intact replay is reserved for injections
             injected = isinstance(e, (InjectedFault, InjectedMemoryError))
+            t_fail = clock.phase("emit")    # failed in the launch: no wait
+            end = time.monotonic()
             self.steplog.record(
-                "decode", wall_s=time.monotonic() - t0, kernel="legacy",
+                "decode", wall_s=end - t0, dispatch_s=t_fail - t0,
+                kernel="legacy",
+                attended_keys=attended_keys_step,
+                resident_tokens=resident_tokens_step,
+                h2d_bytes=h2d_bytes_step,
+                **self._phase_fields(clock, end),
                 active_rows=len(active), decode_rows=len(active),
                 chunk_steps=S, resident_kv_pages=self._used_pages(),
                 compile_events=clog.count() - c0, faults=injected,
@@ -2172,7 +2263,7 @@ class EngineCore:
                     if s is not None:
                         self._replay_or_fail_slot(s, e, kv_intact=True)
             return
-        wall = time.monotonic() - t0
+        clock.phase("wait")
         if not self._decode_warm:
             # first fused chunk on this core's decode key: everything
             # after this is steady state — any further compile on the
@@ -2187,7 +2278,8 @@ class EngineCore:
         fin_out = np.asarray(fin_out)
         # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
         nvalid = np.asarray(nvalid)
-        t_sync = time.monotonic()
+        t_sync = clock.phase("emit")
+        synced = t_sync - t0        # launch to read-back (see _mixed_step)
         # capture the step's page view BEFORE evictions free anything —
         # this is what the dispatched chunk actually ran against
         resident = self._used_pages()
@@ -2243,8 +2335,8 @@ class EngineCore:
                 self._evict(s, RequestState.DONE)
                 evicted.append(s["req"].rid)
         if emitted_total:
-            self._metrics.on_tokens(emitted_total, itl_s=wall / S)
-        self._metrics.on_step(wall * 1e3, len(active), b)
+            self._metrics.on_tokens(emitted_total, itl_s=synced / S)
+        self._metrics.on_step(synced * 1e3, len(active), b)
         self.step_trace.append({
             "step": self._step_idx, "batch_steps": S,
             "active": [s["req"].rid for s in active],
@@ -2256,7 +2348,12 @@ class EngineCore:
         end = time.monotonic()
         self.steplog.record(
             "decode", wall_s=end - t0, dispatch_s=t_sync - t0,
-            host_s=end - t_sync, active_rows=len(active),
+            **self._phase_fields(clock, end),
+            attended_keys=attended_keys_step,
+            resident_tokens=resident_tokens_step,
+            h2d_bytes=h2d_bytes_step,
+            program_temp_bytes=self._program_temp_bytes(dkey),
+            active_rows=len(active),
             kernel="legacy", decode_rows=len(active), chunk_steps=S,
             emitted_tokens=emitted_total, resident_kv_pages=resident,
             prefix_hit_pages=prefix_hits, bytes_est=bts, flops_est=fl,

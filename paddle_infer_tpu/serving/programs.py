@@ -226,16 +226,19 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         pos2d = jnp.where(i2d < qlens[:, None], ctx[:, None] + i2d, 0)
         logits, caches, moe_out = _model_step_with_stats(
             params, ids, pos2d, caches, qlens, i2d, adapter_slots)
-        last = jnp.take_along_axis(
-            logits, jnp.maximum(qlens - 1, 0)[:, None, None], axis=1)[:, 0]
-        if grammar:
-            last = last + gmask
-        proc = _process_rows(last, samp, steps0)
-        tok = _pick_rows(proc, samp, steps0, keys)
-        tok = jnp.where(sample_now, tok, samp["pad"])
-        fin = jnp.logical_and(
-            sample_now,
-            jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"]))
+        # one scope with the head (models/llama.py): the sampling tail
+        with jax.named_scope("lm_head_sample"):
+            last = jnp.take_along_axis(
+                logits, jnp.maximum(qlens - 1, 0)[:, None, None],
+                axis=1)[:, 0]
+            if grammar:
+                last = last + gmask
+            proc = _process_rows(last, samp, steps0)
+            tok = _pick_rows(proc, samp, steps0, keys)
+            tok = jnp.where(sample_now, tok, samp["pad"])
+            fin = jnp.logical_and(
+                sample_now,
+                jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"]))
         return (tok, fin, *moe_out,
                 [c[0] for c in caches], [c[1] for c in caches])
 
