@@ -188,8 +188,8 @@ class ParallelSelfAttention(Layer):
         variant (serving/programs.build_mixed_step): every row carries
         its own ``(query_len, context_len)``, decode rows have
         ``query_len == 1`` and chunk rows a prompt slice, all in one
-        launch — positions past a row's ``query_len`` write to the
-        scratch page and are never attended.
+        launch — positions past a row's ``query_len`` are written
+        nowhere and never attended.
 
         A SEVEN-element cache appends ``verify [b, W] bool`` (per-row
         speculative-verify flag broadcast over the draft window — the

@@ -98,11 +98,16 @@ def kv_dequant_error_bound(fp_pages, scales) -> float:
     return float(np.max(sc / 2.0 + clip)) if fp.size else 0.0
 
 
-def _quantized_scatter(pages, page_idx, slot, kv):
-    """Shared int8 token scatter: slot-0 landings re-seed their page's
+def _quantized_scatter(pages, page_idx, slot, kv, block_tables, starts,
+                       counts):
+    """Shared int8 token write: slot-0 landings re-seed their page's
     scale from the landing token, everything quantizes with the updated
-    scales and writes the payload.  ``page_idx``/``slot`` are [B] or
-    [B, S] int32 and ``kv`` carries matching leading dims + [H, D].
+    scales, and the payload goes into the pool through
+    ``_write_token_spans`` (row ``b``'s first ``counts[b]`` tokens at
+    positions ``starts[b] + i``).  ``page_idx``/``slot`` are the
+    per-token [B, S] int32 landing sites the scale protocol keys on
+    (pads included, wherever the caller parks them) and ``kv`` is
+    [B, S, H, D].
 
     The scale update is a masked-max scatter, NOT ``.set``: pad rows may
     alias a live physical page (table filler points at page 0 / the
@@ -121,7 +126,8 @@ def _quantized_scatter(pages, page_idx, slot, kv):
     sc = scales[page_idx]                                # [..., H]
     q = jnp.clip(jnp.round(kvf / sc[..., None]), -_QMAX, _QMAX) \
         .astype(jnp.int8)
-    return payload.at[page_idx, :, slot].set(q), scales
+    return _write_token_spans(payload, block_tables, q, starts,
+                              counts), scales
 
 
 # ------------------------------------------------------------------ kernel
@@ -504,8 +510,52 @@ def _verify_local(q, k_pages, v_pages, block_tables, lengths,
 
 
 # --------------------------------------------------------- page utilities
-# Pure-XLA writes: scatters into the pool compile to dynamic-update fusions;
-# the per-token bookkeeping (which page/slot) is the native allocator's job.
+# Pure-XLA writes.  The pool is head-major [P, H, page, D], so only a
+# scatter whose one index dimension is the leading (page) dimension, with
+# whole pages as its windows, updates the donated pool in place.  A
+# per-token ``pages.at[page_idx, :, slot].set(kv)`` has the head slice
+# between its two index dimensions: the TPU compiler copies the whole pool
+# to a token-major layout, scatters, and copies it back (two pool-sized
+# copies per call, a quarter of the chat step's device time in the ledger's
+# PR 24 line).  Every token writer below therefore goes through
+# ``_write_token_spans``; tests/test_chip_compile.py holds it to that.  The
+# per-token bookkeeping (which page/slot) is the native allocator's job.
+
+def _write_token_spans(pool, block_tables, vals, starts, counts):
+    """Store row ``b``'s tokens ``vals[b, :counts[b]]`` at absolute
+    positions ``starts[b] + i`` of its table window, page by page.
+
+    pool [P, H, page, D] (an fp pool or an int8 payload), vals
+    [B, C, H, D] in the pool's dtype, starts / counts [B] int32.  A
+    row's span lies in at most ``n`` consecutive logical pages (``n``
+    from the static ``C``); those are gathered whole, their touched
+    slots replaced from ``vals``, and put back with a leading-dimension
+    scatter.  Pages a row does not touch (``counts == 0``, the tail of
+    a short span, anything past the table) carry the out-of-range index
+    ``P``, which the scatter drops: nothing but the rows' own pages is
+    written, so physical indices never collide (a shared prefix page is
+    copied before its row writes)."""
+    num_pages, h, page, d = pool.shape
+    b, c = vals.shape[:2]
+    max_pages = block_tables.shape[1]
+    n = (c + page - 2) // page + 1
+    t = starts[:, None] // page + jnp.arange(n, dtype=jnp.int32)[None]
+    touched = ((t * page < (starts + counts)[:, None])
+               & (counts[:, None] > 0) & (t < max_pages))      # [B, n]
+    own = jnp.take_along_axis(block_tables,
+                              jnp.minimum(t, max_pages - 1), axis=1)
+    old = pool.at[own].get(mode="clip")
+    # chunk index of every slot of the gathered pages: [B, n, page]
+    rel = t[:, :, None] * page + jnp.arange(page, dtype=jnp.int32) \
+        - starts[:, None, None]
+    fresh = jnp.take_along_axis(
+        vals, jnp.clip(rel, 0, c - 1).reshape(b, n * page, 1, 1), axis=1)
+    fresh = fresh.reshape(b, n, page, h, d).transpose(0, 1, 3, 2, 4)
+    written = (rel >= 0) & (rel < counts[:, None, None])
+    merged = jnp.where(written[:, :, None, :, None], fresh, old)
+    return pool.at[jnp.where(touched, own, num_pages).reshape(-1)].set(
+        merged.reshape(b * n, h, page, d), mode="drop")
+
 
 def write_prompt_pages(pages, block_tables, kv):
     """Scatter prompt K or V [B, S, H, D] into the head-major pool
@@ -522,7 +572,9 @@ def write_prompt_pages(pages, block_tables, kv):
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                (b, s))
         page_idx = jnp.take_along_axis(block_tables, pos // page, axis=1)
-        return _quantized_scatter(pages, page_idx, pos % page, kv)
+        return _quantized_scatter(pages, page_idx, pos % page, kv,
+                                  block_tables, jnp.zeros((b,), jnp.int32),
+                                  jnp.full((b,), s, jnp.int32))
     page = pages.shape[2]
     assert s % page == 0, (s, page)
     n = s // page
@@ -557,23 +609,23 @@ def gather_prompt_pages(pages, block_tables, s):
 
 
 def write_chunk_pages(pages, block_tables, kv, offsets):
-    """Scatter a chunk's K or V [B, S, H, D] into the pool at absolute
+    """Write a chunk's K or V [B, S, H, D] into the pool at absolute
     positions ``offsets[b] + i`` — the offset-aware generalisation of
     ``write_prompt_pages`` for suffix prefill over a cached prefix.
     Unlike the aligned writer, the chunk may start mid-page (the
-    copy-on-write tail block), so each token scatters to its own
-    (page, slot).  The caller guarantees ``offsets + S`` stays inside
-    the table window."""
-    b, s, h, d = kv.shape
-    page = pages[0].shape[2] if is_quantized(pages) else pages.shape[2]
-    pos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-    page_idx = jnp.take_along_axis(block_tables, pos // page, axis=1)
-    slot = pos % page
+    copy-on-write tail block), so the pages it touches are read,
+    merged and written back whole (``_write_token_spans``).  The caller
+    guarantees ``offsets + S`` stays inside the table window."""
+    b, s = kv.shape[:2]
+    counts = jnp.full((b,), s, jnp.int32)
     if is_quantized(pages):
-        return _quantized_scatter(pages, page_idx, slot, kv)
-    # advanced indices (page_idx, slot) around the head slice: result
-    # dims [B, S, H, D] match kv
-    return pages.at[page_idx, :, slot].set(kv.astype(pages.dtype))
+        page = pages[0].shape[2]
+        pos = offsets[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        page_idx = jnp.take_along_axis(block_tables, pos // page, axis=1)
+        return _quantized_scatter(pages, page_idx, pos % page, kv,
+                                  block_tables, offsets, counts)
+    return _write_token_spans(pages, block_tables, kv.astype(pages.dtype),
+                              offsets, counts)
 
 
 def prefix_prefill_attention(q, k_pages, v_pages, block_tables, offsets,
@@ -651,17 +703,10 @@ def prefix_prefill_attention(q, k_pages, v_pages, block_tables, offsets,
 
 def write_token_page(pages, block_tables, kv, positions):
     """Write one new token's K or V [B, H, D] at its (page, slot):
-    positions [B] is the 0-based token index in each sequence."""
-    page_size = pages[0].shape[2] if is_quantized(pages) else \
-        pages.shape[2]
-    page_idx = jnp.take_along_axis(
-        block_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-    slot = positions % page_size
-    if is_quantized(pages):
-        return _quantized_scatter(pages, page_idx, slot, kv)
-    # advanced indices (page_idx, slot) around the head slice: result dims
-    # [B, H, D] match kv
-    return pages.at[page_idx, :, slot].set(kv.astype(pages.dtype))
+    positions [B] is the 0-based token index in each sequence.  A chunk
+    of one token: its page is read, one slot replaced, and written
+    back."""
+    return write_chunk_pages(pages, block_tables, kv[:, None], positions)
 
 
 class PagedKVCache:

@@ -30,9 +30,10 @@ Two implementations share the public entry point:
   it is an online-softmax reassociation of the reference (allclose, not
   bitwise), so serving keeps it opt-in until TPU parity runs pin it.
 
-``write_ragged_pages`` is the matching scatter: valid positions
+``write_ragged_pages`` is the matching writer: valid positions
 (``i < query_len``) land at the row's absolute slots, everything else
-is routed to the scratch page no live row ever reads.
+is written nowhere (on an int8 pool the pads re-seed only the scale of
+the scratch page, which no live row ever reads).
 """
 from __future__ import annotations
 
@@ -46,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
 from .paged_attention import (NEG_INF, _page_scales, _quantized_scatter,
-                              _scale_operands, is_quantized,
+                              _scale_operands, _write_token_spans,
+                              is_quantized,
                               paged_attention_decode,
                               paged_attention_verify,
                               prefix_prefill_attention)
@@ -54,16 +56,22 @@ from .paged_attention import (NEG_INF, _page_scales, _quantized_scatter,
 
 def write_ragged_pages(pages, block_tables, kv, context_lens, query_lens,
                        scratch_page):
-    """Scatter a ragged batch's K or V ``[B, C, H, D]`` into the
-    head-major pool.  Row ``b``'s token ``i`` lands at absolute position
-    ``context_lens[b] + i`` when ``i < query_lens[b]``; pad positions
-    (``i >= query_lens[b]``, including whole inactive rows) are routed
-    to ``scratch_page`` — garbage the attention mask never exposes, so
-    rows near the window edge can never clamp into their own live
-    pages.  The caller guarantees ``context_lens + query_lens`` stays
-    inside each row's reserved table window."""
-    b, c, h, d = kv.shape
-    page = pages[0].shape[2] if is_quantized(pages) else pages.shape[2]
+    """Write a ragged batch's K or V ``[B, C, H, D]`` into the
+    head-major pool, page by page (``_write_token_spans``).  Row ``b``'s
+    token ``i`` lands at absolute position ``context_lens[b] + i`` when
+    ``i < query_lens[b]``; pad positions (``i >= query_lens[b]``,
+    including whole inactive rows) are written nowhere, so rows near the
+    window edge can never clamp into their own live pages.  On an int8
+    pool the pads still count as landing on ``scratch_page`` for the
+    scale protocol (below).  The caller guarantees
+    ``context_lens + query_lens`` stays inside each row's reserved
+    table window."""
+    if not is_quantized(pages):
+        return _write_token_spans(pages, block_tables,
+                                  kv.astype(pages.dtype), context_lens,
+                                  query_lens)
+    c = kv.shape[1]
+    page = pages[0].shape[2]
     max_pages = block_tables.shape[1]
     i = jnp.arange(c, dtype=jnp.int32)[None]                 # [1, C]
     pos = context_lens[:, None] + i                          # [B, C]
@@ -74,12 +82,11 @@ def write_ragged_pages(pages, block_tables, kv, context_lens, query_lens,
     page_idx = jnp.where(valid, page_idx,
                          jnp.asarray(scratch_page, jnp.int32))
     slot = jnp.where(valid, safe_pos % page, i % page)
-    if is_quantized(pages):
-        # pad tokens landing at scratch slot 0 only re-seed the scratch
-        # page's scale (deterministically — masked max), which no live
-        # row ever reads
-        return _quantized_scatter(pages, page_idx, slot, kv)
-    return pages.at[page_idx, :, slot].set(kv.astype(pages.dtype))
+    # pad tokens landing at scratch slot 0 only re-seed the scratch
+    # page's scale (deterministically — masked max), which no live row
+    # ever reads; their payload is dropped
+    return _quantized_scatter(pages, page_idx, slot, kv, block_tables,
+                              context_lens, query_lens)
 
 
 def _ragged_reference(q, k_pages, v_pages, block_tables, context_lens,

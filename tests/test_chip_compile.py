@@ -12,6 +12,8 @@ one file: the TPU library loads in the xdist worker that runs it and in
 no other process, and nothing is decided at import time.
 """
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,3 +142,81 @@ def test_flash_kernels_compile_fwd_bwd(spec, kernel, widths):
     # dK/dV and dQ are Mosaic kernels in both; flash adds the forward
     assert text.count("tpu_custom_call") >= (3 if kernel ==
                                              "flash_attention" else 2)
+
+
+# ------------------------------------------------- the K/V write, in place
+# mistral-d12.chat's widths (benchmarks/configs/mistral-7b-v0.1-d12.json):
+# the pool of 16 rows x 2048 tokens + the scratch page, batch 16, chunk 64
+CELL_POOL, CELL_B, CELL_CHUNK = (2049, H, PAGE, D), 16, 64
+
+_POOL_SHAPED = r"= \w+\[2049,32,16,128\]\S* (copy|transpose)\("
+
+
+def _pool_relayouts(text):
+    """Instructions that copy or transpose something of the pool's shape:
+    what a scatter with a sliced dimension between its index dimensions
+    costs on the TPU (two per call), and what a CPU run cannot see."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(_POOL_SHAPED, line)]
+
+
+@pytest.mark.parametrize("chunk", [CELL_CHUNK, 1], ids=["chunk64", "decode"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_kv_writer_updates_the_pool_in_place(spec, quantized, chunk):
+    """``write_ragged_pages`` with the pool donated: temporaries under a
+    tenth of the pool, and no pool-shaped copy in the module."""
+    from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
+
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    pool = spec(CELL_POOL, dtype)
+    pool_bytes = math.prod(CELL_POOL) * jnp.dtype(dtype).itemsize
+    if quantized:
+        pool = (pool, spec(CELL_POOL[:2], jnp.float32))
+    compiled = jax.jit(RPA.write_ragged_pages, donate_argnums=0).lower(
+        pool, spec((CELL_B, MAX_PAGES), jnp.int32),
+        spec((CELL_B, chunk, H, D), jnp.bfloat16),
+        spec((CELL_B,), jnp.int32), spec((CELL_B,), jnp.int32),
+        spec((), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
+    assert not _pool_relayouts(compiled.as_text())
+
+
+def test_mixed_step_layer_has_no_pool_sized_copy(spec, monkeypatch):
+    """One layer of the served mixed step at the chat cell's widths, as
+    ``EngineCore`` builds it (pools donated): the K and V writes reach
+    the compiled step as in-place scatters, with no copy or transpose of
+    the pool around them."""
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.ops.pallas import paged_attention as PA
+    from paddle_infer_tpu.serving.programs import build_mixed_step
+
+    # the step asks the backend whether to interpret its Pallas calls,
+    # and the backend here is the CPU
+    monkeypatch.setattr(PA, "_interpret", lambda: False)
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=4096,
+                      num_hidden_layers=1, num_attention_heads=H,
+                      num_key_value_heads=8, intermediate_size=14336,
+                      max_position_embeddings=32768, rms_norm_eps=1e-5,
+                      rope_theta=10000.0)
+    with abstract_parameters():
+        model = LlamaForCausalLM(cfg)
+    engine = PagedGenerationEngine(model, page_size=PAGE,
+                                   cache_dtype=jnp.bfloat16)
+    run = build_mixed_step(engine, CELL_B, CELL_CHUNK, MAX_PAGES)
+    b, i32, f32 = CELL_B, jnp.int32, jnp.float32
+    rows = lambda dtype: spec((b,), dtype)
+    samp = {"temperature": rows(f32), "top_k": rows(i32),
+            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
+            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
+    params = {n: spec(a.shape, jnp.bfloat16)
+              for n, a in engine._params.items()}
+    pools = [spec(CELL_POOL, jnp.bfloat16)]
+    text = run.lower(
+        params, spec((b, CELL_CHUNK), i32), rows(i32), rows(i32), rows(i32),
+        rows(jnp.bool_), rows(i32), spec((b, MAX_PAGES), i32), samp,
+        spec((b, 2), jnp.uint32), spec((), i32), pools, pools
+    ).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    assert not _pool_relayouts(text)

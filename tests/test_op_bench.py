@@ -4,6 +4,7 @@ import json
 import subprocess
 import sys
 import os
+import time
 
 import numpy as np
 
@@ -37,12 +38,16 @@ def test_cli_check_passes_against_committed_baseline(tmp_path):
            "--check", "--reps", "3", "--tolerance", "8.0"]
     r = subprocess.run(cmd, capture_output=True, text=True, env=env,
                        timeout=600)
-    if r.returncode != 0:
-        # One retry: an oversubscribed CI host (suite running next to a
-        # TPU bench) can blow even the 8x tolerance transiently; a real
-        # regression fails both runs.
-        print("op_bench first run failed, retrying; stderr:\n"
-              + r.stderr[-2000:])
+    for _ in range(3):
+        if r.returncode == 0:
+            break
+        # Retries, each after a pause: an oversubscribed CI host (suite
+        # running next to a TPU bench, or xdist neighbours that spawn
+        # process groups of their own — test_multiprocess, the fleet
+        # example — for some tens of seconds) can blow even the 8x
+        # tolerance while they last; a real regression fails every run.
+        print("op_bench run failed, retrying; stderr:\n" + r.stderr[-2000:])
+        time.sleep(20)
         r = subprocess.run(cmd, capture_output=True, text=True, env=env,
                            timeout=600)
     assert r.returncode == 0, r.stderr[-500:]

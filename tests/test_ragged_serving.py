@@ -4,7 +4,7 @@ EngineCore scheduler).
 
 Three layers of coverage:
 
-* kernel level — ``write_ragged_pages`` scratch routing, and the
+* kernel level — ``write_ragged_pages`` pad handling, and the
   single-launch Pallas kernel vs the exact reference composition
   (allclose: the online softmax reassociates);
 * parity — ragged serving streams bitwise-equal to the legacy
@@ -123,10 +123,12 @@ def _prompt(seed, n=8):
 
 # ------------------------------------------------------------------ kernel
 
-def test_write_ragged_pages_routes_pads_to_scratch():
+def test_write_ragged_pages_pads_touch_no_live_or_unmapped_page():
     """Valid positions land at each row's absolute slots; pad positions
-    (i >= query_len, including whole inactive rows) go to the scratch
-    page — never clamped into a live page."""
+    (i >= query_len, including whole inactive rows) touch no live and
+    no unmapped page — never clamped into a live page.  Whether they are
+    parked on the scratch page or written nowhere is the writer's
+    business (the page-granular writer drops them)."""
     import jax.numpy as jnp
 
     from paddle_infer_tpu.ops.pallas.ragged_paged_attention import (
@@ -151,7 +153,6 @@ def test_write_ragged_pages_routes_pads_to_scratch():
     live[0, 0, 2] = live[0, 0, 3] = live[1, 0, 0] = 0.0
     assert not live.any(), "pad tokens leaked into live pages"
     assert not out[4].any()               # unmapped page untouched
-    assert out[5].any()                   # pads parked on the scratch page
 
 
 def test_ragged_kernel_allclose_reference():
