@@ -2,17 +2,18 @@
 (greedy) token's logit lies below the plain reference's best, over a
 seeded sample of the requests the run served, the longest among them.
 
-The reference runs once over each sampled prompt with its served tokens
-(teacher-forced: under greedy decoding the served stream is its own
-input).  The control reads, at the same positions, the gap of the token a
-lower precision puts first.
+The reference is the module the configuration's file names under
+``reference`` (``reference/__init__.py`` has what is asked of it); it
+runs once over each sampled prompt with its served tokens (teacher-forced:
+under greedy decoding the served stream is its own input).  The control
+reads, at the same positions, the gap of the token a lower precision puts
+first.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from . import weights
-from .reference import mistral
+from . import reference
 from .rng import SplitMix
 
 
@@ -40,35 +41,35 @@ def gaps(config: dict, seed: int, cases, precision: str = "float32"):
     """Per served token: reference's best logit minus the served token's.
     With a lower ``precision`` the "served" token is replaced by the one
     that precision puts first (the control)."""
-    dtype = config["torch_dtype"]
-    outer = weights.llama_outer_weights(config, seed, dtype)
-    layer = lambda i: weights.llama_layer_weights(config, seed, i, dtype)
+    logits = reference.find(config).served_logits
     out = []
     width = int(config["check"]["max_tokens_per_request"])
     for seq, rows, served in cases:
         # one shape of head for every request: rows padded by repetition
         n = len(rows)
         padded = np.concatenate([rows, np.full((width - n,), rows[-1])])
-        ref = np.asarray(mistral.logits_at(config, layer, outer, seq,
-                                           padded))[:n]
+        ref = np.asarray(logits(config, seed, seq, padded))[:n]
         if precision != "float32":
-            low = np.asarray(mistral.logits_at(config, layer, outer, seq,
-                                               padded, precision))[:n]
+            low = np.asarray(logits(config, seed, seq, padded,
+                                    precision))[:n]
             served = low.argmax(-1)
         out.append(ref.max(-1) - ref[np.arange(n), served])
     return np.concatenate(out) if out else np.zeros((0,))
 
 
-def check(config: dict, seed: int, records, say=print) -> bool:
+def check(config: dict, seed: int, records, say=print):
+    """(correct, {number compared: [value, limit]})."""
     spec = config["check"]
     cases = sample(records, seed, int(spec["sample_requests"]),
                    int(spec["max_tokens_per_request"]))
     g = gaps(config, seed, cases)
+    limit = float(spec["limit_logit_gap"])
     if g.size == 0:
         say("check: no served token to compare -> not correct")
-        return False
-    widest, limit = float(g.max()), float(spec["limit_logit_gap"])
+        return False, {"widest_logit_gap": [None, limit]}
+    widest = float(g.max())
     say(f"check: served tokens compared {g.size} over {len(cases)} "
         f"requests; exact argmax {int((g == 0).sum())}; "
         f"widest_logit_gap {widest:.6f} (limit {limit})")
-    return bool(np.isfinite(g).all() and widest <= limit)
+    return (bool(np.isfinite(g).all() and widest <= limit),
+            {"widest_logit_gap": [widest, limit]})

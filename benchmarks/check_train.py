@@ -18,24 +18,22 @@ but the leaves under the pooler take most of theirs from the 64 rows of
 the next-sentence loss, and their norms move as much under another draw
 of the dropout masks as under half a batch (PERF.md section 2);
 ``delta_norm_gap_matrices`` a step that keeps its state, or another
-learning rate.  The rest is recorded beside them, not judged."""
+learning rate.  The rest is recorded beside them, not judged.
+
+The reference is the module the configuration's file names under
+``reference`` (``reference/__init__.py`` has what is asked of it)."""
 from __future__ import annotations
 
 import statistics
 
-from . import weights
-from .reference import ernie
+from . import reference
 
 
 def reference_readings(config: dict, seed: int, batches, n: int = 3,
                        precision: str = "float32",
                        mask_stream: int = 9) -> dict:
-    params = weights.ernie_weights(config, int(config["seq_len"]), seed)
-    return ernie.first_steps(
-        config, config["optimizer"], params,
-        [batches[t % len(batches)] for t in range(n)],
-        weights.seed_key(seed, mask_stream), precision,
-        int(config["check"].get("row_block", 8)))
+    return reference.find(config).first_steps(config, seed, batches, n,
+                                              precision, mask_stream)
 
 
 def worst_leaf(got: dict, ref: dict, leaves=None):
@@ -49,15 +47,8 @@ def worst_leaf(got: dict, ref: dict, leaves=None):
     return worst, at
 
 
-def matrices(config: dict) -> list:
-    """The leaves with two dimensions: sums over enough elements for a
-    norm to be steady under another draw of the dropout masks."""
-    shapes = weights.ernie_shapes(config, int(config["seq_len"]))
-    return sorted(n for n, (shape, _) in shapes.items() if len(shape) == 2)
-
-
 def compare(config: dict, got: dict, ref: dict) -> dict:
-    mats = matrices(config)
+    mats = reference.find(config).matrix_leaves(config)
     g_all, g_at = worst_leaf(got["grad_norms"], ref["grad_norms"])
     g_mat, gm_at = worst_leaf(got["grad_norms"], ref["grad_norms"], mats)
     d_mat, dm_at = worst_leaf(got["delta_norms"], ref["delta_norms"], mats)
@@ -82,17 +73,19 @@ def compare(config: dict, got: dict, ref: dict) -> dict:
                 "delta_matrices": dm_at}}
 
 
-def check(config: dict, seed: int, batches, got: dict, say=print) -> bool:
+def check(config: dict, seed: int, batches, got: dict, say=print):
+    """(correct, {number compared: [value, limit]})."""
     ref = reference_readings(config, seed, batches)
     numbers = compare(config, got, ref)
     say(f"check: losses program {got['losses']} reference {ref['losses']}")
-    ok = True
+    ok, compared = True, {}
     for name, limit in config["check"]["limits"].items():
         value = numbers[name]
         say(f"check: {name} {value:.6f} (limit {limit})")
         ok = ok and value == value and value <= float(limit)
+        compared[name] = [value, float(limit)]
     say(f"check: recorded, not judged: " + ", ".join(
         f"{k} {v:.6f}" for k, v in numbers.items()
         if not k.startswith("_") and k not in config["check"]["limits"]))
     say(f"check: worst leaves {numbers['_at']}")
-    return ok
+    return ok, compared

@@ -1,9 +1,10 @@
-"""``step_roofline``'s share with the step's own counts in place of the
-client-side estimate: the packer records, per step, the query-key pairs
-its attention must compute (``attended_keys``) and the cached tokens it
-reads (``resident_tokens``).  Same costs (costs.py), peaks (peaks.py),
-traced steps and busy seconds.  None where the records have no such
-fields or the run was not traced."""
+"""The step program's share of its roofline over the traced part of the
+window: the least time the chip could take for each step's REAL tokens
+(operations and bytes from shapes, costs.py; peaks from peaks.py), summed,
+over the seconds the device was busy.  The counts are the step's own: the
+packer records, per step, the query-key pairs its attention must compute
+(``attended_keys``) and the cached tokens it reads (``resident_tokens``).
+None where the records have no such fields or the run was not traced."""
 from .. import costs, peaks
 from .steplog_stat import serving_steps
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import weights
 from .lowp import matmul, rounder
 
 
@@ -143,8 +144,8 @@ def _adamw(params, grads, m, v, t, lr, b1, b2, eps, wd):
             {n: o[2] for n, o in out.items()})
 
 
-def first_steps(cfg, opt, params, batches, key, precision="float32",
-                row_block=8):
+def follow_steps(cfg, opt, params, batches, key, precision="float32",
+                 row_block=8):
     """Follow the first ``len(batches)`` steps.  Returns each step's loss,
     the first gradient's norm per leaf and, per leaf, the norm of the
     parameters' change over all the steps."""
@@ -168,3 +169,24 @@ def first_steps(cfg, opt, params, batches, key, precision="float32",
              for n in params}
     return {"losses": losses, "grad_norms": grad_norms,
             "delta_norms": delta}
+
+
+def first_steps(cfg: dict, seed: int, batches, n: int = 3,
+                precision: str = "float32", mask_stream: int = 9) -> dict:
+    """The contract of a training reference (``reference/__init__.py``):
+    the first ``n`` steps from the family's seeded weights, the batches
+    cycled as the window cycles them, dropout masks drawn from
+    ``mask_stream``."""
+    return follow_steps(
+        cfg, cfg["optimizer"],
+        weights.ernie_weights(cfg, int(cfg["seq_len"]), seed),
+        [batches[t % len(batches)] for t in range(n)],
+        weights.seed_key(seed, mask_stream), precision,
+        int(cfg["check"].get("row_block", 8)))
+
+
+def matrix_leaves(cfg: dict) -> list:
+    """The leaves with two dimensions: sums over enough elements for a
+    norm to be steady under another draw of the dropout masks."""
+    shapes = weights.ernie_shapes(cfg, int(cfg["seq_len"]))
+    return sorted(n for n, (shape, _) in shapes.items() if len(shape) == 2)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import weights
 from .lowp import matmul, rounder
 
 
@@ -93,3 +94,16 @@ def logits_at(cfg: dict, layer_weights, outer, tokens, rows,
                    precision)
     return _head(x, jnp.asarray(np.asarray(rows, np.int32)),
                  outer["lm_head"], float(cfg["rms_norm_eps"]), precision)
+
+
+def served_logits(cfg: dict, seed: int, tokens, rows,
+                  precision: str = "float32"):
+    """The contract of a served reference (``reference/__init__.py``):
+    float32 logits [len(rows), vocab] of the model whose weights are the
+    family's seeded ones in the served type, one layer's float32 copy
+    alive at a time."""
+    dtype = cfg["torch_dtype"]
+    return logits_at(
+        cfg, lambda i: weights.llama_layer_weights(cfg, seed, i, dtype),
+        weights.llama_outer_weights(cfg, seed, dtype), tokens, rows,
+        precision)

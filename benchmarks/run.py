@@ -29,8 +29,6 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 EXIT_NO_CHIP, EXIT_NO_PROGRAM, EXIT_BAD_CELL = 3, 4, 5
-RUNNERS = {"serving": "benchmarks.serving_run",
-           "training": "benchmarks.training_run"}
 
 
 def load_json(*parts):
@@ -83,11 +81,19 @@ class Context:
         return int(max(peaks)) if peaks else None
 
 
+def runner_for(kind: str):
+    """``<kind>_run.py`` beside this file: one ``run(ctx, system_mod)``
+    for every configuration of that kind."""
+    return importlib.import_module(f"benchmarks.{kind}_run")
+
+
 def run_cell(ctx: Context, system_mod=None) -> dict:
     """Everything after the look for a chip: returns the run's result
     with its evidence."""
-    kind = ctx.config["kind"]
-    runner = importlib.import_module(RUNNERS[kind])
+    from benchmarks import reference
+
+    runner = runner_for(ctx.config["kind"])
+    reference.find(ctx.config)      # a cell that cannot be checked: now
     return runner.run(ctx, system_mod)
 
 
@@ -132,7 +138,17 @@ def result_line(result, bench, workload, trace, platform, chips) -> dict:
         device["window_s"] = ev.trace["window_s"]
         line["breakdown"] = {"device_ops": ev.trace["device_ops"],
                              "idle_gaps": ev.trace["idle_gaps"]}
+    # last: each number compared beside its limit
+    line["check"] = result["check"]
     return line
+
+
+def say_check(line: dict, file=None):
+    """The numbers compared, each beside its limit: a run's last lines on
+    standard error."""
+    for name, (value, limit) in line["check"].items():
+        print(f"check: {name} {value} (limit {limit})",
+              file=file or sys.stderr, flush=True)
 
 
 def configure_cache():
@@ -186,6 +202,7 @@ def main(argv=None) -> int:
                        devices[0].platform, chips)
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
+    say_check(line)
     return 0
 
 

@@ -7,6 +7,7 @@ import time
 from . import check_served, xplane
 from .accounting import gaps_in_window, quantile
 from .evidence import CompileCounter, Evidence
+from .readers.steplog_phase import per_step
 
 
 def run(ctx, system_mod=None) -> dict:
@@ -50,6 +51,11 @@ def run(ctx, system_mod=None) -> dict:
             allocator_peak_bytes=ctx.allocator_peak(),
             token_budget=system.token_budget, max_batch=system.max_batch,
             trace=trace)
+        # the compiled step's temporaries, which the allocator's peak
+        # leaves out: the largest the program recorded over the window's
+        # steps (what readers/steplog_hbm_share.py reads)
+        ev.program_temp_bytes = int(max(
+            per_step(ev, ["program_temp_bytes"]) or [0])) or None
     finally:
         system.free()
     measured = [r for r in records
@@ -74,6 +80,7 @@ def run(ctx, system_mod=None) -> dict:
                               *(1e3 * quantile(gaps, q)
                                 for q in (.5, .9, .95, .99)),
                               1e3 * gaps[-1]))
-    correct = check_served.check(config, ctx.seed, records, ctx.say)
+    correct, compared = check_served.check(config, ctx.seed, records,
+                                           ctx.say)
     return {"correct": correct, "attempted": len(measured),
-            "failed": len(failed), "evidence": ev}
+            "failed": len(failed), "evidence": ev, "check": compared}

@@ -1,34 +1,12 @@
-"""Host spans the benchmark places around its own calls into the program,
-written into the profiler's trace so that idle gaps on the device can be
-attributed to what the host was doing.  No-ops when the run is not traced.
-"""
+"""The program's own host spans that the reduction reads from the
+profiler's trace, so that idle gaps on the device can be attributed to
+what the host was doing: the serving iteration's phases (the StepClock's
+``engine.step`` and ``engine.<phase>`` annotations) and the Fleet step's
+dispatch.  The benchmark wraps nothing of the program's."""
 from __future__ import annotations
 
-import contextlib
-import functools
-
-# span name -> the gap label it gives (innermost span wins)
-GAP_LABELS = {"bench.dispatch": "in_dispatch",
-              "bench.run_once": "scheduler_host",
-              "bench.train_step": "in_dispatch"}
+# the spans whose names label a gap; innermost first: a phase lies inside
+# its step, and a gap goes to the first span that covers it
+GAP_SPANS = ("engine.admit", "engine.pack", "engine.launch", "engine.wait",
+             "engine.emit", "engine.step", "fleet.train_step")
 OUTSIDE = "between_steps"
-
-
-def span(name: str, traced: bool):
-    if not traced:
-        return contextlib.nullcontext()
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
-def wrap(fn, name: str):
-    """``fn`` inside a span called ``name`` (traced runs only)."""
-    import jax
-
-    @functools.wraps(fn)
-    def inner(*a, **kw):
-        with jax.profiler.TraceAnnotation(name):
-            return fn(*a, **kw)
-
-    return inner

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from .. import spans, weights
+from .. import weights
 
 MODEL_KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
               "num_attention_heads", "num_key_value_heads",
@@ -114,11 +114,6 @@ class System:
             enable_prefix_cache=bool(dep["enable_prefix_cache"]),
             steplog=self.steplog,
             serving_mesh=smesh if smesh.n_devices > 1 else None)
-        if self.traced:
-            self.core.run_once = spans.wrap(self.core.run_once,
-                                            "bench.run_once")
-            engine.run_paged_program = spans.wrap(engine.run_paged_program,
-                                                  "bench.dispatch")
         self.sup = EngineSupervisor(self.core).start()
         self.token_budget = int(self.core._token_budget)
         self.max_batch = int(dep["max_batch"])

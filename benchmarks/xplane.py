@@ -91,21 +91,18 @@ def op_key(name: str) -> str:
 def reduce_events(device_ops: Dict[str, List[tuple]],
                   host_spans: List[tuple], window_s: float) -> dict:
     """``device_ops``: per device, (name, start_s, duration_s) of each
-    operation; ``host_spans``: the benchmark's own spans, same clock.
+    operation; ``host_spans``: the program's spans that
+    ``spans.GAP_SPANS`` names, same clock.
     Returns busy seconds (mean over devices), the idle share of the
-    window, per-operation and collective seconds, and the idle gaps by
+    window, every operation key's seconds (``op_seconds``; the ten largest
+    again as ``device_ops``), collective seconds, and the idle gaps by
     what the host was doing."""
     if not device_ops:
         return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
     busy, ops, coll = [], {}, 0.0
     gaps_by: Dict[str, float] = {}
-    labelled = {}
-    for label in set(spans.GAP_LABELS.values()):
-        labelled[label] = union(
-            (s, s + d) for n, s, d in host_spans
-            if spans.GAP_LABELS.get(n) == label)
-    # innermost first: a dispatch lies inside a run_once
-    order = ["in_dispatch", "scheduler_host"]
+    labelled = {label: union((s, s + d) for n, s, d in host_spans
+                             if n == label) for label in spans.GAP_SPANS}
     for dev, events in device_ops.items():
         iv = union((s, s + d) for _, s, d in events)
         busy.append(total(iv))
@@ -118,8 +115,8 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
         if not iv:
             continue
         rest = complement(iv, iv[0][0], iv[-1][1])
-        for label in order:
-            inside = intersect(rest, labelled.get(label, []))
+        for label in spans.GAP_SPANS:         # innermost first
+            inside = intersect(rest, labelled[label])
             if inside:
                 gaps_by[label] = gaps_by.get(label, 0.0) + total(inside)
                 rest = _subtract(rest, inside)
@@ -134,12 +131,16 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
     if marks:
         window_s = max(b for _, b in marks) - min(a for a, _ in marks)
     busy_s = sum(busy) / n
-    top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
+    # every operation key's seconds, largest first: a reader takes a named
+    # kernel's whatever its rank; the result line prints the first ten
+    op_seconds = {k: v / n for k, v in
+                  sorted(ops.items(), key=lambda kv: -kv[1])}
     return {
         "window_s": window_s, "busy_s": busy_s, "devices": n,
         "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
         "collective_s": coll / n,
-        "device_ops": [[k, v / n] for k, v in top],
+        "op_seconds": op_seconds,
+        "device_ops": [[k, v] for k, v in list(op_seconds.items())[:10]],
         "idle_gaps": [[k, v / n] for k, v in
                       sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
 
@@ -172,11 +173,11 @@ def load(trace_dir: str, device_prefix: str = "/device:TPU",
             if is_device and line.name.startswith(op_line):
                 device_ops.setdefault(plane.name, []).extend(
                     (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                    for e in line.events if e.name not in spans.GAP_LABELS)
+                    for e in line.events if e.name not in spans.GAP_SPANS)
             if plane.name.startswith("/host:"):
                 host_spans.extend(
                     (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                    for e in line.events if e.name in spans.GAP_LABELS)
+                    for e in line.events if e.name in spans.GAP_SPANS)
     return device_ops, host_spans
 
 

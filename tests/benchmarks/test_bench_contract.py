@@ -53,7 +53,7 @@ def test_every_cell_resolves_and_reports(entry):
     from benchmarks import run
 
     e, config, traffic, cell, _ = run.load_cell(entry["name"])
-    assert config["kind"] in run.RUNNERS
+    assert callable(run.runner_for(config["kind"]).run)
     importlib.import_module("benchmarks.systems." + config["system"])
     assert len(entry["why"]) <= 200 and "\n" not in entry["why"]
     reports = lambda m: "workloads" not in m or entry["name"] in m["workloads"]
@@ -63,6 +63,31 @@ def test_every_cell_resolves_and_reports(entry):
     assert layer
     for m in layer:
         assert m["moves"] in e2e, (m["name"], m["moves"])
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"],
+                         ids=[c["name"] for c in BENCH["configs"]])
+def test_every_configuration_names_a_reference_with_its_contract(entry):
+    from benchmarks import reference
+
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        config = json.load(f)
+    mod = reference.find(config)
+    assert mod.__name__ == "benchmarks.reference." + config["reference"]
+    for fn in reference.CONTRACT[config["kind"]]:
+        assert callable(getattr(mod, fn))
+
+
+@pytest.mark.parametrize("name", [
+    "check_served.py", "check_train.py", "serving_run.py",
+    "training_run.py", "control.py", "run.py"])
+def test_the_harness_names_no_architecture(name):
+    """What checks and runs every family names none of them: no model, no
+    weight, no reference module."""
+    with open(os.path.join(ROOT, "benchmarks", name)) as f:
+        text = f.read().lower()
+    for word in ("mistral", "llama", "ernie", "wqkv", "word_emb"):
+        assert word not in text, (name, word)
 
 
 def test_per_layer_entries_are_well_formed():

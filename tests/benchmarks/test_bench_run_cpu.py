@@ -1,6 +1,7 @@
 """The harness from just after its look for a chip to its result, on the
 CPU at a tiny size: sound runs come out correct, and a timed path broken
 underneath comes out not correct."""
+import io
 import json
 import os
 import time
@@ -53,7 +54,7 @@ def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
                              ev, "mistral-d12.chat")
     # not traced: the readers of the device trace found nothing to read
     assert "device_idle_share.chat" not in layer
-    assert "step_roofline_share.chat" not in layer
+    assert "step_roofline_share_counted.chat" not in layer
     assert layer["compiles_in_window.chat"]["value"] == 0
     assert 0 < layer["padded_slot_share.chat"]["value"] < 100
     # a loaded test machine runs late; the chip run reads 1.6 ms
@@ -65,14 +66,27 @@ def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
 def test_result_line_has_the_contract_keys(chat_result, benchmark_json):
     line = run.result_line(dict(chat_result), benchmark_json,
                            "mistral-d12.chat", 0, "cpu", 1)
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "check"]
+    # last: each number compared beside its limit
+    assert line["check"] == {"widest_logit_gap": [
+        pytest.approx(0.0, abs=0.007), 0.007]}
+    said = io.StringIO()
+    run.say_check(line, file=said)
+    assert said.getvalue() == "check: widest_logit_gap %s (limit 0.007)\n" \
+        % line["check"]["widest_logit_gap"][0]
     assert set(line["metrics"]) == {"itl_p95_ms", "setup_s"}
     assert {"platform", "kind", "count", "memory_peak_bytes",
             "allocator_peak_bytes", "program_temp_bytes"} == set(
                 line["device"])
-    # the serving engine offers no memory analysis of its step yet
-    assert line["device"]["program_temp_bytes"] is None
+    # the compiled step's temporaries as the StepLog recorded them: the
+    # largest over the window's steps (null where the program had no
+    # memory analysis to offer: its program_cost finds none once an earlier
+    # test of the process left a multi-device fleet mesh behind)
+    ev = chat_result["evidence"]
+    largest = max(s["program_temp_bytes"] for s in ev.steps)
+    assert line["device"]["program_temp_bytes"] == (largest or None)
+    assert ev.allocator_peak_bytes is None      # a CPU reports none
     json.dumps(line)
 
 
