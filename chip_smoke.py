@@ -64,6 +64,12 @@ REAL = dict(
                window=4, chunk=64),
     flash=[dict(b=8, s=512, h=12, d=64, causal=False),
            dict(b=1, s=2048, h=32, d=128, causal=True)],
+    # axk1-ep16.ragchat's widths (benchmarks/configs/a.x-k1-ep16-d7.json):
+    # 64 heads against one 512 + 64 row a token; 12 experts of 7168 x 2048
+    # over the 64 x 8 assignments a step can make
+    latent=dict(b=16, h=64, rank=512, rope=64, page=16, max_pages=256,
+                pool=4097, chunk=64),
+    grouped=dict(rows=512, hidden=7168, ffn=2048, experts=12),
 )
 TINY = dict(
     llama=dict(preset=None, vocab_size=128, hidden_size=64,
@@ -78,6 +84,9 @@ TINY = dict(
                window=3, chunk=16),
     flash=[dict(b=1, s=128, h=2, d=64, causal=False),
            dict(b=1, s=256, h=2, d=64, causal=True)],
+    latent=dict(b=3, h=4, rank=16, rope=8, page=16, max_pages=6, pool=19,
+                chunk=16),
+    grouped=dict(rows=32, hidden=64, ffn=32, experts=4),
 )
 
 CHILD_TIMEOUT_S = {"checkpoint": 420, "eager": 420, "train": 600,
@@ -713,6 +722,55 @@ def child_kernels(args):
             keep = jnp.asarray(valid)[:, :, None, None]
             close(f"_ragged_kernel_call[{tag}]",
                   jnp.where(keep, got, 0), jnp.where(keep, want, 0))
+
+    # ---- latent pages: the decode kernel against the chunk composition
+    from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
+    from paddle_infer_tpu.ops.pallas import latent_attention as LA
+
+    p = spec["latent"]
+    b, h, rank, page, mp = p["b"], p["h"], p["rank"], p["page"], p["max_pages"]
+    width = rank + p["rope"]
+    lk = jax.random.split(jax.random.PRNGKey(args.seed + 7), 4)
+    lanes = -(-width // 128) * 128         # the pool's rows, zero-padded
+    lpool = LA.pad_lanes(jax.random.normal(
+        lk[0], (p["pool"], page, width), jnp.bfloat16), lanes)
+    ltab = jnp.asarray(np.stack([
+        rs.permutation(p["pool"])[:mp] for _ in range(b)]), jnp.int32)
+    lctx_np = rs.randint(1, mp * page - 2, (b,))
+    lctx_np[0], lctx_np[-1] = 1, mp * page - 2    # one token; every page
+    lctx = jnp.asarray(lctx_np, jnp.int32)
+    # queries scaled so the softmax is neither flat nor one-hot
+    lq = jax.random.normal(lk[1], (b, h, width), jnp.bfloat16)
+    scale = 1.0 / float(np.sqrt(width))
+    got = compiled(lambda q, pool, t, n: LA.latent_paged_decode(
+        q, pool, t, n, scale, rank), lq, lpool, ltab, lctx + 1)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda q, pool, t, c: LA.latent_chunk_attention(
+            jnp.stack([q, jnp.zeros_like(q)], 1).astype(jnp.float32),
+            pool.astype(jnp.float32), t, c, jnp.full((b,), 2, jnp.int32),
+            scale, rank)[:, 0])(lq, lpool, ltab, lctx)
+    close("latent_paged_decode", got, want)
+
+    # ---- grouped matmul: sorted rows, uneven groups, an untouched expert
+    g = spec["grouped"]
+    gk = jax.random.split(jax.random.PRNGKey(args.seed + 8), 2)
+    rows = jax.random.normal(gk[0], (g["rows"], g["hidden"]), jnp.bfloat16)
+    wmat = jax.random.normal(gk[1], (g["experts"], g["hidden"], g["ffn"]),
+                             jnp.bfloat16) * 0.02
+    sizes_np = np.zeros((g["experts"],), np.int32)
+    sizes_np[0], sizes_np[-1] = 3, g["rows"] // 4 + 1
+    sizes_np[1] = g["rows"] // 2 - 2             # straddles row tiles
+    sizes = jnp.asarray(sizes_np)
+    got = compiled(GM.grouped_matmul, rows, wmat, sizes)
+    with jax.default_matmul_precision("highest"):
+        parts, r0 = [], 0
+        for e, n in enumerate(sizes_np):          # expert by expert
+            parts.append(rows[r0:r0 + n].astype(jnp.float32)
+                         @ wmat[e].astype(jnp.float32))
+            r0 += int(n)
+    want = jnp.concatenate(parts + [jnp.zeros(
+        (g["rows"] - r0, g["ffn"]), jnp.float32)])
+    close("moe_grouped_matmul", got, want)
 
     # ---- flash / hybrid, forward and backward, segment ids + dropout
     for shape in spec["flash"]:

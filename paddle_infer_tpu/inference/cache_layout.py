@@ -1,0 +1,109 @@
+"""What one decoder layer keeps per cached token, as the model states it
+and the paged engine allocates it.
+
+Two kinds today:
+
+``kv``      keys and values per head — two pools ``[P, heads, page, dim]``
+            (the LLaMA / GPT block; int8 pools are ``(payload, scales)``
+            pairs of that shape).
+``latent``  one vector per token shared by every head — ONE pool
+            ``[P, page, lanes]``, no V pool and no head axis
+            (latent attention: the normed low-rank key/value row beside
+            its rotated position part).  ``width`` numbers are cached a
+            token; ``lanes`` is ``width`` rounded up to the TPU's 128
+            lanes, the rest zeros.  A row-major ``[P, page, 576]`` array
+            occupies 640 lanes a row on the device anyway, and for a
+            last dimension that is no multiple of 128 the TPU's own
+            layout makes the PAGE index the fastest dimension (measured
+            on the v5e at ``[4097, 16, 576]``): a page would be strewn
+            over the whole pool and every program would transpose the
+            pool on its way in and out.  Stating the lanes keeps a page
+            contiguous and the page write in place.
+
+A model states its layers' kinds through ``cache_layout()`` (a list, one
+entry a layer); a model without it is the ``kv`` case at its config's
+heads.  ``PagedGenerationEngine._ensure_pages``, ``run_paged_program``
+and the serving programs (``serving/programs.py``) go through this one
+description, so the pools still travel as the ``(k_pages, v_pages)``
+pair of per-layer lists every program donates — a ``latent`` layer's
+entry in the second list is ``None``, an empty pytree.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import List, Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    kind: str                 # "kv" | "latent"
+    heads: int = 0            # kv
+    dim: int = 0              # kv
+    width: int = 0            # latent
+
+    @classmethod
+    def kv(cls, heads: int, dim: int) -> "LayerCache":
+        return cls("kv", heads=int(heads), dim=int(dim))
+
+    @classmethod
+    def latent(cls, width: int) -> "LayerCache":
+        return cls("latent", width=int(width))
+
+    @property
+    def lanes(self) -> int:
+        """``width`` rounded up to whole 128-lane tiles (latent)."""
+        return -(-self.width // 128) * 128
+
+    def pool_shapes(self, num_pages: int, page: int):
+        """Shapes of the (first, second) pool; the second is None for a
+        layer that keeps one vector a token."""
+        if self.kind == "latent":
+            return (num_pages, page, self.lanes), None
+        shape = (num_pages, self.heads, page, self.dim)
+        return shape, shape
+
+    def values_per_token(self) -> int:
+        """Numbers cached per token in this layer (both pools)."""
+        if self.kind == "latent":
+            return self.width
+        return 2 * self.heads * self.dim
+
+    def stored_per_token(self) -> int:
+        """Pool elements per token in this layer: the cached numbers and,
+        for a latent row, its lane padding."""
+        if self.kind == "latent":
+            return self.lanes
+        return 2 * self.heads * self.dim
+
+    def head_axis(self) -> Optional[int]:
+        """The pool axis a serving mesh may split over "mp"."""
+        return None if self.kind == "latent" else 1
+
+    # ------------------------------------------------ the step's tuples
+    def step_cache(self, first, second, *rest):
+        """The cache tuple one layer is handed inside a serving program:
+        its pool(s) first, then the step's shared arrays."""
+        if self.kind == "latent":
+            return (first, *rest)
+        return (first, second, *rest)
+
+    def pools_of(self, cache):
+        """(first, second) pools back out of a layer's returned tuple."""
+        if self.kind == "latent":
+            return cache[0], None
+        return cache[0], cache[1]
+
+
+def layout_of(model) -> List[LayerCache]:
+    """The model's own statement, or the ``kv`` case at its config."""
+    stated = getattr(model, "cache_layout", None)
+    if callable(stated):
+        return list(stated())
+    cfg = model.config
+    return [LayerCache.kv(cfg.num_attention_heads,
+                          cfg.hidden_size // cfg.num_attention_heads)
+            ] * int(cfg.num_hidden_layers)
+
+
+def has_latent(layout) -> bool:
+    return any(c.kind == "latent" for c in layout)

@@ -729,6 +729,10 @@ class PagedGenerationEngine(GenerationEngine):
                          prompt_bucket=prompt_bucket,
                          cache_dtype=cache_dtype, mesh=mesh,
                          quantized_allreduce=quantized_allreduce)
+        from .cache_layout import layout_of
+
+        self._cache_layout = layout_of(model)
+        self._cache_bytes_measured = {}
         self.page_size = page_size
         self._requested_pages = num_pages
         self._pool = None
@@ -761,13 +765,19 @@ class PagedGenerationEngine(GenerationEngine):
         return self._pool
 
     def _ensure_pages(self):
-        pshape = (self._pool.num_blocks, self._num_heads, self.page_size,
-                  self._head_dim)
+        """The per-layer device pools, allocated from the model's cache
+        layout (inference/cache_layout.py): a ``kv`` layer gets its two
+        ``[P, h, page, d]`` pools, a ``latent`` layer ONE ``[P, page,
+        lanes]`` pool and ``None`` in the second list."""
+        layout = self._cache_layout
+        shapes = [c.pool_shapes(self._pool.num_blocks, self.page_size)
+                  for c in layout]
 
         def shape_of(p):            # quantized pools are (payload, scales)
             return p[0].shape if isinstance(p, tuple) else p.shape
 
-        if self._k_pages is None or shape_of(self._k_pages[0]) != pshape:
+        if self._k_pages is None or any(
+                shape_of(p) != s[0] for p, s in zip(self._k_pages, shapes)):
             from ..ops.pallas.paged_attention import KV_SCALE_EPS
 
             # under a mesh the pool is head-sharded: each mp shard owns
@@ -775,19 +785,24 @@ class PagedGenerationEngine(GenerationEngine):
             # axis.  Allocated IN that placement — a zeros on the default
             # device that is then moved would stage each layer's whole
             # pool on device 0
-            payload_at = scales_at = None
-            if self._mesh is not None:
+            def placement(cache):
+                if self._mesh is None:
+                    return None, None
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
 
                 from ..parallel.topology import axis_if_divides
 
-                hax = axis_if_divides(self._mesh, "mp", self._num_heads)
-                payload_at = NamedSharding(self._mesh,
-                                           P(None, hax, None, None))
-                scales_at = NamedSharding(self._mesh, P(None, hax))
+                if cache.head_axis() is None:
+                    return NamedSharding(self._mesh, P()), None
+                hax = axis_if_divides(self._mesh, "mp", cache.heads)
+                return (NamedSharding(self._mesh, P(None, hax, None, None)),
+                        NamedSharding(self._mesh, P(None, hax)))
 
-            def alloc():
+            def alloc(cache, pshape):
+                if pshape is None:
+                    return None
+                payload_at, scales_at = placement(cache)
                 quant = self._kv_dtype == "int8"
                 z = jnp.zeros(pshape, jnp.int8 if quant
                               else self._cache_dtype, device=payload_at)
@@ -799,9 +814,46 @@ class PagedGenerationEngine(GenerationEngine):
                 return z, jnp.full(pshape[:2], KV_SCALE_EPS, jnp.float32,
                                    device=scales_at)
 
-            self._k_pages = [alloc() for _ in range(self._num_layers)]
-            self._v_pages = [alloc() for _ in range(self._num_layers)]
+            self._k_pages = [alloc(c, s[0]) for c, s in zip(layout, shapes)]
+            self._v_pages = [alloc(c, s[1]) for c, s in zip(layout, shapes)]
         return self._k_pages, self._v_pages
+
+    def cache_bytes_per_token(self, kind=None, padding: bool = True) -> int:
+        """Bytes of the allocated pools per token of their capacity, over
+        the layers of cache ``kind`` (all layers when None): the arrays'
+        own sizes, a quantized pool's scales and a latent row's lane
+        padding included.  ``padding=False`` takes the lanes past a
+        latent layer's stated ``width`` off again: what is cached.
+        Measured once per engine (a token's bytes do not depend on how
+        many pages the pool has); allocates the pools if nothing has."""
+        key = (kind, padding)
+        if key not in self._cache_bytes_measured:
+            k_pages, v_pages = self._ensure_pages()
+            total = 0
+            for cache, first, second in zip(self._cache_layout, k_pages,
+                                            v_pages):
+                if kind not in (None, cache.kind):
+                    continue
+                nbytes = sum(int(a.nbytes) for a in
+                             jax.tree_util.tree_leaves((first, second)))
+                if not padding:
+                    nbytes = nbytes * cache.values_per_token() \
+                        // cache.stored_per_token()
+                total += nbytes
+            self._cache_bytes_measured[key] = total // (
+                self._pool.num_blocks * self.page_size)
+        return self._cache_bytes_measured[key]
+
+    def _refuse_latent(self, what: str):
+        """The legacy per-call programs walk ``[P, h, page, d]`` pools;
+        a latent layer is served through the mixed step only."""
+        from .cache_layout import has_latent
+
+        if has_latent(self._cache_layout):
+            raise NotImplementedError(
+                f"{what} runs the per-call paged programs, which know "
+                "key/value pools only; a model with a latent cache layer "
+                "is served through serving.EngineCore's mixed step")
 
     # ------------------------------------------------------ serving hooks
     # The serving.EngineCore scheduler owns this engine's pool/pages
@@ -1308,6 +1360,7 @@ class PagedGenerationEngine(GenerationEngine):
         is one device round-trip over the persistent paged pools, and
         the stream stops early when every row hits EOS.  Beam search is
         not streamable (it finalizes globally)."""
+        self._refuse_latent("stream()")
         g = generation_config or GenerationConfig()
         if g.num_beams > 1:
             raise ValueError("stream() supports sampling/greedy only")
@@ -1405,6 +1458,7 @@ class PagedGenerationEngine(GenerationEngine):
 
     def generate(self, input_ids, generation_config: GenerationConfig = None,
                  attention_mask=None, return_scores: bool = False):
+        self._refuse_latent("generate()")
         g = generation_config or GenerationConfig()
         self._params = self._snapshot_params()
         ids, lengths, plen, pages_per_seq, pool, tables = \

@@ -21,6 +21,7 @@ __all__ = [
     "LlamaModel", "LlamaForCausalLM", "LlamaConfig", "LlamaDecoderLayer",
     "LlamaMLP", "LLAMA_PRESETS", "llama_lm_loss",
     "GPTMoEModel", "GPTMoEForCausalLM", "MoEConfig",
+    "LatentMoEConfig", "LatentMoEModel", "LatentMoEForCausalLM",
     "AutoModel", "AutoConfig", "PretrainedMixin",
 ]
 
@@ -45,6 +46,11 @@ def __getattr__(name):
         from . import gpt_moe
 
         return getattr(gpt_moe, name)
+    if name in ("LatentMoEConfig", "LatentMoEModel",
+                "LatentMoEForCausalLM"):
+        from . import latent_moe
+
+        return getattr(latent_moe, name)
     if name in ("AutoModel", "AutoConfig", "PretrainedMixin"):
         from . import pretrained
 

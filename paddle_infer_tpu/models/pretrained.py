@@ -34,6 +34,13 @@ class AutoModel:
         "ErnieForPretraining": ("ernie", "ErnieForPretraining"),
         "ErnieForSequenceClassification": (
             "ernie", "ErnieForSequenceClassification"),
+        "LatentMoEForCausalLM": ("latent_moe", "LatentMoEForCausalLM"),
+    }
+
+    # a directory holding a model's own published config.json carries no
+    # "architecture"; its "model_type" names the family
+    _BY_MODEL_TYPE = {
+        "axk1": ("latent_moe", "LatentMoEForCausalLM"),
     }
 
     @classmethod
@@ -44,11 +51,14 @@ class AutoModel:
         with open(os.path.join(save_dir, _CONFIG)) as f:
             cfg = json.load(f)
         arch = cfg.pop("architecture", None)
-        entry = cls._REGISTRY.get(arch)
+        entry = cls._REGISTRY.get(arch) if arch is not None \
+            else cls._BY_MODEL_TYPE.get(cfg.get("model_type"))
         if entry is None:
             raise ValueError(
-                f"unknown architecture {arch!r} in {save_dir} "
-                f"(known: {sorted(cls._REGISTRY)})")
+                f"unknown architecture {arch!r} / model_type "
+                f"{cfg.get('model_type')!r} in {save_dir} "
+                f"(known: {sorted(cls._REGISTRY)}; model types "
+                f"{sorted(cls._BY_MODEL_TYPE)})")
         mod = importlib.import_module(f".{entry[0]}", __package__)
         return getattr(mod, entry[1]), cfg
 

@@ -83,6 +83,9 @@ _SCHEMA = (
                                  # must compute (sum qlen*ctx + tri(qlen))
     ("resident_tokens", 0),      # cached tokens the step reads (sum over
                                  # rows with qlen > 0 of ctx + qlen)
+    ("decode_keys", 0),          # of attended_keys, those of the decode
+                                 # rows (sum over rows with qlen == 1 of
+                                 # ctx + 1)
     ("h2d_bytes", 0),            # bytes of the host arrays handed to the
                                  # step program this step
     ("program_temp_bytes", 0),   # the compiled step's temporaries
@@ -120,6 +123,24 @@ _SCHEMA = (
                                  # overflow (NEVER silent)
     ("moe_aux_loss", 0.0),       # gate load-balance aux loss (mean
                                  # across moe layers)
+    # dropless expert layers (serving/moe/dropless.py): nothing is ever
+    # dropped there, so these count what was routed, over valid slots
+    ("moe_assignments_total", 0),  # valid tokens x top-k x expert layers
+    ("moe_assignments_held", 0),   # of those, to experts held on this
+                                   # chip (summed over expert layers)
+    ("moe_held_expert_max", 0),  # largest count any held expert got in
+                                 # any layer this step
+    ("moe_experts_touched", 0),  # held experts with at least one token,
+                                 # summed over expert layers
+    ("cache_bytes_per_token", 0),  # the allocated pools' bytes over their
+                                   # token capacity, all layers, scales
+                                   # and a latent row's lane padding
+                                   # included (read from the arrays once;
+                                   # on every ragged step)
+    ("latent_cache_bytes_per_token", 0),  # of the layers of cache kind
+                                          # "latent", the same with the
+                                          # lanes past their stated width
+                                          # taken off: what is cached
     ("planned_tokens", 0),       # tokens the StepPlanner chose to pack
     ("planned_chunk_cap", 0),    # per-row prompt-chunk cap this step
     ("predicted_wall_s", 0.0),   # planner's predicted step wall (0.0
@@ -165,10 +186,12 @@ class StepCostModel:
         else:
             payload_itemsize = itemsize
             scale_bytes = 0
+        layout = getattr(engine, "_cache_layout", None)
+        per_token = (sum(c.stored_per_token() for c in layout) if layout
+                     else engine._num_layers * 2 * engine._num_heads
+                     * engine._head_dim)
         self._page_kv_bytes = float(
-            engine._num_layers * 2 * engine._num_heads
-            * engine.page_size * engine._head_dim * payload_itemsize
-            + scale_bytes)
+            per_token * engine.page_size * payload_itemsize + scale_bytes)
         self._pool_bytes = self._page_kv_bytes * self._pool_pages
         self._weight_bytes: Optional[float] = None
         self._n_params: Optional[float] = None

@@ -1,0 +1,230 @@
+"""Attention over a paged LATENT cache: one row of ``width`` numbers per
+token per layer, shared by every query head, whose first ``value_width``
+lanes are also the value (multi-head latent attention in its absorbed
+form: queries are projected into the latent space before the scores, the
+output back out of it after the weighted sum, so the per-head keys and
+values are never materialised).
+
+Pool layout ``[P, page, lanes]`` — no head axis and no V pool; ``lanes``
+is the cached ``width`` rounded up to whole 128-lane tiles, the rest
+zeros (inference/cache_layout.py ``latent`` says why).  Queries are
+zero-padded to the same lanes, so the padding adds nothing to a score.
+Three pieces, composed by
+``latent_ragged_attention`` the way ``ragged_paged_attention`` composes
+its own:
+
+* ``write_latent_pages`` — the page-granular read-modify-write of
+  ``paged_attention._write_token_spans`` (the pool seen with a head axis
+  of one; the reshape is a bitcast).
+* ``latent_paged_decode`` — the Pallas kernel for decode rows: all query
+  heads of a row against its pages, ``pages_per_step`` pages a grid step
+  (the same pool passed that many times, each with its own page index
+  from the scalar-prefetched table), both contractions on the MXU,
+  online softmax in VMEM scratch.  Pages past a row's length repeat the
+  row's last valid block index, so the pipeline issues no copy for them.
+* ``latent_chunk_attention`` — chunk rows (``query_len > 1``) against
+  their gathered window, one row at a time under a ``lax.cond``: a row
+  that carries no chunk costs nothing, and the widest temporary is one
+  row's ``[heads, chunk, window]`` scores, never ``[rows, window, heads,
+  key+value]`` expanded keys and values.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret as _interpret
+from .paged_attention import NEG_INF, _write_token_spans
+
+PAGES_PER_STEP = 8
+
+
+def pad_lanes(x, lanes: int):
+    """Zero-pad the last axis to ``lanes``."""
+    extra = lanes - x.shape[-1]
+    if extra == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
+def write_latent_pages(pages, block_tables, rows, context_lens, query_lens):
+    """Store row ``b``'s latent vectors ``rows[b, :query_lens[b]]``
+    ([B, C, width]) at absolute positions ``context_lens[b] + i`` of the
+    pool ``[P, page, lanes]``; pad positions are written nowhere."""
+    rows = pad_lanes(rows.astype(pages.dtype), pages.shape[-1])
+    out = _write_token_spans(pages[:, None], block_tables,
+                             rows[:, :, None, :], context_lens, query_lens)
+    return out[:, 0]
+
+
+# ------------------------------------------------------------------ decode
+
+def _decode_kernel(lengths_ref, tables_ref, q_ref, *rest, scale, page_size,
+                   pages_per_step, value_width, steps):
+    page_refs = rest[:pages_per_step]
+    o_ref, m_ref, l_ref, acc_ref = rest[pages_per_step:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    span = pages_per_step * page_size
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    length = lengths_ref[b]
+
+    @pl.when(j * span < length)
+    def _():
+        q = q_ref[0]                                      # [H, lanes]
+        kv = jnp.concatenate([r[0] for r in page_refs], axis=0)
+        v = kv[:, :value_width]                           # [span, value]
+        nt = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(q, kv, nt,
+                                preferred_element_type=jnp.float32)
+        s = s * scale                                     # [H, span]
+        slot = j * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(slot < length, s, NEG_INF)
+        m_prev, l_prev = m_ref[:], l_ref[:]               # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(j == steps - 1)
+    def _():
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-20)
+                    ).astype(o_ref.dtype)
+
+
+def latent_paged_decode(q, pages, block_tables, lengths, scale,
+                        value_width, pages_per_step=PAGES_PER_STEP,
+                        interpret=None):
+    """One decode step of absorbed latent attention.
+
+    q            [B, H, width]   — queries already in the latent space
+                                   (latent part ‖ rotated position part),
+                                   ``width <= lanes``
+    pages        [P, page, lanes]
+    block_tables [B, max_pages] int32
+    lengths      [B] int32       — tokens in cache, the current included;
+                                   0 skips the row (its output is zero)
+    → [B, H, value_width] in q's dtype
+    """
+    interpret = _interpret() if interpret is None else interpret
+    b, h, width = q.shape
+    _, page_size, lanes = pages.shape
+    assert 0 < value_width < width <= lanes, (q.shape, pages.shape)
+    q = pad_lanes(q, lanes)
+    max_pages = block_tables.shape[1]
+    g = max(1, min(int(pages_per_step), max_pages))
+    steps = -(-max_pages // g)
+    lengths = lengths.astype(jnp.int32)
+    block_tables = block_tables.astype(jnp.int32)
+
+    def q_map(b_, j_, lengths_s, tables_s):
+        return (b_, 0, 0)
+
+    def page_map(i):
+        def index(b_, j_, lengths_s, tables_s):
+            last = jnp.clip(lengths_s[b_] - 1, 0,
+                            max_pages * page_size - 1) // page_size
+            return (tables_s[b_, jnp.minimum(j_ * g + i, last)], 0, 0)
+        return index
+
+    kernel = functools.partial(
+        _decode_kernel, scale=float(scale), page_size=page_size,
+        pages_per_step=g, value_width=int(value_width), steps=steps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, steps),
+        in_specs=[pl.BlockSpec((1, h, lanes), q_map)] + [
+            pl.BlockSpec((1, page_size, lanes), page_map(i))
+            for i in range(g)],
+        out_specs=pl.BlockSpec((1, h, value_width), q_map),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, value_width), jnp.float32)],
+    )
+    fn = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="latent_paged_decode",
+    )
+    return fn(lengths, block_tables, q.astype(pages.dtype), *([pages] * g))
+
+
+# ------------------------------------------------------------------- chunk
+
+def latent_chunk_attention(q, pages, block_tables, context_lens, query_lens,
+                           scale, value_width):
+    """Chunk rows: queries ``q[b, i]`` at absolute positions
+    ``context_lens[b] + i`` over row ``b``'s whole table window under an
+    absolute-position causal mask, in the absorbed form.  Rows with
+    ``query_lens <= 1`` return zeros and cost nothing.
+
+    q [B, C, H, width] → [B, C, H, value_width] in q's dtype."""
+    b, c, h, _ = q.shape
+    page, lanes = pages.shape[1:]
+    window = block_tables.shape[1] * page
+    slots = jnp.arange(window, dtype=jnp.int32)
+    q = pad_lanes(q, lanes)
+
+    def attend(qr, table, ctx):
+        kw = pages[table].reshape(window, lanes)           # [window, lanes]
+        s = jnp.einsum("chw,kw->hck", qr.astype(kw.dtype), kw,
+                       preferred_element_type=jnp.float32) * scale
+        pos = ctx + jnp.arange(c, dtype=jnp.int32)
+        s = jnp.where(slots[None, None, :] <= pos[None, :, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        # one plain [heads x chunk, window] @ [window, value] product
+        o = jnp.matmul(p.astype(kw.dtype).reshape(h * c, window),
+                       kw[:, :value_width],
+                       preferred_element_type=jnp.float32)
+        return o.reshape(h, c, value_width).transpose(1, 0, 2).astype(
+            q.dtype)
+
+    def row(args):
+        qr, table, ctx, qlen = args
+        return jax.lax.cond(
+            qlen > 1, attend,
+            lambda *_: jnp.zeros((c, h, value_width), q.dtype),
+            qr, table, ctx)
+
+    return jax.lax.map(row, (q, block_tables.astype(jnp.int32),
+                             context_lens.astype(jnp.int32),
+                             query_lens.astype(jnp.int32)))
+
+
+def latent_ragged_attention(q, pages, block_tables, context_lens,
+                            query_lens, scale, value_width):
+    """The mixed step's attention over latent pages the step has just
+    written: decode rows (``query_lens == 1``) through the kernel, chunk
+    rows through the per-row composition, inactive rows nowhere.
+
+    q [B, C, H, width] → [B, C, H, value_width]; positions past a row's
+    ``query_lens`` hold zeros or garbage the caller never reads."""
+    is_decode = query_lens == 1
+    # the Pallas call stays outside the scope: the TPU compiler names a
+    # Mosaic custom call after its innermost scope, and readers key on
+    # the kernel's own name
+    dec = latent_paged_decode(
+        q[:, 0], pages, block_tables,
+        jnp.where(is_decode, context_lens + 1, 0), scale, value_width)
+    with jax.named_scope("latent_attention"):
+        out = latent_chunk_attention(q, pages, block_tables, context_lens,
+                                     query_lens, scale, value_width)
+        first = jnp.where(is_decode[:, None, None], dec.astype(out.dtype),
+                          out[:, 0])
+        return out.at[:, 0].set(first)

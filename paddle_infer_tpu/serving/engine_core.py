@@ -132,11 +132,26 @@ class EngineCore:
         # incompatible combos (quantized wire + speculation/prefix cache)
         # die here with an actionable message, never mid-step; also catch
         # an engine whose mesh/quantization disagrees with the config
-        from .sharded import (ShardedConfigError, validate_kv_quant_combo,
+        from .sharded import (ShardedConfigError, validate_cache_layout,
+                              validate_kv_quant_combo,
                               validate_moe_quant_combo,
                               validate_serving_config)
         from .moe import (moe_serving_info, prepare_moe_serving,
                           serving_capacity)
+        from .moe.dropless import dropless_moe_info
+
+        # what the model's cache kinds cannot do yet dies here, before
+        # any pool is sized (inference/cache_layout.py)
+        validate_cache_layout(
+            getattr(engine, "_cache_layout", None),
+            mp=int(getattr(serving_mesh, "mp", 1) or 1),
+            kv_dtype=getattr(engine, "_kv_dtype", None),
+            speculate=speculate, kv_host_pages=int(kv_host_pages),
+            ragged=ragged)
+        # dropless expert layers (serving/moe/dropless.py) need no
+        # conversion and no capacity: the mixed step only threads them
+        # its valid mask and returns their counters
+        self._dropless = dropless_moe_info(engine._model)
 
         # KV-pool quantization rides in on the ENGINE (it owns the
         # pools); the kwarg here is a config affordance that must agree
@@ -400,7 +415,6 @@ class EngineCore:
         # the cost model (observability/steplog.py; GET /steps)
         self.steplog = steplog if steplog is not None else StepLog()
         self._cost_model = StepCostModel(engine, self._pool)
-
         # host-RAM KV tier (serving/kv_tier/): a page-accounted host
         # arena under the device pool.  Overload parks whole in-flight
         # rows (the handoff serialization retargeted at a host buffer)
@@ -708,6 +722,10 @@ class EngineCore:
                      "free_blocks": int(free),
                      "used_blocks": int(total - free),
                      "headroom_pages": int(self._headroom_pages),
+                     # only once a step has made the pools: a scrape
+                     # must not be what allocates them
+                     **(self._cache_bytes_fields()
+                        if self._engine._k_pages is not None else {}),
                      "occupancy": (total - free) / total if total else 0.0},
             prefix_cache=(self._prefix_cache.stats_snapshot()
                           if self._prefix_cache is not None else None),
@@ -1003,6 +1021,18 @@ class EngineCore:
         engine keeps beside the cost analysis (a dict look-up per step)."""
         mem = self._engine.program_memory(key)
         return int(mem["temp"]) if mem else 0
+
+    def _cache_bytes_fields(self) -> dict:
+        """The allocated pools' bytes per token of capacity, and of those
+        what the ``latent`` layers cache (lane padding taken off): the
+        engine reads them from the arrays once — on the step record
+        because that is what a reader of the StepLog is handed, and under
+        ``kv_pool`` in the snapshot."""
+        eng = self._engine
+        return dict(
+            cache_bytes_per_token=eng.cache_bytes_per_token(),
+            latent_cache_bytes_per_token=eng.cache_bytes_per_token(
+                "latent", padding=False))
 
     def _iteration(self, now: float) -> bool:
         progressed = False
@@ -1701,6 +1731,9 @@ class EngineCore:
             # the key — still ONE executable per core, warmed once
             mkey = mkey + (W,)
         moe = self._moe
+        # one switch: the step threads its valid mask to whatever expert
+        # layers the model has and returns what they counted
+        moe_stats = moe is not None or self._dropless is not None
         if moe is not None:
             # the [E, C_cap] routing buffers are deployment config, so
             # they join the key — routing changes data, never shapes
@@ -1863,6 +1896,7 @@ class EngineCore:
         cx = np.where(ql > 0, ctx, 0).astype(np.int64)
         attended_keys_step = int((ql * cx + ql * (ql + 1) // 2).sum())
         resident_tokens_step = int((cx + ql).sum())
+        decode_keys_step = int((cx[ql == 1] + 1).sum())
         h2d_bytes_step = 0
         clog = get_compile_log()
         c0 = clog.count()
@@ -1890,11 +1924,10 @@ class EngineCore:
                     mkey, lambda: build_mixed_step(eng, b, C,
                                                    self._max_pages,
                                                    spec_window=W,
-                                                   moe_stats=moe
-                                                   is not None,
+                                                   moe_stats=moe_stats,
                                                    grammar=grammar_on),
                     *step_args)
-                if moe is not None:
+                if moe_stats:
                     tok, n_emit, fin_out, *moe_out = res
                 else:
                     tok, n_emit, fin_out = res
@@ -1902,11 +1935,10 @@ class EngineCore:
                 res = eng.run_paged_program(
                     mkey, lambda: build_mixed_step(eng, b, C,
                                                    self._max_pages,
-                                                   moe_stats=moe
-                                                   is not None,
+                                                   moe_stats=moe_stats,
                                                    grammar=grammar_on),
                     *step_args)
-                if moe is not None:
+                if moe_stats:
                     tok, fin_out, *moe_out = res
                 else:
                     tok, fin_out = res
@@ -1964,7 +1996,15 @@ class EngineCore:
             # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
             n_emit = np.asarray(n_emit)
         moe_kw = {}
-        if moe_out:
+        if moe_out and moe is None:
+            # dropless layers' four counters ride the same per-step sync
+            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
+            counters = [int(x) for x in np.asarray(moe_out)]
+            moe_kw = dict(zip(("moe_assignments_total",
+                               "moe_assignments_held",
+                               "moe_held_expert_max",
+                               "moe_experts_touched"), counters))
+        elif moe_out:
             # moe routing stats ride the same per-step sync: the step's
             # outputs are already host-bound for emission above
             # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
@@ -2123,6 +2163,7 @@ class EngineCore:
             **self._phase_fields(clock, end),
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
+            decode_keys=decode_keys_step,
             h2d_bytes=h2d_bytes_step,
             program_temp_bytes=self._program_temp_bytes(mkey),
             active_rows=len(active),
@@ -2153,7 +2194,7 @@ class EngineCore:
                         if self._kv_tier is not None else 0),
             grammar_rows=grammar_rows_step,
             masked_tokens=masked_tokens_step,
-            **moe_kw)
+            **moe_kw, **self._cache_bytes_fields())
         if self._recovery is not None:
             self._recovery.on_step_ok()
         # chunk-boundary hook: fired by the stepping thread itself (still

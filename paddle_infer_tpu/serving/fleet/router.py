@@ -61,6 +61,15 @@ class FleetRouter:
         names = [h.name for h in replicas]
         if len(set(names)) != len(names):
             raise ValueError(f"duplicate replica names: {names}")
+        from ..sharded import validate_cache_layout
+
+        # a dedicated prefill role hands its rows' KV to a decode
+        # replica; what cannot be handed off is refused here
+        handoff = any(h.role == ReplicaRole.PREFILL for h in replicas)
+        for h in replicas:
+            validate_cache_layout(
+                getattr(h.core._engine, "_cache_layout", None),
+                handoff=handoff)
         self._replicas: List[ReplicaHandle] = list(replicas)
         self._by_name: Dict[str, ReplicaHandle] = {
             h.name: h for h in replicas}

@@ -35,30 +35,49 @@ class MoEStatsCollector:
     mask of real (non-pad) token slots; each MoE layer appends one
     (routed [E] i32, dropped i32, aux f32) triple."""
 
-    def __init__(self, valid):
+    def __init__(self, valid, max_valid=None):
         self.valid = valid
+        # static bound on how many slots of ``valid`` can be set (the
+        # mixed step's token budget); None = every slot may be
+        self.max_valid = max_valid
         self.routed = []
         self.dropped = []
         self.aux = []
+        self.dropless = []
 
     def note(self, routed, dropped, aux):
         self.routed.append(_raw(routed))
         self.dropped.append(_raw(dropped))
         self.aux.append(_raw(aux))
 
+    def note_dropless(self, total, held, held_max, touched):
+        """One dropless expert layer's counts over the valid slots:
+        assignments made, assignments to experts held here, the largest
+        count any held expert got, held experts with at least one."""
+        self.dropless.append((total, held, held_max, touched))
+
     def totals(self):
-        """Sum the per-layer notes into the three program outputs:
-        routed [E] i32 (kept expert assignments over valid slots, summed
-        across layers), dropped i32 (capacity-overflow assignments over
-        valid slots, summed across layers), aux f32 (load-balancing
-        loss, averaged across layers — a gauge, not a counter)."""
+        """Sum the per-layer notes into the program's outputs.  Capacity
+        layers give three: routed [E] i32 (kept expert assignments over
+        valid slots, summed across layers), dropped i32 (capacity-overflow
+        assignments over valid slots, summed across layers), aux f32
+        (load-balancing loss, averaged across layers — a gauge, not a
+        counter).  Dropless layers give four i32: assignments and held
+        assignments summed over layers, the largest held-expert count of
+        any layer, touched held experts summed over layers."""
         import jax.numpy as jnp
 
+        if self.dropless:
+            total, held, held_max, touched = zip(*self.dropless)
+            i32 = lambda x: jnp.asarray(x).astype(jnp.int32)
+            return (i32(sum(total)), i32(sum(held)),
+                    i32(jnp.max(jnp.stack(held_max))), i32(sum(touched)))
         if not self.routed:
             raise RuntimeError(
                 "moe_stats collection ran but no serving MoE layer "
                 "noted stats — the model was not converted with "
-                "prepare_moe_serving (or has no MoE FFN)")
+                "prepare_moe_serving (or has no MoE FFN) and has no "
+                "dropless expert layer")
         routed = self.routed[0]
         for r in self.routed[1:]:
             routed = routed + r
@@ -77,13 +96,14 @@ class collect:
     """Context manager installing a :class:`MoEStatsCollector` for the
     current thread; nests (the previous collector is restored)."""
 
-    def __init__(self, valid):
+    def __init__(self, valid, max_valid=None):
         self._valid = valid
+        self._max_valid = max_valid
         self._prev = None
 
     def __enter__(self) -> MoEStatsCollector:
         self._prev = getattr(_TLS, "active", None)
-        _TLS.active = MoEStatsCollector(self._valid)
+        _TLS.active = MoEStatsCollector(self._valid, self._max_valid)
         return _TLS.active
 
     def __exit__(self, *exc):

@@ -89,6 +89,21 @@ def _pick_rows(proc, samp, steps, keys):
     return jnp.where(samp["do_sample"], sampled, greedy).astype(jnp.int32)
 
 
+def _layer_caches(engine, k_pages, v_pages, *rest):
+    """One cache tuple a layer, through the engine's cache layout
+    (inference/cache_layout.py): a ``kv`` layer's two pools or a
+    ``latent`` layer's one, then the step's shared arrays."""
+    return [c.step_cache(k_pages[i], v_pages[i], *rest)
+            for i, c in enumerate(engine._cache_layout)]
+
+
+def _layer_pools(engine, caches):
+    """The ``(k_pages, v_pages)`` lists back out of the layers' returned
+    tuples (``None`` in the second list for a one-pool layer)."""
+    pools = [c.pools_of(x) for c, x in zip(engine._cache_layout, caches)]
+    return [p[0] for p in pools], [p[1] for p in pools]
+
+
 def build_mixed_step(engine, max_batch, token_budget, max_pages,
                      spec_window=1, moe_stats=False, grammar=False):
     """THE ragged serving executable: one launch per scheduler step,
@@ -163,6 +178,8 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     (serving/moe/stats.py) and returns three extra outputs BEFORE the
     pools — ``(…, moe_routed[E] i32, moe_dropped i32, moe_aux f32, …)``
     — so capacity-overflow drops are surfaced per step, never silent.
+    A model of dropless expert layers (serving/moe/dropless.py) returns
+    its four counters there instead (``MoEStatsCollector.totals``).
     The stats ride the same trace (data outputs, no shape impact), so
     the one-executable invariant is untouched.
 
@@ -205,7 +222,8 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             from .moe import stats as moe_stats_mod
 
             vmask = (i2d < qlens[:, None]).reshape(-1)
-            with moe_stats_mod.collect(vmask) as col:
+            # at most the token budget of the b*C slots is ever valid
+            with moe_stats_mod.collect(vmask, max_valid=C) as col:
                 logits, caches = engine._model_step(params, ids, pos2d,
                                                     None, caches)
             return logits, caches, col.totals()
@@ -213,8 +231,8 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     def run(params, ids, qlens, ctx, steps0, sample_now, adapter_slots,
             tables, samp, keys, gmask, scratch, k_pages, v_pages):
         b = ids.shape[0]
-        caches = [(k_pages[i], v_pages[i], tables, ctx, qlens, scratch)
-                  for i in range(L)]
+        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
+                               qlens, scratch)
         i2d = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
                                (b, C))
         # pad positions pin to 0: a replayed decode row near the window
@@ -239,8 +257,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             fin = jnp.logical_and(
                 sample_now,
                 jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"]))
-        return (tok, fin, *moe_out,
-                [c[0] for c in caches], [c[1] for c in caches])
+        return (tok, fin, *moe_out, *_layer_pools(engine, caches))
 
     W = int(spec_window)
     if W <= 1:
@@ -263,8 +280,8 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
                  scratch, k_pages, v_pages):
         b = ids.shape[0]
         spec2d = jnp.broadcast_to(spec[:, None], (b, W))
-        caches = [(k_pages[i], v_pages[i], tables, ctx, qlens, scratch,
-                   spec2d) for i in range(L)]
+        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
+                               qlens, scratch, spec2d)
         i2d = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
                                (b, C))
         pos2d = jnp.where(i2d < qlens[:, None], ctx[:, None] + i2d, 0)
@@ -355,8 +372,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             out, pad).astype(jnp.int32)
         n_emit = jnp.where(sample_now, r, 0).astype(jnp.int32)
         fin = jnp.logical_and(sample_now, any_eos)
-        return (out, n_emit, fin, *moe_out,
-                [c[0] for c in caches], [c[1] for c in caches])
+        return (out, n_emit, fin, *moe_out, *_layer_pools(engine, caches))
 
     if grammar:
         return jax.jit(run_spec, donate_argnums=(13, 14))
@@ -462,8 +478,12 @@ def build_page_copy(engine):
     ``run(params, src[1], dst[1], k_pages, v_pages)`` →
     ``(src, k_pages, v_pages)``; pools are donated.  One executable per
     pool shape, reused for every CoW.  Quantized pools copy the page's
-    scale row along with its payload — the copy stays bitwise."""
+    scale row along with its payload — the copy stays bitwise.  A page
+    is the pool's leading index in every cache kind (inference/
+    cache_layout.py), so a latent layer's one pool copies the same way."""
     def copy(pages, src, dst):
+        if pages is None:           # a one-pool layer's second entry
+            return None
         if isinstance(pages, tuple):
             payload, scales = pages
             return (payload.at[dst].set(payload[src]),

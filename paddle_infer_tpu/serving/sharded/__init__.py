@@ -22,14 +22,16 @@ error for ~4x fewer mp interconnect bytes (see
 ``parallel.collective.quantization_error_bound``).
 """
 from .mesh import (ServingMesh, ShardedConfigError, build_sharded_engine,
-                   sharding_snapshot, validate_kv_quant_combo,
-                   validate_moe_quant_combo, validate_serving_config)
+                   sharding_snapshot, validate_cache_layout,
+                   validate_kv_quant_combo, validate_moe_quant_combo,
+                   validate_serving_config)
 
 __all__ = [
     "ServingMesh",
     "ShardedConfigError",
     "build_sharded_engine",
     "sharding_snapshot",
+    "validate_cache_layout",
     "validate_kv_quant_combo",
     "validate_moe_quant_combo",
     "validate_serving_config",

@@ -144,6 +144,48 @@ def validate_moe_quant_combo(moe_quant: Optional[str], *,
             "spec_accept_threshold=0.1) or serve weight-only experts")
 
 
+def validate_cache_layout(cache_layout, *, mp: int = 1,
+                          kv_dtype: Optional[str] = None,
+                          speculate: bool = False,
+                          kv_host_pages: int = 0, handoff: bool = False,
+                          ragged: bool = True):
+    """What a ``latent`` cache layer (inference/cache_layout.py: one
+    ``[P, page, lanes]`` pool, no head axis, no V pool) cannot do yet —
+    refused here, at start-up, one mechanism a sentence.  Silent for
+    layouts of ``kv`` layers only."""
+    if not cache_layout or all(c.kind != "latent" for c in cache_layout):
+        return
+    if mp > 1:
+        raise ShardedConfigError(
+            f"mp={mp} splits the page pool over its head axis; a latent "
+            "cache layer has no head axis to split — serve it with mp=1")
+    if kv_dtype is not None:
+        raise ShardedConfigError(
+            f"kv_dtype={kv_dtype!r} scales each page per head; a latent "
+            "cache layer has no heads to scale over — serve it with "
+            "full-precision pages")
+    if speculate:
+        raise ShardedConfigError(
+            "speculative decoding verifies drafts through the per-head "
+            "decode kernel's verify lanes; the latent decode kernel has "
+            "none — drop speculate")
+    if int(kv_host_pages) > 0:
+        raise ShardedConfigError(
+            "the host KV tier parks and resumes a row through its key "
+            "and value pools; a latent cache layer has one pool — drop "
+            "kv_host_pages")
+    if handoff:
+        raise ShardedConfigError(
+            "KV handoff between replicas serialises a row's key and "
+            "value pools; a latent cache layer has one pool — serve it "
+            "without a dedicated prefill role")
+    if not ragged:
+        raise ShardedConfigError(
+            "ragged=False runs the per-plen prefill and fused decode "
+            "programs, which know key/value pools only; a latent cache "
+            "layer is served through the mixed step")
+
+
 def validate_serving_config(cfg: ServingMesh, *, speculate: bool = False,
                             enable_prefix_cache: bool = False,
                             max_batch: Optional[int] = None,
@@ -152,9 +194,14 @@ def validate_serving_config(cfg: ServingMesh, *, speculate: bool = False,
                             kv_dtype: Optional[str] = None,
                             spec_accept_threshold: Optional[float] = None,
                             num_experts: Optional[int] = None,
-                            moe_quant: Optional[str] = None):
+                            moe_quant: Optional[str] = None,
+                            cache_layout=None, kv_host_pages: int = 0,
+                            handoff: bool = False):
     """Raise :class:`ShardedConfigError` for combos that would serve
     incorrectly or crash mid-step; silent on valid configs."""
+    validate_cache_layout(cache_layout, mp=cfg.mp, kv_dtype=kv_dtype,
+                          speculate=speculate, kv_host_pages=kv_host_pages,
+                          handoff=handoff)
     validate_kv_quant_combo(kv_dtype, speculate=speculate,
                             enable_prefix_cache=enable_prefix_cache,
                             spec_accept_threshold=spec_accept_threshold)
@@ -237,9 +284,12 @@ def build_sharded_engine(model, cfg: ServingMesh, *, page_size: int = 16,
 
     avail = len(list(devices) if devices is not None else jax.devices())
     moe = moe_serving_info(model)
+    from ...inference.cache_layout import layout_of
+
     validate_serving_config(
         cfg, num_heads=model.config.num_attention_heads,
         available_devices=avail, kv_dtype=kv_dtype,
+        cache_layout=layout_of(model),
         num_experts=moe["num_experts"] if moe else None,
         moe_quant=moe["algo"] if moe else None)
     mesh = cfg.build(devices) if cfg.n_devices > 1 else None

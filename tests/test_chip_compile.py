@@ -220,3 +220,100 @@ def test_mixed_step_layer_has_no_pool_sized_copy(spec, monkeypatch):
     ).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     assert not _pool_relayouts(text)
+
+
+# ------------------------------------------ latent pages and grouped experts
+# axk1-ep16.ragchat's widths (benchmarks/configs/a.x-k1-ep16-d7.json): 64
+# heads against one 512 + 64 row a token (640 lanes in the pool), the pool of
+# 16 rows x 4096 tokens + the scratch page; 12 experts of 7168 x 2048 over
+# 64 x 8 assignment rows
+LAT_POOL, LAT_PAGES, LAT_B = (4097, PAGE, 640), 256, 16
+
+
+def _latent_pool_copies(text):
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(r"= \w+\[4097,(1,)?16,640\]\S* (copy|transpose)\(",
+                         line)]
+
+
+def test_a_latent_pool_at_its_cached_width_would_not_be_row_major(spec):
+    """Why the pool states its lanes: for a last dimension that is no
+    multiple of 128 the TPU's own layout makes the page index the fastest
+    dimension, and a donated write then transposes the pool in and out."""
+    from paddle_infer_tpu.ops.pallas import latent_attention as LA
+
+    def layouts(width):
+        text = jax.jit(LA.write_latent_pages, donate_argnums=0).lower(
+            spec((4097, PAGE, width), jnp.bfloat16),
+            spec((LAT_B, LAT_PAGES), jnp.int32),
+            spec((LAT_B, CELL_CHUNK, 576), jnp.bfloat16),
+            spec((LAT_B,), jnp.int32), spec((LAT_B,), jnp.int32)
+        ).compile().as_text()
+        root = [ln for ln in text.splitlines() if "ROOT" in ln][-1]
+        return re.search(r"bf16\[4097,16,\d+\]\{([\d,]+)", root).group(1)
+
+    assert layouts(576) == "0,2,1"
+    assert layouts(640) == "2,1,0"
+
+
+def test_latent_decode_and_grouped_matmul_compile(spec, monkeypatch):
+    from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
+    from paddle_infer_tpu.ops.pallas import latent_attention as LA
+
+    monkeypatch.setattr(LA, "_interpret", lambda: False)
+    monkeypatch.setattr(GM, "_interpret", lambda: False)
+    i32 = jnp.int32
+    _compile(lambda q, pool, t, n: LA.latent_paged_decode(
+        q, pool, t, n, 0.13, 512),
+        spec((LAT_B, 64, 576), jnp.bfloat16), spec(LAT_POOL, jnp.bfloat16),
+        spec((LAT_B, LAT_PAGES), i32), spec((LAT_B,), i32))
+    for k, n in ((7168, 2048), (2048, 7168)):
+        _compile(GM.grouped_matmul, spec((512, k), jnp.bfloat16),
+                 spec((12, k, n), jnp.bfloat16), spec((12,), i32))
+
+
+def test_latent_mixed_step_keeps_its_pool_in_place(spec, monkeypatch):
+    """A dense and an expert layer of the served mixed step at the cell's
+    widths, pools donated: nothing copies or transposes anything of the
+    pool's shape, and both kernels are in the step under their own
+    names."""
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.latent_moe import (LatentMoEConfig,
+                                                    LatentMoEForCausalLM)
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
+    from paddle_infer_tpu.ops.pallas import latent_attention as LA
+    from paddle_infer_tpu.serving.programs import build_mixed_step
+
+    monkeypatch.setattr(LA, "_interpret", lambda: False)
+    monkeypatch.setattr(GM, "_interpret", lambda: False)
+    cfg = LatentMoEConfig(
+        vocab_size=20480, num_hidden_layers=2, n_routed_experts=12,
+        n_routed_experts_published=192, rope_scaling=dict(
+            beta_fast=32, beta_slow=1, factor=32, mscale=1, mscale_all_dim=1,
+            original_max_position_embeddings=4096, type="yarn"))
+    with abstract_parameters():
+        model = LatentMoEForCausalLM(cfg)
+    engine = PagedGenerationEngine(model, page_size=PAGE,
+                                   cache_dtype=jnp.bfloat16)
+    run = build_mixed_step(engine, LAT_B, CELL_CHUNK, LAT_PAGES,
+                           moe_stats=True)
+    b, i32, f32 = LAT_B, jnp.int32, jnp.float32
+    rows = lambda dtype: spec((b,), dtype)
+    samp = {"temperature": rows(f32), "top_k": rows(i32),
+            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
+            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
+    params = {n: spec(a.shape, jnp.bfloat16)
+              for n, a in engine._params.items()}
+    pools = [spec(LAT_POOL, jnp.bfloat16)] * 2
+    compiled = run.lower(
+        params, spec((b, CELL_CHUNK), i32), rows(i32), rows(i32), rows(i32),
+        rows(jnp.bool_), rows(i32), spec((b, LAT_PAGES), i32), samp,
+        spec((b, 2), jnp.uint32), spec((), i32), pools, [None, None]
+    ).compile()
+    text = compiled.as_text()
+    assert "latent_paged_decode" in text and "moe_grouped_matmul" in text
+    assert not _latent_pool_copies(text)
+    # the widest temporary is one row's [heads, chunk, window] scores, not
+    # every row's window of expanded keys and values (2 GB a layer)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
