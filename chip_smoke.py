@@ -644,8 +644,10 @@ def child_kernels(args):
     """Each Pallas entry the serve and train paths (and serving's
     options) can reach, compiled by Mosaic — never interpreted — at the
     phases' widths, against ``prefix_prefill_attention`` (the XLA gather
-    composition) and ``_xla_sdpa``."""
+    composition), ``_ragged_reference`` and ``_xla_sdpa``."""
     device, spec = _child_start(args, "kernels")
+    import functools
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -716,12 +718,59 @@ def child_kernels(args):
                   compiled(PA.paged_attention_verify, qw, kpool, vpool,
                            tables, lens_w),
                   ref(qw, kpool, vpool, tables, ctx))
-            got = compiled(RPA._ragged_kernel_call, qc, kpool, vpool,
-                           tables, ctx, qlens)
-            want = ref(qc, kpool, vpool, tables, ctx)
+            # the served entry point against the plain composition, on
+            # decode, chunk, inactive and verify rows (bf16 results: one
+            # ulp is 4e-3 of the value)
             keep = jnp.asarray(valid)[:, :, None, None]
-            close(f"_ragged_kernel_call[{tag}]",
-                  jnp.where(keep, got, 0), jnp.where(keep, want, 0))
+            for name, kw in (("", {}), ("+verify", dict(
+                    verify_rows=jnp.asarray(qlens_np > w),
+                    verify_window=w))):
+                got = compiled(
+                    functools.partial(RPA.ragged_paged_attention, **kw),
+                    qc, kpool, vpool, tables, ctx, qlens)
+                want = jax.jit(functools.partial(
+                    RPA._ragged_reference, **kw))(
+                    qc, kpool, vpool, tables, ctx, qlens)
+                close(f"ragged_paged_attention{name}[{tag}]",
+                      jnp.where(keep, got, 0), jnp.where(keep, want, 0),
+                      tol=1e-2)
+            # float32 queries give a float32 result: the two sides then
+            # differ by the online softmax's reassociation alone, and
+            # probabilities rounded to bf16 (1e-3) would show
+            qf = qc.astype(jnp.float32)
+            close(f"ragged_paged_attention float32 queries[{tag}]",
+                  jnp.where(keep, compiled(
+                      RPA.ragged_paged_attention, qf, kpool, vpool,
+                      tables, ctx, qlens), 0),
+                  jnp.where(keep, jax.jit(RPA._ragged_reference)(
+                      qf, kpool, vpool, tables, ctx, qlens), 0),
+                  tol=1e-4)
+            # its decode rows take the decode kernel's own page step, and
+            # a position's bits do not depend on how its chunk was cut
+            served = jax.jit(RPA.ragged_paged_attention)
+            ones = jnp.ones_like(qlens)
+            assert np.array_equal(
+                np.asarray(served(qc, kpool, vpool, tables, ctx,
+                                  ones)[:, 0].astype(jnp.float32)),
+                np.asarray(PA.paged_attention_decode(
+                    qc[:, 0], kpool, vpool, tables,
+                    ctx + 1).astype(jnp.float32))), tag
+            half = c // 2
+            whole = served(qc[:1], kpool, vpool, tables[:1], ctx[:1],
+                           jnp.asarray([c], jnp.int32))[0]
+            cut = served(
+                jnp.stack([qc[0], jnp.roll(qc[0], -half, axis=0)]), kpool,
+                vpool, jnp.tile(tables[:1], (2, 1)),
+                jnp.stack([ctx[0], ctx[0] + half]),
+                jnp.asarray([half, c - half], jnp.int32))
+            assert np.array_equal(
+                np.asarray(whole.astype(jnp.float32)),
+                np.asarray(jnp.concatenate(
+                    [cut[0, :half], cut[1, :c - half]]).astype(
+                        jnp.float32))), tag
+            report[f"ragged_schedule_independent[{tag}]"] = True
+            print(f"ragged decode rows and chunk cuts bitwise [{tag}]: ok",
+                  flush=True)
 
     # ---- latent pages: the decode kernel against the chunk composition
     from paddle_infer_tpu.ops.pallas import grouped_matmul as GM

@@ -155,6 +155,50 @@ def _scale_operands(k_scales, v_scales, block_tables):
     return [spec, spec], [rows(k_scales), rows(v_scales)]
 
 
+def _decode_page_step(q_ref, k_ref, v_ref, scale_refs, j, length,
+                      m_ref, l_ref, acc_ref, scale, page_size):
+    """One page of a one-query-per-head walk: the online-softmax update of
+    ``(m, l, acc)`` ([H, 1], [H, 1], [H, D] scratch) with page ``j`` of a
+    row whose window is ``length`` tokens.  ``scale_refs`` is the row's
+    ``(ks_ref, vs_ref)`` scale blocks on an int8 pool, else None.  The
+    decode kernel's body, and the ragged kernel's for its decode rows."""
+    # Decode attention is HBM-bound, not FLOP-bound, so scores/weights
+    # are broadcast-multiply + reductions (VPU).  The head-major page
+    # layout keeps every intermediate in [H, page|D] orientation — no
+    # cross-lane relayouts, which Mosaic can't lower for these shapes.
+    q = q_ref[0].astype(jnp.float32)            # [H, D]
+    k = k_ref[0].astype(jnp.float32)            # [H, page, D]
+    v = v_ref[0].astype(jnp.float32)            # [H, page, D]
+    # scores over this page's slots: [H, page]
+    s = jnp.sum(q[:, None, :] * k, axis=2) * scale
+    if scale_refs is not None:
+        # per-(page, head) dequant: the int8 payload is what the DMA
+        # streamed, and a page's scale is constant over its slots and
+        # D, so it factors out of both reductions — [H, 1] against
+        # [H, page] / [H, D], heads on sublanes throughout
+        ks, vs = _page_scales(*scale_refs, j)
+        s = s * ks
+    # mask slots beyond the sequence length
+    slot = j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
+    s = jnp.where(slot < length, s, NEG_INF)
+
+    m_prev = m_ref[:]                            # [H, 1]
+    l_prev = l_ref[:]
+    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)                       # [H, page]
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    # weighted values: [H, D]
+    pv = jnp.sum(p[:, :, None] * v, axis=1)
+    if scale_refs is not None:
+        pv = pv * vs
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = m_new
+    l_ref[:] = l_new
+
+
 def _decode_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                    q_ref, k_ref, v_ref,          # blocks (VMEM)
                    *rest,                        # [ks, vs,] o + scratch
@@ -176,41 +220,9 @@ def _decode_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
 
     @pl.when(j * page_size < length)
     def _():
-        # Decode attention is HBM-bound, not FLOP-bound, so scores/weights
-        # are broadcast-multiply + reductions (VPU).  The head-major page
-        # layout keeps every intermediate in [H, page|D] orientation — no
-        # cross-lane relayouts, which Mosaic can't lower for these shapes.
-        q = q_ref[0].astype(jnp.float32)            # [H, D]
-        k = k_ref[0].astype(jnp.float32)            # [H, page, D]
-        v = v_ref[0].astype(jnp.float32)            # [H, page, D]
-        # scores over this page's slots: [H, page]
-        s = jnp.sum(q[:, None, :] * k, axis=2) * scale
-        if quantized:
-            # per-(page, head) dequant: the int8 payload is what the DMA
-            # streamed, and a page's scale is constant over its slots and
-            # D, so it factors out of both reductions — [H, 1] against
-            # [H, page] / [H, D], heads on sublanes throughout
-            ks, vs = _page_scales(ks_ref, vs_ref, j)
-            s = s * ks
-        # mask slots beyond the sequence length
-        slot = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(slot < length, s, NEG_INF)
-
-        m_prev = m_ref[:]                            # [H, 1]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                       # [H, page]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # weighted values: [H, D]
-        pv = jnp.sum(p[:, :, None] * v, axis=1)
-        if quantized:
-            pv = pv * vs
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = m_new
-        l_ref[:] = l_new
+        _decode_page_step(
+            q_ref, k_ref, v_ref, (ks_ref, vs_ref) if quantized else None,
+            j, length, m_ref, l_ref, acc_ref, scale, page_size)
 
     @pl.when(j == max_pages - 1)
     def _():

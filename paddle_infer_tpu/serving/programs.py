@@ -425,7 +425,7 @@ def build_prefill(engine, plen, max_pages):
 
 
 # legacy ragged=False path: the per-plen windowed family is kept as
-# the bitwise-parity anchor the ragged reference composes against
+# the parity anchor the ragged kernel's reference composes against
 # tpulint: disable-next-line=recompile-hazard -- bounded family: per-plen windowed executables are the bitwise-parity anchor
 def build_prefix_prefill(engine, plen, max_pages):
     """Windowed suffix prefill for prefix-cache hits: the row's first

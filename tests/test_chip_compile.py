@@ -100,15 +100,21 @@ def test_paged_attention_verify_compiles(spec, quantized):
              spec((B, WINDOW), jnp.int32))
 
 
+@pytest.mark.parametrize("ambient", [None, "highest"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-def test_ragged_kernel_compiles(spec, quantized):
+def test_ragged_kernel_compiles(spec, quantized, ambient):
+    """Also under a caller's ``default_matmul_precision("highest")``
+    (``chip_smoke.py`` compares under one): the kernel states its own
+    contraction precisions, and Mosaic refuses a float32 contraction of
+    bf16 operands."""
     from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
 
     pool = _pool(spec, quantized)
-    _compile(functools.partial(RPA._ragged_kernel_call, interpret=False),
-             spec((B, CHUNK, H, D), jnp.bfloat16), pool, pool,
-             spec((B, MAX_PAGES), jnp.int32), spec((B,), jnp.int32),
-             spec((B,), jnp.int32))
+    with jax.default_matmul_precision(ambient or "default"):
+        _compile(functools.partial(RPA._ragged_local, interpret=False),
+                 spec((B, CHUNK, H, D), jnp.bfloat16), pool, pool,
+                 spec((B, MAX_PAGES), jnp.int32), spec((B,), jnp.int32),
+                 spec((B,), jnp.int32))
 
 
 # (batch, seq, heads, head_dim, causal): ernie-3.0-base's training step,
@@ -181,20 +187,34 @@ def test_kv_writer_updates_the_pool_in_place(spec, quantized, chunk):
     assert not _pool_relayouts(compiled.as_text())
 
 
-def test_mixed_step_layer_has_no_pool_sized_copy(spec, monkeypatch):
+def _window_relayouts(text):
+    """Instructions shaped like every row's whole table window: the
+    ``k_pages[block_tables]`` gather (``[16 x 128 pages, 32, 16, 128]``)
+    and its transpose (``[16, 128, 16, 32, 128]``), which a step pays
+    when its attention is the dense composition and not the kernel."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(r"= \w+\[(2048,32,16,128|16,128,16,32,128)\]", line)]
+
+
+def test_mixed_step_layer_has_no_pool_or_window_sized_copy(spec,
+                                                           monkeypatch):
     """One layer of the served mixed step at the chat cell's widths, as
     ``EngineCore`` builds it (pools donated): the K and V writes reach
     the compiled step as in-place scatters, with no copy or transpose of
-    the pool around them."""
+    the pool around them, and its attention is the ragged kernel's one
+    launch: nothing gathers, transposes or scores a row's whole table
+    window."""
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_infer_tpu.nn.initializer import abstract_parameters
     from paddle_infer_tpu.ops.pallas import paged_attention as PA
+    from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
     from paddle_infer_tpu.serving.programs import build_mixed_step
 
     # the step asks the backend whether to interpret its Pallas calls,
     # and the backend here is the CPU
     monkeypatch.setattr(PA, "_interpret", lambda: False)
+    monkeypatch.setattr(RPA, "_interpret", lambda: False)
     cfg = LlamaConfig(vocab_size=32000, hidden_size=4096,
                       num_hidden_layers=1, num_attention_heads=H,
                       num_key_value_heads=8, intermediate_size=14336,
@@ -219,7 +239,23 @@ def test_mixed_step_layer_has_no_pool_sized_copy(spec, monkeypatch):
         spec((b, 2), jnp.uint32), spec((), i32), pools, pools
     ).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    assert "ragged_paged_attention" in text
     assert not _pool_relayouts(text)
+    assert not _window_relayouts(text)
+
+
+def test_the_window_shapes_are_what_the_dense_composition_compiles_to(spec):
+    """The refusal above looks for the right thing: the plain composition
+    the kernel is tested against does gather and transpose every row's
+    window at these widths."""
+    from paddle_infer_tpu.ops.pallas import paged_attention as PA
+
+    pool = spec(CELL_POOL, jnp.bfloat16)
+    text = jax.jit(PA.prefix_prefill_attention).lower(
+        spec((CELL_B, CELL_CHUNK, H, D), jnp.bfloat16), pool, pool,
+        spec((CELL_B, MAX_PAGES), jnp.int32), spec((CELL_B,), jnp.int32)
+    ).compile().as_text()
+    assert _window_relayouts(text)
 
 
 # ------------------------------------------ latent pages and grouped experts

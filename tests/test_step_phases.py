@@ -31,6 +31,22 @@ def _isolated_compile_log():
     get_compile_log().reset()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _meshless():
+    """These cores run unsharded: clear any hybrid mesh another module
+    of the same worker left behind (``fleet.init`` in
+    tests/test_zero_placement.py; ops and engines consult
+    ``topology.get_current_mesh()`` at call time), which otherwise turns
+    the one-device step into a sharded one with another step count and
+    no memory analysis."""
+    from paddle_infer_tpu.parallel import topology
+
+    prev = topology.get_current_mesh()
+    topology.set_current_mesh(None)
+    yield
+    topology.set_current_mesh(prev)
+
+
 @pytest.fixture(scope="module")
 def model():
     pit.seed(0)
