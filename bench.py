@@ -329,11 +329,6 @@ def main():
     speculative = run_section("speculative", 600,
                               lambda: _speculative_bench(on_tpu))
 
-    # ragged chunked prefill vs monolithic legacy prefill: decode ITL
-    # tail while a long prompt arrives mid-stream
-    mixed_traffic = run_section("mixed_traffic", 600,
-                                lambda: _mixed_traffic_bench(on_tpu))
-
     # prefix KV-cache: warm (shared system prompt) vs cold TTFT
     prefix_cache = run_section("prefix_cache", 420,
                                lambda: _prefix_cache_bench(on_tpu))
@@ -412,8 +407,6 @@ def main():
         result["serving"] = serving
     if speculative is not None:
         result["speculative"] = speculative
-    if mixed_traffic is not None:
-        result["mixed_traffic"] = mixed_traffic
     if prefix_cache is not None:
         result["prefix_cache"] = prefix_cache
     if quantized_kv is not None:
@@ -717,7 +710,7 @@ def _serving_bench(on_tpu: bool):
     # platforms where the scatter isn't done in place)
     core = EngineCore(
         PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-        max_batch=n_clients, decode_chunk=8,
+        max_batch=n_clients,
         max_model_len=max(lens) + max_new).start()
     try:
         for p in prompts[:2]:                 # compile-warm both plens
@@ -822,7 +815,7 @@ def _speculative_bench(on_tpu: bool):
         # per-slot page tables, so the step stays cheap.
         core = EngineCore(
             PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-            max_batch=n_clients, decode_chunk=8,
+            max_batch=n_clients,
             max_model_len=max(lens) + max_new,
             enable_prefix_cache=True,
             prefix_cache_headroom_pages=48,
@@ -939,7 +932,7 @@ def _multi_tenant_bench(on_tpu: bool):
         request_mod._rid_counter = itertools.count(50_000)
         core = EngineCore(
             PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-            max_batch=8, decode_chunk=8,
+            max_batch=8,
             max_model_len=max_plen + max_new,
             enable_prefix_cache=True,
             sched_policy=policy, slo_ttft_s=0.5, slo_itl_s=0.25)
@@ -1106,7 +1099,7 @@ def _kv_tier_bench(on_tpu: bool):
         request_mod._rid_counter = itertools.count(60_000)
         core = EngineCore(
             PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-            max_batch=4, decode_chunk=8,
+            max_batch=4,
             max_model_len=max_plen + max_new,
             enable_prefix_cache=True,
             sched_policy="slack", slo_ttft_s=0.5, slo_itl_s=0.25,
@@ -1245,7 +1238,7 @@ def _structured_bench(on_tpu: bool):
         core = EngineCore(
             PagedGenerationEngine(model, page_size=16,
                                   prompt_bucket=16),
-            max_batch=4, decode_chunk=8, max_model_len=56,
+            max_batch=4, max_model_len=56,
             grammar_vocab=vocab)
         try:
             g = GenerationConfig(max_new_tokens=40)
@@ -1422,118 +1415,6 @@ def _adapter_tenancy_bench(on_tpu: bool):
     return out
 
 
-def _mixed_traffic_bench(on_tpu: bool):
-    """Decode-ITL tail under a long-prompt arrival mid-stream: 8
-    clients stream short-prompt decodes while one long prompt (the 4k
-    arrival of the acceptance scenario, scaled to the bench model's
-    window) lands in the middle.  Run twice — ragged mixed steps with
-    chunked prefill (the prompt shares steps with decode rows under the
-    token budget) vs the legacy program family (one monolithic bucketed
-    prefill that blocks every decode row for its whole wall) — and
-    compare CLIENT-OBSERVED inter-token gaps: each client stamps the
-    arrival of every token it waits on, so the prefill stall shows up
-    as fat p99 gaps on the unchunked side.  Both sides are
-    compile-warmed first (short plen, long plen, decode/mixed step), so
-    the tail measures scheduling, not XLA."""
-    import threading
-
-    import paddle_infer_tpu as pit
-    from paddle_infer_tpu.inference import (GenerationConfig,
-                                            PagedGenerationEngine)
-    from paddle_infer_tpu.models import GPTConfig, GPTForCausalLM
-    from paddle_infer_tpu.serving import EngineCore
-
-    pit.seed(0)
-    cfg = GPTConfig(vocab_size=512, hidden_size=128,
-                    num_hidden_layers=2, num_attention_heads=4,
-                    intermediate_size=256, max_position_embeddings=256,
-                    hidden_dropout_prob=0.0,
-                    attention_probs_dropout_prob=0.0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    n_dec, max_new, short_len, long_len = 8, 40, 16, 192
-    prefill_chunk = 24
-    rng = np.random.RandomState(0)
-    shorts = [rng.randint(0, cfg.vocab_size, (short_len,)).astype(np.int32)
-              for _ in range(n_dec)]
-    long_prompt = rng.randint(0, cfg.vocab_size,
-                              (long_len,)).astype(np.int32)
-    g = GenerationConfig(max_new_tokens=max_new)
-    g_long = GenerationConfig(max_new_tokens=8)
-
-    def run(chunked: bool):
-        if chunked:
-            core = EngineCore(
-                PagedGenerationEngine(model, page_size=16),
-                max_batch=n_dec + 1, max_model_len=long_len + max_new,
-                ragged=True, token_budget=32,
-                prefill_chunk=prefill_chunk).start()
-        else:
-            core = EngineCore(
-                PagedGenerationEngine(model, page_size=16,
-                                      prompt_bucket=16),
-                max_batch=n_dec + 1, max_model_len=long_len + max_new,
-                ragged=False, decode_chunk=4).start()
-        gaps = []
-        lock = threading.Lock()
-        try:
-            core.submit(shorts[0], g)[0].result(timeout=600)   # warm
-            core.submit(long_prompt, g_long)[0].result(timeout=600)
-            started = [0] * n_dec
-
-            def client(i):
-                (r,) = core.submit(shorts[i], g)
-                prev = time.perf_counter()
-                for k in range(1, max_new + 1):
-                    try:
-                        r.wait_tokens(k, timeout=300)
-                    except TimeoutError:
-                        return
-                    now = time.perf_counter()
-                    with lock:
-                        gaps.append(now - prev)
-                    prev = now
-                    started[i] = k
-                    if r.done and r.emitted <= k:
-                        return
-
-            threads = [threading.Thread(target=client, args=(i,))
-                       for i in range(n_dec)]
-            for t in threads:
-                t.start()
-            # the long prompt lands once every stream is mid-decode
-            deadline = time.perf_counter() + 300
-            while (min(started) < max_new // 4
-                   and time.perf_counter() < deadline):
-                time.sleep(0.002)
-            long_req = core.submit(long_prompt, g_long)[0]
-            for t in threads:
-                t.join()
-            long_req.result(timeout=600)
-        finally:
-            core.close()
-        gaps.sort()
-        if not gaps:
-            return None, None
-        return (gaps[int(0.50 * (len(gaps) - 1))],
-                gaps[int(0.99 * (len(gaps) - 1))])
-
-    p50_c, p99_c = run(chunked=True)
-    p50_u, p99_u = run(chunked=False)
-    out = {
-        "decode_clients": n_dec,
-        "long_prompt_tokens": long_len,
-        "prefill_chunk": prefill_chunk,
-        "itl_p50_chunked_s": round(p50_c, 5),
-        "itl_p99_chunked_s": round(p99_c, 5),
-        "itl_p50_unchunked_s": round(p50_u, 5),
-        "itl_p99_unchunked_s": round(p99_u, 5),
-        "itl_p99_speedup_chunked": round(p99_u / p99_c, 2),
-    }
-    out["chunked_improves_itl_p99"] = bool(p99_c < p99_u)
-    return out
-
-
 def _prefix_cache_bench(on_tpu: bool):
     """Prefix-cache TTFT evidence: N clients sharing one long system
     prompt (distinct short tails), admitted one at a time so TTFT is
@@ -1570,7 +1451,7 @@ def _prefix_cache_bench(on_tpu: bool):
     g = GenerationConfig(max_new_tokens=max_new)
     core = EngineCore(
         PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-        max_batch=4, decode_chunk=8,
+        max_batch=4,
         max_model_len=sys_len + tail_len + max_new,
         enable_prefix_cache=True).start()
     try:
@@ -1854,7 +1735,7 @@ def _resilience_bench(on_tpu: bool):
             get_compile_log
         core = EngineCore(
             PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-            max_batch=4, decode_chunk=4,
+            max_batch=4,
             max_model_len=max(lens) + max_new,
             enable_prefix_cache=True, fault_plane=plane)
         sup = EngineSupervisor(core, watchdog_s=60.0,
@@ -1966,7 +1847,7 @@ def _evidence_main(out_dir: str) -> int:
     rng = np.random.RandomState(0)
     core = EngineCore(
         PagedGenerationEngine(model, page_size=16, prompt_bucket=16),
-        max_batch=4, decode_chunk=4, max_model_len=64).start()
+        max_batch=4, max_model_len=64).start()
     try:
         reqs = []
         for plen in (16, 16, 32):

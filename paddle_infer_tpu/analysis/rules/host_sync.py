@@ -9,7 +9,7 @@ pipeline against Python and shows up directly as inter-token latency.
 
 Detection is call-graph based, not textual: within every class that
 owns a scheduler entry point (``run_once`` / ``step`` /
-``_decode_step``), the rule BFS-walks ``self.<method>`` calls (and
+``decode_step``), the rule BFS-walks ``self.<method>`` calls (and
 property reads) to the full set of hot methods, then flags sync
 constructs inside them.  Intentional chunk-boundary syncs stay, with a
 ``# tpulint: disable=host-sync -- <why>`` comment — the reason is
@@ -29,8 +29,7 @@ from typing import Dict, List, Set
 
 from ..core import FileContext, Rule, dotted
 
-HOT_ROOTS = {"run_once", "_run_once_locked", "step", "_decode_step",
-             "decode_step"}
+HOT_ROOTS = {"run_once", "_run_once_locked", "step", "decode_step"}
 
 _SYNC_DOTTED = {"jax.device_get", "jax.block_until_ready"}
 # Eager collective entry points (parallel/collective.py): each call from

@@ -17,15 +17,15 @@ What counts as a cache key, statically:
   * a subscript write into a name containing ``cache`` / ``compiled``.
 
 Flagged elements: f-strings, ``len(...)``, ``str(...)`` / ``repr(...)``.
-Bare names are deliberately NOT flagged — ``plen`` is fine precisely
-because ``_plen()`` bucketed it — so the rule stays quiet on
+Bare names are deliberately NOT flagged (where a name's value comes
+from is the ``key-provenance`` rule's job), so the rule stays quiet on
 disciplined keys and loud on raw ones.
 
 Program BUILDERS are also checked: a ``def build_*`` whose signature
 takes a shape-valued parameter (``plen`` / ``batch`` / ``chunk``)
 closes one executable over every distinct value — the per-shape program
-family the ragged mixed step exists to collapse.  Legacy builders that
-are deliberately kept (behind ``ragged=False``) carry a reasoned
+family the mixed step replaced.  A bounded family that is kept on
+purpose carries a reasoned
 ``# tpulint: disable-next-line=recompile-hazard -- <why>``
 suppression.
 """

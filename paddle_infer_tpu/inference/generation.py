@@ -845,7 +845,7 @@ class PagedGenerationEngine(GenerationEngine):
         return self._cache_bytes_measured[key]
 
     def _refuse_latent(self, what: str):
-        """The legacy per-call programs walk ``[P, h, page, d]`` pools;
+        """The offline per-call programs walk ``[P, h, page, d]`` pools;
         a latent layer is served through the mixed step only."""
         from .cache_layout import has_latent
 
@@ -916,9 +916,7 @@ class PagedGenerationEngine(GenerationEngine):
         if is_compile:
             sigs.add(sig)
             tag = _key_tag(key)
-            site = ("serving-decode" if tag in ("serve-step",)
-                    else "serving-prefill"
-                    if tag in ("serve-prefill", "serve-prefill-px")
+            site = ("serving-decode" if tag == "serve-step"
                     else "serving-page-copy" if tag == "serve-page-copy"
                     else f"serving-{tag}")
             get_compile_log().record(site, key, sig,

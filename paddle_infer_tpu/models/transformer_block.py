@@ -176,13 +176,6 @@ class ParallelSelfAttention(Layer):
         at its per-row position and walk the page table with the Pallas
         decode kernel.
 
-        A FIVE-element cache (trailing marker, see
-        serving/programs.build_prefix_prefill) selects the windowed
-        suffix-prefill variant: the chunk starts at position
-        ``positions[b]`` (cached-prefix length, possibly mid-page) and
-        attends over the row's whole gathered page window so cached
-        prefix KV participates — the prefix-cache warm path.
-
         A SIX-element cache ``(k_pages, v_pages, tables, positions,
         query_lens, scratch_page)`` selects the ragged mixed-batch
         variant (serving/programs.build_mixed_step): every row carries
@@ -239,16 +232,7 @@ class ParallelSelfAttention(Layer):
             new = (wrap(k_pages), wrap(v_pages), Tensor(tables),
                    Tensor(positions + qlens), cache[4], cache[5])
             return out, (new + (cache[6],) if len(cache) == 7 else new)
-        windowed = len(cache) == 5
-        if s > 1 and windowed:
-            k_pages = PA.write_chunk_pages(k_pages, tables, k._data,
-                                           positions)
-            v_pages = PA.write_chunk_pages(v_pages, tables, v._data,
-                                           positions)
-            out = Tensor(PA.prefix_prefill_attention(
-                q._data, k_pages, v_pages, tables, positions))
-            new_pos = positions + s
-        elif s > 1:
+        if s > 1:
             # prefill: pages for slots 0..s-1 (s % page_size == 0, padded
             # by the engine); garbage in pad slots is masked by `lengths`
             # at every later read

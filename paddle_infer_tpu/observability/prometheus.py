@@ -34,7 +34,7 @@ _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 # still exposed as stat-labelled gauges (reservoir percentiles)
 SERIES_FAMILIES = {
     "decode_step_ms": ("serving_decode_step_milliseconds",
-                       "One fused decode chunk wall time in ms"),
+                       "One scheduler step wall time in ms"),
     "occupancy": ("serving_step_occupancy_ratio",
                   "Active rows / max_batch per decode step"),
 }
@@ -542,8 +542,7 @@ def render_prometheus(snapshot: dict,
         _hist_samples(w, "serving_ttft_seconds", hists["ttft"])
     if (hists.get("itl") or {}).get("buckets"):
         w.family("serving_inter_token_latency_seconds", "histogram",
-                 "Per-token latency inside a fused decode chunk in "
-                 "seconds")
+                 "Gap between a row's consecutive tokens in seconds")
         _hist_samples(w, "serving_inter_token_latency_seconds",
                       hists["itl"])
     if (hists.get("e2e") or {}).get("buckets"):
@@ -552,8 +551,8 @@ def render_prometheus(snapshot: dict,
         _hist_samples(w, "serving_e2e_latency_seconds", hists["e2e"])
     if (hists.get("step_wall") or {}).get("buckets"):
         w.family("serving_step_wall_seconds", "histogram",
-                 "One scheduler step (fused decode chunk or prefill) "
-                 "wall time in seconds")
+                 "One scheduler step (the mixed step, launch to "
+                 "read-back) wall time in seconds")
         _hist_samples(w, "serving_step_wall_seconds", hists["step_wall"])
     if (hists.get("queue_wait") or {}).get("buckets"):
         w.family("serving_queue_wait_seconds", "histogram",
@@ -586,7 +585,7 @@ def render_prometheus(snapshot: dict,
             w.sample("steplog_records_total", 0, {"kind": "none"})
         w.family("steplog_steps_by_kernel_total", "counter",
                  "StepLog scheduler-step records by serving kernel "
-                 "(ragged mixed step vs legacy per-shape programs)")
+                 "(the ragged mixed step)")
         by_kernel = sl.get("by_kernel") or {}
         if by_kernel:
             for kernel in sorted(by_kernel):

@@ -8,8 +8,8 @@ vs host bookkeeping — is exactly the feature set a per-step cost model
 trains on ("A Learned Performance Model for TPUs", PAPERS.md), and the
 ROADMAP's cost-model-driven-scheduling item starts from it.
 
-``serving.EngineCore`` appends one record per step event (prefill /
-fused decode chunk / page copy / evict) into a bounded ring with a
+``serving.EngineCore`` appends one record per step event (mixed step /
+page copy / evict) into a bounded ring with a
 fixed schema (``SCHEMA_KEYS``; the table in docs/OBSERVABILITY.md).
 ``GET /steps`` serves the recent ring, ``to_jsonl()`` exports it, and
 ``summary()`` folds the ring into Prometheus-ready aggregates plus a
@@ -55,7 +55,7 @@ _SCHEMA = (
     ("ts", 0.0),                 # wall-clock capture time (time.time())
     ("kind", ""),                # prefill | decode | mixed | page_copy
                                  # | evict
-    ("kernel", ""),              # ragged | legacy (step-serving records)
+    ("kernel", ""),              # ragged (step-serving records)
     ("wall_s", 0.0),             # whole step event, edge to edge
     ("dispatch_s", 0.0),         # device dispatch + readback sync
                                  # (== launch_s + wait_s)
@@ -91,7 +91,7 @@ _SCHEMA = (
     ("program_temp_bytes", 0),   # the compiled step's temporaries
                                  # (memory_analysis; 0 where not offered)
     ("active_rows", 0),          # occupied slots at capture
-    ("decode_rows", 0),          # rows in this fused decode chunk
+    ("decode_rows", 0),          # rows that fed a decode token
     ("prefill_tokens", 0),       # uncached suffix tokens prefetched
     ("prefill_chunk_tokens", 0),  # prompt tokens chunked into this
                                   # ragged mixed step
@@ -353,12 +353,13 @@ class StepCostModel:
 
     def estimate(self, kind: str, key=None, *, rows: int = 1,
                  max_rows: int = 1, pages_touched: int = 0,
-                 chunk: int = 1, tokens: Optional[int] = None,
+                 tokens: Optional[int] = None,
                  adapter_rows: int = 0):
         """Return ``(bytes_est, flops_est, cost_source)`` for one step
         event.  ``pages_touched`` is the KV pages the step reads or
-        writes (resident pages for decode — every scan step re-reads
-        them; the reservation for prefill; freed pages for evict).
+        writes (resident pages for a serving step; freed pages for
+        evict).  ``tokens`` is the step's query tokens (one a row when
+        left out).
         ``adapter_rows`` prices the per-row LoRA factor gathers of the
         multi-adapter mixed step on top of the base weight pass."""
         pages = max(0, int(pages_touched))
@@ -382,13 +383,9 @@ class StepCostModel:
         elif kind == "decode":
             # every query token re-streams its row's page window, so
             # decode is priced per token: tokens / rows positions per
-            # row.  Legacy fused chunks pass tokens = rows × chunk and
-            # reduce exactly to the old pages × chunk product; ragged
-            # speculative steps pass decode + draft tokens, pricing a
-            # verify row at its true query_len instead of the old
-            # query_len == 1 assumption.
-            ntok_kv = float(tokens if tokens is not None
-                            else rows * chunk)
+            # row.  Speculative steps pass decode + draft tokens, pricing
+            # a verify row at its true query_len.
+            ntok_kv = float(tokens if tokens is not None else rows)
             kv_moved = (pages * self._page_kv_bytes
                         * max(ntok_kv, 1.0) / max(rows, 1))
         else:
@@ -410,9 +407,8 @@ class StepCostModel:
             if bytes_est > 0.0:
                 return bytes_est, flops_est, "xla+pages"
         wb, n_params = self._weights()
-        ntok = float(tokens if tokens is not None else rows * chunk)
-        steps = chunk if kind == "decode" else 1
-        bytes_est = wb * steps + kv_moved
+        ntok = float(tokens if tokens is not None else rows)
+        bytes_est = wb + kv_moved
         flops_est = 2.0 * n_params * ntok
         return bytes_est, flops_est, "analytic"
 

@@ -35,9 +35,9 @@ contribute ``exp(-inf) = 0`` with ``alpha = 1`` exactly, and the rows of
 a matmul do not see each other.  So a position computed in one chunk, in
 two, after a warm prefix hit, on replay or after park/resume re-runs the
 same arithmetic on the same bytes (tests/test_ragged_serving.py holds
-the kernel to that bitwise).  It is NOT the arithmetic of the legacy
-program family (``ragged=False``: SDPA cold prefill, the dense windowed
-``prefix_prefill_attention``); against those and against the float32
+the kernel to that bitwise).  It is NOT the arithmetic of the offline
+``generate()`` programs (SDPA prefill) nor of the dense windowed
+``prefix_prefill_attention``; against those and against the float32
 reference it is close, not equal.
 
 Speculative verify rows (``verify_rows``) keep their contract: the first

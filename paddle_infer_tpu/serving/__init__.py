@@ -13,10 +13,11 @@ Layer map:
                       per-request deadlines; overload answers with a
                       graceful rejection (HTTP 429/504) instead of OOM.
   ``EngineCore``      the scheduler: each iteration admits queued
-                      requests into free KV-block slots (one compiled
-                      prefill per request), runs ONE fused decode step
-                      for every active row, evicts finished rows and
-                      immediately backfills their slots — no
+                      requests into free KV-block slots (staging KV
+                      only), runs ONE mixed step — prompt chunks and
+                      decode tokens — for every active row, evicts
+                      finished rows and immediately backfills their
+                      slots — no
                       stop-the-world between request generations.
   ``ServingMetrics``  queue depth, batch occupancy, TTFT, inter-token
                       latency p50/p99, tokens/s, rejection counts —

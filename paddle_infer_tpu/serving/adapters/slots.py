@@ -11,7 +11,7 @@ tracer of the SAME jit trace (the context only lives across one
 ``_model_step`` call on one thread), so no value ever crosses a trace
 boundary.
 
-Outside an active context (eager forwards, the legacy fused builders,
+Outside an active context (eager forwards, the offline engines,
 training-style use of a converted model) the wrappers return the base
 layer's output unchanged — the adapter plane is invisible unless the
 mixed step turns it on.

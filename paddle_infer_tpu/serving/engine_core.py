@@ -9,32 +9,25 @@ repeats it):
   2. run any exclusive requests at the queue head (engine calls the
      continuous batch can't host — beams, repetition penalty,
      speculative — executed on this thread so they never race the pool);
-  3. admit queued requests into free KV-block slots: one compiled
-     prefill each, first token emitted right there (that's the TTFT
-     sample);
-  4. run ONE fused decode chunk for all active rows (a ``lax.scan`` of
-     exactly ``decode_chunk`` steps — rows whose budget ends mid-chunk
-     have their surplus tokens clamped off host-side, so one compiled
-     program serves every batch composition);
+  3. admit queued requests into free KV-block slots: admission only
+     stages KV (prefix-cache match, page reservation) and queues the
+     uncached prompt suffix as ``pending`` token slices;
+  4. run ONE mixed step for all active rows: live decode rows (one
+     token each, packed first) plus up to ``prefill_chunk`` pending
+     prompt tokens per row under a per-step ``token_budget``, in a
+     single executable (serving/programs.build_mixed_step, backed by
+     ops/pallas/ragged_paged_attention) — a long prompt interleaves
+     with decode instead of stalling it, and a row's first token is
+     emitted the step its last chunk runs (that's the TTFT sample);
   5. evict finished rows, free their pages, and loop — freed slots are
      backfilled at the next iteration's step 3, so a late-arriving
-     request joins the SAME fused step as requests admitted long before
-     it (``step_trace`` records the per-step active set to prove it).
+     request joins the SAME step as requests admitted long before it
+     (``step_trace`` records the per-step active set to prove it).
 
-There is no stop-the-world: admission, decode and eviction interleave
-at chunk granularity, and per-row sampling parameters live in arrays
-(serving/programs.py) so none of it ever recompiles the hot loop.
-
-Ragged mode (the default): steps 3–4 collapse into ONE mixed-step
-launch.  Admission only stages KV and queues the prompt as ``pending``
-token slices; every scheduler step then packs live decode rows (one
-token each, packed first) plus up to ``prefill_chunk`` pending prompt
-tokens per row under a per-step ``token_budget`` into a single ragged
-executable (serving/programs.build_mixed_step, backed by
-ops/pallas/ragged_paged_attention), so a long prompt interleaves with
-decode instead of stalling it and one executable serves every batch
-composition.  ``ragged=False`` restores the legacy per-plen /
-per-chunk program families.
+There is no stop-the-world: admission, prefill chunks, decode and
+eviction interleave at step granularity, and per-row sampling
+parameters live in arrays (serving/programs.py), so one executable
+serves every batch composition and nothing recompiles the hot loop.
 
 Slot/pool layout: slot ``s`` (0..max_batch-1) reserves native-pool
 sequence id ``s``; a one-page scratch reservation (seq id max_batch)
@@ -63,8 +56,7 @@ from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
-from .programs import (build_decode, build_mixed_step, build_page_copy,
-                       build_prefill, build_prefix_prefill)
+from .programs import build_mixed_step, build_page_copy
 from .request import (DeadlineExceededError, GrammarError,
                       GrammarIncompleteError, HandoffError, LoadShedError,
                       QuarantinedError, QueueFullError, RejectedError,
@@ -97,7 +89,7 @@ class EngineCore:
     (``tools/serve.py`` uses the dense ``GenerationEngine``)."""
 
     def __init__(self, engine: PagedGenerationEngine, max_batch: int = 8,
-                 max_queue: int = 64, decode_chunk: int = 4,
+                 max_queue: int = 64,
                  default_timeout_s: Optional[float] = None,
                  max_model_len: Optional[int] = None,
                  metrics: Optional[ServingMetrics] = None,
@@ -107,7 +99,6 @@ class EngineCore:
                  prefix_cache_headroom_pages: int = 0,
                  fault_plane=None,
                  steplog: Optional[StepLog] = None,
-                 ragged: bool = True,
                  prefill_chunk: Optional[int] = None,
                  token_budget: Optional[int] = None,
                  speculate: bool = False,
@@ -146,8 +137,7 @@ class EngineCore:
             getattr(engine, "_cache_layout", None),
             mp=int(getattr(serving_mesh, "mp", 1) or 1),
             kv_dtype=getattr(engine, "_kv_dtype", None),
-            speculate=speculate, kv_host_pages=int(kv_host_pages),
-            ragged=ragged)
+            speculate=speculate, kv_host_pages=int(kv_host_pages))
         # dropless expert layers (serving/moe/dropless.py) need no
         # conversion and no capacity: the mixed step only threads them
         # its valid mask and returns their counters
@@ -172,22 +162,6 @@ class EngineCore:
         # further down, the in-place conversion to static-capacity
         # serving layers that must precede the engine's param snapshot
         self._moe = moe_serving_info(engine._model)
-        if self._moe is not None and not ragged:
-            raise ShardedConfigError(
-                "MoE serving requires ragged=True: the static-capacity "
-                "routing buffers are sized from the mixed step's fixed "
-                "token budget, and the legacy per-(plen|batch,chunk) "
-                "program zoo would need one capacity per shape")
-
-        # multi-LoRA adapter plane (serving/adapters/): per-row slot
-        # gathers only exist inside the mixed step — the legacy program
-        # zoo has no slot side-channel, so its executables would
-        # silently serve the BASE model under every adapter
-        if adapter_store is not None and not ragged:
-            raise ShardedConfigError(
-                "adapter serving requires ragged=True: per-row adapter "
-                "slots ride the mixed step's side-channel; the legacy "
-                "program families would silently drop the LoRA delta")
 
         engine_quant = getattr(engine, "_quant_allreduce", None)
         if serving_mesh is not None:
@@ -236,7 +210,6 @@ class EngineCore:
         self._recovery = None
         self._drain_evt = threading.Event()
         self._loop_tb_seen: set = set()
-        self._decode_chunk = max(1, int(decode_chunk))
         self._default_timeout = default_timeout_s
         self._metrics = metrics or ServingMetrics()
         # span-based request tracing: every request's wall time is
@@ -261,31 +234,23 @@ class EngineCore:
         # every slot's page table has one fixed width, covering the
         # worst-case reservation (page-padded prompt or prompt+max_new)
         self._max_pages = _round_up(self._max_model_len, page) // page
-        self._plen_cap = self._max_pages * page
+        window = self._max_pages * page
 
-        # ragged mixed-step scheduling (the default): ONE executable
-        # keyed by (max_batch, token_budget, max_pages) serves every
-        # batch composition — each row of a step carries its own
-        # (query_len, context_len), so decode rows and prompt chunks
-        # share a launch and nothing is ever padded to a prompt bucket.
-        # Prompts longer than ``prefill_chunk`` are admitted as token
-        # slices spread over successive steps under the per-step
-        # ``token_budget``, so a long prompt arrival no longer stalls
-        # streaming decode rows (docs/SERVING.md "Ragged attention and
-        # chunked prefill").  ``ragged=False`` keeps the legacy
-        # per-(plen|batch,chunk) program zoo.
-        self._ragged = bool(ragged)
-        if self._ragged:
-            budget = int(token_budget or min(self._plen_cap,
-                                             max(4 * page, 32)))
-            # every active row must at least fit its decode token
-            budget = max(2, self._max_batch, min(budget, self._plen_cap))
-            self._token_budget = budget
-            chunk = int(prefill_chunk or budget)
-            self._prefill_chunk = max(1, min(chunk, budget))
-        else:
-            self._token_budget = 0
-            self._prefill_chunk = 0
+        # mixed-step scheduling: ONE executable keyed by (max_batch,
+        # token_budget, max_pages) serves every batch composition — each
+        # row of a step carries its own (query_len, context_len), so
+        # decode rows and prompt chunks share a launch and nothing is
+        # ever padded to a prompt bucket.  Prompts longer than
+        # ``prefill_chunk`` are admitted as token slices spread over
+        # successive steps under the per-step ``token_budget``, so a
+        # long prompt arrival does not stall streaming decode rows
+        # (docs/SERVING.md "Ragged attention and chunked prefill").
+        budget = int(token_budget or min(window, max(4 * page, 32)))
+        # every active row must at least fit its decode token
+        budget = max(2, self._max_batch, min(budget, window))
+        self._token_budget = budget
+        chunk = int(prefill_chunk or budget)
+        self._prefill_chunk = max(1, min(chunk, budget))
 
         if self._moe is not None:
             # convert the MoE FFNs in place BEFORE the param snapshot so
@@ -338,11 +303,6 @@ class EngineCore:
         # enforces this).
         self._grammar: Optional[GrammarCache] = None
         if grammar_vocab is not None:
-            if not self._ragged:
-                raise ShardedConfigError(
-                    "structured decoding requires ragged=True: the "
-                    "grammar mask rides the mixed step's data inputs; "
-                    "the legacy program families have no mask input")
             vs = getattr(getattr(engine._model, "config", None),
                          "vocab_size", None)
             if vs is not None and len(grammar_vocab) != int(vs):
@@ -377,10 +337,7 @@ class EngineCore:
 
         # automatic prefix caching: finished sequences' pages are
         # retained in a radix tree and matched against new prompts at
-        # admission (docs/SERVING.md "Prefix caching").  When enabled,
-        # ALL prefills (cold included) run the windowed
-        # ``serve-prefill-px`` program family so warm and cold logits
-        # are bitwise-identical.
+        # admission (docs/SERVING.md "Prefix caching").
         self._prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self._pool, page, prefix_cache_watermark)
             if enable_prefix_cache else None)
@@ -395,9 +352,6 @@ class EngineCore:
         # composition, exactly like the plain mixed step.
         self._speculate = bool(speculate)
         if self._speculate:
-            if not self._ragged:
-                raise ValueError("speculate=True requires ragged=True "
-                                 "(drafts ride the mixed step)")
             if int(num_draft_tokens) < 1:
                 raise ValueError("num_draft_tokens must be >= 1")
             self._spec_window = max(
@@ -410,7 +364,7 @@ class EngineCore:
             self._draft_source = None
 
         # step-level flight recorder: every scheduler step event
-        # (prefill / fused decode chunk / page copy / evict) appends one
+        # (admission / mixed step / page copy / evict) appends one
         # schema-fixed record with an analytic bytes/FLOPs estimate from
         # the cost model (observability/steplog.py; GET /steps)
         self.steplog = steplog if steplog is not None else StepLog()
@@ -424,10 +378,6 @@ class EngineCore:
         # prices swap traffic (int8 pools halve host bytes for free).
         self._kv_tier: Optional[HostKVTier] = None
         if int(kv_host_pages) > 0:
-            if not self._ragged:
-                raise ValueError(
-                    "kv_host_pages requires ragged=True: park/resume "
-                    "serializes the mixed step's slot state")
             self._kv_tier = HostKVTier(
                 int(kv_host_pages),
                 park_watermark=float(kv_park_watermark),
@@ -450,17 +400,13 @@ class EngineCore:
         from .sched import StepPlanner, make_policy
         self._sched = make_policy(sched_policy, slo_ttft_s=slo_ttft_s,
                                   slo_itl_s=slo_itl_s)
-        if self._sched.reorders and not self._ragged:
-            raise ValueError(
-                f"sched_policy={sched_policy!r} requires ragged=True "
-                "(the planner prices the mixed step's token budget)")
-        self._planner = (StepPlanner(
+        self._planner = StepPlanner(
             self._cost_model, self.steplog,
             max_batch=self._max_batch,
             token_budget=self._token_budget,
             prefill_chunk=self._prefill_chunk,
             slo_itl_s=slo_itl_s,
-            dynamic=self._sched.reorders) if self._ragged else None)
+            dynamic=self._sched.reorders)
         self._predictive_sheds = 0
         # rolling |predicted - actual| completion error for requests
         # the slack policy scored (reads/writes under the step lock)
@@ -665,8 +611,7 @@ class EngineCore:
                 "max_abs_err_s": max(errs) if errs else None,
             },
         }
-        if self._planner is not None:
-            out["planner"] = self._planner.snapshot()
+        out["planner"] = self._planner.snapshot()
         return out
 
     def _kv_quant_info(self) -> Optional[dict]:
@@ -1101,24 +1046,11 @@ class EngineCore:
             progressed = True
 
         if self.active_count:
-            if self._ragged:
-                self._mixed_step()
-            else:
-                self._decode_step()
+            self._mixed_step()
             progressed = True
         return progressed
 
     # --------------------------------------------------------- admission
-    def _plen(self, length: int) -> int:
-        if self._ragged:
-            # ragged mode pads nothing: the mixed step's shape depends
-            # only on (max_batch, token_budget), so the "padded" suffix
-            # IS the suffix and reservations are exact
-            return max(int(length), 1)
-        plen = _round_up(max(length, 1), self._engine._prompt_bucket)
-        plen = _round_up(min(plen, self._plen_cap), self._page)
-        return max(plen, _round_up(length, self._page))
-
     def _samp_arrays(self, cfgs):
         n = len(cfgs)
         samp = {"temperature": np.ones((n,), np.float32),
@@ -1142,22 +1074,18 @@ class EngineCore:
 
     def _match_prefix(self, req: Request, tokens: np.ndarray):
         """Query the radix tree for the longest cached prefix of
-        ``tokens`` (the prompt; on replay, prompt + delivered tokens)
-        and trim it until the padded suffix fits the fixed table window
-        (``cached + plen(length - cached) <= plen_cap``; cached == 0
-        always fits because the cold plen clamps to the cap)."""
+        ``tokens`` (the prompt; on replay, prompt + delivered tokens).
+        The match always fits the fixed table window: nothing is padded,
+        so cached + suffix is ``len(tokens)``, and both intake paths
+        (``submit``, ``enqueue``) hold prompt + max_new to
+        ``max_model_len``, which the ``max_pages`` wide table covers."""
         self._fault.fire("prefix.match", rid=req.rid)
         cache = self._prefix_cache
-        length = int(tokens.size)
         # route_salt composes the tenant salt with the adapter binding:
         # KV written under one fine-tune is never warm for another
         match = cache.match(tokens, salt=req.route_salt())
         if self._kv_tier is not None and self._kv_tier.demoted_count:
             self._promote_into_match(req, tokens, match)
-        while (match.cached_tokens and
-               match.cached_tokens +
-               self._plen(length - match.cached_tokens) > self._plen_cap):
-            cache.trim(match, match.cached_tokens - 1)
         return match
 
     def _used_pages(self) -> int:
@@ -1200,8 +1128,7 @@ class EngineCore:
         page = self._page
         while True:
             cached = match.cached_tokens
-            reserve = max(cached + self._plen(length - cached),
-                          length + max_new)
+            reserve = length + max_new
             total_pages = -(-reserve // page)
             cache.ensure_free(total_pages - len(match.blocks))
             try:
@@ -1299,7 +1226,6 @@ class EngineCore:
         length = int(full.size)
         budget = g.max_new_tokens - already
         cache = self._prefix_cache
-        eng = self._engine
         # adapter pinning precedes KV staging: the row must never enter
         # the batch without its fine-tune resident.  ``pin`` makes the
         # adapter resident (LRU-evicting an unpinned slot if it has to,
@@ -1350,7 +1276,7 @@ class EngineCore:
             else:
                 cached = 0
                 prefill_t = admit_t
-                reserve = max(self._plen(length), length + budget)
+                reserve = length + budget
                 self._pool.reserve(sid, reserve)
         except Exception as e:
             if aslot:
@@ -1361,7 +1287,7 @@ class EngineCore:
                                  slot=sid, outcome="failed")
             self.steplog.record(
                 "prefill", wall_s=now - admit_t, host_s=now - admit_t,
-                kernel="ragged" if self._ragged else "legacy",
+                kernel="ragged",
                 active_rows=self.active_count,
                 resident_kv_pages=self._used_pages(),
                 compile_events=clog.count() - c0, failed=True,
@@ -1379,162 +1305,54 @@ class EngineCore:
         # tpulint: disable-next-line=host-sync -- host-side page-table/cache-key staging buffer, built before dispatch
         key = np.asarray(
             jax.random.fold_in(jax.random.PRNGKey(g.seed), req.rid))  # tpulint: disable=determinism -- the rng key derives from (seed, rid) only; the time taint is a container-coarse read of the packet dict whose journey metadata carries wall-clocks
-        if self._ragged:
-            # ragged admission stages KV only: the uncached suffix waits
-            # in ``pending`` and enters the NEXT mixed steps as
-            # prefill_chunk-sized slices sharing launches with live
-            # decode rows.  The prefill.run fault site still fires at
-            # admission so injected prefill faults keep routing through
-            # the admission-failure/replay path.
-            try:
-                self._fault.fire("prefill.run", rid=req.rid)
-            except Exception as e:
-                if aslot:
-                    self._adapters.unpin(aslot)
-                self._release_slot_kv(sid, match)
-                now = time.monotonic()
-                self.tracer.add_span(req.rid, "prefill", admit_t, now,
-                                     slot=sid, outcome="failed")
-                self.steplog.record(
-                    "prefill", wall_s=now - admit_t, host_s=now - admit_t,
-                    prefill_tokens=suffix, kernel="ragged",
-                    active_rows=self.active_count,
-                    resident_kv_pages=self._used_pages(),
-                    prefix_hit_pages=len(match.blocks) if match else 0,
-                    compile_events=clog.count() - c0, failed=True,
-                    retries=req.retries,
-                    degraded=self._effective_max_batch < self._max_batch)
-                self._admit_failure(req, e)
-                return
-            req._mark_active()
-            # per-row FSM state is a pure function of the emitted
-            # stream: advance from start through req.tokens (skipping
-            # EOS).  Fresh admissions start at the start state; replays
-            # recompute the exact state the lost slot held.
-            fsm_state = None
-            gfsm = getattr(req, "grammar_fsm", None)
-            if gfsm is not None:
-                fsm_state, _ = grammar_rt.advance_many(
-                    gfsm, gfsm.start, req.tokens, g.eos_token_id)
-            self._slots[sid] = {
-                "req": req, "sid": sid, "g": g,
-                "length": int(req.prompt.size), "plen": suffix,
-                "emitted": already, "steps_base": already,
-                "last_tok": 0, "last_emit": admit_t,
-                "table": table, "key": key, "match": match,
-                "adapter_slot": aslot, "fsm": fsm_state,
-                "span_end": prefill_t, "full": full,
-                # host-side numpy slice of the staged prompt, no device sync
-                # tpulint: disable-next-line=host-sync -- host-side prompt/token-history assembly; req.tokens are already-emitted Python ints, not device arrays
-                "pending": np.asarray(full[cached:], np.int32),
-                "ctx": int(cached)}
-            return
-        plen = self._plen(suffix)
-        ids = np.full((1, plen), g.pad_token_id, np.int32)
-        ids[0, :suffix] = full[cached:]
-        steps0 = np.asarray([already], np.int32)
-        span_name = "prefill" if cache is None else "suffix_prefill"
-        t_run0 = time.monotonic()
+        # admission stages KV only: the uncached suffix waits in
+        # ``pending`` and enters the NEXT mixed steps as
+        # prefill_chunk-sized slices sharing launches with live decode
+        # rows.  The prefill.run fault site still fires at admission so
+        # injected prefill faults keep routing through the
+        # admission-failure/replay path.
         try:
             self._fault.fire("prefill.run", rid=req.rid)
-            if cache is not None:
-                # windowed family: cold (offset 0) and warm (offset c)
-                # share one executable per plen bucket, so a hit never
-                # compiles anything new
-                # tpulint: disable-next-line=key-provenance -- legacy per-plen program family: plen is bucket-rounded by _plen (deployment-capped bucket set), so the key space is bounded; the ragged mixed step is the zero-recompile path
-                pkey = ("serve-prefill-px", plen, self._max_pages,
-                        self._pool.num_blocks)
-                tok, fin = eng.run_paged_program(
-                    pkey,
-                    lambda: build_prefix_prefill(eng, plen,
-                                                 self._max_pages),
-                    ids, np.asarray([suffix], np.int32),
-                    np.asarray([cached], np.int32), steps0, table[None],
-                    self._samp_arrays([g]), key[None])
-            else:
-                # tpulint: disable-next-line=key-provenance -- legacy per-plen program family: plen is bucket-rounded by _plen (deployment-capped bucket set), so the key space is bounded; the ragged mixed step is the zero-recompile path
-                pkey = ("serve-prefill", plen, self._max_pages,
-                        self._pool.num_blocks)
-                tok, fin = eng.run_paged_program(
-                    pkey,
-                    lambda: build_prefill(eng, plen, self._max_pages),
-                    ids, np.asarray([length], np.int32), steps0,
-                    table[None], self._samp_arrays([g]), key[None])
         except Exception as e:
+            if aslot:
+                self._adapters.unpin(aslot)
             self._release_slot_kv(sid, match)
             now = time.monotonic()
-            self.tracer.add_span(req.rid, span_name, prefill_t, now,
-                                 slot=sid, plen=plen, outcome="failed")
+            self.tracer.add_span(req.rid, "prefill", admit_t, now,
+                                 slot=sid, outcome="failed")
             self.steplog.record(
-                "prefill", wall_s=now - admit_t, kernel="legacy",
-                dispatch_s=now - t_run0, prefill_tokens=suffix,
-                prefix_hit_pages=len(match.blocks) if match else 0,
+                "prefill", wall_s=now - admit_t, host_s=now - admit_t,
+                prefill_tokens=suffix, kernel="ragged",
                 active_rows=self.active_count,
                 resident_kv_pages=self._used_pages(),
+                prefix_hit_pages=len(match.blocks) if match else 0,
                 compile_events=clog.count() - c0, failed=True,
                 retries=req.retries,
                 degraded=self._effective_max_batch < self._max_batch)
             self._admit_failure(req, e)
             return
-        # the intentional once-per-admission sync: the first token and
-        # finish flag drive host-side slot bookkeeping
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        tok = int(np.asarray(tok)[0])
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        finished = bool(np.asarray(fin)[0])
-        t_sync = time.monotonic()
         req._mark_active()
-        if already == 0:
-            # TTFT is a first-admission metric; a replayed request's
-            # first token was delivered long ago
-            self._metrics.on_prefill(time.monotonic() - req.arrival)
-        # tpulint: disable-next-line=determinism -- container-coarse packet read: the emitted token comes from the device prefill output; the handoff packet's journey wall-clocks are sibling metadata in the same dict
-        req._emit(np.asarray([tok], np.int32))
-        self._metrics.on_tokens(1)
-        # the prefill span runs edge-to-edge (admission bookkeeping +
-        # compiled prefill + first-token emit) so no scheduler time
-        # between queue_wait and the first decode chunk is unattributed
-        span_end = time.monotonic()
-        self.tracer.add_span(req.rid, span_name, prefill_t, span_end,
-                             slot=sid, plen=plen, cached_tokens=cached,
-                             replay=req.retries)
-        bts, fl, src_tag = self._cost_model.estimate(
-            "prefill", pkey, rows=1, max_rows=1,
-            pages_touched=-(-reserve // self._page), tokens=plen)
-        ici, ici_saved = self._cost_model.interconnect(plen)
-        self.steplog.record(
-            "prefill", wall_s=span_end - admit_t, kernel="legacy",
-            dispatch_s=t_sync - t_run0,
-            host_s=(span_end - admit_t) - (t_sync - t_run0),
-            active_rows=self.active_count, prefill_tokens=suffix,
-            chunk_steps=1, emitted_tokens=1,
-            resident_kv_pages=self._used_pages(),
-            prefix_hit_pages=len(match.blocks) if match else 0,
-            bytes_est=bts, flops_est=fl, cost_source=src_tag,
-            ici_bytes_est=ici, ici_bytes_saved_est=ici_saved,
-            compile_events=clog.count() - c0, retries=req.retries,
-            degraded=self._effective_max_batch < self._max_batch)
-        if finished or budget <= 1:
-            # KV through the penultimate delivered token is fully
-            # written — retain it even though the row never reaches a
-            # decode chunk (cold case: that's exactly the prompt)
-            self._release_slot_kv(
-                sid, match, retain_tokens=np.concatenate(
-                    # req.tokens is a host-side list — no readback
-                    # tpulint: disable-next-line=host-sync -- host-side prompt/token-history assembly; req.tokens are already-emitted Python ints, not device arrays
-                    [req.prompt, np.asarray(req.tokens[:-1], np.int32)]),
-                salt=req.route_salt())
-            req._finish(RequestState.DONE)
-            self._metrics.on_completed(time.monotonic() - req.arrival)
-            self._trace_end(req, RequestState.DONE)
-            return
-        self._slots[sid] = {"req": req, "sid": sid, "g": g,
-                            "length": int(req.prompt.size), "plen": plen,
-                            "emitted": already + 1, "last_tok": tok,
-                            "last_emit": time.monotonic(),
-                            "table": table, "key": key,
-                            "match": match,
-                            "span_end": span_end}
+        # per-row FSM state is a pure function of the emitted
+        # stream: advance from start through req.tokens (skipping
+        # EOS).  Fresh admissions start at the start state; replays
+        # recompute the exact state the lost slot held.
+        fsm_state = None
+        gfsm = getattr(req, "grammar_fsm", None)
+        if gfsm is not None:
+            fsm_state, _ = grammar_rt.advance_many(
+                gfsm, gfsm.start, req.tokens, g.eos_token_id)
+        self._slots[sid] = {
+            "req": req, "sid": sid, "g": g,
+            "length": int(req.prompt.size), "plen": suffix,
+            "emitted": already, "steps_base": already,
+            "last_tok": 0, "last_emit": admit_t,
+            "table": table, "key": key, "match": match,
+            "adapter_slot": aslot, "fsm": fsm_state,
+            "span_end": prefill_t, "full": full,
+            # host-side numpy slice of the staged prompt, no device sync
+            # tpulint: disable-next-line=host-sync -- host-side prompt/token-history assembly; req.tokens are already-emitted Python ints, not device arrays
+            "pending": np.asarray(full[cached:], np.int32),
+            "ctx": int(cached)}
 
     # ---------------------------------------------------- failure paths
     def _admit_failure(self, req: Request, err: BaseException):
@@ -1765,8 +1583,7 @@ class EngineCore:
             i = s["sid"]
             ids[i, 0] = s["last_tok"]
             qlens[i] = 1
-            # same position algebra as the legacy fused decode: the fed
-            # token's KV lands at length + emitted - 1
+            # the fed token's KV lands at length + emitted - 1
             ctx[i] = s["length"] + s["emitted"] - 1
             steps0[i] = s["emitted"]
             sample_now[i] = True
@@ -1944,8 +1761,11 @@ class EngineCore:
                     tok, fin_out = res
         except Exception as e:
             self._metrics.on_failed(0)
-            # same contract as the legacy chunk: only a pre-dispatch
-            # injection provably leaves the donated pools intact
+            # only a fault-plane injection raised BEFORE dispatch leaves
+            # the pools provably intact; any exception out of the real
+            # donated call may have consumed them (their contents —
+            # every row's KV and every retained cache page — are then
+            # garbage), so KV-intact replay is reserved for injections
             injected = isinstance(e, (InjectedFault, InjectedMemoryError))
             t_fail = clock.phase("emit")    # failed in the launch: no wait
             end = time.monotonic()
@@ -2030,8 +1850,7 @@ class EngineCore:
         poisoned = set()
         if fault is not None and fault.get("nan_rids"):
             # injected NaN/inf logits poison the whole row (sampled or
-            # mid-chunk) — quarantine it below, exactly like the legacy
-            # path's non-finite sentinel
+            # mid-chunk) — quarantine it below
             poisoned = set(fault["nan_rids"])
         self._step_idx += 1
         emitted_decode = 0
@@ -2148,7 +1967,7 @@ class EngineCore:
         # token is one more processed position (KV walk + weight pass)
         bts, fl, src_tag = self._cost_model.estimate(
             kind, mkey, rows=len(active), max_rows=b,
-            pages_touched=resident, chunk=1,
+            pages_touched=resident,
             tokens=n_decode + prefill_tokens_step + draft_tokens_step,
             adapter_rows=adapter_rows_step)
         ici, ici_saved = self._cost_model.interconnect(
@@ -2212,201 +2031,6 @@ class EngineCore:
                     _log.exception(
                         "on_prefill_complete hook failed for rid=%d",
                         _req.rid)
-
-    # ------------------------------------------------------------ decode
-    def _decode_step(self):
-        clock = self._clock
-        clock.phase("pack")
-        active = [s for s in self._slots if s is not None]
-        # ALWAYS run the full chunk: a variable tail size would compile a
-        # fresh program for every distinct min-remaining-budget value
-        # (admission staggering makes those near-arbitrary).  Rows whose
-        # budget ends mid-chunk decode junk for the remaining steps —
-        # harmless: the junk tokens are clamped off host-side below,
-        # overshoot writes land in the row's own reserved pages (or the
-        # scratch page past its table), and the row is evicted before its
-        # pages are ever freed for reuse.
-        S = self._decode_chunk
-        b = self._max_batch
-        tok = np.zeros((b,), np.int32)
-        fin = np.ones((b,), bool)
-        pos0 = np.zeros((b,), np.int32)
-        steps0 = np.zeros((b,), np.int32)
-        tables = np.full((b, self._max_pages), self._scratch, np.int32)
-        keys = np.zeros((b,) + active[0]["key"].shape,
-                        active[0]["key"].dtype)
-        cfgs: List[Optional[GenerationConfig]] = [None] * b
-        for s in active:
-            i = s["sid"]
-            tok[i] = s["last_tok"]
-            fin[i] = False
-            pos0[i] = s["length"] + s["emitted"] - 1
-            steps0[i] = s["emitted"]
-            tables[i] = s["table"]
-            keys[i] = s["key"]
-            cfgs[i] = s["g"]
-        eng = self._engine
-        dkey = ("serve-step", b, S, self._max_pages, self._pool.num_blocks)
-        # each of the chunk's S steps feeds one token per live row, which
-        # attends to the row's cache so far and itself
-        live = np.logical_not(fin)
-        cx = np.where(live, pos0, 0).astype(np.int64)
-        n_live = int(live.sum())
-        attended_keys_step = int(S * cx.sum()) + n_live * S * (S + 1) // 2
-        resident_tokens_step = int(cx.sum()) + n_live * S
-        h2d_bytes_step = 0
-        clog = get_compile_log()
-        c0 = clog.count()
-        t0 = clock.phase("launch")
-        try:
-            fault = self._fault.fire(
-                "decode.step", rids=[s["req"].rid for s in active])
-            step_args = (tok, fin, pos0, steps0, tables,
-                         self._samp_arrays(cfgs), keys)
-            h2d_bytes_step = _host_bytes(step_args)
-            toks, fin_out, nvalid = eng.run_paged_program(
-                dkey, lambda: build_decode(eng, b, S, self._max_pages),
-                *step_args)
-        except Exception as e:
-            self._metrics.on_failed(0)
-            # only a fault-plane injection raised BEFORE dispatch leaves
-            # the pools provably intact; any exception out of the real
-            # donated call may have consumed them (their contents —
-            # every row's KV and every retained cache page — are then
-            # garbage), so KV-intact replay is reserved for injections
-            injected = isinstance(e, (InjectedFault, InjectedMemoryError))
-            t_fail = clock.phase("emit")    # failed in the launch: no wait
-            end = time.monotonic()
-            self.steplog.record(
-                "decode", wall_s=end - t0, dispatch_s=t_fail - t0,
-                kernel="legacy",
-                attended_keys=attended_keys_step,
-                resident_tokens=resident_tokens_step,
-                h2d_bytes=h2d_bytes_step,
-                **self._phase_fields(clock, end),
-                active_rows=len(active), decode_rows=len(active),
-                chunk_steps=S, resident_kv_pages=self._used_pages(),
-                compile_events=clog.count() - c0, faults=injected,
-                retries=sum(s["req"].retries for s in active),
-                failed=True,
-                degraded=self._effective_max_batch < self._max_batch)
-            if getattr(e, "lose_kv", False) or not injected:
-                self._engine.drop_kv_state()
-            rec = self._recovery
-            if rec is not None:
-                rec.on_engine_failure(e)
-            if self._engine.kv_state_lost():
-                self._recover_lost_state(e)
-            else:
-                # injected pre-dispatch fault: each row's KV is intact,
-                # so replays can retain their pages through the cache
-                for s in list(self._slots):
-                    if s is not None:
-                        self._replay_or_fail_slot(s, e, kv_intact=True)
-            return
-        clock.phase("wait")
-        if not self._decode_warm:
-            # first fused chunk on this core's decode key: everything
-            # after this is steady state — any further compile on the
-            # serving-decode site is a recompile and logs a warning
-            get_compile_log().mark_warm("serving-decode", dkey)
-            self._decode_warm = True
-        # the one designed sync per fused chunk: the whole chunk's
-        # tokens/finish/valid-counts come back in a single readback
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        toks = np.asarray(toks)
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        fin_out = np.asarray(fin_out)
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        nvalid = np.asarray(nvalid)
-        t_sync = clock.phase("emit")
-        synced = t_sync - t0        # launch to read-back (see _mixed_step)
-        # capture the step's page view BEFORE evictions free anything —
-        # this is what the dispatched chunk actually ran against
-        resident = self._used_pages()
-        prefix_hits = sum(len(s["match"].blocks)
-                          if s.get("match") is not None else 0
-                          for s in active)
-        if fault is not None and fault.get("nan_rids"):
-            # injected NaN/inf logits: overwrite the target rows' chunk
-            # with the non-finite sampling sentinel (-1), exactly what a
-            # categorical over all-masked logits returns — the row
-            # validity check below then quarantines them.  ``toks`` was
-            # already read back above; this copy is host-only.
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            toks = np.array(toks)
-            bad = fault["nan_rids"]
-            for s in active:
-                if s["req"].rid in bad:
-                    toks[s["sid"], :] = -1
-        self._step_idx += 1
-        emitted_total = 0
-        evicted = []
-        now = time.monotonic()
-        for s in active:
-            i = s["sid"]
-            n = min(int(nvalid[i]),
-                    s["g"].max_new_tokens - s["emitted"])
-            if n > 0 and int(toks[i, :n].min()) < 0:
-                # non-finite logits produce the negative sampling
-                # sentinel; poison is row-local (per-row tables and
-                # masks), so quarantine ONLY this row — the rest of the
-                # batch keeps its tokens from this very chunk
-                self._metrics.on_quarantined()
-                self._evict(s, RequestState.FAILED, QuarantinedError(
-                    f"request {s['req'].rid} quarantined: non-finite "
-                    f"logits in decode chunk {self._step_idx}"))
-                evicted.append(s["req"].rid)
-                continue
-            if n > 0:
-                s["req"]._emit(toks[i, :n])
-                s["last_tok"] = int(toks[i, n - 1])
-                s["emitted"] += n
-                s["last_emit"] = now
-                emitted_total += n
-            # one decode span per active row per chunk, stitched from
-            # the row's previous span end so inter-chunk scheduler time
-            # is attributed, not lost
-            self.tracer.add_span(s["req"].rid, "decode",
-                                 s.get("span_end", t0), now,
-                                 step=self._step_idx, chunk_steps=S,
-                                 tokens=n)
-            s["span_end"] = now
-            if bool(fin_out[i]) or s["emitted"] >= s["g"].max_new_tokens:
-                self._evict(s, RequestState.DONE)
-                evicted.append(s["req"].rid)
-        if emitted_total:
-            self._metrics.on_tokens(emitted_total, itl_s=synced / S)
-        self._metrics.on_step(synced * 1e3, len(active), b)
-        self.step_trace.append({
-            "step": self._step_idx, "batch_steps": S,
-            "active": [s["req"].rid for s in active],
-            "evicted": evicted})
-        bts, fl, src_tag = self._cost_model.estimate(
-            "decode", dkey, rows=len(active), max_rows=b,
-            pages_touched=resident, chunk=S, tokens=len(active) * S)
-        ici, ici_saved = self._cost_model.interconnect(len(active) * S)
-        end = time.monotonic()
-        self.steplog.record(
-            "decode", wall_s=end - t0, dispatch_s=t_sync - t0,
-            **self._phase_fields(clock, end),
-            attended_keys=attended_keys_step,
-            resident_tokens=resident_tokens_step,
-            h2d_bytes=h2d_bytes_step,
-            program_temp_bytes=self._program_temp_bytes(dkey),
-            active_rows=len(active),
-            kernel="legacy", decode_rows=len(active), chunk_steps=S,
-            emitted_tokens=emitted_total, resident_kv_pages=resident,
-            prefix_hit_pages=prefix_hits, bytes_est=bts, flops_est=fl,
-            ici_bytes_est=ici, ici_bytes_saved_est=ici_saved,
-            cost_source=src_tag, compile_events=clog.count() - c0,
-            faults=fault is not None,
-            retries=sum(s["req"].retries for s in active),
-            degraded=self._effective_max_batch < self._max_batch)
-        if self._recovery is not None:
-            # a clean chunk resets crash/memory streaks and climbs the
-            # recovery ladder back toward full batch width
-            self._recovery.on_step_ok()
 
     # ---------------------------------------------------------- eviction
     def _evict(self, slot: dict, state: RequestState,
@@ -2687,8 +2311,7 @@ class EngineCore:
                     or self.active_count >= self._effective_max_batch):
                 break
             g = packet["g"]
-            reserve = max(self._plen(int(np.size(packet["full"]))),
-                          int(req.prompt.size) + g.max_new_tokens)
+            reserve = int(req.prompt.size) + g.max_new_tokens
             need = -(-reserve // self._page)
             busy = self.active_count > 0 or len(self._queue) > 0
             aged = (self._step_idx - parked_step) >= tier.aging_steps
@@ -2724,8 +2347,7 @@ class EngineCore:
                 return False    # pins free as active rows exit
         length = int(req.prompt.size)
         full = packet["full"]
-        reserve = max(self._plen(int(np.size(full))),
-                      length + g.max_new_tokens)
+        reserve = length + g.max_new_tokens
         self._pool.free(sid)
         try:
             if self._prefix_cache is not None:
@@ -2873,8 +2495,6 @@ class EngineCore:
         consumes.  Raises ``HandoffError`` without side effects when
         the request holds no slot here."""
         with self._step_lock:
-            if not self._ragged:
-                raise HandoffError("KV handoff requires ragged=True")
             s = None
             for cand in self._slots:
                 if cand is not None and cand["req"] is req:
@@ -3003,8 +2623,6 @@ class EngineCore:
                 raise HandoffError("serving engine is closed")
             if self._drain_evt.is_set():
                 raise HandoffError("target replica is draining")
-            if not self._ragged:
-                raise HandoffError("KV handoff requires ragged=True")
             if int(packet["page"]) != self._page:
                 raise HandoffError(
                     f"page-size mismatch: source {packet['page']} vs "
@@ -3057,8 +2675,7 @@ class EngineCore:
                         f"target replica cannot pin adapter "
                         f"{req.adapter_id!r}: {e}") from e
             t0 = time.monotonic()
-            reserve = max(self._plen(int(np.size(full))),
-                          length + g.max_new_tokens)
+            reserve = length + g.max_new_tokens
             self._pool.free(sid)
             try:
                 if self._prefix_cache is not None:

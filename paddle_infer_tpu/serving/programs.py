@@ -1,36 +1,35 @@
-"""Compiled prefill / decode-step programs for the continuous-batching
-scheduler.
+"""The compiled serving programs of the continuous-batching scheduler:
+``build_mixed_step`` (the one step executable) and ``build_page_copy``
+(the prefix cache's copy-on-write).
 
-Design constraint: admitting a request must NEVER recompile the decode
-hot loop, whatever its sampling config.  The dense/paged engines key
+Design constraint: admitting a request must NEVER recompile the hot
+loop, whatever its sampling config.  The dense/paged engines key
 executables by ``GenerationConfig.cache_key()`` — fine when one call
 serves one homogeneous batch, fatal for continuous batching where every
 row can carry different knobs.  Here temperature / top-k / top-p /
 min-length / eos / do_sample ride as **per-row arrays** (the ``samp``
-dict), so there is exactly one decode executable per
-(batch, chunk, table-width, pool-size) and heterogeneous requests share
-it.  Greedy rows stay argmax-exact with ``GenerationEngine`` output:
-temperature scaling, top-k and top-p masking never change the argmax
-(the top token always survives every filter), so token parity with the
-engines' greedy path holds bit-for-bit.
+dict), so there is exactly one step executable per
+(batch, token-budget, table-width, pool-size) and heterogeneous requests
+share it.  Greedy rows stay argmax-exact with ``GenerationEngine``
+output: temperature scaling, top-k and top-p masking never change the
+argmax (the top token always survives every filter).
 
-Layout contract with ``EngineCore`` (mirrors PagedGenerationEngine's
-stream programs):
+Layout contract with ``EngineCore``:
 
-  * prompts are RIGHT-padded to a page multiple; ``write_prompt_pages``
-    writes all ``plen`` slots but decode attends only ``pos+1`` entries,
-    so pad KV past the true length is never read;
-  * decode step ``i`` of a chunk feeds the last emitted token, writes
-    its KV at per-row position ``pos0 + i`` and samples the next token
-    (same step algebra as ``_build_stream_chunk``, but with *per-row*
-    lengths/offsets so rows at different generation depths coexist);
+  * nothing is padded to a prompt bucket: row ``b`` of a step carries
+    ``qlens[b]`` real tokens starting at absolute position ``ctx[b]``;
+    the slots past ``qlens[b]`` are written nowhere and never attended;
+  * a decode row feeds its last emitted token, writes its KV at
+    ``length + emitted - 1`` and samples the next token, with *per-row*
+    lengths/offsets so rows at different generation depths coexist;
   * inactive batch rows point every table entry at the scratch page
-    with ``fin=True`` — their writes land in garbage the attention mask
+    with ``qlens = 0`` — their writes land in garbage the attention mask
     never exposes to live rows.
 
 Per-row RNG: each request owns a base key (``fold_in(PRNGKey(seed),
 rid)``); step ``s`` uses ``fold_in(base, s)`` — independent streams per
-row that survive the row moving between chunk shapes.
+row that do not depend on which step or beside which rows a token is
+sampled.
 """
 from __future__ import annotations
 
@@ -106,16 +105,15 @@ def _layer_pools(engine, caches):
 
 def build_mixed_step(engine, max_batch, token_budget, max_pages,
                      spec_window=1, moe_stats=False, grammar=False):
-    """THE ragged serving executable: one launch per scheduler step,
+    """THE serving step executable: one launch per scheduler step,
     whatever the batch composition.  Row ``b`` carries ``qlens[b]``
     query tokens starting at absolute position ``ctx[b]`` — 1 for a
     decode row (``ids[b, 0]`` is its last emitted token), >1 for a
     prefill chunk (a slice of the prompt), 0 for an inactive row (all
     table entries at the scratch page).  The executable's shape depends
-    only on ``(max_batch, token_budget, max_pages, pool)``: no plen
-    buckets, no per-(batch, chunk) decode family, so after ONE warmup
-    compile every mix of cold chunks, warm-prefix suffixes and decode
-    rows reuses it.
+    only on ``(max_batch, token_budget, max_pages, pool)``, so after ONE
+    warmup compile every mix of cold chunks, warm-prefix suffixes and
+    decode rows reuses it.
 
     ``run(params, ids[b, C], qlens[b], ctx[b], steps0[b],
     sample_now[b], adapter_slots[b], tables[b, max_pages], samp,
@@ -131,15 +129,13 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     shape for every deployment.
 
     Sampling: each row's next-token logits sit at chunk position
-    ``qlens - 1`` (for decode rows that is position 0 — exactly the
-    legacy fused-decode read).  ``sample_now`` is False for
-    non-final prefill chunks: their row emits no token this step (the
-    pad id is returned and the engine ignores it).  ``steps0`` is the
-    sampled token's generation-step index, so the ``fold_in`` RNG
-    stream and the min-length window are IDENTICAL to the legacy
-    per-program path — that, plus the attention composition in
-    ``ops/pallas/ragged_paged_attention.py`` reusing the legacy paths'
-    exact math per row type, is the bitwise-parity guarantee.
+    ``qlens - 1`` (for decode rows that is position 0).  ``sample_now``
+    is False for non-final prefill chunks: their row emits no token
+    this step (the pad id is returned and the engine ignores it).
+    ``steps0`` is the sampled token's generation-step index, so the
+    ``fold_in`` RNG stream and the min-length window depend on the
+    request and its step alone, never on how the prompt was chunked or
+    which rows shared the launch.
 
     ``spec_window = W > 1`` builds the speculative draft/verify variant
     instead (EngineCore ``speculate=True``; the non-speculative
@@ -201,7 +197,6 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     carry all-zero mask rows.  Deployments without a grammar vocab get
     the ``grammar=False`` signatures below VERBATIM — same arity, same
     donation indices, same executable key."""
-    L = engine._num_layers
     C = token_budget
 
     def _model_step_with_stats(params, ids, pos2d, caches, qlens, i2d,
@@ -387,91 +382,6 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     return jax.jit(run_spec_plain, donate_argnums=(12, 13))
 
 
-# legacy ragged=False path: one executable per plen bucket is the
-# pre-ragged contract, bounded by the bucketing in EngineCore._plen
-# tpulint: disable-next-line=recompile-hazard -- bounded family: one executable per plen bucket is the pre-ragged contract
-def build_prefill(engine, plen, max_pages):
-    """Prefill one request (batch of 1) into its reserved pages and pick
-    the first token.  ``run(params, ids[1,plen], lengths[1], steps0[1],
-    tables[1,max_pages], samp, keys[1,2], k_pages, v_pages)`` →
-    ``(tok[1], fin[1], k_pages, v_pages)``; pools are donated.
-
-    ``steps0`` is the row's generation-step index for the token this
-    prefill samples: 0 for a fresh admission, ``req.emitted`` when the
-    supervisor replays a half-served request — so the replayed token
-    draws from the SAME ``fold_in(base, step)`` stream (and the same
-    min-length window) the lost decode step would have used."""
-    L = engine._num_layers
-
-    def run(params, ids, lengths, steps0, tables, samp, keys,
-            k_pages, v_pages):
-        b = ids.shape[0]
-        zero_pos = jnp.zeros((b,), jnp.int32)
-        caches = [(k_pages[i], v_pages[i], tables, zero_pos)
-                  for i in range(L)]
-        pos2d = jnp.broadcast_to(
-            jnp.arange(plen, dtype=jnp.int32)[None], (b, plen))
-        logits, caches = engine._model_step(params, ids, pos2d, None,
-                                            caches)
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        proc = _process_rows(last, samp, steps0)
-        tok = _pick_rows(proc, samp, steps0, keys)
-        fin = jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"])
-        return (tok, fin,
-                [c[0] for c in caches], [c[1] for c in caches])
-
-    return jax.jit(run, donate_argnums=(7, 8))
-
-
-# legacy ragged=False path: the per-plen windowed family is kept as
-# the parity anchor the ragged kernel's reference composes against
-# tpulint: disable-next-line=recompile-hazard -- bounded family: per-plen windowed executables are the bitwise-parity anchor
-def build_prefix_prefill(engine, plen, max_pages):
-    """Windowed suffix prefill for prefix-cache hits: the row's first
-    ``offsets[0]`` positions already hold cached KV (shared blocks mapped
-    into ``tables``), so only the suffix chunk runs through the model.
-    The chunk writes KV at absolute positions ``offsets + i`` and
-    attends over the row's whole gathered page window with an
-    absolute-position causal mask (see
-    ``transformer_block._forward_paged`` windowed branch), which keeps
-    logits bitwise-identical to a cold full prefill: the reduce window
-    is the constant ``max_pages * page`` for every (plen, offset), so
-    XLA emits the same reduction order, masked slots contribute exactly
-    zero, and the cached KV values are the very floats the cold path
-    would have recomputed.
-
-    ``run(params, ids[1,plen], lengths[1], offsets[1], steps0[1],
-    tables[1,max_pages], samp, keys[1,2], k_pages, v_pages)`` →
-    ``(tok[1], fin[1], k_pages, v_pages)``; pools are donated.
-    ``lengths`` counts valid suffix tokens within the padded chunk;
-    cold requests (offset 0) also run through this family when the
-    prefix cache is enabled, so one executable per plen serves both.
-    ``steps0`` is the sampled token's generation-step index (0 fresh,
-    ``req.emitted`` on supervisor replay — see ``build_prefill``)."""
-    L = engine._num_layers
-
-    def run(params, ids, lengths, offsets, steps0, tables, samp, keys,
-            k_pages, v_pages):
-        b = ids.shape[0]
-        marker = jnp.zeros((b,), jnp.int32)
-        caches = [(k_pages[i], v_pages[i], tables, offsets, marker)
-                  for i in range(L)]
-        pos2d = offsets[:, None] + jnp.broadcast_to(
-            jnp.arange(plen, dtype=jnp.int32)[None], (b, plen))
-        logits, caches = engine._model_step(params, ids, pos2d, None,
-                                            caches)
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-        proc = _process_rows(last, samp, steps0)
-        tok = _pick_rows(proc, samp, steps0, keys)
-        fin = jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"])
-        return (tok, fin,
-                [c[0] for c in caches], [c[1] for c in caches])
-
-    return jax.jit(run, donate_argnums=(8, 9))
-
-
 def build_page_copy(engine):
     """Copy one physical page across every layer's pools (the
     copy-on-write step for a shared partial tail block):
@@ -496,45 +406,3 @@ def build_page_copy(engine):
         return (src, k_pages, v_pages)
 
     return jax.jit(run, donate_argnums=(3, 4))
-
-
-# legacy ragged=False path: batch/chunk are fixed core config here,
-# so the family stays a single executable per core
-# tpulint: disable-next-line=recompile-hazard -- batch/chunk are fixed core config, one executable per core
-def build_decode(engine, batch, chunk, max_pages):
-    """One fused decode chunk over ALL batch rows: a ``lax.scan`` of
-    ``chunk`` steps (amortizing host dispatch), each feeding every row's
-    last token, writing KV at per-row ``pos0 + i`` and sampling with
-    per-row knobs.  Returns ``(toks[b, chunk], fin[b], nvalid[b],
-    k_pages, v_pages)`` where ``nvalid`` counts tokens emitted before
-    the row finished (rows never see each other's KV: tables are
-    per-row and attention masks by per-row position)."""
-    L = engine._num_layers
-
-    def run(params, tok, fin, pos0, steps0, tables, samp, keys,
-            k_pages, v_pages):
-        def body(carry, i):
-            tok, fin, nvalid, caches = carry
-            pos = pos0 + i
-            steps = steps0 + i
-            caches = [(kp, vp, tb, pos) for kp, vp, tb, _ in caches]
-            logits, caches = engine._model_step(
-                params, tok[:, None], pos[:, None], None, caches)
-            proc = _process_rows(logits[:, -1], samp, steps)
-            nxt = _pick_rows(proc, samp, steps, keys)
-            nxt = jnp.where(fin, samp["pad"], nxt)
-            nvalid = nvalid + jnp.logical_not(fin).astype(jnp.int32)
-            fin = jnp.logical_or(
-                fin, jnp.logical_and(samp["eos"] >= 0, nxt == samp["eos"]))
-            return (nxt, fin, nvalid, caches), nxt
-
-        caches = [(k_pages[i], v_pages[i], tables,
-                   jnp.zeros((batch,), jnp.int32)) for i in range(L)]
-        nvalid0 = jnp.zeros((batch,), jnp.int32)
-        (tok, fin, nvalid, caches), toks = jax.lax.scan(
-            body, (tok, fin, nvalid0, caches),
-            jnp.arange(chunk, dtype=jnp.int32))
-        return (toks.T, fin, nvalid,
-                [c[0] for c in caches], [c[1] for c in caches])
-
-    return jax.jit(run, donate_argnums=(8, 9))

@@ -6,10 +6,10 @@ on the 7th decode step" must mean the same step on every run, or a chaos
 test that passes proves nothing.  The plane is a list of ``FaultSpec``s
 evaluated at named **sites** woven into the scheduler hot path
 (``EngineCore``), the KV block pool reservation path and the compiled
-prefill/decode/page-copy program dispatches:
+step/page-copy program dispatches:
 
-  ``decode.step``    before each fused decode chunk dispatch
-  ``prefill.run``    before each compiled (suffix) prefill dispatch
+  ``decode.step``    before each mixed-step dispatch
+  ``prefill.run``    at each admission, after KV staging
   ``kv.alloc``       before each slot KV reservation
   ``page.copy``      before each CoW page-copy dispatch
   ``prefix.match``   before each radix-tree prefix lookup

@@ -167,7 +167,7 @@ class StepPlanner:
                 ("prefill" if chunk_tokens else "decode"))
         bts, _, _ = self._cost_model.estimate(
             kind, key, rows=max(rows, 1), max_rows=self._max_batch,
-            pages_touched=pages, chunk=1, tokens=tokens)
+            pages_touched=pages, tokens=tokens)
         return tokens, self.predict_wall(bts)
 
     def plan(self, *, n_decode: int, pending: List[int], pages: int,

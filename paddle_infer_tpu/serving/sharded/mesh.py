@@ -147,8 +147,7 @@ def validate_moe_quant_combo(moe_quant: Optional[str], *,
 def validate_cache_layout(cache_layout, *, mp: int = 1,
                           kv_dtype: Optional[str] = None,
                           speculate: bool = False,
-                          kv_host_pages: int = 0, handoff: bool = False,
-                          ragged: bool = True):
+                          kv_host_pages: int = 0, handoff: bool = False):
     """What a ``latent`` cache layer (inference/cache_layout.py: one
     ``[P, page, lanes]`` pool, no head axis, no V pool) cannot do yet —
     refused here, at start-up, one mechanism a sentence.  Silent for
@@ -179,11 +178,6 @@ def validate_cache_layout(cache_layout, *, mp: int = 1,
             "KV handoff between replicas serialises a row's key and "
             "value pools; a latent cache layer has one pool — serve it "
             "without a dedicated prefill role")
-    if not ragged:
-        raise ShardedConfigError(
-            "ragged=False runs the per-plen prefill and fused decode "
-            "programs, which know key/value pools only; a latent cache "
-            "layer is served through the mixed step")
 
 
 def validate_serving_config(cfg: ServingMesh, *, speculate: bool = False,

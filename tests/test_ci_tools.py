@@ -459,14 +459,10 @@ def test_tpulint_key_provenance_gate():
     comps = {c["expr"]: c["classes"] for c in mixed[0]["components"]}
     assert comps["'grammar'"] == ["const"]
     assert all("request-data" not in cl for cl in comps.values())
-    # the ONLY request-shaped components are the bucket-rounded plen
-    # of the legacy per-plen prefill family (reason-suppressed at the
-    # site; the table still records the truth)
+    # no site of the tree keys an executable on anything request-shaped
     reqs = [(s["site"], c["expr"]) for s in table["sites"]
             for c in s["components"] if "request-data" in c["classes"]]
-    assert reqs == [
-        ("paddle_infer_tpu/serving/engine_core.py::EngineCore._admit",
-         "plen")] * 2
+    assert reqs == []
     # deterministic: two runs, identical table JSON
     _, rep2 = run()
     assert json.dumps(rep2["table"], sort_keys=True) \
@@ -480,7 +476,9 @@ def test_tpulint_key_provenance_dot():
         env=_env(), timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr[-800:]
     assert r.stdout.startswith("digraph key_provenance")
-    assert '"request-data" [shape=octagon];' in r.stdout
+    # nothing request-shaped keys an executable of this tree (the
+    # octagon's rendering is held by tests/test_dataflow.py's fixture)
+    assert '"request-data"' not in r.stdout
     assert '"const"' in r.stdout and "serve-step" in r.stdout
 
 

@@ -92,7 +92,6 @@ def make_core(engines):
     def make(**kw):
         for k, v in CORE_SHAPE.items():
             kw.setdefault(k, v)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(pool.pop(0), **kw)
         cores.append(core)
         return core
@@ -642,7 +641,7 @@ def test_weight_only_fleet_handoff_parity(model):
     # reference (its pool drains fully before the handoff run), saving
     # a third executable compile for the quantized model
     cores = [EngineCore(PagedGenerationEngine(qm, page_size=8),
-                        decode_chunk=4, **CORE_SHAPE) for _ in range(2)]
+                        **CORE_SHAPE) for _ in range(2)]
     try:
         g = GenerationConfig(max_new_tokens=8, do_sample=True,
                              temperature=0.9, top_p=0.9, seed=3)

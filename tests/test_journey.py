@@ -89,7 +89,6 @@ def make_core(engines):
     def make(**kw):
         for k, v in CORE_SHAPE.items():
             kw.setdefault(k, v)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(pool.pop(0), **kw)
         cores.append(core)
         return core

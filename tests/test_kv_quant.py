@@ -99,7 +99,6 @@ def make_core(q_engines):
     def make(engine=None, **kw):
         for k, v in CORE_SHAPE.items():
             kw.setdefault(k, v)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(engine if engine is not None else pool.pop(0),
                           **kw)
         cores.append(core)
@@ -324,7 +323,7 @@ def test_handoff_refused_between_quantized_and_fp_pools(make_core,
     exchange page bytes."""
     g = GenerationConfig(max_new_tokens=8)
     src = ReplicaHandle("p0", make_core(), ReplicaRole.PREFILL)
-    dst_core = EngineCore(fp_engine, **CORE_SHAPE, decode_chunk=4)
+    dst_core = EngineCore(fp_engine, **CORE_SHAPE)
     try:
         dst = ReplicaHandle("d0", dst_core, ReplicaRole.DECODE)
         req = src.core.submit(_prompt(43, 24), g)[0]
@@ -361,7 +360,7 @@ def test_mixed_traffic_fuzz_int8_invariants_and_zero_compiles(
     # OTHER engines; this test's own warmup would otherwise count as
     # post-warmup decode recompiles
     log.reset()
-    core = make_core(ragged=True)
+    core = make_core()
     ref = q_engines[-1]                        # never core-owned
     total = core._pool.num_blocks
     # warmup: one request per prompt-length bucket, greedy and sampled,
@@ -488,7 +487,7 @@ def test_weight_only_checkpoint_serves_and_reports():
     again = np.asarray(eng.generate(_prompt(71, 12)[None], g))
     np.testing.assert_array_equal(first, again)
 
-    core = EngineCore(eng, **CORE_SHAPE, decode_chunk=4)
+    core = EngineCore(eng, **CORE_SHAPE)
     try:
         (r,) = core.submit(_prompt(72, 12), g)
         _drive(core, [r])

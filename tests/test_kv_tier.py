@@ -70,7 +70,7 @@ def engine_int8(model):
     return PagedGenerationEngine(model, page_size=8, kv_dtype="int8")
 
 
-CORE_KW = dict(max_batch=2, decode_chunk=4, max_model_len=48)
+CORE_KW = dict(max_batch=2, max_model_len=48)
 TIER_PAGES = 64
 
 
@@ -173,11 +173,6 @@ class TestHostKVTier:
         assert t.restart_reconciles_total == 1
         assert sorted(rid for rid, _ in t.drain_parked()) == [1, 2]
         assert t.parked_count == 0 and t.resident_pages == 0
-
-
-def test_kv_host_pages_requires_ragged(engine):
-    with pytest.raises(ValueError, match="ragged"):
-        EngineCore(engine, ragged=False, kv_host_pages=8, **CORE_KW)
 
 
 # --------------------------------------------------- bitwise parity matrix
@@ -632,7 +627,7 @@ def test_park_resume_fuzz_invariants(engine):
     def run(do_park):
         request_mod._rid_counter = itertools.count(9600)
         core = EngineCore(engine, enable_prefix_cache=True,
-                          kv_host_pages=48, max_batch=4, decode_chunk=4,
+                          kv_host_pages=48, max_batch=4,
                           max_model_len=48)
         try:
             baseline = core._pool.free_blocks

@@ -353,7 +353,6 @@ REFUSALS = {
     "speculate": (dict(speculate=True), "verify lanes"),
     "host tier": (dict(kv_host_pages=8), "host KV tier"),
     "handoff": (dict(handoff=True), "KV handoff"),
-    "legacy programs": (dict(ragged=False), "mixed step"),
 }
 
 
@@ -369,7 +368,7 @@ def test_what_a_latent_layer_cannot_do_is_refused_at_start_up(what):
     validate_cache_layout([LayerCache.latent(24)])
 
 
-def test_engine_core_and_the_legacy_programs_refuse_a_latent_model():
+def test_engine_core_and_the_offline_programs_refuse_a_latent_model():
     from paddle_infer_tpu.inference.generation import (GenerationConfig,
                                                        PagedGenerationEngine)
     from paddle_infer_tpu.serving import EngineCore, ServingMesh
@@ -390,8 +389,7 @@ def test_engine_core_and_the_legacy_programs_refuse_a_latent_model():
                    max_batch=2, max_model_len=64)
     eng = PagedGenerationEngine(model)
     for kw, says in ((dict(speculate=True), "verify lanes"),
-                     (dict(kv_host_pages=4), "host KV tier"),
-                     (dict(ragged=False), "mixed step")):
+                     (dict(kv_host_pages=4), "host KV tier")):
         with pytest.raises(ShardedConfigError, match=says):
             EngineCore(eng, max_batch=2, max_model_len=64, **kw)
     ids = np.arange(5, dtype=np.int32)[None]

@@ -398,8 +398,7 @@ def test_engine_core_instrumented_end_to_end():
         model.eval()
         engine = PagedGenerationEngine(model, page_size=8)
         core = EngineCore(engine, max_batch=2, max_model_len=48,
-                          token_budget=16, prefill_chunk=16,
-                          decode_chunk=4)
+                          token_budget=16, prefill_chunk=16)
         prompt = np.random.RandomState(7).randint(
             0, 96, (8,)).astype(np.int32)
         (req,) = core.submit(prompt, GenerationConfig(max_new_tokens=6))
@@ -443,7 +442,6 @@ def test_structured_instrumented_end_to_end():
         engine = PagedGenerationEngine(model, page_size=8)
         core = EngineCore(engine, max_batch=2, max_model_len=48,
                           token_budget=16, prefill_chunk=16,
-                          decode_chunk=4, ragged=True,
                           grammar_vocab=default_vocab(96))
         prompt = np.random.RandomState(7).randint(
             0, 96, (8,)).astype(np.int32)

@@ -48,9 +48,6 @@ def model():
 
 @pytest.fixture(scope="module")
 def engine(model):
-    # prompt_bucket < max positions so a cached prefix actually shrinks
-    # the padded suffix (with bucket == window every suffix pads to the
-    # full window and admission correctly degrades to cold)
     return PagedGenerationEngine(model, page_size=8, prompt_bucket=16)
 
 
@@ -60,7 +57,6 @@ def make_core(engine):
 
     def make(**kw):
         kw.setdefault("max_batch", 2)
-        kw.setdefault("decode_chunk", 4)
         kw.setdefault("enable_prefix_cache", True)
         core = EngineCore(engine, **kw)
         cores.append(core)
@@ -235,12 +231,13 @@ def test_prefix_cache_fuzz():
 
 
 # --------------------------------------------------------------- parity
-def test_windowed_prefill_logits_bitwise_equal(model):
-    """Cold full prefill vs warm suffix prefill over shared blocks:
-    the windowed program family keeps the attention reduce window at
-    the constant table width, so logits at the same absolute positions
-    are EXACTLY equal (np.array_equal on raw float32), not just
-    allclose — across two different suffix-length executables."""
+def test_chunk_logits_bitwise_equal_warm_and_cold(model):
+    """Cold full prefill vs warm suffix prefill over shared blocks,
+    through the mixed step's cache tuple: the ragged kernel walks each
+    row's pages the same way whatever the chunk carries, so logits at
+    the same absolute positions are EXACTLY equal (np.array_equal on
+    raw float32), not just allclose — across two executables of
+    different chunk widths."""
     import jax
     import jax.numpy as jnp
 
@@ -248,22 +245,24 @@ def test_windowed_prefill_logits_bitwise_equal(model):
     pool = eng.serving_pool(17)
     L = eng._num_layers
     max_pages = 4
+    scratch = np.asarray(16, np.int32)
     prompt = _prompt(7, 20)
 
-    def logits_builder(plen):
+    def logits_builder(width):
         def build():
-            def run(params, ids, offsets, tables, k_pages, v_pages):
+            def run(params, ids, qlens, ctx, tables, k_pages, v_pages):
                 b = ids.shape[0]
-                marker = jnp.zeros((b,), jnp.int32)
-                caches = [(k_pages[i], v_pages[i], tables, offsets,
-                           marker) for i in range(L)]
-                pos2d = offsets[:, None] + jnp.broadcast_to(
-                    jnp.arange(plen, dtype=jnp.int32)[None], (b, plen))
+                caches = [(k_pages[i], v_pages[i], tables, ctx, qlens,
+                           scratch) for i in range(L)]
+                i2d = jnp.broadcast_to(
+                    jnp.arange(width, dtype=jnp.int32)[None], (b, width))
+                pos2d = jnp.where(i2d < qlens[:, None],
+                                  ctx[:, None] + i2d, 0)
                 logits, caches = eng._model_step(params, ids, pos2d,
                                                  None, caches)
                 return (logits, [c[0] for c in caches],
                         [c[1] for c in caches])
-            return jax.jit(run, donate_argnums=(4, 5))
+            return jax.jit(run, donate_argnums=(5, 6))
         return build
 
     pool.reserve(0, 32)
@@ -274,7 +273,7 @@ def test_windowed_prefill_logits_bitwise_equal(model):
     ids0[0, :20] = prompt
     (cold,) = eng.run_paged_program(
         ("px-parity-cold", 32), logits_builder(32), ids0,
-        np.zeros((1,), np.int32), tables0)
+        np.full((1,), 20, np.int32), np.zeros((1,), np.int32), tables0)
     cold = np.asarray(cold)
 
     c = 16                                    # 2 shared full pages
@@ -288,7 +287,7 @@ def test_windowed_prefill_logits_bitwise_equal(model):
     ids1[0, :4] = prompt[c:20]
     (warm,) = eng.run_paged_program(
         ("px-parity-warm", 16), logits_builder(16), ids1,
-        np.full((1,), c, np.int32), tables1)
+        np.full((1,), 4, np.int32), np.full((1,), c, np.int32), tables1)
     warm = np.asarray(warm)
 
     assert np.array_equal(warm[0, :4], cold[0, c:20])
@@ -306,7 +305,7 @@ def test_warm_token_stream_identical_with_cow(make_core, engine):
 
     # no-cache reference stream first (cores share the engine's pool,
     # so never run two cores concurrently)
-    ref = EngineCore(engine, max_batch=2, decode_chunk=4)
+    ref = EngineCore(engine, max_batch=2)
     try:
         (r0,) = ref.submit(prompt, g)
         _drive(ref, [r0])
@@ -396,16 +395,13 @@ def test_mid_decode_failure_releases_all_blocks(make_core, engine,
     assert again.error is None
 
 
-@pytest.mark.parametrize("ragged", [True, False])
-def test_prefill_failure_releases_match(make_core, ragged):
+def test_prefill_failure_releases_match(make_core):
     """A prefill failure on a warm-hit admission must release the
     request's pins while leaving the tree intact.  Injected via the
-    ``prefill.run`` fault site — the one prefill hook both serving
-    kernels share (the legacy path fires it before the suffix-prefill
-    dispatch, the ragged path at KV staging)."""
+    ``prefill.run`` fault site, which fires at KV staging."""
     from paddle_infer_tpu.serving import FaultPlane, FaultSpec
 
-    core = make_core(ragged=ragged, fault_plane=FaultPlane(
+    core = make_core(fault_plane=FaultPlane(
         [FaultSpec("prefill.run", at=2)]))
     prompt = _prompt(8, 20)
     (warm,) = core.submit(prompt, GenerationConfig(max_new_tokens=4))

@@ -9,19 +9,20 @@ Three layers of coverage:
   (allclose at float32 rounding: the online softmax reassociates), for
   bf16 and int8 pools over every row type; and its schedule
   independence, bitwise;
-* parity — ragged serving streams against the legacy per-program path
-  (``ragged=False``) for greedy AND seeded-sampled requests, including
-  warm prefix-cache hits and supervisor replay after KV loss.  The two
-  families no longer share their attention arithmetic (the mixed step
-  runs the kernel, the legacy programs SDPA and the dense windowed
-  composition), so their logits agree to float32 rounding and not by
-  construction; on this tiny float32 model no argmax or sampled draw
-  sits that close to a tie, and the streams are still compared token
-  for token.  What IS equal by construction is the kernel against
-  itself (schedule independence, above).  Sampled comparisons pin the
-  request-id counter: per-request sampling keys are
-  ``fold_in(PRNGKey(seed), rid)``, so the two runs must hand out the
-  same rids;
+* parity — served token streams against oracles that are not the
+  served schedule: greedy streams (cold, and prompts cut into chunks at
+  lengths that straddle a page and a chunk boundary) against a direct
+  ``generate()`` of the offline paged engine, an independent program
+  whose logits agree with the mixed step's to float32 rounding (on this
+  tiny float32 model no argmax sits that close to a tie); seeded-sampled
+  streams against the SAME executable under another co-schedule (the
+  requests served one at a time), warm prefix-cache hits against a core
+  without the cache, and supervisor replay after KV loss against the
+  uninterrupted stream — equal bit for bit, because the kernel is
+  schedule independent (above) and every other operation of the step
+  is row-wise.  Sampled comparisons pin the request-id counter:
+  per-request sampling keys are ``fold_in(PRNGKey(seed), rid)``, so the
+  two runs must hand out the same rids;
 * composition fuzz — 160+ scheduler steps of random arrivals (chunked
   long prompts, decode, mixed, drained-idle) with pool invariants
   checked every step and ZERO new XLA compiles after the one-step
@@ -45,10 +46,11 @@ from paddle_infer_tpu.serving import request as request_mod
 
 @pytest.fixture(scope="module", autouse=True)
 def _meshless():
-    """Ragged-vs-legacy parity compares tokens across differently-shaped
-    executables, which is steady only when both run unsharded — clear
-    any hybrid mesh a failing test in another module leaked behind
-    (ops consult ``topology.get_current_mesh()`` at call time)."""
+    """The parity tests compare tokens across differently-shaped
+    executables (the mixed step and ``generate()``'s), which is steady
+    only when both run unsharded — clear any hybrid mesh a failing test
+    in another module leaked behind (ops consult
+    ``topology.get_current_mesh()`` at call time)."""
     from paddle_infer_tpu.parallel import topology
 
     prev = topology.get_current_mesh()
@@ -108,7 +110,6 @@ def make_core(engine):
     def make(**kw):
         for k, v in CORE_SHAPE.items():
             kw.setdefault(k, v)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(engine, **kw)
         cores.append(core)
         return core
@@ -390,69 +391,87 @@ def test_served_kernel_is_schedule_independent_bitwise(pool):
 
 # ------------------------------------------------------------------ parity
 
-def _serve(engine, prompts, cfgs, ragged, rid_base, **kw):
-    """Run one batch of requests through a fresh core with the rid
-    counter pinned, returning the emitted streams."""
+def _serve(engine, prompts, cfgs, rid_base, together=True, **kw):
+    """Run the requests through a fresh core with the rid counter
+    pinned — all submitted at once, or one at a time (each alone in the
+    batch, same rids) — returning the emitted streams."""
     for k, v in CORE_SHAPE.items():
         kw.setdefault(k, v)
     request_mod._rid_counter = itertools.count(rid_base)
-    core = EngineCore(engine, ragged=ragged, **kw)
+    core = EngineCore(engine, **kw)
     try:
-        reqs = [core.submit(p, g)[0] for p, g in zip(prompts, cfgs)]
-        _drive(core, reqs)
+        if together:
+            reqs = [core.submit(p, g)[0] for p, g in zip(prompts, cfgs)]
+            _drive(core, reqs)
+        else:
+            reqs = []
+            for p, g in zip(prompts, cfgs):
+                reqs.append(core.submit(p, g)[0])
+                _drive(core, reqs[-1:])
         assert all(r.state is RequestState.DONE for r in reqs)
         return [np.asarray(r.padded_result()) for r in reqs]
     finally:
         core.close()
 
 
-@pytest.mark.parametrize("sampled", [False, True],
-                         ids=["greedy", "sampled"])
-def test_ragged_stream_bitwise_equals_legacy(engine, sampled):
-    """For the same admissions (same rids), the ragged mixed-step path
-    emits the token streams the legacy cold prefill + fused decode path
-    does — greedy and seeded-sampled (logits equal to float32 rounding;
-    see the module docstring)."""
+def test_greedy_stream_equals_offline_generate(engine, ref):
+    """Three prompts admitted together (their chunks and decode rows
+    share mixed steps) emit, each, the greedy stream a direct paged
+    ``generate()`` of the prompt alone does."""
     prompts = [_prompt(1, 11), _prompt(2, 21), _prompt(3, 5)]
-    if sampled:
-        cfgs = [GenerationConfig(max_new_tokens=8, do_sample=True,
-                                 temperature=0.8, top_k=12, top_p=0.9,
-                                 seed=7),
-                GenerationConfig(max_new_tokens=6, do_sample=True,
-                                 temperature=1.2, seed=11),
-                GenerationConfig(max_new_tokens=7, do_sample=True,
-                                 top_k=5, seed=3)]
-    else:
-        cfgs = [GenerationConfig(max_new_tokens=8),
-                GenerationConfig(max_new_tokens=6),
-                GenerationConfig(max_new_tokens=7)]
-    legacy = _serve(engine, prompts, cfgs, ragged=False, rid_base=5000,
-                    decode_chunk=4)
-    ragged = _serve(engine, prompts, cfgs, ragged=True, rid_base=5000)
-    for lg, rg in zip(legacy, ragged):
-        np.testing.assert_array_equal(rg, lg)
+    cfgs = [GenerationConfig(max_new_tokens=8),
+            GenerationConfig(max_new_tokens=6),
+            GenerationConfig(max_new_tokens=7)]
+    served = _serve(engine, prompts, cfgs, rid_base=5000)
+    for ids, g, got in zip(prompts, cfgs, served):
+        np.testing.assert_array_equal(got, ref.generate(ids[None], g)[0])
 
 
-def test_ragged_chunked_long_prompt_matches_legacy_and_ref(engine, ref):
-    """A prompt longer than the prefill chunk crosses several mixed
-    steps; the stream must still equal both the legacy path and a
-    direct paged generate()."""
-    ids = _prompt(4, 40)
-    g = GenerationConfig(max_new_tokens=8)
-    (legacy,) = _serve(engine, [ids], [g], ragged=False, rid_base=5100,
-                       decode_chunk=4)
-    (ragged,) = _serve(engine, [ids], [g], ragged=True, rid_base=5100)
-    np.testing.assert_array_equal(ragged, legacy)
-    np.testing.assert_array_equal(ragged, ref.generate(ids[None], g)[0])
+def test_sampled_stream_is_co_schedule_independent_bitwise(engine):
+    """Seeded-sampled requests emit the same streams whether they share
+    their mixed steps (chunks cut by the shared token budget, decode
+    rows beside other rows) or are served one at a time under the same
+    rids: the stream-level face of the kernel's schedule independence."""
+    prompts = [_prompt(1, 11), _prompt(2, 21), _prompt(3, 5)]
+    cfgs = [GenerationConfig(max_new_tokens=8, do_sample=True,
+                             temperature=0.8, top_k=12, top_p=0.9,
+                             seed=7),
+            GenerationConfig(max_new_tokens=6, do_sample=True,
+                             temperature=1.2, seed=11),
+            GenerationConfig(max_new_tokens=7, do_sample=True,
+                             top_k=5, seed=3)]
+    together = _serve(engine, prompts, cfgs, rid_base=5000)
+    alone = _serve(engine, prompts, cfgs, rid_base=5000, together=False)
+    for tg, al in zip(together, alone):
+        np.testing.assert_array_equal(tg, al)
+
+
+_PAGE = 8
+_CHUNK = CORE_SHAPE["prefill_chunk"]
+_MAX_NEW = 8
+
+
+@pytest.mark.parametrize("length", [
+    _PAGE - 1, _PAGE, _PAGE + 1, _CHUNK - 1, _CHUNK, _CHUNK + 1,
+    2 * _CHUNK + 1, CORE_SHAPE["max_model_len"] - _MAX_NEW])
+def test_chunked_prompt_matches_offline_generate(engine, ref, length):
+    """Prompts whose lengths straddle a page and a chunk boundary, up to
+    the longest the window admits (three mixed steps of prefill): the
+    stream equals a direct paged generate(), which pads the prompt to
+    its bucket and prefills it in one program."""
+    ids = _prompt(4, length)
+    g = GenerationConfig(max_new_tokens=_MAX_NEW)
+    (served,) = _serve(engine, [ids], [g], rid_base=5100)
+    np.testing.assert_array_equal(served, ref.generate(ids[None], g)[0])
 
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
-def test_ragged_warm_prefix_hit_bitwise_equals_legacy(engine, sampled):
-    """Warm prefix-cache hits (full and partial-tail) emit the legacy
-    family's streams: the ragged path stages the matched pages and
-    chunks only the uncached suffix, whose positions the kernel computes
-    as it would have in a cold chunk."""
+def test_warm_prefix_hit_bitwise_equals_cold(engine, sampled):
+    """Warm prefix-cache hits (full and partial-tail) emit the streams a
+    core without the cache does: the warm path stages the matched pages
+    and chunks only the uncached suffix, whose positions the kernel
+    computes as it would have in a cold chunk."""
     base = _prompt(5, 24)
     tail = np.concatenate([base[:16], _prompt(6, 6)])
     if sampled:
@@ -461,47 +480,46 @@ def test_ragged_warm_prefix_hit_bitwise_equals_legacy(engine, sampled):
     else:
         g = GenerationConfig(max_new_tokens=6)
 
-    def run(ragged):
+    def run(enable_prefix_cache):
         request_mod._rid_counter = itertools.count(5200)
-        core = EngineCore(engine, ragged=ragged, decode_chunk=4,
-                          enable_prefix_cache=True, **CORE_SHAPE)
+        core = EngineCore(engine, enable_prefix_cache=enable_prefix_cache,
+                          **CORE_SHAPE)
         try:
             outs = []
             for ids in (base, base, tail):   # cold, full hit, partial
                 (r,) = core.submit(ids, g)
                 _drive(core, [r])
                 outs.append(np.asarray(r.padded_result()))
-            stats = core.prefix_cache.stats_snapshot()
-            assert stats["hits"] >= 2, "warm admissions never hit"
+            if enable_prefix_cache:
+                stats = core.prefix_cache.stats_snapshot()
+                assert stats["hits"] >= 2, "warm admissions never hit"
             return outs
         finally:
             core.close()
 
-    legacy, ragged = run(False), run(True)
-    for lg, rg in zip(legacy, ragged):
-        np.testing.assert_array_equal(rg, lg)
+    cold, warm = run(False), run(True)
+    for cd, wm in zip(cold, warm):
+        np.testing.assert_array_equal(wm, cd)
 
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
-def test_ragged_replay_after_kv_loss_equals_legacy_stream(engine, sampled):
+def test_replay_after_kv_loss_equals_uninterrupted_stream(engine, sampled):
     """Supervisor replay parity: a mid-decode crash that loses the KV
-    pools replays the in-flight row; the recovered ragged stream equals
-    the legacy path's uninterrupted one (same rid, so sampled rows
-    resume at the original fold_in offsets)."""
+    pools replays the in-flight row; the recovered stream equals the
+    uninterrupted one (same rid, so sampled rows resume at the original
+    fold_in offsets)."""
     ids = _prompt(7, 10)
     if sampled:
         g = GenerationConfig(max_new_tokens=12, do_sample=True,
                              temperature=0.8, top_k=12, seed=17)
     else:
         g = GenerationConfig(max_new_tokens=12)
-    (want,) = _serve(engine, [ids], [g], ragged=False, rid_base=5300,
-                     decode_chunk=4)
+    (want,) = _serve(engine, [ids], [g], rid_base=5300)
 
     request_mod._rid_counter = itertools.count(5300)
     plane = FaultPlane([FaultSpec("decode.step", at=4, lose_kv=True)])
-    core = EngineCore(engine, ragged=True, fault_plane=plane,
-                      **CORE_SHAPE)
+    core = EngineCore(engine, fault_plane=plane, **CORE_SHAPE)
     sup = EngineSupervisor(core)
     try:
         (req,) = core.submit(ids, g)
@@ -527,7 +545,7 @@ def test_composition_fuzz_invariants_and_zero_compiles(engine, ref):
     from paddle_infer_tpu.observability import get_compile_log
 
     log = get_compile_log()
-    core = EngineCore(engine, ragged=True, **CORE_SHAPE)
+    core = EngineCore(engine, **CORE_SHAPE)
     try:
         total = core._pool.num_blocks
         (w,) = core.submit(_prompt(900, 20), GenerationConfig(
@@ -590,7 +608,7 @@ def test_composition_fuzz_invariants_and_zero_compiles(engine, ref):
 def test_steplog_records_kernel_and_chunk_fields(make_core):
     """StepLog satellite: ragged steps record kernel="ragged" and
     chunked-prefill token counts; the summary aggregates both."""
-    core = make_core(ragged=True, prefill_chunk=8)
+    core = make_core(prefill_chunk=8)
     (r,) = core.submit(_prompt(8, 20), GenerationConfig(max_new_tokens=4))
     _drive(core, [r])
     records = core.steplog.records()

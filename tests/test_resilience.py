@@ -87,7 +87,6 @@ def make_sup(engine):
 
     def make(plane=None, **kw):
         core_kw = {"max_batch": kw.pop("max_batch", 2),
-                   "decode_chunk": kw.pop("decode_chunk", 4),
                    "max_model_len": kw.pop("max_model_len", 48),
                    "enable_prefix_cache": kw.pop("enable_prefix_cache",
                                                  False),
@@ -207,7 +206,7 @@ def test_replay_after_kv_loss_preserves_greedy_stream(make_sup, ref):
 
     # decode fire #3 (after prefill + two clean chunks of 4) crashes
     plane = FaultPlane([FaultSpec("decode.step", at=3, lose_kv=True)])
-    core, sup = make_sup(plane, decode_chunk=4)
+    core, sup = make_sup(plane)
     (req,) = core.submit(ids, g)
     _drive(sup, [req])
     np.testing.assert_array_equal(req.padded_result(), want)
@@ -229,7 +228,7 @@ def test_replay_sampled_row_draws_the_same_stream(make_sup):
 
     def run(plane):
         request_mod._rid_counter = itertools.count(7000)
-        core, sup = make_sup(plane, decode_chunk=4)
+        core, sup = make_sup(plane)
         (req,) = core.submit(ids, g)
         _drive(sup, [req])
         return req
@@ -278,7 +277,7 @@ def test_crash_loop_goes_down_and_resume_recovers(make_sup):
 
 def test_expired_request_is_cancelled_not_replayed(make_sup):
     plane = FaultPlane([FaultSpec("decode.step", at=2)])
-    core, sup = make_sup(plane, decode_chunk=4)
+    core, sup = make_sup(plane)
     (req,) = core.submit(_prompt(5), GenerationConfig(max_new_tokens=12),
                          timeout_s=0.05)
     sup.run_once()                       # admit + first chunk
@@ -298,7 +297,7 @@ def test_expired_request_is_cancelled_not_replayed(make_sup):
 
 def test_memory_pressure_halves_batch_then_ladder_recovers(make_sup):
     plane = FaultPlane([FaultSpec("kv.alloc", at=1, exc="MemoryError")])
-    core, sup = make_sup(plane, max_batch=4, decode_chunk=4,
+    core, sup = make_sup(plane, max_batch=4,
                          recover_after=1)
     assert core.effective_max_batch == 4
     reqs = [core.submit(_prompt(10 + i), GenerationConfig(
@@ -316,7 +315,7 @@ def test_memory_pressure_halves_batch_then_ladder_recovers(make_sup):
 def test_second_pressure_sheds_queued_low_headroom(make_sup):
     specs = [FaultSpec("kv.alloc", at=1, exc="MemoryError"),
              FaultSpec("kv.alloc", at=2, exc="MemoryError")]
-    core, sup = make_sup(FaultPlane(specs), max_batch=1, decode_chunk=4,
+    core, sup = make_sup(FaultPlane(specs), max_batch=1,
                          shed_headroom_s=5.0, recover_after=100)
     g = GenerationConfig(max_new_tokens=8)
     # the OOM magnet has no deadline (never shed); the doomed request
@@ -346,7 +345,7 @@ def test_nan_logits_quarantine_only_the_offending_row(make_sup, ref):
     request_mod._rid_counter = itertools.count(7100)
     plane = FaultPlane([FaultSpec("decode.step", action="nan_rows",
                                   at=2, rid=7100)])
-    core, sup = make_sup(plane, decode_chunk=4)
+    core, sup = make_sup(plane)
     (ra,) = core.submit(ids_a, ga)
     (rb,) = core.submit(ids_b, ga)
     _drive(sup, [ra, rb])
@@ -366,7 +365,7 @@ def test_nan_logits_quarantine_only_the_offending_row(make_sup, ref):
 def test_watchdog_trips_on_hung_step(make_sup):
     plane = FaultPlane([FaultSpec("decode.step", action="hang", at=2,
                                   delay_s=0.25)])
-    core, sup = make_sup(plane, decode_chunk=4, watchdog_s=0.1)
+    core, sup = make_sup(plane, watchdog_s=0.1)
     (req,) = core.submit(_prompt(40), GenerationConfig(max_new_tokens=8))
     sup.run_once()                       # admit + first (clean) chunk
     trips0 = core.metrics.watchdog_trips
@@ -420,7 +419,7 @@ def test_drain_resume_gate_admission(make_sup):
 
 
 def test_supervisor_background_thread_and_stop(make_sup):
-    core, sup = make_sup(decode_chunk=4)
+    core, sup = make_sup()
     sup.start()
     (req,) = core.submit(_prompt(42), GenerationConfig(max_new_tokens=8))
     req.result(timeout=60)
@@ -454,14 +453,12 @@ def test_seeded_chaos_exact_streams_across_200_steps(model):
                if i % 8 == 5 else
                GenerationConfig(max_new_tokens=max_new)
                for i in range(n_req)]
-    # prompt_bucket < window, or every cached prefix is trimmed away
-    # (suffix pads to the full window) and CoW/replay reuse never runs
     chaos_engine = PagedGenerationEngine(model, page_size=8,
                                          prompt_bucket=16)
 
     def run(plane):
         request_mod._rid_counter = itertools.count(5000)
-        core = EngineCore(chaos_engine, max_batch=4, decode_chunk=1,
+        core = EngineCore(chaos_engine, max_batch=4,
                           max_queue=64, max_model_len=40,
                           enable_prefix_cache=True, fault_plane=plane)
         sup = EngineSupervisor(core, watchdog_s=0.5, max_retries=3,
@@ -575,7 +572,7 @@ def test_resilience_counters_render_as_prometheus_families(make_sup):
 
 def test_fault_counts_reach_metrics_snapshot(make_sup):
     plane = FaultPlane([FaultSpec("decode.step", at=1)])
-    core, sup = make_sup(plane, decode_chunk=4)
+    core, sup = make_sup(plane)
     (req,) = core.submit(_prompt(60), GenerationConfig(max_new_tokens=8))
     _drive(sup, [req])
     text = core.metrics.to_prometheus(core.metrics_snapshot())

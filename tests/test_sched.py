@@ -47,7 +47,6 @@ def make_core(engine):
 
     def make(**kw):
         kw.setdefault("max_batch", 2)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(engine, **kw)
         cores.append(core)
         return core
@@ -202,7 +201,7 @@ class _FlatCost:
     wall proportional to planned tokens so the halving loop is exact."""
 
     def estimate(self, kind, key=None, *, rows, max_rows, pages_touched,
-                 chunk, tokens):
+                 tokens):
         return float(tokens), 0.0, "analytic"
 
 
@@ -367,12 +366,6 @@ def test_cold_slack_never_sheds(make_core):
     _drive(core, reqs)
     assert all(r.state is RequestState.DONE for r in reqs)
     assert core.metrics_snapshot()["sched"]["predictive_sheds"] == 0
-
-
-def test_slack_requires_ragged(engine):
-    with pytest.raises(ValueError, match="requires ragged"):
-        EngineCore(engine, max_batch=2, ragged=False,
-                   sched_policy="slack")
 
 
 # ------------------------------------------------ engine: observability

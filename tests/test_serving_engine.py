@@ -50,7 +50,6 @@ def make_core(engine):
 
     def make(**kw):
         kw.setdefault("max_batch", 2)
-        kw.setdefault("decode_chunk", 4)
         core = EngineCore(engine, **kw)
         cores.append(core)
         return core
@@ -87,7 +86,7 @@ def test_late_arrival_joins_inflight_batch(make_core, ref):
     """A request enqueued AFTER another started decoding must decode in
     the same fused step (continuous batching, not stop-the-world) —
     asserted via the step trace — and both rows stay correct."""
-    core = make_core(decode_chunk=1)
+    core = make_core()
     g = GenerationConfig(max_new_tokens=8)
     (ra,) = core.submit(_prompt(1), g)
     core.run_once()                      # admit A + first decode step
@@ -256,7 +255,7 @@ def test_trace_spans_cover_request_wall_time(make_core):
     """Acceptance: every request's trace attributes >=95% of its
     end-to-end wall time to explicit spans — queue_wait, prefill, one
     decode span per fused chunk, evict — stitched edge-to-edge."""
-    core = make_core(decode_chunk=2)
+    core = make_core()
     g = GenerationConfig(max_new_tokens=8)
     reqs = [core.submit(_prompt(40 + i), g)[0] for i in range(3)]
     _drive(core, reqs)
@@ -311,8 +310,7 @@ def test_decode_loop_compile_free_after_warmup(make_core, ref):
     warm = GenerationConfig(max_new_tokens=4)
     (r0,) = core.submit(_prompt(50), warm)
     _drive(core, [r0])                   # warmup: compiles are expected
-    dkey = ("serve-step", core._max_batch,
-            core._token_budget if core._ragged else core._decode_chunk,
+    dkey = ("serve-step", core._max_batch, core._token_budget,
             core._max_pages, core._pool.num_blocks)
     assert log.is_warm("serving-decode", dkey)
     # the compile log is process-global and other tests of this worker
@@ -483,15 +481,14 @@ def test_close_escalates_past_wedged_external_step(make_core):
     core = make_core(max_batch=1)
     entered = threading.Event()
     release = threading.Event()
-    step_attr = "_mixed_step" if core._ragged else "_decode_step"
-    orig_step = getattr(core, step_attr)
+    orig_step = core._mixed_step
 
     def slow_step():
         entered.set()
         release.wait(20.0)
         return orig_step()
 
-    setattr(core, step_attr, slow_step)
+    core._mixed_step = slow_step
     (ra,) = core.submit(_prompt(91), GenerationConfig(max_new_tokens=8))
 
     def worker():

@@ -13,8 +13,8 @@ routing-determinism bar MoE adds:
   2-device ep mesh;
 * config — every unservable combination (ep over a dense model, ep not
   dividing the expert count, int8-activation experts under speculation
-  without an accept margin, MoE over the legacy per-shape programs,
-  mixed expert counts/algos) is rejected at construction;
+  without an accept margin, mixed expert counts/algos) is rejected at
+  construction;
 * parity — the acceptance bar: EngineCore token streams over a MoE
   model are BITWISE identical to the unconverted engine, to ep=1 vs
   ep=2, and across supervisor replay, with zero post-warmup compiles
@@ -341,10 +341,6 @@ class TestMoEServingConfig:
             validate_moe_quant_combo("int8_act", speculate=True)
         validate_moe_quant_combo("int8_act", speculate=True,
                                  spec_accept_threshold=0.05)
-
-    def test_moe_requires_ragged_step(self, engine_single):
-        with pytest.raises(ShardedConfigError):
-            EngineCore(engine_single, ragged=False, **CORE_SHAPE)
 
     def test_mixed_expert_algos_rejected(self):
         m = _fresh_model()

@@ -86,7 +86,7 @@ def ref(model):
 # executable key, and the headroom is what lets the radix tree — the
 # draft source — survive next to a fully occupied batch.
 CORE_SHAPE = dict(max_batch=3, max_model_len=48, token_budget=16,
-                  prefill_chunk=16, decode_chunk=4,
+                  prefill_chunk=16,
                   enable_prefix_cache=True,
                   prefix_cache_headroom_pages=12)
 

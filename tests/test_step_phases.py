@@ -100,15 +100,14 @@ def _serve_some(core):
     return _step_records(core)
 
 
-@pytest.mark.parametrize("ragged", [True, False], ids=["mixed", "legacy"])
-def test_phases_tile_the_run(make_core, ragged):
-    core = make_core(ragged=ragged, decode_chunk=2)
+def test_phases_tile_the_run(make_core):
+    core = make_core()
     recs = _serve_some(core)
     assert len(recs) >= 4
     assert recs[0]["gap_s"] == 0.0
     assert [r["step"] for r in recs] == list(range(1, len(recs) + 1))
     for r in recs:
-        assert r["kernel"] == ("ragged" if ragged else "legacy")
+        assert r["kernel"] == "ragged"
         assert all(r[f] >= 0.0 for f in PHASE_FIELDS)
         assert r["dispatch_s"] == pytest.approx(
             r["launch_s"] + r["wait_s"], abs=1e-9)

@@ -191,7 +191,7 @@ def core():
         attention_probs_dropout_prob=0.0))
     model.eval()
     c = EngineCore(PagedGenerationEngine(model, page_size=8),
-                   max_batch=2, decode_chunk=4)
+                   max_batch=2)
     yield c
     c.close()
 
@@ -214,7 +214,7 @@ def test_cost_model_estimates(core):
     assert (b, src) == (2 * cm.page_kv_bytes, "analytic")
     # no program key -> analytic roofline, still nonzero
     b, f, src = cm.estimate("decode", None, rows=2, max_rows=2,
-                            pages_touched=4, chunk=4)
+                            pages_touched=4, tokens=8)
     assert src == "analytic" and b > 0 and f > 0
 
 
@@ -241,10 +241,8 @@ def test_steplog_records_every_bench_style_step(core):
             assert r["cost_source"] in ("xla+pages", "analytic")
         if r["kind"] == "decode":
             assert r["dispatch_s"] <= r["wall_s"] + 1e-9
-            # ragged mixed steps emit one token per decode row per
-            # scheduler step; the legacy fused chunk runs decode_chunk
-            assert r["chunk_steps"] == (1 if r["kernel"] == "ragged"
-                                        else 4)
+            # a mixed step emits one token per decode row
+            assert r["chunk_steps"] == 1
     assert {r["kernel"] for r in recs
             if r["kind"] in ("prefill", "decode")} == {"ragged"}
     model = core.steplog.summary()["decode_model"]

@@ -46,7 +46,7 @@ from paddle_infer_tpu.serving import (EngineCore, GrammarCache,
                                       GrammarError,
                                       GrammarIncompleteError,
                                       ReplicaHandle, ReplicaRole,
-                                      RequestState, ShardedConfigError,
+                                      RequestState,
                                       conforms, decode_text,
                                       default_vocab, grammar_digest)
 from paddle_infer_tpu.serving import request as request_mod
@@ -120,10 +120,10 @@ def engines(model):
     return [PagedGenerationEngine(model, page_size=8) for _ in range(3)]
 
 
-CORE_KW = dict(max_batch=2, decode_chunk=4, max_model_len=64)
+CORE_KW = dict(max_batch=2, max_model_len=64)
 # handoff needs chunked prefill so a 24-token prompt crosses a
 # boundary while still streaming — same shape as tests/test_fleet.py
-FLEET_KW = dict(max_batch=2, decode_chunk=4, max_model_len=64,
+FLEET_KW = dict(max_batch=2, max_model_len=64,
                 token_budget=16, prefill_chunk=16)
 
 
@@ -364,11 +364,6 @@ class TestAdmission:
             assert core.active_count == 0 and core.queue_depth == 0
         finally:
             core.close()
-
-    def test_grammar_vocab_requires_ragged(self, engine):
-        with pytest.raises(ShardedConfigError):
-            EngineCore(engine, ragged=False, grammar_vocab=VOCAB,
-                       **CORE_KW)
 
     def test_grammar_vocab_size_must_match_model(self, engine):
         with pytest.raises(ValueError, match="vocab"):

@@ -74,7 +74,7 @@ def main() -> int:
         return EngineCore(
             PagedGenerationEngine(model, page_size=16),
             max_batch=n_dec + 1, max_model_len=long_len + max_new,
-            ragged=True, token_budget=32,
+            token_budget=32,
             prefill_chunk=prefill_chunk).start()
 
     def measure(submit_short, submit_long):

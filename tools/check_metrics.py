@@ -57,15 +57,15 @@ def fabricated_exposition():
     steplog.record("prefill", wall_s=0.08, dispatch_s=0.07,
                    bytes_est=2.0e6, flops_est=5.0e6,
                    cost_source="xla+pages", emitted_tokens=1,
-                   kernel="legacy")
+                   kernel="ragged")
     steplog.record("decode", wall_s=0.010, dispatch_s=0.008,
                    bytes_est=1.0e6, flops_est=3.0e6,
                    cost_source="xla+pages", decode_rows=2, chunk_steps=4,
-                   kernel="legacy")
+                   kernel="ragged")
     steplog.record("decode", wall_s=0.021, dispatch_s=0.017,
                    bytes_est=2.1e6, flops_est=6.0e6,
                    cost_source="xla+pages", decode_rows=4, chunk_steps=4,
-                   kernel="legacy")
+                   kernel="ragged")
     steplog.record("mixed", wall_s=0.015, dispatch_s=0.012,
                    bytes_est=1.6e6, flops_est=4.5e6,
                    ici_bytes_est=4.0e4, ici_bytes_saved_est=1.2e5,
@@ -301,15 +301,15 @@ def fabricated_exposition():
          "active": 2},
     ]}
 
-    # local CompileLog (not the process singleton): one prefill, one
-    # warmed decode, one post-warmup recompile so the recompile/storm
+    # local CompileLog (not the process singleton): one page copy, one
+    # warmed step, one post-warmup recompile so the recompile/storm
     # families render with non-trivial values
     logging.getLogger("paddle_infer_tpu.observability").disabled = True
     try:
         log = CompileLog()
         dkey = ("serve-step", 4, 4, 8, 33)
-        log.record("serving-prefill", ("serve-prefill", 16, 8, 33),
-                   (((1, 16), "int32"),), 0.25)
+        log.record("serving-page-copy", ("serve-page-copy", 33),
+                   (((1,), "int32"),), 0.25)
         log.record("serving-decode", dkey, (((4,), "int32"),), 0.40)
         log.mark_warm("serving-decode", dkey)
         log.record("serving-decode", dkey, (((4,), "int32"),), 0.40)

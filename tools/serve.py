@@ -166,7 +166,6 @@ def _build_fleet(roles):
             engine,
             max_batch=_STATE["max_batch"],
             max_queue=_STATE["max_queue"],
-            decode_chunk=_STATE["decode_chunk"],
             default_timeout_s=_STATE["request_timeout"],
             max_model_len=_STATE["max_model_len"],
             tracer=Tracer(), steplog=steplog,
@@ -176,7 +175,6 @@ def _build_fleet(roles):
                 "prefix_cache_watermark", 0.5),
             prefix_cache_headroom_pages=_STATE.get(
                 "prefix_cache_headroom_pages", 0),
-            ragged=True,
             prefill_chunk=_STATE.get("prefill_chunk"),
             token_budget=_STATE.get("token_budget"),
             sched_policy=_STATE.get("sched_policy", "fifo"),
@@ -238,7 +236,6 @@ def _core():
                 engine,
                 max_batch=_STATE["max_batch"],
                 max_queue=_STATE["max_queue"],
-                decode_chunk=_STATE["decode_chunk"],
                 default_timeout_s=_STATE["request_timeout"],
                 max_model_len=_STATE["max_model_len"],
                 enable_prefix_cache=_STATE.get("enable_prefix_cache",
@@ -247,7 +244,6 @@ def _core():
                     "prefix_cache_watermark", 0.5),
                 prefix_cache_headroom_pages=_STATE.get(
                     "prefix_cache_headroom_pages", 0),
-                ragged=_STATE.get("ragged", True),
                 prefill_chunk=_STATE.get("prefill_chunk"),
                 token_budget=_STATE.get("token_budget"),
                 sched_policy=_STATE.get("sched_policy", "fifo"),
@@ -819,8 +815,6 @@ def main(argv=None):
                     help="continuous-batching slots (KV reservations)")
     ap.add_argument("--max_queue", type=int, default=64,
                     help="admission-control queue depth (beyond -> 429)")
-    ap.add_argument("--decode_chunk", type=int, default=4,
-                    help="fused decode steps per scheduler iteration")
     ap.add_argument("--request_timeout", type=float, default=None,
                     help="per-request deadline in seconds (beyond -> 504)")
     ap.add_argument("--max_model_len", type=int, default=None,
@@ -843,12 +837,6 @@ def main(argv=None):
                          "retention — keeps the radix tree (and the "
                          "tree-backed speculative draft source) resident "
                          "under a full batch (docs/SERVING.md)")
-    ap.add_argument("--prompt_bucket", type=int, default=None,
-                    help="DEPRECATED no-op: ragged mixed-batch attention "
-                         "removed prompt bucketing (prompts are chunked "
-                         "under --token_budget instead); the flag is "
-                         "still parsed so old launch scripts keep "
-                         "working")
     ap.add_argument("--token_budget", type=int, default=None,
                     help="per-step token budget for the ragged mixed "
                          "step: decode rows take one token each, the "
@@ -877,10 +865,6 @@ def main(argv=None):
                          "planner shrinks per-step prompt chunking so "
                          "the predicted mixed-step wall stays under it "
                          "when decode rows share the step")
-    ap.add_argument("--legacy_programs", action="store_true",
-                    help="run the pre-ragged per-shape program family "
-                         "(bucketed prefill + fused decode) instead of "
-                         "the single ragged mixed-step executable")
     ap.add_argument("--draft_dir", default=None,
                     help="optional draft model for speculative decoding "
                          "of greedy requests")
@@ -890,9 +874,7 @@ def main(argv=None):
                          "query_len up to num_draft_tokens+1)")
     ap.add_argument("--speculate", action="store_true",
                     help="in-engine speculative decoding: draft/verify "
-                         "rows inside the ragged mixed step (requires "
-                         "the ragged scheduler, i.e. not "
-                         "--legacy_programs)")
+                         "rows inside the mixed step")
     ap.add_argument("--draft_source", default="auto",
                     choices=("auto", "ngram", "prefix_cache"),
                     help="where draft tokens come from: prompt-lookup "
@@ -985,8 +967,7 @@ def main(argv=None):
                          "'<layer_path>.b' [r, d_out] and an optional "
                          "scalar 'scale'; requests bind a tenant via a "
                          "per-request \"adapter_id\" body field "
-                         "(docs/SERVING.md 'Multi-LoRA serving'); "
-                         "requires the ragged scheduler")
+                         "(docs/SERVING.md 'Multi-LoRA serving')")
     ap.add_argument("--adapter_rank", type=int, default=None,
                     help="the deployment's fixed LoRA rank r (required "
                          "with --adapter_dir): every adapter checkpoint "
@@ -1005,8 +986,7 @@ def main(argv=None):
                          "resume bitwise later — instead of shedding, "
                          "and prefix-cache evictions demote full pages "
                          "for promote-on-hit (docs/SERVING.md 'KV "
-                         "tiering and preemption'); requires the "
-                         "ragged scheduler")
+                         "tiering and preemption')")
     ap.add_argument("--kv_park_watermark", type=float, default=0.95,
                     help="device-pool occupancy at or above which the "
                          "scheduler preemptively parks (predictive "
@@ -1025,8 +1005,8 @@ def main(argv=None):
                          "token-level FSMs at admission (cached by "
                          "spec digest) and apply as per-row logit "
                          "masks inside the one mixed-step executable "
-                         "(docs/SERVING.md 'Constrained decoding'); "
-                         "requires the ragged scheduler.  The demo "
+                         "(docs/SERVING.md 'Constrained decoding').  "
+                         "The demo "
                          "token vocabulary is printable ASCII "
                          "(serving.default_vocab) — real deployments "
                          "wire their tokenizer's token strings here")
@@ -1037,8 +1017,8 @@ def main(argv=None):
                          "prefix-affinity FleetRouter with KV page "
                          "handoff at chunk boundaries (docs/SERVING.md "
                          "'Disaggregated serving'); incompatible with "
-                         "--mp/--dp_replicas/--legacy_programs/"
-                         "--speculate/--fault_script")
+                         "--mp/--dp_replicas/--speculate/"
+                         "--fault_script")
     ap.add_argument("--prefix_affinity", default="on",
                     choices=("on", "off"),
                     help="fleet routing: steer each request to the "
@@ -1070,7 +1050,6 @@ def main(argv=None):
             ("--dp_replicas > 1", args.dp_replicas > 1),
             ("--ep > 1", args.ep > 1),
             ("--quantized_allreduce", bool(args.quantized_allreduce)),
-            ("--legacy_programs", args.legacy_programs),
             ("--speculate", args.speculate),
             ("--fault_script", bool(args.fault_script)),
             # fleet replicas share one model object; per-replica
@@ -1079,7 +1058,7 @@ def main(argv=None):
         if incompatible:
             print("error: --fleet_roles is incompatible with "
                   + ", ".join(incompatible)
-                  + " (fleet replicas are single-device ragged cores)",
+                  + " (fleet replicas are single-device cores)",
                   file=sys.stderr, flush=True)
             return 2
     _STATE["fleet_roles"] = fleet_roles
@@ -1119,11 +1098,6 @@ def main(argv=None):
         from paddle_infer_tpu.serving import (AdapterError, AdapterStore,
                                               adapter_layer_spec)
 
-        if args.legacy_programs:
-            print("error: multi-LoRA serving requires the ragged mixed "
-                  "step; drop --legacy_programs",
-                  file=sys.stderr, flush=True)
-            return 2
         if not args.adapter_rank:
             print("error: --adapter_dir needs --adapter_rank (the "
                   "deployment's fixed LoRA rank)",
@@ -1174,10 +1148,6 @@ def main(argv=None):
               file=sys.stderr, flush=True)
         return 2
     if moe is not None:
-        if args.legacy_programs:
-            print("error: MoE serving requires the ragged mixed step; "
-                  "drop --legacy_programs", file=sys.stderr, flush=True)
-            return 2
         if args.num_experts and args.num_experts != moe["num_experts"]:
             print(f"error: --num_experts {args.num_experts} does not "
                   f"match the checkpoint ({moe['num_experts']} experts)",
@@ -1224,11 +1194,6 @@ def main(argv=None):
         print(f"error: --kv_host_pages must be >= 0, got "
               f"{args.kv_host_pages}", file=sys.stderr, flush=True)
         return 2
-    if args.kv_host_pages and args.legacy_programs:
-        print("error: --kv_host_pages requires the ragged scheduler — "
-              "park/resume serializes the mixed step's slot state; "
-              "drop --legacy_programs", file=sys.stderr, flush=True)
-        return 2
     if args.kv_host_pages and not (
             0.0 < args.kv_resume_watermark
             < args.kv_park_watermark <= 1.0):
@@ -1245,25 +1210,13 @@ def main(argv=None):
     _STATE["page_size"] = args.page_size
     _STATE["max_batch"] = args.max_batch
     _STATE["max_queue"] = args.max_queue
-    _STATE["decode_chunk"] = args.decode_chunk
     _STATE["request_timeout"] = args.request_timeout
     _STATE["max_model_len"] = args.max_model_len
     _STATE["enable_prefix_cache"] = args.enable_prefix_cache
     _STATE["prefix_cache_watermark"] = args.prefix_cache_watermark
     _STATE["prefix_cache_headroom_pages"] = args.prefix_cache_headroom_pages
-    if args.prompt_bucket is not None:
-        print("warning: --prompt_bucket is deprecated and ignored — "
-              "ragged mixed-batch attention schedules prompts under "
-              "--token_budget instead of padding them to buckets",
-              file=sys.stderr, flush=True)
-    _STATE["ragged"] = not args.legacy_programs
     _STATE["grammar_vocab"] = None
     if args.structured:
-        if args.legacy_programs:
-            print("error: --structured requires the ragged mixed step "
-                  "(the grammar mask is a per-row data input); drop "
-                  "--legacy_programs", file=sys.stderr, flush=True)
-            return 2
         from paddle_infer_tpu.serving import default_vocab
 
         mcfg = _STATE["model"].config
