@@ -38,7 +38,10 @@ def run(ctx, system_mod=None) -> dict:
                                   on_window_open=on_open)
         ctx.say("window closed")
         compiles = counter.since_mark()
-        trace = state["trace"].finish() if ctx.trace else None
+        trace = None
+        if ctx.trace:
+            trace = state["trace"].finish()
+            ctx.say(xplane.cost_line(trace))
         off = state["clock_offset"]
         steps = [dict(r, t=r["ts"] - off) for r in system.steplog.records()]
         ev = Evidence(

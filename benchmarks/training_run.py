@@ -47,6 +47,9 @@ def run(ctx, system_mod=None) -> dict:
         compiles = counter.since_mark()
         temp = system.program_temp_bytes(batches[0])
         program = system.program_report()
+        if trace is not None:
+            trace = trace.finish()
+            ctx.say(xplane.cost_line(trace))
         ev = Evidence(
             config=config, traffic=traffic, cell=cell,
             device_kind=ctx.devices[0].device_kind, chips=ctx.chips,
@@ -57,7 +60,7 @@ def run(ctx, system_mod=None) -> dict:
             compiles_in_window=int(compiles),
             allocator_peak_bytes=ctx.allocator_peak(),
             program_temp_bytes=temp, program=program,
-            trace=trace.finish() if trace is not None else None)
+            trace=trace)
     finally:
         system.free()
     ctx.say(f"window: {len(done)} steps, last loss {last_loss:.4f}")

@@ -10,7 +10,7 @@ import re
 import shutil
 import threading
 import time
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from . import spans
 
@@ -96,22 +96,48 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
     Returns busy seconds (mean over devices), the idle share of the
     window, every operation key's seconds (``op_seconds``; the ten largest
     again as ``device_ops``), collective seconds, and the idle gaps by
-    what the host was doing."""
+    what the host was doing.
+
+    The cost is linear in events and spans but for the sorts: one pass
+    over the events, and for each label one merge-walk over the gaps that
+    are left.  A faster program puts more steps, so more events, into the
+    same traced seconds; nothing here may grow faster than they do."""
     if not device_ops:
         return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
     busy, ops, coll = [], {}, 0.0
     gaps_by: Dict[str, float] = {}
-    labelled = {label: union((s, s + d) for n, s, d in host_spans
-                             if n == label) for label in spans.GAP_SPANS}
+    by_label: Dict[str, List[Interval]] = {
+        label: [] for label in spans.GAP_SPANS}
+    for n, s, d in host_spans:
+        if n in by_label:
+            by_label[n].append((s, s + d))
+    labelled = {label: union(iv) for label, iv in by_label.items()}
+    # a step repeats the same few hundred instruction names: what follows
+    # from the name alone (its key, None for a container; whether it is a
+    # collective) is worked out once for each
+    named: Dict[str, Tuple[Optional[str], bool]] = {}
+    first, last = float("inf"), float("-inf")
     for dev, events in device_ops.items():
-        iv = union((s, s + d) for _, s, d in events)
-        busy.append(total(iv))
+        marks = []
         for n, s, d in events:
-            key = op_key(n)
-            if key.split(" ", 1)[0] not in CONTAINERS:
-                ops[key] = ops.get(key, 0.0) + d
-            if any(c in n for c in COLLECTIVES):
+            e = s + d
+            marks.append((s, e))
+            if s < first:
+                first = s
+            if e > last:
+                last = e
+            about = named.get(n)
+            if about is None:
+                key = op_key(n)
+                about = named[n] = (
+                    None if key.split(" ", 1)[0] in CONTAINERS else key,
+                    any(c in n for c in COLLECTIVES))
+            if about[0] is not None:
+                ops[about[0]] = ops.get(about[0], 0.0) + d
+            if about[1]:
                 coll += d
+        iv = union(marks)
+        busy.append(total(iv))
         if not iv:
             continue
         rest = complement(iv, iv[0][0], iv[-1][1])
@@ -127,9 +153,8 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
     # the window on the trace's own clock, first start to last end of the
     # device's operations: the host's stop call returns a little early, and
     # its clock is not the trace's
-    marks = [(s, s + d) for ev in device_ops.values() for _, s, d in ev]
-    if marks:
-        window_s = max(b for _, b in marks) - min(a for a, _ in marks)
+    if first <= last:
+        window_s = last - first
     busy_s = sum(busy) / n
     # every operation key's seconds, largest first: a reader takes a named
     # kernel's whatever its rank; the result line prints the first ten
@@ -146,9 +171,20 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
 
 
 def _subtract(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:
-    out = []
+    """``xs`` less ``ys``, both sorted and disjoint: one walk, an index
+    into ``ys`` that only moves forward."""
+    out, j, n = [], 0, len(ys)
     for a, b in xs:
-        out.extend(complement([y for y in ys if y[1] > a and y[0] < b], a, b))
+        while j < n and ys[j][1] <= a:
+            j += 1
+        at, k = a, j
+        while k < n and ys[k][0] < b:
+            if ys[k][0] > at:
+                out.append((at, ys[k][0]))
+            at = max(at, ys[k][1])
+            k += 1
+        if at < b:
+            out.append((at, b))
     return out
 
 
@@ -167,23 +203,26 @@ def load(trace_dir: str, device_prefix: str = "/device:TPU",
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = jax.profiler.ProfileData.from_file(files[-1])
     device_ops, host_spans = {}, []
+    labels = frozenset(spans.GAP_SPANS)
     for plane in data.planes:
         is_device = plane.name.startswith(device_prefix)
         for line in plane.lines:
             if is_device and line.name.startswith(op_line):
                 device_ops.setdefault(plane.name, []).extend(
                     (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                    for e in line.events if e.name not in spans.GAP_SPANS)
+                    for e in line.events if e.name not in labels)
             if plane.name.startswith("/host:"):
                 host_spans.extend(
                     (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                    for e in line.events if e.name in spans.GAP_SPANS)
+                    for e in line.events if e.name in labels)
     return device_ops, host_spans
 
 
 class TraceWindow:
     """Trace ``span_s`` seconds from ``start()``; ``finish()`` waits for
-    the stop and returns the reduction."""
+    the stop and returns the reduction, with what it cost under
+    ``cost``: the device events and host spans it loaded, and the seconds
+    loading and reducing them took."""
 
     def __init__(self, trace_dir: str, span_s: float):
         self.dir, self.span_s = trace_dir, float(span_s)
@@ -223,8 +262,23 @@ class TraceWindow:
             raise self._err
         if self._t1 is None:
             raise RuntimeError("the trace was never stopped")
+        began = time.monotonic()
         device_ops, host_spans = load(self.dir)
+        loaded = time.monotonic()
         out = reduce_events(device_ops, host_spans, self._t1 - self._t0)
+        out["cost"] = {
+            "device_events": sum(len(v) for v in device_ops.values()),
+            "host_spans": len(host_spans), "load_s": loaded - began,
+            "reduce_s": time.monotonic() - loaded}
         out["t0"], out["t1"] = self._t0, self._t1      # monotonic clock
         shutil.rmtree(self.dir, ignore_errors=True)
         return out
+
+
+def cost_line(trace: dict) -> str:
+    """What a runner says once ``TraceWindow.finish`` has returned: a
+    traced run that is slow to close shows here where the time went."""
+    c = trace["cost"]
+    return ("trace: %d device events, %d host spans, loaded in %.2f s, "
+            "reduced in %.2f s" % (c["device_events"], c["host_spans"],
+                                   c["load_s"], c["reduce_s"]))

@@ -22,3 +22,17 @@ def load_data(name):
 def benchmark_json():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+@pytest.fixture
+def cpu_trace_loader(monkeypatch):
+    """On the CPU the operations sit on the host plane's XLA threads: a
+    test of a traced run tells ``xplane.load`` so here; the window and the
+    runners have no option for it."""
+    import functools
+
+    from benchmarks import xplane
+
+    monkeypatch.setattr(xplane, "load", functools.partial(
+        xplane.load, device_prefix="/host:CPU",
+        op_line="tf_XLAPjRtCpuClient"))

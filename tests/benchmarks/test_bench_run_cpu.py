@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import run
+from benchmarks import run, xplane
 from benchmarks.systems import ernie_train, llama_serving
 
 from conftest import ROOT, load_data
@@ -20,10 +20,9 @@ PRETRAIN = json.load(open(os.path.join(ROOT, "benchmarks", "traffic",
                                        "pretrain.json")))
 
 
-def _ctx(config, traffic, cell, seed, seconds, tmp_path, trace=0):
+def _ctx(config, traffic, cell, seed, seconds, tmp_path, trace=0, say=print):
     return run.Context(config, traffic, cell, 1, seed, seconds, trace,
-                       jax.devices()[:1], time.monotonic(),
-                       say=lambda s: print(s),
+                       jax.devices()[:1], time.monotonic(), say=say,
                        trace_dir=str(tmp_path / "trace"))
 
 
@@ -90,6 +89,34 @@ def test_result_line_has_the_contract_keys(chat_result, benchmark_json):
     json.dumps(line)
 
 
+def _said_what_the_trace_cost(said, ev):
+    """One line once the trace is reduced: events, spans, seconds to load,
+    seconds to reduce."""
+    assert [s for s in said if s.startswith("trace: ")] \
+        == [xplane.cost_line(ev.trace)]
+    cost = ev.trace["cost"]
+    assert cost["device_events"] > 0 and cost["host_spans"] > 0
+    assert 0 < cost["load_s"] < 60 and 0 <= cost["reduce_s"] < 60
+    assert 0 < ev.trace["busy_s"] <= ev.trace["window_s"]
+    assert ev.trace["device_ops"] and ev.trace["idle_gaps"]
+
+
+def test_a_traced_serving_run_says_what_its_trace_cost(tmp_path,
+                                                       cpu_trace_loader):
+    said = []
+    ctx = _ctx(load_data("tiny-llama.json"), load_data("tiny-chat.json"),
+               {"rate_rps": 4.0}, 2 ** 31 + 31, 1.0, tmp_path, trace=1,
+               say=said.append)
+    res = run.run_cell(ctx)
+    assert res["correct"] is True and res["failed"] == 0
+    ev = res["evidence"]
+    if not ev.trace["devices"]:
+        pytest.skip("the CPU profiler wrote no operations to read")
+    _said_what_the_trace_cost(said, ev)
+    gaps = dict(ev.trace["idle_gaps"])
+    assert gaps.get("engine.launch", 0) + gaps.get("engine.wait", 0) > 0
+
+
 class _AlteredStream:
     """A request whose tokens are altered where they are produced."""
 
@@ -118,9 +145,11 @@ def test_altered_tokens_come_out_not_correct(tmp_path):
     assert res["failed"] == 0 and res["correct"] is False
 
 
-def test_training_cell_runs_and_is_correct(tmp_path, benchmark_json):
+def test_training_cell_runs_and_is_correct(tmp_path, benchmark_json,
+                                           cpu_trace_loader):
+    said = []
     ctx = _ctx(load_data("tiny-ernie.json"), PRETRAIN, {}, 2 ** 31 + 3, 1.0,
-               tmp_path)
+               tmp_path, trace=1, say=said.append)
     res = run.run_cell(ctx)
     assert res["correct"] is True and res["attempted"] >= 3
     ev = res["evidence"]
@@ -137,6 +166,8 @@ def test_training_cell_runs_and_is_correct(tmp_path, benchmark_json):
                                         "autotune_cache_file": ""}
     assert line["program"]["autotune_winners"] == {}
     json.dumps(line)
+    if ev.trace["devices"]:     # traced: the CPU profiler wrote operations
+        _said_what_the_trace_cost(said, ev)
 
 
 class _FrozenTraining(ernie_train.System):

@@ -1,5 +1,7 @@
 import json
 import os
+import random
+import time
 
 import pytest
 
@@ -17,6 +19,12 @@ def test_interval_algebra():
         (2, 3), (5, 5.5), (6.5, 7)]
     assert xplane._subtract([(0, 10)], [(2, 3), (5, 7)]) == [
         (0, 2), (3, 5), (7, 10)]
+    # one interval of ys over several of xs, and over an edge of each
+    assert xplane._subtract([(0, 2), (3, 5), (6, 8), (9, 9)],
+                            [(-1, 1), (1.5, 6.5), (7, 7.5)]) == [
+        (1, 1.5), (6.5, 7), (7.5, 8)]
+    assert xplane._subtract([(0, 1)], []) == [(0, 1)]
+    assert xplane._subtract([], [(0, 1)]) == []
 
 
 def test_op_key_drops_the_instance_number():
@@ -137,3 +145,269 @@ def test_loader_reads_a_trace_the_profiler_just_wrote(tmp_path):
     inner = next(h for h in host if h[0] == "engine.launch")
     outer = next(h for h in host if h[0] == "engine.step")
     assert outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2] + 1e-6
+
+
+# ------------------------------------------------- the quadratic oracle
+# The attribution as it stood before PR 31: ``_subtract`` scans every
+# interval of ``ys`` for every interval of ``xs``, ``op_key`` and the
+# search for a collective run on every event, the window takes a second
+# pass.  Kept here, where it costs nothing, as what ``reduce_events`` has
+# to return on any trace.
+
+def _oracle_subtract(xs, ys):
+    out = []
+    for a, b in xs:
+        out.extend(xplane.complement(
+            [y for y in ys if y[1] > a and y[0] < b], a, b))
+    return out
+
+
+def _oracle_reduce_events(device_ops, host_spans, window_s):
+    if not device_ops:
+        return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
+    busy, ops, coll = [], {}, 0.0
+    gaps_by = {}
+    labelled = {label: xplane.union((s, s + d) for n, s, d in host_spans
+                                    if n == label)
+                for label in spans.GAP_SPANS}
+    for dev, events in device_ops.items():
+        iv = xplane.union((s, s + d) for _, s, d in events)
+        busy.append(xplane.total(iv))
+        for n, s, d in events:
+            key = xplane.op_key(n)
+            if key.split(" ", 1)[0] not in xplane.CONTAINERS:
+                ops[key] = ops.get(key, 0.0) + d
+            if any(c in n for c in xplane.COLLECTIVES):
+                coll += d
+        if not iv:
+            continue
+        rest = xplane.complement(iv, iv[0][0], iv[-1][1])
+        for label in spans.GAP_SPANS:
+            inside = xplane.intersect(rest, labelled[label])
+            if inside:
+                gaps_by[label] = gaps_by.get(label, 0.0) \
+                    + xplane.total(inside)
+                rest = _oracle_subtract(rest, inside)
+        if rest:
+            gaps_by[spans.OUTSIDE] = gaps_by.get(spans.OUTSIDE, 0.0) \
+                + xplane.total(rest)
+    n = len(device_ops)
+    marks = [(s, s + d) for ev in device_ops.values() for _, s, d in ev]
+    if marks:
+        window_s = max(b for _, b in marks) - min(a for a, _ in marks)
+    busy_s = sum(busy) / n
+    op_seconds = {k: v / n for k, v in
+                  sorted(ops.items(), key=lambda kv: -kv[1])}
+    return {
+        "window_s": window_s, "busy_s": busy_s, "devices": n,
+        "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
+        "collective_s": coll / n,
+        "op_seconds": op_seconds,
+        "device_ops": [[k, v] for k, v in list(op_seconds.items())[:10]],
+        "idle_gaps": [[k, v / n] for k, v in
+                      sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
+
+
+def _same(got, want):
+    """Key for key, in the same order, every number to 1e-9."""
+    assert list(got) == list(want)
+    for k in ("window_s", "busy_s", "idle_share", "collective_s"):
+        assert got[k] == pytest.approx(want[k], rel=1e-9, abs=1e-9), k
+    assert got["devices"] == want["devices"]
+    for k in ("device_ops", "idle_gaps"):
+        assert [n for n, _ in got[k]] == [n for n, _ in want[k]], k
+        assert [v for _, v in got[k]] == pytest.approx(
+            [v for _, v in want[k]], rel=1e-9, abs=1e-9), k
+    assert list(got["op_seconds"]) == list(want["op_seconds"])
+    assert list(got["op_seconds"].values()) == pytest.approx(
+        list(want["op_seconds"].values()), rel=1e-9, abs=1e-9)
+
+
+_NAMES = (
+    ["%%fusion.%d = bf16[16,64,%d]{2,1,0} fusion(bf16[16,64,4096]{2,1,0} "
+     "%%p.%d), kind=kLoop" % (i, 128 * (i % 5 + 1), i) for i in range(12)]
+    + ["%all-reduce.3 = f32[4096]{0} all-reduce(f32[4096]{0} %x)",
+       "%all-gather.7 = bf16[8,128]{1,0} all-gather(bf16[2,128]{1,0} %p)",
+       "%while.5 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t)",
+       "%conditional.2 = f32[8]{0} conditional(pred[] %c, f32[8]{0} %a)",
+       "%ragged_paged_attention.9 = bf16[16,32,64,128]{3,2,1,0} "
+       "custom-call(bf16[16,64,32,128]{3,2,1,0} %q)",
+       "copy.4", "copy.11", "fusion.123", "collective-permute-start.1"])
+_PHASES = [g for g in spans.GAP_SPANS if g not in ("engine.step",
+                                                   "fleet.train_step")]
+
+
+def _random_trace(seed, with_spans=True):
+    """Two devices whose operations overlap, abut, have no length or leave
+    a gap; steps whose phases nest inside them, abut, overlap one another,
+    hang over the step's end or are missing; spans that begin or end on an
+    operation's own edge, so gaps straddle them; a span no label names."""
+    r = random.Random(seed)
+    ops, edges = {}, []
+    for dev in range(2):
+        t, events = r.uniform(0.0, 0.01), []
+        for _ in range(r.randint(15, 30)):
+            for _ in range(r.randint(3, 12)):
+                d = 0.0 if r.random() < 0.1 else r.uniform(1e-5, 2e-3)
+                events.append((r.choice(_NAMES), t, d))
+                edges += [t, t + d]
+                t += r.choice([d * r.random(), d, d + r.uniform(0, 3e-3)])
+            t += r.uniform(0, 5e-3)
+        # several lines of one plane come one after the other, each in its
+        # own order: nothing may lean on the events being sorted
+        cut = r.randrange(len(events))
+        ops["/device:TPU:%d" % dev] = events[cut:] + events[:cut]
+    host = []
+    if with_spans:
+        end, t = max(edges), 0.0
+        while t < end:
+            length = r.uniform(2e-3, 2e-2)
+            if r.random() < 0.5:
+                length = r.choice(edges) - t if r.choice(edges) > t \
+                    else length
+            if r.random() < 0.7:
+                host.append(("engine.step", t, length))
+            at = t + r.choice([0.0, r.uniform(0, 1e-3)])
+            for phase in _PHASES:
+                if r.random() < 0.2:
+                    continue
+                d = r.choice([0.0, r.uniform(0, length / 2),
+                              max(0.0, r.choice(edges) - at)])
+                host.append((phase, at, min(d, 2 * length)))
+                at += r.choice([d, d + r.uniform(0, 5e-4), d / 2])
+            if r.random() < 0.3:
+                host.append(("fleet.train_step", t + r.uniform(-1e-3, length),
+                             r.uniform(0, 2e-2)))
+            host.append(("engine.retire", t, length))       # no label
+            t += length + r.choice([0.0, r.uniform(0, 3e-3),
+                                    r.uniform(5e-3, 2e-2)])
+        r.shuffle(host)
+    return ops, host, r.uniform(0.1, 1.0)
+
+
+@pytest.mark.parametrize("seed", range(10))
+def test_reduction_equals_the_quadratic_oracle(seed):
+    ops, host, window = _random_trace(seed)
+    got = xplane.reduce_events(ops, host, window)
+    _same(got, _oracle_reduce_events(ops, host, window))
+    labels = {k for k, _ in got["idle_gaps"]}
+    assert spans.OUTSIDE in labels and len(labels) >= 5
+    assert got["collective_s"] > 0
+    assert not any(k.startswith(xplane.CONTAINERS) for k in got["op_seconds"])
+
+
+@pytest.mark.parametrize("seed", (100, 101))
+def test_reduction_equals_the_oracle_with_no_spans_at_all(seed):
+    ops, host, window = _random_trace(seed, with_spans=False)
+    assert host == []
+    got = xplane.reduce_events(ops, host, window)
+    _same(got, _oracle_reduce_events(ops, host, window))
+    assert [k for k, _ in got["idle_gaps"]] == [spans.OUTSIDE]
+
+
+def test_reduction_of_devices_without_events_equals_the_oracle():
+    for ops in ({"/device:TPU:0": []},
+                {"/device:TPU:0": [], "/device:TPU:1": [("a.1", 1.0, 0.0)]},
+                {"/device:TPU:0": [("a.1", 1.0, 0.5)], "/device:TPU:1": []}):
+        _same(xplane.reduce_events(ops, [("engine.step", 0.0, 2.0)], 3.0),
+              _oracle_reduce_events(ops, [("engine.step", 0.0, 2.0)], 3.0))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_subtract_equals_the_quadratic_one(seed):
+    """Any two sorted disjoint lists, not only gaps and their parts."""
+    r = random.Random(seed)
+
+    def intervals(n):
+        cuts = sorted(r.choice([r.uniform(0, 10), float(r.randint(0, 10))])
+                      for _ in range(2 * n))
+        return xplane.union(zip(cuts[::2], cuts[1::2]))
+
+    for _ in range(50):
+        xs, ys = intervals(r.randint(0, 12)), intervals(r.randint(0, 12))
+        assert xplane._subtract(xs, ys) == _oracle_subtract(xs, ys)
+
+
+def test_recorded_trace_sample_equals_the_oracle():
+    with open(os.path.join(DATA, "trace_sample.json")) as f:
+        sample = json.load(f)
+    ops = {k: [tuple(e) for e in v] for k, v in sample["device_ops"].items()}
+    host = [tuple(e) for e in sample["host_spans"]]
+    _same(xplane.reduce_events(ops, host, sample["window_s"]),
+          _oracle_reduce_events(ops, host, sample["window_s"]))
+
+
+def _long_trace(steps, ops_a_step, seed=31):
+    """``steps`` serving steps of ``ops_a_step`` operations under a few
+    hundred instruction names, each step with a span of every label."""
+    r = random.Random(seed)
+    names = ["%%fusion.%d = bf16[16,64,%d]{2,1,0} fusion(bf16[16,64,4096]"
+             "{2,1,0} %%p.%d), kind=kLoop" % (i, 128 * (i % 50 + 1), i)
+             for i in range(320)]
+    ops, host, t = [], [], 0.0
+    for _ in range(steps):
+        t0 = t
+        host.append(("engine.admit", t, 1e-4))
+        host.append(("engine.pack", t + 1e-4, 2e-4))
+        host.append(("engine.launch", t + 3e-4, 3e-3))
+        d = t + 1.3e-3
+        for i in range(ops_a_step):
+            dur = r.uniform(2e-5, 6e-5)
+            ops.append((names[i % 320], d, dur))
+            d += dur + r.uniform(0, 4e-6)
+        host.append(("engine.wait", t + 3.3e-3, d - t - 3.2e-3))
+        host.append(("engine.emit", d + 1e-4, 3e-4))
+        t = d + 4e-4
+        host.append(("engine.step", t0, t - t0))
+        host.append(("fleet.train_step", t, 1e-4))
+        t += 2e-4
+    return {"/device:TPU:0": ops}, host, t
+
+
+def test_reduction_time_grows_with_the_events_not_their_square():
+    """300,000 events and 6,000 spans of each label: the quadratic
+    attribution took 12.3 s at 22,400 events and would take over half an
+    hour here."""
+    ops, host, window = _long_trace(6000, 50)
+    assert len(ops["/device:TPU:0"]) == 300_000
+    assert all(sum(n == g for n, _, _ in host) == 6000
+               for g in spans.GAP_SPANS)
+    began = time.perf_counter()
+    r = xplane.reduce_events(ops, host, window)
+    assert time.perf_counter() - began < 20.0
+    assert set(dict(r["idle_gaps"])) == set(spans.GAP_SPANS) | {
+        spans.OUTSIDE}
+    assert sum(v for _, v in r["idle_gaps"]) == pytest.approx(
+        r["window_s"] - r["busy_s"], rel=1e-9)
+    # and a slice of it still reads as the oracle does
+    ops, host, window = _long_trace(12, 50)
+    _same(xplane.reduce_events(ops, host, window),
+          _oracle_reduce_events(ops, host, window))
+
+
+def test_a_finished_window_says_what_its_trace_cost(tmp_path,
+                                                    cpu_trace_loader):
+    """The four numbers a runner prints after ``TraceWindow.finish``, on a
+    trace the CPU's profiler just wrote."""
+    import jax
+    import jax.numpy as jnp
+
+    win = xplane.TraceWindow(str(tmp_path / "tr"), 0.2)
+    win.start()
+    with jax.profiler.StepTraceAnnotation("engine.step", step_num=3):
+        x = jnp.ones((256, 256))
+        with jax.profiler.TraceAnnotation("engine.launch"):
+            (x @ x).block_until_ready()
+    out = win.finish()
+    cost = out["cost"]
+    assert set(cost) == {"device_events", "host_spans", "load_s", "reduce_s"}
+    assert cost["host_spans"] == 2
+    assert cost["load_s"] > 0 and cost["reduce_s"] >= 0
+    if not cost["device_events"]:
+        pytest.skip("the CPU profiler wrote no operations to read")
+    assert out["busy_s"] > 0 and out["t1"] > out["t0"]
+    assert xplane.cost_line(out) == (
+        "trace: %d device events, 2 host spans, loaded in %.2f s, "
+        "reduced in %.2f s" % (cost["device_events"], cost["load_s"],
+                               cost["reduce_s"]))
+    assert not os.path.exists(str(tmp_path / "tr"))
