@@ -794,10 +794,13 @@ def child_kernels(args):
     got = compiled(lambda q, pool, t, n: LA.latent_paged_decode(
         q, pool, t, n, scale, rank), lq, lpool, ltab, lctx + 1)
     with jax.default_matmul_precision("highest"):
+        # each query as the first of a chunk of two, the chunks end to
+        # end on the composition's flat token axis
         want = jax.jit(lambda q, pool, t, c: LA.latent_chunk_attention(
-            jnp.stack([q, jnp.zeros_like(q)], 1).astype(jnp.float32),
+            jnp.stack([q, jnp.zeros_like(q)], 1).reshape(
+                (2 * b,) + q.shape[1:]).astype(jnp.float32),
             pool.astype(jnp.float32), t, c, jnp.full((b,), 2, jnp.int32),
-            scale, rank)[:, 0])(lq, lpool, ltab, lctx)
+            scale, rank)[0::2])(lq, lpool, ltab, lctx)
     close("latent_paged_decode", got, want)
 
     # ---- grouped matmul: sorted rows, uneven groups, an untouched expert

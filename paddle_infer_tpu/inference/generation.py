@@ -354,10 +354,16 @@ class GenerationEngine:
                  zero_idx)
                 for _ in range(self._num_layers)]
 
-    def _model_step(self, params, ids, position_ids, pad_mask_add, caches):
+    def _model_step(self, params, ids, position_ids, pad_mask_add, caches,
+                    head_rows=None):
         """One forward over the Layer with traced arrays; returns raw
         logits + cache arrays.  The Layer runs under no_grad so dispatch
         skips tape recording inside the trace.
+
+        ``head_rows`` (the served mixed step alone): the flat token slots
+        whose logits the step reads; the model then runs its final norm
+        and head over those rows only and returns ``[*head_rows.shape,
+        vocab]`` (models/transformer_block.take_head_rows).
 
         Quantized paged pools ride as plain ``(payload, scales)`` tuples
         inside the cache — wrapped/unwrapped element-wise so the pytree
@@ -380,11 +386,12 @@ class GenerationEngine:
             params = {n: a for n, a in params.items() if n not in bnames}
         tcaches = [tuple(wrap(a) for a in c) for c in caches]
         mask_t = Tensor(pad_mask_add) if pad_mask_add is not None else None
+        head = {} if head_rows is None else {"head_rows": Tensor(head_rows)}
         with no_grad():
             logits, new = self._model.functional_call(
                 params, Tensor(ids),
                 position_ids=Tensor(position_ids),
-                attention_mask=mask_t, caches=tcaches, buffers=bufs)
+                attention_mask=mask_t, caches=tcaches, buffers=bufs, **head)
         return logits._data, [tuple(unwrap(x) for x in c) for c in new]
 
     def _pad_mask_add(self, prompt_mask, cache_len):

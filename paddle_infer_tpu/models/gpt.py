@@ -17,7 +17,7 @@ from ..nn.layer import Layer
 from ..nn.layers_common import Dropout, Embedding, LayerList, LayerNorm
 from ..parallel.mp_layers import VocabParallelEmbedding
 from .pretrained import PretrainedMixin
-from .transformer_block import ParallelTransformerLayer
+from .transformer_block import ParallelTransformerLayer, take_head_rows
 
 GPT_PRESETS = {
     "gpt2-small": dict(hidden_size=768, num_hidden_layers=12,
@@ -126,7 +126,7 @@ class GPTModel(Layer):
         return total
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
-                caches=None):
+                caches=None, head_rows=None):
         b, s = input_ids.shape[0], input_ids.shape[1]
         x = self.word_embeddings(input_ids)
         if position_ids is None:
@@ -163,7 +163,7 @@ class GPTModel(Layer):
                 new_caches.append(c)
             else:
                 x = layer(x, attn_mask=attention_mask)
-        x = self.final_norm(x)
+        x = self.final_norm(take_head_rows(x, head_rows))
         if caches is not None:
             return x, new_caches
         return x
@@ -199,10 +199,11 @@ class GPTForCausalLM(PretrainedMixin, Layer):
                                          attention_mask=attention_mask)
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
-                caches=None):
+                caches=None, head_rows=None):
         if caches is not None:
             hidden, new_caches = self.gpt(input_ids, position_ids,
-                                          attention_mask, caches)
+                                          attention_mask, caches,
+                                          head_rows=head_rows)
         else:
             hidden = self.gpt(input_ids, position_ids, attention_mask)
         logits = D("matmul", hidden, self.gpt.word_embeddings.weight,

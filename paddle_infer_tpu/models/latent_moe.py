@@ -49,6 +49,7 @@ from ..parallel.mp_layers import (ColumnParallelLinear, RowParallelLinear,
                                   VocabParallelEmbedding)
 from .llama import LlamaMLP
 from .pretrained import PretrainedMixin
+from .transformer_block import take_head_rows
 
 
 class LatentMoEConfig:
@@ -194,6 +195,10 @@ class LatentAttention(Layer):
         """-> q_nope [b, s, H, nope], q_pe [b, s, H, rope] (rotated)."""
         b, s = x.shape[0], x.shape[1]
         q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))._data
+        # keep the head split out of the projection: over the mixed
+        # step's few flat tokens the TPU compiler otherwise computes the
+        # product head-major and transposes the whole weight every step
+        q = jax.lax.optimization_barrier(q)
         q = q.reshape(b, s, self.heads, self.nope + self.rope_dim)
         q_pe = _rope(q[..., self.nope:], positions[:, :, None],
                      self.inv_freq, self.rope_mscale).astype(q.dtype)
@@ -225,7 +230,11 @@ class LatentAttention(Layer):
         if cache is None:
             return self._forward_expanded(x, positions)
         from ..ops.pallas import latent_attention as LA
+        from ..ops.pallas.ragged_paged_attention import (ragged_rows,
+                                                         rows_from_flat)
 
+        # the mixed step's flat token axis: x [1, T, hidden], rows end to
+        # end; tables, ctx and qlens stay per row
         pages, tables, ctx, qlens, scratch = cache
         wk, wv = self._w_kvb()
         with jax.named_scope("mla_q_proj"):
@@ -236,12 +245,14 @@ class LatentAttention(Layer):
         with jax.named_scope("mla_kv_proj"):
             rows = self._latent_rows(x, positions)
         with jax.named_scope("latent_write"):
-            pool = LA.write_latent_pages(pages._data, tables._data, rows,
-                                         ctx._data, qlens._data)
+            starts = ragged_rows(qlens._data, s)[0]
+            pool = LA.write_latent_pages(
+                pages._data, tables._data,
+                rows_from_flat(rows[0], starts, s), ctx._data, qlens._data)
         # scoped "latent_attention" inside (its Pallas call keeps its name)
         o_lat = LA.latent_ragged_attention(
-            q_abs, pool, tables._data, ctx._data, qlens._data, self.scale,
-            self.rank)
+            q_abs[0], pool, tables._data, ctx._data, qlens._data,
+            self.scale, self.rank)[None]
         with jax.named_scope("attn_out"):
             o = jnp.einsum("bshc,chd->bshd", o_lat, wv,
                            preferred_element_type=jnp.float32)
@@ -339,7 +350,8 @@ class LatentMoEModel(Layer):
                                  for i in range(cfg.num_hidden_layers)])
         self.norm = RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
 
-    def forward(self, input_ids, position_ids=None, caches=None):
+    def forward(self, input_ids, position_ids=None, caches=None,
+                head_rows=None):
         x = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
@@ -348,7 +360,7 @@ class LatentMoEModel(Layer):
                 new_caches.append(c)
             else:
                 x = layer(x, position_ids=position_ids)
-        x = self.norm(x)
+        x = self.norm(take_head_rows(x, head_rows))
         return (x, new_caches) if caches is not None else x
 
 
@@ -376,13 +388,13 @@ class LatentMoEForCausalLM(PretrainedMixin, Layer):
                 ] * cfg.num_hidden_layers
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
-                caches=None):
+                caches=None, head_rows=None):
         if attention_mask is not None:
             raise NotImplementedError(
                 "the latent-attention decoder takes right-padded rows "
                 "with per-row lengths, not an additive pad mask")
         out = self.model(input_ids, position_ids=position_ids,
-                         caches=caches)
+                         caches=caches, head_rows=head_rows)
         with jax.named_scope("lm_head_sample"):
             if caches is not None:
                 x, new_caches = out

@@ -25,7 +25,7 @@ from ..nn.layers_common import LayerList, RMSNorm
 from ..parallel.mp_layers import (ColumnParallelLinear, RowParallelLinear,
                                   VocabParallelEmbedding)
 from .pretrained import PretrainedMixin
-from .transformer_block import ParallelSelfAttention
+from .transformer_block import ParallelSelfAttention, take_head_rows
 
 LLAMA_PRESETS = {
     # (hidden, layers, heads, kv_heads, ffn, vocab, max_pos, theta)
@@ -141,7 +141,7 @@ class LlamaModel(Layer):
                             epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
-                caches=None):
+                caches=None, head_rows=None):
         x = self.embed_tokens(input_ids)
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
@@ -152,7 +152,7 @@ class LlamaModel(Layer):
             else:
                 x = layer(x, attn_mask=attention_mask,
                           position_ids=position_ids)
-        x = self.norm(x)
+        x = self.norm(take_head_rows(x, head_rows))
         if caches is not None:
             return x, new_caches
         return x
@@ -191,9 +191,10 @@ class LlamaForCausalLM(PretrainedMixin, Layer):
                                          attention_mask=attention_mask)
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
-                caches=None):
+                caches=None, head_rows=None):
         out = self.llama(input_ids, position_ids=position_ids,
-                         attention_mask=attention_mask, caches=caches)
+                         attention_mask=attention_mask, caches=caches,
+                         head_rows=head_rows)
         with jax.named_scope("lm_head_sample"):
             if caches is not None:
                 x, new_caches = out

@@ -29,6 +29,20 @@ def _sep_active() -> bool:
     return mesh is not None and dict(mesh.shape).get("sep", 1) > 1
 
 
+def take_head_rows(x, head_rows):
+    """The hidden states the head will read.  ``x`` is a served step's
+    flat token axis ``[1, T, hidden]`` and ``head_rows`` the in-range
+    flat slots whose next-token logits the step samples from (``[b]``, or
+    ``[b, W]`` under speculation): the result is ``[b(, W), hidden]``, so
+    the final norm and the head run over those rows alone.  ``None``
+    (every other caller) leaves ``x`` as it is."""
+    if head_rows is None:
+        return x
+    from ..core.tensor import Tensor
+
+    return Tensor(x._data[0][head_rows._data])
+
+
 class ParallelSelfAttention(Layer):
     """Self-attention with heads sharded over "mp"; optional KV cache for
     decode (cache layout [b, s, h, d] — the reference CacheKV is
@@ -93,6 +107,14 @@ class ParallelSelfAttention(Layer):
         # part after a refactor), no operation changes
         with jax.named_scope("qkv_proj"):
             qkv = self.qkv_proj(x)
+            if cache is not None and len(cache) >= 6:
+                # the mixed step's few flat tokens: keep the head split
+                # out of the projection, or the TPU compiler computes the
+                # product head-major and transposes the whole weight for
+                # it on every step (tests/test_chip_compile.py)
+                from ..core.tensor import Tensor
+
+                qkv = Tensor(jax.lax.optimization_barrier(qkv._data))
             q, k, v = self._split_qkv(qkv, b, s)
             if self.rope_theta:
                 if position_ids is None:
@@ -178,11 +200,16 @@ class ParallelSelfAttention(Layer):
 
         A SIX-element cache ``(k_pages, v_pages, tables, positions,
         query_lens, scratch_page)`` selects the ragged mixed-batch
-        variant (serving/programs.build_mixed_step): every row carries
+        variant (serving/programs.build_mixed_step): ``x`` is the
+        step's flat token axis ``[1, T, hidden]`` with the rows laid end
+        to end (``ragged_paged_attention.ragged_rows``), while tables,
+        positions and ``query_lens`` stay per row.  Every row carries
         its own ``(query_len, context_len)``, decode rows have
         ``query_len == 1`` and chunk rows a prompt slice, all in one
-        launch — positions past a row's ``query_len`` are written
-        nowhere and never attended.
+        launch.  Only the writers and the kernel see the per-row
+        ``[B, T, h, d]`` view, gathered from the flat axis here and
+        gathered back after the launch — positions past a row's
+        ``query_len`` are written nowhere and never attended.
 
         A SEVEN-element cache appends ``verify [b, W] bool`` (per-row
         speculative-verify flag broadcast over the draft window — the
@@ -214,18 +241,24 @@ class ParallelSelfAttention(Layer):
             qlens = cache[4]._data
             scratch = cache[5]._data
             verify = cache[6]._data if len(cache) == 7 else None
+            starts, row, offset, _ = RPA.ragged_rows(qlens, s)
+            per_row = lambda t: RPA.rows_from_flat(t._data[0], starts, s)
             with jax.named_scope("kv_write"):
-                k_pages = RPA.write_ragged_pages(k_pages, tables, k._data,
+                k_pages = RPA.write_ragged_pages(k_pages, tables, per_row(k),
                                                  positions, qlens, scratch)
-                v_pages = RPA.write_ragged_pages(v_pages, tables, v._data,
+                v_pages = RPA.write_ragged_pages(v_pages, tables, per_row(v),
                                                  positions, qlens, scratch)
+            with jax.named_scope("paged_attention"):
+                q_rows = per_row(q)
             # scoped "paged_attention" inside (it keeps its Pallas calls'
             # instruction names out of the scope, see there)
-            out = Tensor(RPA.ragged_paged_attention(
-                q._data, k_pages, v_pages, tables, positions, qlens,
+            out = RPA.ragged_paged_attention(
+                q_rows, k_pages, v_pages, tables, positions, qlens,
                 verify_rows=None if verify is None else verify[:, 0],
                 verify_window=None if verify is None
-                else verify.shape[1]))
+                else verify.shape[1])
+            with jax.named_scope("paged_attention"):
+                out = Tensor(out[row, offset][None])
             with jax.named_scope("attn_out"):
                 out = D("reshape", out, shape=(b, s, self.hidden))
                 out = self.out_proj(out)

@@ -95,6 +95,11 @@ _SCHEMA = (
     ("prefill_tokens", 0),       # uncached suffix tokens prefetched
     ("prefill_chunk_tokens", 0),  # prompt tokens chunked into this
                                   # ragged mixed step
+    ("token_slots", 0),          # length of the step program's flat
+                                 # token axis (the token budget): the
+                                 # slots decode_rows + prefill_chunk_
+                                 # tokens + draft_tokens fill; 0 on
+                                 # records that launch no step
     ("chunk_steps", 0),          # fused scan steps (decode) / 1
     ("emitted_tokens", 0),       # tokens delivered to consumers
     ("resident_kv_pages", 0),    # pool pages in use at capture

@@ -23,10 +23,15 @@ its own:
   online softmax in VMEM scratch.  Pages past a row's length repeat the
   row's last valid block index, so the pipeline issues no copy for them.
 * ``latent_chunk_attention`` — chunk rows (``query_len > 1``) against
-  their gathered window, one row at a time under a ``lax.cond``: a row
-  that carries no chunk costs nothing, and the widest temporary is one
-  row's ``[heads, chunk, window]`` scores, never ``[rows, window, heads,
-  key+value]`` expanded keys and values.
+  their gathered window, one row at a time in a loop over the chunk rows
+  alone: a row that carries no chunk costs nothing, and the widest
+  temporary is one row's ``[heads, chunk, window]`` scores, never
+  ``[rows, window, heads, key+value]`` expanded keys and values.
+
+Queries and outputs of the last two lie on the step's flat token axis
+(rows end to end, ``[T, heads, width]``): decode rows need the one query
+at their first slot, a chunk row's queries are gathered inside its own
+iteration, so no ``[rows, T, heads, width]`` view is ever built.
 """
 from __future__ import annotations
 
@@ -169,62 +174,72 @@ def latent_paged_decode(q, pages, block_tables, lengths, scale,
 
 def latent_chunk_attention(q, pages, block_tables, context_lens, query_lens,
                            scale, value_width):
-    """Chunk rows: queries ``q[b, i]`` at absolute positions
-    ``context_lens[b] + i`` over row ``b``'s whole table window under an
-    absolute-position causal mask, in the absorbed form.  Rows with
-    ``query_lens <= 1`` return zeros and cost nothing.
+    """Chunk rows of a step's flat token axis: row ``b``'s
+    ``query_lens[b]`` queries lie end to end after row ``b - 1``'s
+    (``ragged_paged_attention.ragged_rows``), sit at absolute positions
+    ``context_lens[b] + i`` and attend over the row's whole table window
+    under an absolute-position causal mask, in the absorbed form.  One
+    row at a time, and only the rows with ``query_lens > 1``: a step
+    with no chunk row runs nothing here, and a row's ``[T, H, lanes]``
+    queries are gathered inside its own iteration.
 
-    q [B, C, H, width] → [B, C, H, value_width] in q's dtype."""
-    b, c, h, _ = q.shape
+    q [T, H, width] → [T, H, value_width] in q's dtype; slots of rows
+    with ``query_lens <= 1`` and the padded tail hold zeros."""
+    t, h, _ = q.shape
+    b = block_tables.shape[0]
     page, lanes = pages.shape[1:]
     window = block_tables.shape[1] * page
     slots = jnp.arange(window, dtype=jnp.int32)
-    q = pad_lanes(q, lanes)
+    i = jnp.arange(t, dtype=jnp.int32)
+    query_lens = query_lens.astype(jnp.int32)
+    starts = jnp.cumsum(query_lens) - query_lens
+    is_chunk = query_lens > 1
+    chunk_rows = jnp.nonzero(is_chunk, size=b, fill_value=0)[0]
 
-    def attend(qr, table, ctx):
-        kw = pages[table].reshape(window, lanes)           # [window, lanes]
+    def attend(n, out):
+        r = chunk_rows[n]
+        idx = starts[r] + i
+        qr = pad_lanes(q[jnp.minimum(idx, t - 1)], lanes)  # [T, H, lanes]
+        kw = pages[block_tables[r]].reshape(window, lanes)
         s = jnp.einsum("chw,kw->hck", qr.astype(kw.dtype), kw,
                        preferred_element_type=jnp.float32) * scale
-        pos = ctx + jnp.arange(c, dtype=jnp.int32)
+        pos = context_lens[r] + i
         s = jnp.where(slots[None, None, :] <= pos[None, :, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         # one plain [heads x chunk, window] @ [window, value] product
-        o = jnp.matmul(p.astype(kw.dtype).reshape(h * c, window),
+        o = jnp.matmul(p.astype(kw.dtype).reshape(h * t, window),
                        kw[:, :value_width],
                        preferred_element_type=jnp.float32)
-        return o.reshape(h, c, value_width).transpose(1, 0, 2).astype(
-            q.dtype)
+        o = o.reshape(h, t, value_width).transpose(1, 0, 2).astype(q.dtype)
+        # the row's own slots; what lies past its length goes nowhere
+        return out.at[jnp.where(i < query_lens[r], idx, t)].set(
+            o, mode="drop")
 
-    def row(args):
-        qr, table, ctx, qlen = args
-        return jax.lax.cond(
-            qlen > 1, attend,
-            lambda *_: jnp.zeros((c, h, value_width), q.dtype),
-            qr, table, ctx)
-
-    return jax.lax.map(row, (q, block_tables.astype(jnp.int32),
-                             context_lens.astype(jnp.int32),
-                             query_lens.astype(jnp.int32)))
+    return jax.lax.fori_loop(
+        0, jnp.sum(is_chunk.astype(jnp.int32)), attend,
+        jnp.zeros((t, h, value_width), q.dtype))
 
 
 def latent_ragged_attention(q, pages, block_tables, context_lens,
                             query_lens, scale, value_width):
     """The mixed step's attention over latent pages the step has just
-    written: decode rows (``query_lens == 1``) through the kernel, chunk
-    rows through the per-row composition, inactive rows nowhere.
+    written, on the step's flat token axis: decode rows
+    (``query_lens == 1``) through the kernel, which reads the one query
+    at each row's first slot; chunk rows through the per-row
+    composition; inactive rows nowhere.
 
-    q [B, C, H, width] → [B, C, H, value_width]; positions past a row's
-    ``query_lens`` hold zeros or garbage the caller never reads."""
+    q [T, H, width] → [T, H, value_width]; the padded tail holds zeros."""
+    t = q.shape[0]
     is_decode = query_lens == 1
+    starts = jnp.cumsum(query_lens) - query_lens
     # the Pallas call stays outside the scope: the TPU compiler names a
     # Mosaic custom call after its innermost scope, and readers key on
     # the kernel's own name
     dec = latent_paged_decode(
-        q[:, 0], pages, block_tables,
+        q[jnp.minimum(starts, t - 1)], pages, block_tables,
         jnp.where(is_decode, context_lens + 1, 0), scale, value_width)
     with jax.named_scope("latent_attention"):
         out = latent_chunk_attention(q, pages, block_tables, context_lens,
                                      query_lens, scale, value_width)
-        first = jnp.where(is_decode[:, None, None], dec.astype(out.dtype),
-                          out[:, 0])
-        return out.at[:, 0].set(first)
+        return out.at[jnp.where(is_decode, starts, t)].set(
+            dec.astype(out.dtype), mode="drop")

@@ -76,6 +76,39 @@ from .paged_attention import (NEG_INF, _current_mesh, _decode_page_step,
                               prefix_prefill_attention)
 
 
+def ragged_rows(query_lens, tokens: int):
+    """Where a step's rows lie on its flat token axis of ``tokens``
+    slots: row ``b``'s ``query_lens[b]`` tokens follow row ``b - 1``'s,
+    the tail is padding.  Returns ``(starts [B], row [T], offset [T],
+    valid [T])``: each row's first slot (the exclusive cumulative sum of
+    ``query_lens``), and for every slot the row it belongs to, its index
+    inside that row, and whether it holds a token at all.  A pad slot
+    reads ``valid`` False with ``row`` and ``offset`` clamped into range,
+    so it can index anything a real slot can."""
+    query_lens = query_lens.astype(jnp.int32)
+    ends = jnp.cumsum(query_lens)
+    starts = ends - query_lens
+    t = jnp.arange(tokens, dtype=jnp.int32)
+    # rows whose span ends at or before t (a row of length 0 ends where
+    # it starts, so it is stepped over)
+    row = jnp.sum((t[:, None] >= ends[None, :]).astype(jnp.int32), axis=1)
+    row = jnp.minimum(row, query_lens.shape[0] - 1)
+    valid = t < ends[-1]
+    offset = jnp.where(valid, t - starts[row], 0)
+    return starts, row, offset, valid
+
+
+def rows_from_flat(x, starts, chunk: int):
+    """The per-row view ``[B, chunk, ...]`` of a flat ``[T, ...]`` token
+    axis: row ``b`` holds slots ``starts[b] + i``.  Positions past a
+    row's ``query_len`` hold other rows' tokens (or the last slot's);
+    the writers store them nowhere and the kernels never read them.
+    Per-row results ``y [B, chunk, ...]`` go back on the flat axis as
+    ``y[row, offset]`` (``ragged_rows``)."""
+    i = jnp.arange(chunk, dtype=jnp.int32)[None]
+    return x[jnp.minimum(starts[:, None] + i, x.shape[0] - 1)]
+
+
 def write_ragged_pages(pages, block_tables, kv, context_lens, query_lens,
                        scratch_page):
     """Write a ragged batch's K or V ``[B, C, H, D]`` into the

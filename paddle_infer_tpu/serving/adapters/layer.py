@@ -142,14 +142,14 @@ class LoRAServingLinear(Layer):
         if rows is None:
             return y
         raw = lora_slots._raw
-        xd = raw(x)                       # [b, s, d_in]
-        sl = raw(rows)                    # [b] int32
-        ga = raw(self.lora_a)[sl]         # [b, d_in, r]
-        gb = raw(self.lora_b)[sl]         # [b, r, d_out]
-        gs = raw(self.lora_scale)[sl]     # [b]
-        delta = jnp.einsum("bsd,bdr->bsr", xd, ga)
-        delta = jnp.einsum("bsr,bro->bso", delta, gb)
-        return Tensor(raw(y) + gs[:, None, None] * delta)
+        xd = raw(x)[0]                    # [T, d_in]: the flat token axis
+        sl = raw(rows)                    # [T] int32, each token's row's
+        ga = raw(self.lora_a)[sl]         # [T, d_in, r]
+        gb = raw(self.lora_b)[sl]         # [T, r, d_out]
+        gs = raw(self.lora_scale)[sl]     # [T]
+        delta = jnp.einsum("td,tdr->tr", xd, ga)
+        delta = jnp.einsum("tr,tro->to", delta, gb)
+        return Tensor(raw(y) + (gs[:, None] * delta)[None])
 
     def extra_repr(self):
         return (f"in={self.in_features}, out={self.out_features}, "

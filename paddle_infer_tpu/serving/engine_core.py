@@ -259,9 +259,8 @@ class EngineCore:
             # deployment config — part of the executable's config key,
             # never of the data — and with the default capacity_factor
             # the routing is bitwise the unconverted fused path over the
-            # same max_batch × token_budget token block.
-            cap = serving_capacity(self._max_batch, self._token_budget,
-                                   self._moe)
+            # same token_budget token slots.
+            cap = serving_capacity(self._token_budget, self._moe)
             prepare_moe_serving(engine._model, cap)
             self._moe = dict(
                 self._moe, capacity=int(cap),
@@ -1525,7 +1524,9 @@ class EngineCore:
         active = [s for s in self._slots if s is not None]
         b = self._max_batch
         C = self._token_budget
-        ids = np.zeros((b, C), np.int32)
+        # each row's tokens as the packer deals them; the program takes
+        # them end to end on one flat [C] axis (``ids``, below)
+        row_ids = np.zeros((b, C), np.int32)
         qlens = np.zeros((b,), np.int32)
         ctx = np.zeros((b,), np.int32)
         steps0 = np.zeros((b,), np.int32)
@@ -1581,7 +1582,7 @@ class EngineCore:
         chunk_taken = {}
         for s in decode_rows:
             i = s["sid"]
-            ids[i, 0] = s["last_tok"]
+            row_ids[i, 0] = s["last_tok"]
             qlens[i] = 1
             # the fed token's KV lands at length + emitted - 1
             ctx[i] = s["length"] + s["emitted"] - 1
@@ -1597,7 +1598,7 @@ class EngineCore:
             n = min(plan.chunk_cap, budget, int(s["pending"].size))
             if n <= 0:
                 continue        # budget spent: the row waits this step
-            ids[i, :n] = s["pending"][:n]
+            row_ids[i, :n] = s["pending"][:n]
             qlens[i] = n
             ctx[i] = s["ctx"]
             steps0[i] = s["emitted"]
@@ -1658,8 +1659,8 @@ class EngineCore:
                     continue
                 # proposals are host ints from the draft source
                 # tpulint: disable-next-line=host-sync -- speculative scratch readback at the verification boundary; verification is a host decision
-                ids[i, 1:1 + k_row] = np.asarray(proposal[:k_row],
-                                                 np.int32)
+                row_ids[i, 1:1 + k_row] = np.asarray(proposal[:k_row],
+                                                     np.int32)
                 qlens[i] = 1 + k_row
                 spec[i] = True
                 budget -= k_row
@@ -1687,7 +1688,7 @@ class EngineCore:
                     if spec[i]:
                         lanes = grammar_rt.lane_masks(
                             gf, s["fsm"],
-                            [int(t) for t in ids[i, 1:qlens[i]]],
+                            [int(t) for t in row_ids[i, 1:qlens[i]]],
                             W, eos_id)
                     else:
                         lanes = np.broadcast_to(
@@ -1700,6 +1701,11 @@ class EngineCore:
                     grammar_rows_step += 1
                     masked_tokens_step += grammar_rt.masked_count(
                         gf, s["fsm"], eos_id)
+        # the step's token axis: rows end to end in slot order (the
+        # budget above bounds their sum by C), the tail padded
+        ids = np.zeros((C,), np.int32)
+        ids[:int(qlens.sum())] = row_ids[np.arange(C)[None]
+                                         < qlens[:, None]]
         draft_tokens_step = sum(drafted.values())
         prefill_tokens_step = sum(chunk_taken.values())
         n_decode = len(decode_rows)
@@ -1779,7 +1785,7 @@ class EngineCore:
                 **self._phase_fields(clock, end),
                 active_rows=len(active), decode_rows=n_decode,
                 chunk_steps=1, prefill_tokens=prefill_tokens_step,
-                prefill_chunk_tokens=prefill_tokens_step,
+                prefill_chunk_tokens=prefill_tokens_step, token_slots=C,
                 kernel="ragged",
                 resident_kv_pages=self._used_pages(),
                 compile_events=clog.count() - c0, faults=injected,
@@ -1988,7 +1994,7 @@ class EngineCore:
             active_rows=len(active),
             decode_rows=n_decode, chunk_steps=1,
             prefill_tokens=prefill_tokens_step,
-            prefill_chunk_tokens=prefill_tokens_step,
+            prefill_chunk_tokens=prefill_tokens_step, token_slots=C,
             kernel="ragged",
             emitted_tokens=emitted_decode + emitted_prefill,
             resident_kv_pages=resident,

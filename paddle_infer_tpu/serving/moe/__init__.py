@@ -14,7 +14,7 @@ dense models (docs/SERVING.md "MoE serving"):
   ``moe_serving_info``    detection + description of a model's MoE
                           plane (validation matrix, metrics).
   ``serving_capacity``    the per-expert buffer width from deployment
-                          config (max_batch × token_budget through the
+                          config (the token_budget slots through the
                           training capacity formula — default-capacity
                           serving is bitwise the unconverted stream).
   ``stats``               the thread-local side-channel carrying

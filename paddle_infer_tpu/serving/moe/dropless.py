@@ -11,10 +11,13 @@ layer computes ``sum_{i in T ∩ held} w_i E_i(x)`` with ``w`` normalised
 over all ``k`` chosen.  What absent experts would add is left out — on
 one chip the layer runs without its exchange.
 
-Pad slots route nowhere: the mixed step threads its valid-slot mask and
-its bound on valid slots (the token budget) through the stats
-side-channel (``serving/moe/stats.py``); the rows buffer of the grouped
-matmul is sized from that bound times ``k``, so it can never overflow.
+Pad slots route nowhere: the mixed step hands the layer its flat token
+axis (``N = token_budget`` slots, the rows' tokens end to end) and
+threads the valid-slot mask ``arange(N) < sum(query_lens)`` and its
+bound on valid slots (all ``N``) through the stats side-channel
+(``serving/moe/stats.py``); the sort runs over ``N x k`` keys, the rows
+buffer of the grouped matmul is sized from the bound times ``k``, so it
+can never overflow, and the combine adds into ``[N, hidden]``.
 Outside a collecting context every slot is valid and the buffer holds
 every assignment.
 """

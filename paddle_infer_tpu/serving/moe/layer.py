@@ -12,7 +12,7 @@ snapshot unchanged; only the forward dispatch differs.
 ``quantization.slim._swap``), ``moe_serving_info`` detects and
 describes a model's MoE plane for validation/observability, and
 ``serving_capacity`` fixes the per-expert buffer size from deployment
-config — ``max_batch × token_budget`` tokens through the same
+config — the mixed step's ``token_budget`` token slots through the same
 ``_capacity`` formula the training fused path applies to its live
 token count, so the converted routing is bitwise what the unconverted
 model computes inside the mixed step.
@@ -188,14 +188,14 @@ def moe_serving_info(model) -> Optional[dict]:
     }
 
 
-def serving_capacity(max_batch: int, token_budget: int, info: dict) -> int:
+def serving_capacity(token_budget: int, info: dict) -> int:
     """The fixed per-expert buffer width for a deployment config: the
     training ``_capacity`` formula applied to the mixed step's static
-    token count (max_batch × token_budget), so default-capacity serving
-    routes bitwise-identically to the unconverted fused path."""
-    return _capacity(int(max_batch) * int(token_budget),
-                     info["num_experts"], info["capacity_factor"],
-                     info["top_k"])
+    token count (the ``token_budget`` slots of its flat token axis, pad
+    slots among them: they compete for capacity), so default-capacity
+    serving routes bitwise-identically to the unconverted fused path."""
+    return _capacity(int(token_budget), info["num_experts"],
+                     info["capacity_factor"], info["top_k"])
 
 
 def prepare_moe_serving(model, capacity: int) -> int:

@@ -5,12 +5,13 @@ the live token count inside the trace — fine there (every training step
 has the same [b, s]), fatal for serving if anything shape-valued ever
 depended on batch composition.  These ops take ``capacity`` as an
 explicit attribute fixed by deployment config
-(``serving.moe.serving_capacity``: max_batch × token_budget tokens), so
+(``serving.moe.serving_capacity``: token_budget token slots), so
 the dispatch/combine buffers are ``[E, C]``-shaped once per config and
 routing changes DATA, never shapes.  In the ragged mixed step the token
-count is itself the static max_batch × token_budget, so with the
-default capacity the routing numerics are bitwise what the training
-fused path computes — conversion changes nothing in the stream.
+count is itself static, the ``token_budget`` slots of the step's flat
+token axis, so with the default capacity the routing numerics are
+bitwise what the training fused path computes — conversion changes
+nothing in the stream.
 
 Three variants mirror the fused-MoE matrix (float / weight-only int8
 and int4 / int8-activation), each returning the routed/dropped/aux

@@ -38,7 +38,8 @@ class MoEStatsCollector:
     def __init__(self, valid, max_valid=None):
         self.valid = valid
         # static bound on how many slots of ``valid`` can be set (the
-        # mixed step's token budget); None = every slot may be
+        # mixed step's token budget, which is also the length of its
+        # flat token axis); None = every slot may be
         self.max_valid = max_valid
         self.routed = []
         self.dropped = []

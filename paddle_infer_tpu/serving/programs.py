@@ -18,7 +18,12 @@ Layout contract with ``EngineCore``:
 
   * nothing is padded to a prompt bucket: row ``b`` of a step carries
     ``qlens[b]`` real tokens starting at absolute position ``ctx[b]``;
-    the slots past ``qlens[b]`` are written nowhere and never attended;
+  * the step's tokens ride ONE flat axis ``ids[token_budget]``: the
+    rows' tokens end to end in slot order, row ``b``'s at ``starts[b] ..
+    starts[b] + qlens[b] - 1`` with ``starts`` the exclusive cumulative
+    sum of ``qlens`` (computed here, never shipped), the tail padded;
+    every token-wise layer runs over those slots and nothing wider, and
+    pad slots are written nowhere and never attended;
   * a decode row feeds its last emitted token, writes its KV at
     ``length + emitted - 1`` and samples the next token, with *per-row*
     lengths/offsets so rows at different generation depths coexist;
@@ -37,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference import sampling
+from ..ops.pallas.ragged_paged_attention import ragged_rows
 
 # samp dict fields (all shaped [batch]):
 #   temperature f32, top_k i32 (0 = off), top_p f32 (1.0 = off),
@@ -108,14 +114,23 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     """THE serving step executable: one launch per scheduler step,
     whatever the batch composition.  Row ``b`` carries ``qlens[b]``
     query tokens starting at absolute position ``ctx[b]`` — 1 for a
-    decode row (``ids[b, 0]`` is its last emitted token), >1 for a
-    prefill chunk (a slice of the prompt), 0 for an inactive row (all
-    table entries at the scratch page).  The executable's shape depends
+    decode row (its last emitted token), >1 for a prefill chunk (a slice
+    of the prompt), 0 for an inactive row (all table entries at the
+    scratch page) — laid on the flat token axis ``ids[T]``, ``T =
+    token_budget``, behind the rows before it (``ragged_rows``).  The
+    model runs over ``[1, T]`` with ``position_ids = ctx[row] + offset``
+    (pad slots at 0); the per-row ``[b, T, heads, d]`` view lives only
+    inside the attention layers, around the cache writers and the
+    kernel (models/transformer_block._forward_paged; the latent layer
+    gathers one query a decode row and a chunk row's queries inside its
+    own iteration, models/latent_moe.py), and the hidden state is
+    gathered at each row's sampled slot before the final norm and the
+    head (``head_rows``).  The executable's shape depends
     only on ``(max_batch, token_budget, max_pages, pool)``, so after ONE
     warmup compile every mix of cold chunks, warm-prefix suffixes and
     decode rows reuses it.
 
-    ``run(params, ids[b, C], qlens[b], ctx[b], steps0[b],
+    ``run(params, ids[T], qlens[b], ctx[b], steps0[b],
     sample_now[b], adapter_slots[b], tables[b, max_pages], samp,
     keys[b, 2], scratch[], k_pages, v_pages)`` →
     ``(tok[b], fin[b], k_pages, v_pages)``; pools are donated.
@@ -128,8 +143,9 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     the engine always packs the array (zeros), so the signature is one
     shape for every deployment.
 
-    Sampling: each row's next-token logits sit at chunk position
-    ``qlens - 1`` (for decode rows that is position 0).  ``sample_now``
+    Sampling: each row's next-token logits sit at flat slot ``starts +
+    qlens - 1`` (for decode rows that is the row's one slot), and the
+    head computes those ``b`` rows alone.  ``sample_now``
     is False for non-final prefill chunks: their row emits no token
     this step (the pad id is returned and the engine ignores it).
     ``steps0`` is the sampled token's generation-step index, so the
@@ -157,7 +173,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     draw from the disjoint ``fold_in(fold_in(base, step), 1|2)``
     streams), so a non-spec row reproduces the plain step bit-for-bit.
 
-    Spec signature: ``run(params, ids[b, C], qlens, ctx, steps0,
+    Spec signature: ``run(params, ids[T], qlens, ctx, steps0,
     sample_now, adapter_slots, spec[b] bool, tables, samp, keys,
     scratch, k_pages, v_pages)`` →
     ``(out[b, W], n_emit[b], fin[b], k_pages, v_pages)``
@@ -197,53 +213,56 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     carry all-zero mask rows.  Deployments without a grammar vocab get
     the ``grammar=False`` signatures below VERBATIM — same arity, same
     donation indices, same executable key."""
-    C = token_budget
+    T = token_budget
 
-    def _model_step_with_stats(params, ids, pos2d, caches, qlens, i2d,
-                               adapter_slots):
-        """One model step under the adapter-slot side-channel,
-        optionally collecting MoE routing stats masked to the step's
-        valid (non-pad) token slots.  The slot context is opened
-        unconditionally: unconverted models never read it, and a
-        converted model with an all-zero slot vector gathers the
-        identity rows — same executable either way."""
+    def _model_step(params, ids, qlens, ctx, adapter_slots, caches,
+                    head_offset):
+        """One model step over the flat token axis ``ids[T]`` under the
+        adapter-slot side-channel, optionally collecting MoE routing
+        stats masked to the step's valid (non-pad) token slots.  The
+        hidden state is gathered at ``starts + head_offset`` (``[b]`` or
+        ``[b, W]`` offsets inside each row) before the final norm and
+        the head, so the logits come back as ``[b(, W), vocab]``.  The
+        slot context is opened unconditionally: unconverted models never
+        read it, and a converted model with an all-zero slot vector
+        gathers the identity rows — same executable either way."""
         from .adapters import slots as lora_slots_mod
 
-        with lora_slots_mod.activate(adapter_slots):
-            if not moe_stats:
-                logits, caches = engine._model_step(params, ids, pos2d,
-                                                    None, caches)
-                return logits, caches, ()
-            from .moe import stats as moe_stats_mod
-
-            vmask = (i2d < qlens[:, None]).reshape(-1)
-            # at most the token budget of the b*C slots is ever valid
-            with moe_stats_mod.collect(vmask, max_valid=C) as col:
-                logits, caches = engine._model_step(params, ids, pos2d,
-                                                    None, caches)
-            return logits, caches, col.totals()
-
-    def run(params, ids, qlens, ctx, steps0, sample_now, adapter_slots,
-            tables, samp, keys, gmask, scratch, k_pages, v_pages):
-        b = ids.shape[0]
-        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
-                               qlens, scratch)
-        i2d = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
-                               (b, C))
+        starts, row, offset, valid = ragged_rows(qlens, T)
         # pad positions pin to 0: a replayed decode row near the window
         # edge would push ``ctx + i`` past max_position_embeddings,
         # where the embedding gather fills NaN — the pad K/V then plants
         # NaN in the scratch page and 0-weight * NaN poisons every row
         # whose table carries scratch filler.  Pad K/V is never
         # attended, so valid logits are bitwise unchanged.
-        pos2d = jnp.where(i2d < qlens[:, None], ctx[:, None] + i2d, 0)
-        logits, caches, moe_out = _model_step_with_stats(
-            params, ids, pos2d, caches, qlens, i2d, adapter_slots)
+        pos = jnp.where(valid, ctx[row] + offset, 0)
+        head_rows = jnp.minimum(
+            starts.reshape(starts.shape + (1,) * (head_offset.ndim - 1))
+            + head_offset, T - 1)
+
+        def model():
+            return engine._model_step(params, ids[None], pos[None], None,
+                                      caches, head_rows=head_rows)
+
+        # LoRA slots follow the axis: one per token, its row's
+        with lora_slots_mod.activate(adapter_slots[row]):
+            if not moe_stats:
+                return (*model(), ())
+            from .moe import stats as moe_stats_mod
+
+            with moe_stats_mod.collect(valid, max_valid=T) as col:
+                logits, caches = model()
+            return logits, caches, col.totals()
+
+    def run(params, ids, qlens, ctx, steps0, sample_now, adapter_slots,
+            tables, samp, keys, gmask, scratch, k_pages, v_pages):
+        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
+                               qlens, scratch)
+        last, caches, moe_out = _model_step(
+            params, ids, qlens, ctx, adapter_slots, caches,
+            jnp.maximum(qlens - 1, 0))
         # one scope with the head (models/llama.py): the sampling tail
         with jax.named_scope("lm_head_sample"):
-            last = jnp.take_along_axis(
-                logits, jnp.maximum(qlens - 1, 0)[:, None, None],
-                axis=1)[:, 0]
             if grammar:
                 last = last + gmask
             proc = _process_rows(last, samp, steps0)
@@ -273,16 +292,10 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     def run_spec(params, ids, qlens, ctx, steps0, sample_now,
                  adapter_slots, spec, tables, samp, keys, gmask,
                  scratch, k_pages, v_pages):
-        b = ids.shape[0]
+        b = qlens.shape[0]
         spec2d = jnp.broadcast_to(spec[:, None], (b, W))
         caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
                                qlens, scratch, spec2d)
-        i2d = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None],
-                               (b, C))
-        pos2d = jnp.where(i2d < qlens[:, None], ctx[:, None] + i2d, 0)
-        logits, caches, moe_out = _model_step_with_stats(
-            params, ids, pos2d, caches, qlens, i2d, adapter_slots)
-
         # per-window-position logits: spec rows read positions 0..W-1
         # (clamped to their qlen), plain rows replicate qlens-1 so
         # their column 0 is exactly the non-spec gather
@@ -290,7 +303,8 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         j = jnp.arange(W, dtype=jnp.int32)[None]               # [1, W]
         gidx = jnp.where(spec[:, None], jnp.minimum(j, base[:, None]),
                          base[:, None])                        # [b, W]
-        lg_w = jnp.take_along_axis(logits, gidx[:, :, None], axis=1)
+        lg_w, caches, moe_out = _model_step(
+            params, ids, qlens, ctx, adapter_slots, caches, gidx)
         if grammar:
             lg_w = lg_w + gmask
         steps_w = steps0[:, None] + jnp.where(spec[:, None], j, 0)
@@ -300,11 +314,12 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             lambda p, st: _pick_rows(p, samp, st, keys),
             in_axes=(1, 1), out_axes=1)(proc_w, steps_w)       # [b, W]
 
-        # drafts ride at ids[:, 1 + j]; position j carries one only on
-        # spec rows with j < qlens - 1
-        didx = jnp.broadcast_to(jnp.minimum(j[:, :W - 1] + 1, C - 1),
-                                (b, W - 1))
-        drafts = jnp.take_along_axis(ids, didx, axis=1)        # [b, W-1]
+        # drafts ride behind the row's first token, at ids[starts + 1
+        # + j]; position j carries one only on spec rows with
+        # j < qlens - 1
+        starts = jnp.cumsum(qlens) - qlens
+        drafts = ids[jnp.minimum(starts[:, None] + 1 + j[:, :W - 1],
+                                 T - 1)]                       # [b, W-1]
         has_draft = jnp.logical_and(spec[:, None],
                                     j[:, :W - 1] < base[:, None])
 

@@ -196,25 +196,49 @@ def _window_relayouts(text):
             if re.search(r"= \w+\[(2048,32,16,128|16,128,16,32,128)\]", line)]
 
 
-def test_mixed_step_layer_has_no_pool_or_window_sized_copy(spec,
-                                                           monkeypatch):
+@pytest.fixture(scope="module")
+def not_interpreted():
+    """The served step asks the backend whether to interpret its Pallas
+    calls, and the backend here is the CPU: for the compiles of whole
+    steps below, every kernel module answers no."""
+    from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
+    from paddle_infer_tpu.ops.pallas import latent_attention as LA
+    from paddle_infer_tpu.ops.pallas import paged_attention as PA
+    from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
+
+    mods = (PA, RPA, LA, GM)
+    prev = [m._interpret for m in mods]
+    for m in mods:
+        m._interpret = lambda: False
+    yield
+    for m, f in zip(mods, prev):
+        m._interpret = f
+
+
+def _step_args(spec, params, b, tokens, max_pages, k_pools, v_pools):
+    """The mixed step's arguments as ``EngineCore`` hands them over: the
+    flat ``ids[tokens]``, then the per-row arrays."""
+    i32, f32 = jnp.int32, jnp.float32
+    rows = lambda dtype: spec((b,), dtype)
+    samp = {"temperature": rows(f32), "top_k": rows(i32),
+            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
+            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
+    return (params, spec((tokens,), i32), rows(i32), rows(i32), rows(i32),
+            rows(jnp.bool_), rows(i32), spec((b, max_pages), i32), samp,
+            spec((b, 2), jnp.uint32), spec((), i32), k_pools, v_pools)
+
+
+@pytest.fixture(scope="module")
+def chat_step_text(one_chip, not_interpreted):
     """One layer of the served mixed step at the chat cell's widths, as
-    ``EngineCore`` builds it (pools donated): the K and V writes reach
-    the compiled step as in-place scatters, with no copy or transpose of
-    the pool around them, and its attention is the ragged kernel's one
-    launch: nothing gathers, transposes or scores a row's whole table
-    window."""
+    ``EngineCore`` builds it (pools donated), compiled for the chip."""
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_infer_tpu.nn.initializer import abstract_parameters
-    from paddle_infer_tpu.ops.pallas import paged_attention as PA
-    from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
     from paddle_infer_tpu.serving.programs import build_mixed_step
 
-    # the step asks the backend whether to interpret its Pallas calls,
-    # and the backend here is the CPU
-    monkeypatch.setattr(PA, "_interpret", lambda: False)
-    monkeypatch.setattr(RPA, "_interpret", lambda: False)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
     cfg = LlamaConfig(vocab_size=32000, hidden_size=4096,
                       num_hidden_layers=1, num_attention_heads=H,
                       num_key_value_heads=8, intermediate_size=14336,
@@ -225,23 +249,67 @@ def test_mixed_step_layer_has_no_pool_or_window_sized_copy(spec,
     engine = PagedGenerationEngine(model, page_size=PAGE,
                                    cache_dtype=jnp.bfloat16)
     run = build_mixed_step(engine, CELL_B, CELL_CHUNK, MAX_PAGES)
-    b, i32, f32 = CELL_B, jnp.int32, jnp.float32
-    rows = lambda dtype: spec((b,), dtype)
-    samp = {"temperature": rows(f32), "top_k": rows(i32),
-            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
-            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
     params = {n: spec(a.shape, jnp.bfloat16)
               for n, a in engine._params.items()}
     pools = [spec(CELL_POOL, jnp.bfloat16)]
-    text = run.lower(
-        params, spec((b, CELL_CHUNK), i32), rows(i32), rows(i32), rows(i32),
-        rows(jnp.bool_), rows(i32), spec((b, MAX_PAGES), i32), samp,
-        spec((b, 2), jnp.uint32), spec((), i32), pools, pools
-    ).compile().as_text()
+    return run.lower(*_step_args(spec, params, CELL_B, CELL_CHUNK,
+                                 MAX_PAGES, pools, pools)
+                     ).compile().as_text()
+
+
+def test_mixed_step_layer_has_no_pool_or_window_sized_copy(chat_step_text):
+    """The K and V writes reach the compiled step as in-place scatters,
+    with no copy or transpose of the pool around them, and its attention
+    is the ragged kernel's one launch: nothing gathers, transposes or
+    scores a row's whole table window."""
+    text = chat_step_text
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     assert "ragged_paged_attention" in text
     assert not _pool_relayouts(text)
     assert not _window_relayouts(text)
+
+
+def _shaped(text, dims):
+    """Instructions of the compiled program whose output has ``dims``."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(r"= \w+\[%s\]\S* \w[\w-]*\(" % dims, line)]
+
+
+@pytest.mark.parametrize("dims, what", [
+    ("16,64,14336", "the FFN over every row's slots"),
+    ("16,64,6144", "QKV over every row's slots"),
+    ("16,64,32000", "the head over every row's slots"),
+    ("1024,\\d+", "anything over max_batch x token_budget flat slots")])
+def test_chat_step_runs_its_token_wise_layers_over_the_flat_axis(
+        chat_step_text, dims, what):
+    """The step's token-wise layers run over ``token_budget`` slots: no
+    instruction's output is shaped like the per-row slot array."""
+    assert not _shaped(chat_step_text, dims), what
+    # the shapes looked for are the ones such a program would hold
+    assert _shaped(chat_step_text, "64,14336")
+
+
+def test_chat_step_head_reads_max_batch_rows(chat_step_text):
+    """The hidden state is gathered at each row's sampled slot before the
+    final norm and the head: the logits are ``[max_batch, vocab]``."""
+    assert _shaped(chat_step_text, "16,32000")
+    assert not _shaped(chat_step_text, "64,32000")
+    assert not _shaped(chat_step_text, "1,64,32000")
+
+
+def _copies_of(text, dims):
+    """Copies or transposes whose output has ``dims`` (either order of a
+    matrix's two sides)."""
+    return [line.strip()[:120] for line in text.splitlines()
+            if re.search(r"= \w+\[(%s)\]\S* (copy|transpose)\(" % dims, line)]
+
+
+def test_chat_step_does_not_copy_the_qkv_weight(chat_step_text):
+    """Over 64 flat tokens the TPU compiler would rather compute QKV
+    head-major and transpose the whole ``[4096, 6144]`` weight for it on
+    every step (50 MB moved a layer); the barrier behind the projection
+    (models/transformer_block.py) keeps the head split out of it."""
+    assert not _copies_of(chat_step_text, "6144,4096|4096,6144")
 
 
 def test_the_window_shapes_are_what_the_dense_composition_compiles_to(spec):
@@ -308,21 +376,18 @@ def test_latent_decode_and_grouped_matmul_compile(spec, monkeypatch):
                  spec((12, k, n), jnp.bfloat16), spec((12,), i32))
 
 
-def test_latent_mixed_step_keeps_its_pool_in_place(spec, monkeypatch):
-    """A dense and an expert layer of the served mixed step at the cell's
-    widths, pools donated: nothing copies or transposes anything of the
-    pool's shape, and both kernels are in the step under their own
-    names."""
+@pytest.fixture(scope="module")
+def latent_step(one_chip, not_interpreted):
+    """A dense and an expert layer of the served mixed step at the ragchat
+    cell's widths, pools donated, compiled for the chip."""
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.latent_moe import (LatentMoEConfig,
                                                     LatentMoEForCausalLM)
     from paddle_infer_tpu.nn.initializer import abstract_parameters
-    from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
-    from paddle_infer_tpu.ops.pallas import latent_attention as LA
     from paddle_infer_tpu.serving.programs import build_mixed_step
 
-    monkeypatch.setattr(LA, "_interpret", lambda: False)
-    monkeypatch.setattr(GM, "_interpret", lambda: False)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
     cfg = LatentMoEConfig(
         vocab_size=20480, num_hidden_layers=2, n_routed_experts=12,
         n_routed_experts_published=192, rope_scaling=dict(
@@ -334,22 +399,43 @@ def test_latent_mixed_step_keeps_its_pool_in_place(spec, monkeypatch):
                                    cache_dtype=jnp.bfloat16)
     run = build_mixed_step(engine, LAT_B, CELL_CHUNK, LAT_PAGES,
                            moe_stats=True)
-    b, i32, f32 = LAT_B, jnp.int32, jnp.float32
-    rows = lambda dtype: spec((b,), dtype)
-    samp = {"temperature": rows(f32), "top_k": rows(i32),
-            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
-            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
     params = {n: spec(a.shape, jnp.bfloat16)
               for n, a in engine._params.items()}
     pools = [spec(LAT_POOL, jnp.bfloat16)] * 2
-    compiled = run.lower(
-        params, spec((b, CELL_CHUNK), i32), rows(i32), rows(i32), rows(i32),
-        rows(jnp.bool_), rows(i32), spec((b, LAT_PAGES), i32), samp,
-        spec((b, 2), jnp.uint32), spec((), i32), pools, [None, None]
-    ).compile()
-    text = compiled.as_text()
+    return run.lower(*_step_args(spec, params, LAT_B, CELL_CHUNK, LAT_PAGES,
+                                 pools, [None, None])).compile()
+
+
+def test_latent_mixed_step_keeps_its_pool_in_place(latent_step):
+    """Nothing copies or transposes anything of the pool's shape, and both
+    kernels are in the step under their own names."""
+    text = latent_step.as_text()
     assert "latent_paged_decode" in text and "moe_grouped_matmul" in text
     assert not _latent_pool_copies(text)
     # the widest temporary is one row's [heads, chunk, window] scores, not
     # every row's window of expanded keys and values (2 GB a layer)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    assert latent_step.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_latent_step_does_not_copy_the_query_up_projection(latent_step):
+    """The same barrier behind ``q_b_proj`` (models/latent_moe.py): the
+    ``[1536, 12288]`` weight is read where it lies."""
+    assert not _copies_of(latent_step.as_text(), "1536,12288|12288,1536")
+
+
+@pytest.mark.parametrize("dims, what", [
+    ("16,64,18432", "the dense FFN over every row's slots"),
+    ("16,64,2048", "the shared expert over every row's slots"),
+    ("16,64,20480", "the head over every row's slots"),
+    ("1024,7168", "the experts' combine over max_batch x token_budget"),
+    ("16,64,64,5\\d\\d", "a per-row view of every head's query or output"),
+    ("8192", "a sort over max_batch x token_budget x top-k keys")])
+def test_latent_step_runs_its_token_wise_layers_over_the_flat_axis(
+        latent_step, dims, what):
+    """Token-wise layers, the expert layer's sort and combine and the
+    queries' relayouts all follow the flat ``token_budget`` axis."""
+    text = latent_step.as_text()
+    assert not _shaped(text, dims), what
+    assert _shaped(text, "64,18432") and _shaped(text, "64,7168")
+    # the head's product has max_batch rows
+    assert _shaped(text, "16,20480") and not _shaped(text, "64,20480")

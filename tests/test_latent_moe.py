@@ -56,22 +56,23 @@ def system():
     s.free()
 
 
-def _logits_program(engine, batch, chunk):
-    """The mixed step's model call, returning every position's logits."""
+def _logits_program(engine, tokens):
+    """The mixed step's model call over its flat token axis, returning
+    every slot's logits."""
+    from paddle_infer_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_rows
     from paddle_infer_tpu.serving.programs import (_layer_caches,
                                                    _layer_pools)
 
     def run(params, ids, qlens, ctx, tables, scratch, k_pages, v_pages):
         caches = _layer_caches(engine, k_pages, v_pages, tables, ctx, qlens,
                                scratch)
-        i2d = jnp.broadcast_to(jnp.arange(chunk, dtype=jnp.int32)[None],
-                               (batch, chunk))
-        valid = i2d < qlens[:, None]
-        pos = jnp.where(valid, ctx[:, None] + i2d, 0)
-        with moe_stats.collect(valid.reshape(-1)) as col:
-            logits, caches = engine._model_step(params, ids, pos, None,
-                                                caches)
-        return (logits, *col.totals(),
+        _, row, offset, valid = ragged_rows(qlens, tokens)
+        pos = jnp.where(valid, ctx[row] + offset, 0)
+        with moe_stats.collect(valid, max_valid=tokens) as col:
+            logits, caches = engine._model_step(params, ids[None], pos[None],
+                                                None, caches)
+        return (logits[0], *col.totals(),
                 *_layer_pools(engine, caches))
 
     return jax.jit(run, donate_argnums=(6, 7))
@@ -81,7 +82,7 @@ def test_program_logits_match_the_reference_through_the_latent_cache(system):
     """Chunked prefill, then decode through the latent cache, rows of
     different lengths and kinds in one step."""
     eng, cfg = system.engine, system.config
-    b, c = 4, 16
+    b, t = 4, 32            # four rows laid end to end on 32 token slots
     max_pages = system.core._max_pages
     rng = np.random.default_rng(3)
     seqs = [rng.integers(0, cfg["vocab_size"], n).astype(np.int32)
@@ -97,21 +98,24 @@ def test_program_logits_match_the_reference_through_the_latent_cache(system):
     plan = [(16, 9, 0), (16, 0, 7), (1, 0, 7), (1, 0, 7), (1, 0, 7),
             (1, 0, 2), (1, 0, 0)]
     for step in plan + [(1, 0, 0)] * 8:
-        ids = np.zeros((b, c), np.int32)
+        ids = np.zeros((t,), np.int32)
         qlens = np.zeros((b,), np.int32)
         ctx = np.zeros((b,), np.int32)
         for r, n in enumerate(step):
             n = min(n, len(seqs[r]) - done[r])
-            ids[r, :n] = seqs[r][done[r]:done[r] + n]
+            at = int(qlens.sum())
+            ids[at:at + n] = seqs[r][done[r]:done[r] + n]
             qlens[r], ctx[r] = n, done[r]
         logits, total, held, _, _ = eng.run_paged_program(
-            ("test-logits", b, c), lambda: _logits_program(eng, b, c),
+            ("test-logits", b, t), lambda: _logits_program(eng, t),
             ids, qlens, ctx, tables, np.asarray(system.core._scratch,
                                                 np.int32))
         # every expert is held here: nothing routed is left out
         assert int(total) == int(held) == int(qlens.sum()) * 4 * 2
+        starts = np.cumsum(qlens) - qlens
         for r in range(3):
-            got[r].append(np.asarray(logits[r, :qlens[r]]))
+            got[r].append(np.asarray(
+                logits[starts[r]:starts[r] + qlens[r]]))
             done[r] += int(qlens[r])
     for r, seq in enumerate(seqs):
         mine = np.concatenate(got[r])
@@ -223,11 +227,12 @@ def test_latent_decode_kernel_equals_the_chunk_composition(pages_per_step):
     ctx = jnp.asarray([0, 7, 8, 50, 95], jnp.int32)
     dec = LA.latent_paged_decode(q, pages, tables, ctx + 1, 0.3, value,
                                  pages_per_step=pages_per_step)
-    # the composition treats the same query as the first of a chunk of two
-    q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1)
+    # the composition treats the same query as the first of a chunk of
+    # two, the chunks end to end on its flat token axis
+    q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1).reshape(2 * b, h, width)
     comp = LA.latent_chunk_attention(q2, pages, tables, ctx,
                                      jnp.full((b,), 2, jnp.int32), 0.3,
-                                     value)[:, 0]
+                                     value)[0::2]
     np.testing.assert_allclose(np.asarray(dec), np.asarray(comp), atol=1e-5)
     # a row of length zero is skipped and reads zero
     none = LA.latent_paged_decode(q, pages, tables, jnp.zeros_like(ctx), 0.3,

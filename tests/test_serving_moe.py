@@ -354,9 +354,9 @@ class TestMoEServingConfig:
         assert info["num_experts"] == 4 and info["layers"] == 2
         assert info["algo"] == "fp" and info["gate"] == "gshard"
         assert info["expert_hbm_bytes"] > 0
-        cap = serving_capacity(CORE_SHAPE["max_batch"],
-                               CORE_SHAPE["token_budget"], info)
-        assert cap == _capacity(4 * 16, 4, info["capacity_factor"], 2)
+        # sized on the step's flat token axis, not max_batch rows of it
+        cap = serving_capacity(CORE_SHAPE["token_budget"], info)
+        assert cap == _capacity(16, 4, info["capacity_factor"], 2)
 
     def test_prepare_idempotent(self):
         m = _fresh_model()
