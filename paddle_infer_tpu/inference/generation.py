@@ -207,6 +207,13 @@ class GenerationEngine:
                 "the mp all-reduce wire format)")
         self._model = model
         self._mesh = mesh
+        # where host inputs go: replicated under the mesh, the default
+        # device without one
+        self._feed_sharding = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._feed_sharding = NamedSharding(mesh, PartitionSpec())
         self._quant_allreduce = quantized_allreduce
         self._placed = {}            # name -> (source array, placed array)
         self._shard_record = {}      # name -> sharded|replicated|fallback
@@ -334,12 +341,7 @@ class GenerationEngine:
     def _replicated(self, arr):
         """Pin a host input to an explicit replicated placement under the
         mesh (so GSPMD never guesses a layout for feeds)."""
-        if self._mesh is None:
-            return jnp.asarray(arr)
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.device_put(jnp.asarray(arr),
-                              NamedSharding(self._mesh, PartitionSpec()))
+        return jax.device_put(arr, self._feed_sharding)
 
     # ------------------------------------------------------------ plumbing
     def _empty_caches(self, batch, cache_len):
@@ -908,7 +910,9 @@ class PagedGenerationEngine(GenerationEngine):
         sig = signature_of(args)
         is_compile = sig not in sigs
         k_pages, v_pages = self._ensure_pages()
-        args = jax.tree_util.tree_map(self._replicated, tuple(args))
+        # one host-to-device put for the whole argument tree, the host
+        # arrays as they are (the mixed step hands one packed buffer)
+        args = jax.device_put(tuple(args), self._feed_sharding)
         if key not in self._program_shapes:
             # abstract (shape, dtype) trees for program_cost(): captured
             # before donation consumes the pools, costing only a

@@ -88,6 +88,11 @@ _SCHEMA = (
                                  # ctx + 1)
     ("h2d_bytes", 0),            # bytes of the host arrays handed to the
                                  # step program this step
+    ("h2d_arrays", 0),           # how many host arrays that was: 1, the
+                                 # packed buffer (2 with a grammar mask);
+                                 # 0 on records that launch no step
+    ("d2h_arrays", 0),           # device arrays the step read back,
+                                 # counted as read: 1, the packed output
     ("program_temp_bytes", 0),   # the compiled step's temporaries
                                  # (memory_analysis; 0 where not offered)
     ("active_rows", 0),          # occupied slots at capture

@@ -56,7 +56,8 @@ from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
-from .programs import build_mixed_step, build_page_copy
+from .programs import (DROPLESS_COUNTERS, build_mixed_step, build_page_copy,
+                       step_input_layout, step_output_layout)
 from .request import (DeadlineExceededError, GrammarError,
                       GrammarIncompleteError, HandoffError, LoadShedError,
                       QuarantinedError, QueueFullError, RejectedError,
@@ -361,6 +362,30 @@ class EngineCore:
         else:
             self._spec_window = 1
             self._draft_source = None
+
+        # the step's host interface (serving/programs.StepLayout): ONE
+        # preallocated int32 buffer in, written through per-field views
+        # by the packer and put on the device as it is, and ONE int32
+        # array back.  Both tables are deployment constants.
+        self._step_in = step_input_layout(
+            self._max_batch, self._token_budget, self._max_pages,
+            self._spec_window)
+        self._step_buf = np.zeros((self._step_in.size,), np.int32)
+        self._step_fields = self._step_in.views(self._step_buf)
+        # a request's key is fold_in(PRNGKey(seed), rid): the layout's
+        # keys row has to hold the PRNG implementation's key as it is
+        key_row, prng_key = (self._step_fields["keys"][0],
+                             jax.eval_shape(jax.random.PRNGKey, 0))
+        if (key_row.shape, key_row.dtype) != (prng_key.shape,
+                                              prng_key.dtype):
+            raise ValueError(
+                f"the step layout carries keys as {key_row.dtype}"
+                f"{list(key_row.shape)}; this PRNG implementation's are "
+                f"{prng_key.dtype}{list(prng_key.shape)}")
+        self._step_out = step_output_layout(
+            self._max_batch, self._spec_window,
+            moe=(self._moe["num_experts"] if self._moe is not None
+                 else "dropless" if self._dropless is not None else None))
 
         # step-level flight recorder: every scheduler step event
         # (admission / mixed step / page copy / evict) appends one
@@ -1050,27 +1075,6 @@ class EngineCore:
         return progressed
 
     # --------------------------------------------------------- admission
-    def _samp_arrays(self, cfgs):
-        n = len(cfgs)
-        samp = {"temperature": np.ones((n,), np.float32),
-                "top_k": np.zeros((n,), np.int32),
-                "top_p": np.ones((n,), np.float32),
-                "min_len": np.zeros((n,), np.int32),
-                "eos": np.full((n,), -1, np.int32),
-                "do_sample": np.zeros((n,), bool),
-                "pad": np.zeros((n,), np.int32)}
-        for i, g in enumerate(cfgs):
-            if g is None:
-                continue
-            samp["temperature"][i] = g.temperature
-            samp["top_k"][i] = g.top_k or 0
-            samp["top_p"][i] = g.top_p
-            samp["min_len"][i] = g.min_length
-            samp["eos"][i] = -1 if g.eos_token_id is None else g.eos_token_id
-            samp["do_sample"][i] = g.do_sample
-            samp["pad"][i] = g.pad_token_id
-        return samp
-
     def _match_prefix(self, req: Request, tokens: np.ndarray):
         """Query the radix tree for the longest cached prefix of
         ``tokens`` (the prompt; on replay, prompt + delivered tokens).
@@ -1524,21 +1528,43 @@ class EngineCore:
         active = [s for s in self._slots if s is not None]
         b = self._max_batch
         C = self._token_budget
-        # each row's tokens as the packer deals them; the program takes
-        # them end to end on one flat [C] axis (``ids``, below)
-        row_ids = np.zeros((b, C), np.int32)
-        qlens = np.zeros((b,), np.int32)
-        ctx = np.zeros((b,), np.int32)
-        steps0 = np.zeros((b,), np.int32)
-        sample_now = np.zeros((b,), bool)
+        # the step's one host buffer (serving/programs.step_input_layout),
+        # reset to an all-inactive step: every table entry at the
+        # scratch page, the sampling fields at their pass-through
+        # values.  The packer writes each field through its view; bools
+        # ride as 0 / 1 words.
+        f = self._step_fields
+        self._step_buf[:] = 0
+        f["tables"][:] = self._scratch
+        f["temperature"][:] = 1.0
+        f["top_p"][:] = 1.0
+        f["eos"][:] = -1
+        f["scratch"][()] = self._scratch
+        ids, qlens, ctx, steps0, sample_now = (
+            f["ids"], f["qlens"], f["ctx"], f["steps0"], f["sample_now"])
         # per-row LoRA slot selection: slot 0 (all-zero identity) for
         # base-model rows and every inactive lane — pure data, so a
         # batch mixing 8 different fine-tunes runs the SAME executable
-        aslots = np.zeros((b,), np.int32)
-        tables = np.full((b, self._max_pages), self._scratch, np.int32)
-        keys = np.zeros((b,) + active[0]["key"].shape,
-                        active[0]["key"].dtype)
-        cfgs: List[Optional[GenerationConfig]] = [None] * b
+        aslots = f["adapter_slots"]
+        # each row's tokens as the packer deals them; the program takes
+        # them end to end on one flat [C] axis (``ids``, below)
+        row_ids = np.zeros((b, C), np.int32)
+
+        def pack_row(i, s):
+            """Row ``i``'s fields that are the request's own, whatever
+            it feeds this step: table, adapter slot, key, sampling."""
+            g = s["g"]
+            aslots[i] = s.get("adapter_slot", 0)
+            f["tables"][i] = s["table"]
+            f["keys"][i] = s["key"]
+            f["temperature"][i] = g.temperature
+            f["top_k"][i] = g.top_k or 0
+            f["top_p"][i] = g.top_p
+            f["min_len"][i] = g.min_length
+            f["eos"][i] = -1 if g.eos_token_id is None else g.eos_token_id
+            f["do_sample"][i] = g.do_sample
+            f["pad"][i] = g.pad_token_id
+
         decode_rows = [s for s in active if s["pending"].size == 0]
         chunk_rows = [s for s in active if s["pending"].size > 0]
         eng = self._engine
@@ -1588,10 +1614,7 @@ class EngineCore:
             ctx[i] = s["length"] + s["emitted"] - 1
             steps0[i] = s["emitted"]
             sample_now[i] = True
-            aslots[i] = s.get("adapter_slot", 0)
-            tables[i] = s["table"]
-            keys[i] = s["key"]
-            cfgs[i] = s["g"]
+            pack_row(i, s)
             budget -= 1
         for s in chunk_rows:
             i = s["sid"]
@@ -1605,10 +1628,7 @@ class EngineCore:
             # only the chunk holding the prompt's last token samples;
             # mid-prompt chunks return the pad id and emit nothing
             sample_now[i] = n == int(s["pending"].size)
-            aslots[i] = s.get("adapter_slot", 0)
-            tables[i] = s["table"]
-            keys[i] = s["key"]
-            cfgs[i] = s["g"]
+            pack_row(i, s)
             budget -= n
             chunk_taken[i] = n
         # speculative drafts: ONLY leftover budget, so decode packing
@@ -1617,9 +1637,8 @@ class EngineCore:
         # (k <= remaining - 1) and inside the window (k <= W - 1);
         # sampled rows take deterministic-by-history proposals only, so
         # supervisor replay regenerates the identical stream.
-        spec = np.zeros((b,), bool)
+        spec = f.get("spec")         # a field of the W > 1 layout alone
         drafted = {}
-        W = self._spec_window
         if self._speculate and budget > 0:
             for s in decode_rows:
                 if budget <= 0:
@@ -1703,7 +1722,6 @@ class EngineCore:
                         gf, s["fsm"], eos_id)
         # the step's token axis: rows end to end in slot order (the
         # budget above bounds their sum by C), the tail padded
-        ids = np.zeros((C,), np.int32)
         ids[:int(qlens.sum())] = row_ids[np.arange(C)[None]
                                          < qlens[:, None]]
         draft_tokens_step = sum(drafted.values())
@@ -1720,51 +1738,26 @@ class EngineCore:
         attended_keys_step = int((ql * cx + ql * (ql + 1) // 2).sum())
         resident_tokens_step = int((cx + ql).sum())
         decode_keys_step = int((cx[ql == 1] + 1).sum())
-        h2d_bytes_step = 0
+        h2d_bytes_step = h2d_arrays_step = 0
         clog = get_compile_log()
         c0 = clog.count()
         t0 = clock.phase("launch")
-        n_emit = None
         try:
             fault = self._fault.fire(
                 "decode.step", rids=[s["req"].rid for s in active])
-            moe_out = ()
-            # the host arrays handed to the step program, in call order.
-            # The optional mask input sits between keys and scratch —
-            # absent entirely on non-grammar deployments, so their
-            # executable signatures are byte-identical to before
-            step_args = (
-                (ids, qlens, ctx, steps0, sample_now, aslots)
-                + ((spec,) if W > 1 else ())
-                + (tables, self._samp_arrays(cfgs), keys)
-                + ((gmask,) if grammar_on else ())
-                # scratch page id is a host int, no device sync
-                # tpulint: disable-next-line=host-sync -- speculative scratch readback at the verification boundary; verification is a host decision
-                + (np.asarray(self._scratch, np.int32),))
+            # the host arrays handed to the step program: the packed
+            # buffer, and on a grammar deployment the mask behind it (2
+            # MB at a 32,000-token vocabulary: copying it into the
+            # buffer would cost more than its own put)
+            step_args = (self._step_buf,) + ((gmask,) if grammar_on else ())
             h2d_bytes_step = _host_bytes(step_args)
-            if W > 1:
-                res = eng.run_paged_program(
-                    mkey, lambda: build_mixed_step(eng, b, C,
-                                                   self._max_pages,
-                                                   spec_window=W,
-                                                   moe_stats=moe_stats,
-                                                   grammar=grammar_on),
-                    *step_args)
-                if moe_stats:
-                    tok, n_emit, fin_out, *moe_out = res
-                else:
-                    tok, n_emit, fin_out = res
-            else:
-                res = eng.run_paged_program(
-                    mkey, lambda: build_mixed_step(eng, b, C,
-                                                   self._max_pages,
-                                                   moe_stats=moe_stats,
-                                                   grammar=grammar_on),
-                    *step_args)
-                if moe_stats:
-                    tok, fin_out, *moe_out = res
-                else:
-                    tok, fin_out = res
+            h2d_arrays_step = len(step_args)
+            step_outs = eng.run_paged_program(
+                mkey, lambda: build_mixed_step(eng, b, C, self._max_pages,
+                                               spec_window=W,
+                                               moe_stats=moe_stats,
+                                               grammar=grammar_on),
+                *step_args)
         except Exception as e:
             self._metrics.on_failed(0)
             # only a fault-plane injection raised BEFORE dispatch leaves
@@ -1781,7 +1774,7 @@ class EngineCore:
                 wall_s=end - t0, dispatch_s=t_fail - t0,
                 attended_keys=attended_keys_step,
                 resident_tokens=resident_tokens_step,
-                h2d_bytes=h2d_bytes_step,
+                h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
                 **self._phase_fields(clock, end),
                 active_rows=len(active), decode_rows=n_decode,
                 chunk_steps=1, prefill_tokens=prefill_tokens_step,
@@ -1813,32 +1806,20 @@ class EngineCore:
             # compile on the serving-decode site is a recompile
             get_compile_log().mark_warm("serving-decode", mkey)
             self._decode_warm = True
-        # the one designed sync per step
+        # the one designed sync per step: every host-bound output of the
+        # program is read here (ONE array, step_output_layout), the
+        # fields sliced out of it as views
         # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        tok = np.asarray(tok)
-        # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-        fin_out = np.asarray(fin_out)
-        if n_emit is not None:
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            n_emit = np.asarray(n_emit)
+        host_outs = [np.asarray(o) for o in step_outs]
+        out = self._step_out.views(host_outs[0])
+        tok, fin_out, n_emit = out["tok"], out["fin"], out.get("n_emit")
         moe_kw = {}
-        if moe_out and moe is None:
-            # dropless layers' four counters ride the same per-step sync
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            counters = [int(x) for x in np.asarray(moe_out)]
-            moe_kw = dict(zip(("moe_assignments_total",
-                               "moe_assignments_held",
-                               "moe_held_expert_max",
-                               "moe_experts_touched"), counters))
-        elif moe_out:
-            # moe routing stats ride the same per-step sync: the step's
-            # outputs are already host-bound for emission above
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            m_routed = np.asarray(moe_out[0])
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            m_dropped = int(np.asarray(moe_out[1]))
-            # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-            m_aux = float(np.asarray(moe_out[2]))
+        if DROPLESS_COUNTERS[0] in out:
+            moe_kw = {name: int(out[name]) for name in DROPLESS_COUNTERS}
+        elif "moe_routed" in out:
+            m_routed = out["moe_routed"]
+            m_dropped = int(out["moe_dropped"])
+            m_aux = float(out["moe_aux"])
             moe_kw = dict(moe_tokens_routed=int(m_routed.sum()),
                           moe_tokens_dropped=m_dropped,
                           moe_aux_loss=m_aux)
@@ -1989,7 +1970,8 @@ class EngineCore:
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
             decode_keys=decode_keys_step,
-            h2d_bytes=h2d_bytes_step,
+            h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
+            d2h_arrays=len(host_outs),
             program_temp_bytes=self._program_temp_bytes(mkey),
             active_rows=len(active),
             decode_rows=n_decode, chunk_steps=1,

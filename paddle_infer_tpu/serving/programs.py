@@ -8,7 +8,8 @@ executables by ``GenerationConfig.cache_key()`` — fine when one call
 serves one homogeneous batch, fatal for continuous batching where every
 row can carry different knobs.  Here temperature / top-k / top-p /
 min-length / eos / do_sample ride as **per-row arrays** (the ``samp``
-dict), so there is exactly one step executable per
+fields of the step's packed input), so there is exactly one step
+executable per
 (batch, token-budget, table-width, pool-size) and heterogeneous requests
 share it.  Greedy rows stay argmax-exact with ``GenerationEngine``
 output: temperature scaling, top-k and top-p masking never change the
@@ -40,6 +41,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..inference import sampling
 from ..ops.pallas.ragged_paged_attention import ragged_rows
@@ -47,6 +49,111 @@ from ..ops.pallas.ragged_paged_attention import ragged_rows
 # samp dict fields (all shaped [batch]):
 #   temperature f32, top_k i32 (0 = off), top_p f32 (1.0 = off),
 #   min_len i32, eos i32 (-1 = none), do_sample bool, pad i32
+SAMP_FIELDS = (("temperature", "float32"), ("top_k", "int32"),
+               ("top_p", "float32"), ("min_len", "int32"),
+               ("eos", "int32"), ("do_sample", "bool"), ("pad", "int32"))
+
+# the expert counters that ride out with the tokens (moe/stats.py
+# ``MoEStatsCollector.totals``): a dropless model's four, in this order
+DROPLESS_COUNTERS = ("moe_assignments_total", "moe_assignments_held",
+                     "moe_held_expert_max", "moe_experts_touched")
+# ... and the capacity path's three
+CAPACITY_COUNTERS = ("moe_routed", "moe_dropped", "moe_aux")
+
+
+class StepLayout:
+    """One side of the step's host interface as ONE ``int32[size]``
+    buffer: ``rows`` of ``(name, shape, dtype)`` laid end to end at
+    static offsets, 4 bytes an element — ``int32`` as it is, ``float32``
+    and ``uint32`` by bit pattern, ``bool`` widened to 0 / 1.  The host
+    (``views``) and the traced program (``unpack`` / ``pack``) read the
+    same table, so the two cannot drift."""
+
+    def __init__(self, rows):
+        self.rows = []
+        off = 0
+        for name, shape, dtype in rows:
+            n = int(np.prod(shape, dtype=np.int64))
+            self.rows.append((name, tuple(shape), np.dtype(dtype), off, n))
+            off += n
+        self.size = off
+
+    def views(self, buf):
+        """Host side: ``{name: view}`` into ``buf`` (numpy
+        ``int32[size]``), each in its field's shape; a ``float32`` or
+        ``uint32`` field is a view of that type over the same words, a
+        ``bool`` field the ``int32`` words themselves (0 / 1)."""
+        out = {}
+        for name, shape, dtype, off, n in self.rows:
+            v = buf[off:off + n].reshape(shape)
+            out[name] = v if dtype == np.bool_ else v.view(dtype)
+        return out
+
+    def unpack(self, packed):
+        """Traced side of an input: ``{name: array}`` in each field's
+        own shape and dtype, by static slices of ``packed``."""
+        out = {}
+        for name, shape, dtype, off, n in self.rows:
+            x = packed[off:off + n].reshape(shape)
+            if dtype == np.bool_:
+                x = x != 0
+            elif dtype != np.int32:
+                x = jax.lax.bitcast_convert_type(x, dtype)
+            out[name] = x
+        return out
+
+    def pack(self, fields):
+        """Traced side of an output: the fields of ``{name: array}``
+        (each of its row's shape and dtype) as one ``int32[size]``."""
+        parts = []
+        for name, shape, dtype, _, _ in self.rows:
+            x = fields[name]
+            if x.shape != shape or x.dtype != dtype:
+                raise ValueError(
+                    f"step field {name!r} is {x.dtype}{list(x.shape)}, "
+                    f"its layout row says {dtype}{list(shape)}")
+            if dtype == np.bool_:
+                x = x.astype(jnp.int32)
+            elif dtype != np.int32:
+                x = jax.lax.bitcast_convert_type(x, jnp.int32)
+            parts.append(x.reshape(-1))
+        return jnp.concatenate(parts)
+
+
+def step_input_layout(max_batch, token_budget, max_pages, spec_window=1):
+    """THE layout of the mixed step's packed input: every per-step host
+    field but the grammar mask, a function of these four deployment
+    constants and nothing else."""
+    b = (int(max_batch),)
+    rows = [("ids", (int(token_budget),), "int32"), ("qlens", b, "int32"),
+            ("ctx", b, "int32"), ("steps0", b, "int32"),
+            ("sample_now", b, "bool"), ("adapter_slots", b, "int32")]
+    if int(spec_window) > 1:
+        rows.append(("spec", b, "bool"))
+    rows.append(("tables", b + (int(max_pages),), "int32"))
+    rows.extend((name, b, dtype) for name, dtype in SAMP_FIELDS)
+    rows += [("keys", b + (2,), "uint32"), ("scratch", (), "int32")]
+    return StepLayout(rows)
+
+
+def step_output_layout(max_batch, spec_window=1, moe=None):
+    """THE layout of the mixed step's packed output: the sampled tokens,
+    the finished flags, the emit counts of a speculating step, then the
+    expert counters — ``moe`` is None (no expert layer counted),
+    ``"dropless"`` (the four ``DROPLESS_COUNTERS``) or the capacity
+    path's expert count ``E`` (``moe_routed[E]``, ``moe_dropped``,
+    ``moe_aux`` float32 by bit pattern)."""
+    b, W = int(max_batch), int(spec_window)
+    rows = [("tok", (b,) if W <= 1 else (b, W), "int32"),
+            ("fin", (b,), "bool")]
+    if W > 1:
+        rows.append(("n_emit", (b,), "int32"))
+    if moe == "dropless":
+        rows.extend((name, (), "int32") for name in DROPLESS_COUNTERS)
+    elif moe is not None:
+        rows += [("moe_routed", (int(moe),), "int32"),
+                 ("moe_dropped", (), "int32"), ("moe_aux", (), "float32")]
+    return StepLayout(rows)
 
 
 def _process_rows(logits, samp, steps):
@@ -130,10 +237,19 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     warmup compile every mix of cold chunks, warm-prefix suffixes and
     decode rows reuses it.
 
-    ``run(params, ids[T], qlens[b], ctx[b], steps0[b],
-    sample_now[b], adapter_slots[b], tables[b, max_pages], samp,
-    keys[b, 2], scratch[], k_pages, v_pages)`` →
-    ``(tok[b], fin[b], k_pages, v_pages)``; pools are donated.
+    ``run(params, packed, k_pages, v_pages)`` → ``(out, k_pages,
+    v_pages)``; pools are donated.  The step's host interface is ONE
+    ``int32`` buffer each way.  ``packed`` holds, by
+    ``step_input_layout(max_batch, token_budget, max_pages, W)``:
+    ``ids[T], qlens[b], ctx[b], steps0[b], sample_now[b],
+    adapter_slots[b], tables[b, max_pages]``, the seven ``samp`` fields
+    ``[b]`` each, ``keys[b, 2], scratch[]`` — ``float32`` and ``uint32``
+    fields by bit pattern, bools as 0 / 1 — unpacked here by static
+    slices; below the unpack the program is what separate arguments
+    would give.  ``out`` holds, by ``step_output_layout``: ``tok[b],
+    fin[b]`` and the expert counters of ``moe_stats``.  The engine puts
+    the buffer with one ``device_put`` and reads ``out`` back with one
+    ``np.asarray`` (StepLog ``h2d_arrays``, ``d2h_arrays``).
 
     ``adapter_slots`` is the per-row LoRA binding (slot 0 = identity):
     pure gather DATA over the stacked pools
@@ -154,9 +270,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     which rows shared the launch.
 
     ``spec_window = W > 1`` builds the speculative draft/verify variant
-    instead (EngineCore ``speculate=True``; the non-speculative
-    executable above is returned VERBATIM for ``W == 1`` so existing
-    cores are untouched).  A speculating decode row packs
+    instead (EngineCore ``speculate=True``).  A speculating decode row packs
     ``[last_tok, d_1..d_k]`` (``k <= W - 1`` drafts, ``qlens = k + 1``)
     and its ``spec`` flag routes the first W query positions through
     per-position decode-kernel attention (the 7-element cache /
@@ -173,12 +287,11 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     draw from the disjoint ``fold_in(fold_in(base, step), 1|2)``
     streams), so a non-spec row reproduces the plain step bit-for-bit.
 
-    Spec signature: ``run(params, ids[T], qlens, ctx, steps0,
-    sample_now, adapter_slots, spec[b] bool, tables, samp, keys,
-    scratch, k_pages, v_pages)`` →
-    ``(out[b, W], n_emit[b], fin[b], k_pages, v_pages)``
-    — row ``i`` emits ``out[i, :n_emit[i]]`` (truncated at its first
-    eos; 0 when ``sample_now`` is off).  Rejected-tail KV needs NO pool
+    Spec signature: the same ``run(params, packed, k_pages,
+    v_pages)``; ``packed`` gains ``spec[b]`` behind ``adapter_slots``,
+    ``out`` is ``tok[b, W], fin[b], n_emit[b]`` then the counters — row
+    ``i`` emits ``tok[i, :n_emit[i]]`` (truncated at its first eos; 0
+    when ``sample_now`` is off).  Rejected-tail KV needs NO pool
     ops: stale entries at positions ``>= ctx + n_emit`` sit inside the
     row's reservation, are never attended (every read masks by the
     row's true length) and are overwritten before they become
@@ -187,19 +300,23 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     ``moe_stats = True`` (EngineCore sets it when the model's FFNs were
     converted by ``serving.moe.prepare_moe_serving``) threads the
     step's valid-slot mask through the MoE stats side-channel
-    (serving/moe/stats.py) and returns three extra outputs BEFORE the
-    pools — ``(…, moe_routed[E] i32, moe_dropped i32, moe_aux f32, …)``
-    — so capacity-overflow drops are surfaced per step, never silent.
-    A model of dropless expert layers (serving/moe/dropless.py) returns
-    its four counters there instead (``MoEStatsCollector.totals``).
-    The stats ride the same trace (data outputs, no shape impact), so
-    the one-executable invariant is untouched.
+    (serving/moe/stats.py) and appends three fields to ``out`` —
+    ``moe_routed[E] i32, moe_dropped i32, moe_aux f32`` (by bit
+    pattern) — so capacity-overflow drops are surfaced per step, never
+    silent.  A model of dropless expert layers
+    (serving/moe/dropless.py) appends its four counters there instead
+    (``DROPLESS_COUNTERS``, ``MoEStatsCollector.totals``).  The stats
+    ride the same trace and the same read-back (data, no shape
+    impact), so the one-executable invariant is untouched.
 
     ``grammar = True`` (EngineCore sets it when constructed with a
-    ``grammar_vocab``) threads ONE extra input between ``keys`` and
-    ``scratch``: an additive logit mask — ``gmask[b, V]`` here,
-    ``gmask[b, W, V]`` for the speculative variant, always f32 with 0
-    for allowed and ``sampling.NEG_INF`` for banned entries.  The mask
+    ``grammar_vocab``) threads ONE extra input behind ``packed`` —
+    ``run(params, packed, gmask, k_pages, v_pages)`` — an additive
+    logit mask: ``gmask[b, V]`` here, ``gmask[b, W, V]`` for the
+    speculative variant, always f32 with 0 for allowed and
+    ``sampling.NEG_INF`` for banned entries.  It stays an array of its
+    own (2 MB at a 32,000-token vocabulary: copying it into the buffer
+    on the host would cost more than its put).  The mask
     is pure per-row DATA gathered host-side from each row's FSM state
     (serving/structured/), applied to the last-position logits BEFORE
     the processor chain, so constrained greedy stays masked-argmax
@@ -211,8 +328,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     what makes constrained spec-vs-plain bitwise identical and keeps
     lanes from ever emitting a violating token.  Unconstrained rows
     carry all-zero mask rows.  Deployments without a grammar vocab get
-    the ``grammar=False`` signatures below VERBATIM — same arity, same
-    donation indices, same executable key."""
+    the ``grammar=False`` signature: no mask argument at all."""
     T = token_budget
 
     def _model_step(params, ids, qlens, ctx, adapter_slots, caches,
@@ -247,55 +363,67 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         # LoRA slots follow the axis: one per token, its row's
         with lora_slots_mod.activate(adapter_slots[row]):
             if not moe_stats:
-                return (*model(), ())
+                return (*model(), (None, {}))
             from .moe import stats as moe_stats_mod
 
             with moe_stats_mod.collect(valid, max_valid=T) as col:
                 logits, caches = model()
-            return logits, caches, col.totals()
+            totals = col.totals()
+            if col.dropless:
+                return logits, caches, ("dropless",
+                                        dict(zip(DROPLESS_COUNTERS, totals)))
+            return logits, caches, (totals[0].shape[0],
+                                    dict(zip(CAPACITY_COUNTERS, totals)))
 
-    def run(params, ids, qlens, ctx, steps0, sample_now, adapter_slots,
-            tables, samp, keys, gmask, scratch, k_pages, v_pages):
-        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
-                               qlens, scratch)
-        last, caches, moe_out = _model_step(
-            params, ids, qlens, ctx, adapter_slots, caches,
+    W = int(spec_window)
+    in_layout = step_input_layout(max_batch, T, max_pages, W)
+
+    def _packed_out(fields, counted):
+        """The step's one host-bound output: the sampled fields and
+        whatever the expert layers counted (``_model_step``'s ``(moe,
+        counters)``), by ``step_output_layout``."""
+        moe, counters = counted
+        return step_output_layout(max_batch, W, moe).pack(
+            {**fields, **counters})
+
+    def run(params, packed, *mask_and_pools):
+        # positional tail: the mask of a grammar deployment, then the pools
+        *gmask, k_pages, v_pages = mask_and_pools
+        f = in_layout.unpack(packed)
+        qlens, ctx, steps0, sample_now = (
+            f["qlens"], f["ctx"], f["steps0"], f["sample_now"])
+        samp = {name: f[name] for name, _ in SAMP_FIELDS}
+        caches = _layer_caches(engine, k_pages, v_pages, f["tables"], ctx,
+                               qlens, f["scratch"])
+        last, caches, counted = _model_step(
+            params, f["ids"], qlens, ctx, f["adapter_slots"], caches,
             jnp.maximum(qlens - 1, 0))
         # one scope with the head (models/llama.py): the sampling tail
         with jax.named_scope("lm_head_sample"):
             if grammar:
-                last = last + gmask
+                last = last + gmask[0]
             proc = _process_rows(last, samp, steps0)
-            tok = _pick_rows(proc, samp, steps0, keys)
+            tok = _pick_rows(proc, samp, steps0, f["keys"])
             tok = jnp.where(sample_now, tok, samp["pad"])
             fin = jnp.logical_and(
                 sample_now,
                 jnp.logical_and(samp["eos"] >= 0, tok == samp["eos"]))
-        return (tok, fin, *moe_out, *_layer_pools(engine, caches))
+        return (_packed_out(dict(tok=tok, fin=fin), counted),
+                *_layer_pools(engine, caches))
 
-    W = int(spec_window)
-    if W <= 1:
-        if grammar:
-            return jax.jit(run, donate_argnums=(12, 13))
+    def run_spec(params, packed, *mask_and_pools):
+        from ..inference import spec_accept
 
-        def run_plain(params, ids, qlens, ctx, steps0, sample_now,
-                      adapter_slots, tables, samp, keys, scratch,
-                      k_pages, v_pages):
-            return run(params, ids, qlens, ctx, steps0, sample_now,
-                       adapter_slots, tables, samp, keys, None,
-                       scratch, k_pages, v_pages)
-
-        return jax.jit(run_plain, donate_argnums=(11, 12))
-
-    from ..inference import spec_accept
-
-    def run_spec(params, ids, qlens, ctx, steps0, sample_now,
-                 adapter_slots, spec, tables, samp, keys, gmask,
-                 scratch, k_pages, v_pages):
+        *gmask, k_pages, v_pages = mask_and_pools
+        f = in_layout.unpack(packed)
+        ids, qlens, ctx, steps0, sample_now, spec, keys = (
+            f["ids"], f["qlens"], f["ctx"], f["steps0"], f["sample_now"],
+            f["spec"], f["keys"])
+        samp = {name: f[name] for name, _ in SAMP_FIELDS}
         b = qlens.shape[0]
         spec2d = jnp.broadcast_to(spec[:, None], (b, W))
-        caches = _layer_caches(engine, k_pages, v_pages, tables, ctx,
-                               qlens, scratch, spec2d)
+        caches = _layer_caches(engine, k_pages, v_pages, f["tables"], ctx,
+                               qlens, f["scratch"], spec2d)
         # per-window-position logits: spec rows read positions 0..W-1
         # (clamped to their qlen), plain rows replicate qlens-1 so
         # their column 0 is exactly the non-spec gather
@@ -303,10 +431,10 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         j = jnp.arange(W, dtype=jnp.int32)[None]               # [1, W]
         gidx = jnp.where(spec[:, None], jnp.minimum(j, base[:, None]),
                          base[:, None])                        # [b, W]
-        lg_w, caches, moe_out = _model_step(
-            params, ids, qlens, ctx, adapter_slots, caches, gidx)
+        lg_w, caches, counted = _model_step(
+            params, ids, qlens, ctx, f["adapter_slots"], caches, gidx)
         if grammar:
-            lg_w = lg_w + gmask
+            lg_w = lg_w + gmask[0]
         steps_w = steps0[:, None] + jnp.where(spec[:, None], j, 0)
         proc_w = jax.vmap(_process_rows, in_axes=(1, None, 1),
                           out_axes=1)(lg_w, samp, steps_w)     # [b, W, V]
@@ -382,19 +510,14 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             out, pad).astype(jnp.int32)
         n_emit = jnp.where(sample_now, r, 0).astype(jnp.int32)
         fin = jnp.logical_and(sample_now, any_eos)
-        return (out, n_emit, fin, *moe_out, *_layer_pools(engine, caches))
+        return (_packed_out(dict(tok=out, fin=fin, n_emit=n_emit), counted),
+                *_layer_pools(engine, caches))
 
-    if grammar:
-        return jax.jit(run_spec, donate_argnums=(13, 14))
-
-    def run_spec_plain(params, ids, qlens, ctx, steps0, sample_now,
-                       adapter_slots, spec, tables, samp, keys,
-                       scratch, k_pages, v_pages):
-        return run_spec(params, ids, qlens, ctx, steps0, sample_now,
-                        adapter_slots, spec, tables, samp, keys, None,
-                        scratch, k_pages, v_pages)
-
-    return jax.jit(run_spec_plain, donate_argnums=(12, 13))
+    # the pools sit behind the mask slot, which only a grammar
+    # deployment's signature has
+    pools = 2 + int(bool(grammar))
+    return jax.jit(run_spec if W > 1 else run,
+                   donate_argnums=(pools, pools + 1))
 
 
 def build_page_copy(engine):

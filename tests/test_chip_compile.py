@@ -217,21 +217,32 @@ def not_interpreted():
 
 def _step_args(spec, params, b, tokens, max_pages, k_pools, v_pools):
     """The mixed step's arguments as ``EngineCore`` hands them over: the
-    flat ``ids[tokens]``, then the per-row arrays."""
-    i32, f32 = jnp.int32, jnp.float32
-    rows = lambda dtype: spec((b,), dtype)
-    samp = {"temperature": rows(f32), "top_k": rows(i32),
-            "top_p": rows(f32), "min_len": rows(i32), "eos": rows(i32),
-            "do_sample": rows(jnp.bool_), "pad": rows(i32)}
-    return (params, spec((tokens,), i32), rows(i32), rows(i32), rows(i32),
-            rows(jnp.bool_), rows(i32), spec((b, max_pages), i32), samp,
-            spec((b, 2), jnp.uint32), spec((), i32), k_pools, v_pools)
+    parameters, the ONE packed ``int32`` buffer of ``step_input_layout``,
+    the pools."""
+    from paddle_infer_tpu.serving.programs import step_input_layout
+
+    size = step_input_layout(b, tokens, max_pages).size
+    return (params, spec((size,), jnp.int32), k_pools, v_pools)
 
 
-@pytest.fixture(scope="module")
-def chat_step_text(one_chip, not_interpreted):
-    """One layer of the served mixed step at the chat cell's widths, as
-    ``EngineCore`` builds it (pools donated), compiled for the chip."""
+def _entry_io(compiled):
+    """``(inputs, outputs)`` of the compiled program's entry computation,
+    counted in its text: ``parameter(i)`` instructions, and the elements
+    of the root tuple."""
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    body = entry[:entry.index("\n}")]
+    n_in = len(set(re.findall(r" parameter\((\d+)\)", body)))
+    root = next(line for line in body.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    n_out = (len(re.findall(r"%[\w.-]+(?=[,)])", root.split(" tuple(", 1)[1]))
+             if " tuple(" in root else 1)
+    return n_in, n_out
+
+
+def _llama_step(one_chip, grammar=False, **widths):
+    """One layer of the served mixed step of a LLaMA-block model, as
+    ``EngineCore`` builds it (pools donated), compiled for the chip; with
+    it, how many parameters the step was handed (it has two pools)."""
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_infer_tpu.nn.initializer import abstract_parameters
@@ -239,22 +250,54 @@ def chat_step_text(one_chip, not_interpreted):
 
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=one_chip)
-    cfg = LlamaConfig(vocab_size=32000, hidden_size=4096,
-                      num_hidden_layers=1, num_attention_heads=H,
-                      num_key_value_heads=8, intermediate_size=14336,
+    cfg = LlamaConfig(vocab_size=32000, num_hidden_layers=1,
                       max_position_embeddings=32768, rms_norm_eps=1e-5,
-                      rope_theta=10000.0)
+                      rope_theta=10000.0, **widths)
     with abstract_parameters():
         model = LlamaForCausalLM(cfg)
     engine = PagedGenerationEngine(model, page_size=PAGE,
                                    cache_dtype=jnp.bfloat16)
-    run = build_mixed_step(engine, CELL_B, CELL_CHUNK, MAX_PAGES)
+    run = build_mixed_step(engine, CELL_B, CELL_CHUNK, MAX_PAGES,
+                           grammar=grammar)
     params = {n: spec(a.shape, jnp.bfloat16)
               for n, a in engine._params.items()}
-    pools = [spec(CELL_POOL, jnp.bfloat16)]
-    return run.lower(*_step_args(spec, params, CELL_B, CELL_CHUNK,
-                                 MAX_PAGES, pools, pools)
-                     ).compile().as_text()
+    pools = [spec((CELL_POOL[0], cfg.num_attention_heads) + CELL_POOL[2:],
+                  jnp.bfloat16)]
+    _, packed, k_pools, v_pools = _step_args(
+        spec, params, CELL_B, CELL_CHUNK, MAX_PAGES, pools, pools)
+    # a grammar deployment's mask rides behind the packed buffer
+    mask = (spec((CELL_B, 32000), jnp.float32),) if grammar else ()
+    return run.lower(params, packed, *mask, k_pools,
+                     v_pools).compile(), len(params)
+
+
+@pytest.fixture(scope="module")
+def chat_step(one_chip, not_interpreted):
+    """The step at the chat cell's widths."""
+    return _llama_step(one_chip, hidden_size=4096, num_attention_heads=H,
+                       num_key_value_heads=8, intermediate_size=14336)
+
+
+@pytest.fixture(scope="module")
+def chat_step_text(chat_step):
+    return chat_step[0].as_text()
+
+
+def test_chat_step_takes_one_host_array_and_returns_one(chat_step):
+    """Beside its parameters and its two pools the compiled step has ONE
+    input, the packed buffer, and beside the pools ONE output."""
+    compiled, n_params = chat_step
+    assert _entry_io(compiled) == (n_params + 1 + 2, 1 + 2)
+
+
+def test_grammar_step_takes_the_mask_as_its_second_host_array(
+        one_chip, not_interpreted):
+    """A grammar deployment's step has TWO such inputs: the packed buffer
+    and the ``[max_batch, vocab]`` mask, which is not copied into it."""
+    compiled, n_params = _llama_step(
+        one_chip, grammar=True, hidden_size=1024, num_attention_heads=8,
+        num_key_value_heads=8, intermediate_size=2048)
+    assert _entry_io(compiled) == (n_params + 2 + 2, 1 + 2)
 
 
 def test_mixed_step_layer_has_no_pool_or_window_sized_copy(chat_step_text):
@@ -415,6 +458,14 @@ def test_latent_mixed_step_keeps_its_pool_in_place(latent_step):
     # the widest temporary is one row's [heads, chunk, window] scores, not
     # every row's window of expanded keys and values (2 GB a layer)
     assert latent_step.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_latent_step_takes_one_host_array_and_returns_one(latent_step):
+    """The dropless layers' four counters ride out in the one packed
+    output: beside parameters and pools (one a latent layer), one input
+    and one output."""
+    n_params = len(latent_step.args_info[0][0])
+    assert _entry_io(latent_step) == (n_params + 1 + 2, 1 + 2)
 
 
 def test_latent_step_does_not_copy_the_query_up_projection(latent_step):

@@ -142,8 +142,10 @@ def _watch_steps(core, engine, monkeypatch):
 
     def spy(key, builder, *args):
         if key[0] == "serve-step":
-            ids, qlens, ctx = (np.array(a) for a in args[:3])
-            spec = (np.array(args[6]) if core._spec_window > 1
+            # one packed buffer, reused by the packer: copy, then view
+            f = core._step_in.views(np.array(args[0]))
+            ids, qlens, ctx = f["ids"], f["qlens"], f["ctx"]
+            spec = (f["spec"] != 0 if core._spec_window > 1
                     else np.zeros_like(qlens, bool))
             seen.append(dict(
                 ids=ids, qlens=qlens, ctx=ctx, spec=spec,

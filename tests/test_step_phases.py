@@ -161,7 +161,8 @@ def test_counts_equal_a_brute_force_count(make_core):
 
     def spy(key, builder, *args):
         if key[0] == "serve-step":
-            seen.append(args)
+            # the packer reuses its one buffer: keep this step's copy
+            seen.append(tuple(np.array(a) for a in args))
         return real(key, builder, *args)
 
     eng.run_paged_program = spy
@@ -180,7 +181,9 @@ def test_counts_equal_a_brute_force_count(make_core):
     assert len(recs) == len(seen)
     mixes = set()
     for r, args in zip(recs, seen):
-        ids, qlens, ctx = (np.asarray(a) for a in args[:3])
+        (packed,) = args                  # one host array a step
+        fields = core._step_in.views(packed)
+        qlens, ctx = fields["qlens"], fields["ctx"]
         keys = resident = 0
         for q, c in zip(qlens.tolist(), ctx.tolist()):
             for i in range(q):
@@ -188,10 +191,8 @@ def test_counts_equal_a_brute_force_count(make_core):
             resident += (c + q) if q else 0
         assert r["attended_keys"] == keys
         assert r["resident_tokens"] == resident
-        leaves = []
-        for a in args:
-            leaves.extend(a.values() if isinstance(a, dict) else [a])
-        assert r["h2d_bytes"] == sum(np.asarray(a).nbytes for a in leaves)
+        assert r["h2d_bytes"] == packed.nbytes == 4 * core._step_in.size
+        assert (r["h2d_arrays"], r["d2h_arrays"]) == (1, 1)
         rows = [(q, c) for q, c in zip(qlens.tolist(), ctx.tolist()) if q]
         mixes.add((any(q == 1 for q, _ in rows),
                    any(q > 1 and c == 0 for q, c in rows),
