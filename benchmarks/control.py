@@ -35,7 +35,7 @@ def serving_readings(ctx, precision):
     cases = check_served.sample(ev.records, ctx.seed,
                                 int(spec["sample_requests"]),
                                 int(spec["max_tokens_per_request"]))
-    q = lambda g, p: float(sorted(g)[int(p * (len(g) - 1))])
+    q = check_served.gap_quantile
 
     def summary(g):
         return {"widest": float(g.max()), "p99": q(g, 0.99),

@@ -24,13 +24,13 @@ FIELDS = ("attended_keys", "resident_tokens", "decode_keys",
 
 
 def _kernel_seconds(tr, kernel):
-    return sum(v for k, v in (tr.get("op_seconds") or {}).items()
+    return sum(v for k, v in tr["op_seconds"].items()
                if kernel in k)
 
 
 def read(ev, what, kernel=None):
     tr = ev.trace
-    if not tr or not tr.get("busy_s"):
+    if not tr or not tr["busy_s"]:
         return None
     steps = [s for s in serving_steps(ev) if tr["t0"] <= s["t"] < tr["t1"]]
     if not steps or any(f not in s for s in steps for f in FIELDS):

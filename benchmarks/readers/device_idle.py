@@ -3,6 +3,6 @@ over the chips used."""
 
 
 def read(ev):
-    if not ev.trace or ev.trace.get("idle_share") is None:
+    if not ev.trace or ev.trace["idle_share"] is None:
         return None
     return 100.0 * ev.trace["idle_share"]

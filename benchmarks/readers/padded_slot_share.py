@@ -1,12 +1,11 @@
-"""Share of the step program's [max_batch, token_budget] token slots that
-carried no real token: 1 - (decode rows + prompt-chunk tokens) / slots."""
-from .steplog_stat import serving_steps
+"""Share of the step program's flat token axis that carried no real
+token: 100 less ``token_slot_fill`` (the StepLog's own ``token_slots``,
+not ``max_batch x token_budget``, which the program has not run since the
+token-wise layers went onto one flat axis).  Kept for ``.chat`` alone:
+``tests/test_latent_moe.py`` pins that entry's place in BENCHMARK.json."""
+from . import token_slot_fill
 
 
 def read(ev):
-    steps = serving_steps(ev)
-    if not steps or not ev.max_batch or not ev.token_budget:
-        return None
-    real = sum(s["decode_rows"] + s["prefill_chunk_tokens"] for s in steps)
-    return 100.0 * (1.0 - real / (len(steps) * ev.max_batch
-                                  * ev.token_budget))
+    fill = token_slot_fill.read(ev)
+    return None if fill is None else 100.0 - fill

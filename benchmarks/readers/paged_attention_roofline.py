@@ -17,9 +17,9 @@ FIELDS = ("attended_keys", "resident_tokens")
 
 def read(ev, kernel):
     tr = ev.trace
-    if not tr or not tr.get("busy_s"):
+    if not tr or not tr["busy_s"]:
         return None
-    seconds = sum(v for k, v in (tr.get("op_seconds") or {}).items()
+    seconds = sum(v for k, v in tr["op_seconds"].items()
                   if kernel in k)
     steps = [s for s in serving_steps(ev) if tr["t0"] <= s["t"] < tr["t1"]]
     if not seconds or not steps or any(f not in s for s in steps

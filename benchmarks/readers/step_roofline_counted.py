@@ -23,7 +23,7 @@ def step_costs(ev, steps):
 
 def read(ev, what="share"):
     tr = ev.trace
-    if not tr or not tr.get("busy_s"):
+    if not tr or not tr["busy_s"]:
         return None
     steps = [s for s in serving_steps(ev) if tr["t0"] <= s["t"] < tr["t1"]]
     if not steps or any(f not in s for s in steps for f in FIELDS):

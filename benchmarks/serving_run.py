@@ -28,8 +28,10 @@ def run(ctx, system_mod=None) -> dict:
         state["clock_offset"] = time.time() - time.monotonic()
         ctx.say(f"window open after {w0 - ctx.process_start:.1f}s of set-up")
         if ctx.trace:
-            span = min(float(traffic.get("trace_s", 6.0)), w1 - w0)
-            state["trace"] = xplane.TraceWindow(ctx.trace_dir, span)
+            # the window's LAST trace_s seconds: the profiler's stop then
+            # falls after the close and slows no step a metric reads
+            state["trace"] = xplane.TraceWindow(
+                ctx.trace_dir, float(traffic.get("trace_s", 6.0)), w0, w1)
             state["trace"].start()
 
     try:

@@ -27,9 +27,9 @@ def run(ctx, system_mod=None) -> dict:
         w1 = w0 + float(ctx.seconds)
         trace = None
         if ctx.trace:
+            # the window's last trace_s seconds, as a serving run's
             trace = xplane.TraceWindow(
-                ctx.trace_dir, min(float(traffic.get("trace_s", 6.0)),
-                                   w1 - w0))
+                ctx.trace_dir, float(traffic.get("trace_s", 6.0)), w0, w1)
             trace.start()
         pending, done = [], []
         i = 3

@@ -101,9 +101,13 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
     The cost is linear in events and spans but for the sorts: one pass
     over the events, and for each label one merge-walk over the gaps that
     are left.  A faster program puts more steps, so more events, into the
-    same traced seconds; nothing here may grow faster than they do."""
-    if not device_ops:
-        return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
+    same traced seconds; nothing here may grow faster than they do.
+
+    A trace in which nothing ran on a device gives the same keys
+    (``_nothing_ran``): a run's line is printed whatever its trace
+    holds."""
+    if not any(device_ops.values()):
+        return _nothing_ran(len(device_ops), host_spans, window_s)
     busy, ops, coll = [], {}, 0.0
     gaps_by: Dict[str, float] = {}
     by_label: Dict[str, List[Interval]] = {
@@ -170,6 +174,33 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
                       sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
 
 
+def _nothing_ran(devices: int, host_spans: List[tuple],
+                 window_s: float) -> dict:
+    """The reduction of a trace in which no operation ran on any device
+    (the engine had no request alive while it was traced): every key a
+    trace with operations has.  The whole window is idle; its seconds go
+    to the host span they fall in, innermost first as ever, and what no
+    span covers to ``spans.OUTSIDE``.  With no operation to take the
+    window's ends from, its length stays the host's."""
+    gaps_by: Dict[str, float] = {}
+    covered: List[Interval] = []
+    for label in spans.GAP_SPANS:
+        inside = _subtract(union((s, s + d) for n, s, d in host_spans
+                                 if n == label), covered)
+        if inside:
+            gaps_by[label] = total(inside)
+            covered = union(covered + inside)
+    outside = window_s - total(covered)
+    if outside > 0:
+        gaps_by[spans.OUTSIDE] = outside
+    return {
+        "window_s": window_s, "busy_s": 0.0, "devices": devices,
+        "idle_share": 1.0, "collective_s": 0.0, "op_seconds": {},
+        "device_ops": [],
+        "idle_gaps": [[k, v] for k, v in
+                      sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
+
+
 def _subtract(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:
     """``xs`` less ``ys``, both sorted and disjoint: one walk, an index
     into ``ys`` that only moves forward."""
@@ -219,33 +250,58 @@ def load(trace_dir: str, device_prefix: str = "/device:TPU",
 
 
 class TraceWindow:
-    """Trace ``span_s`` seconds from ``start()``; ``finish()`` waits for
-    the stop and returns the reduction, with what it cost under
-    ``cost``: the device events and host spans it loaded, and the seconds
-    loading and reducing them took."""
+    """Trace the last ``span_s`` seconds of the measured window [w0, w1)
+    (all of a shorter window): ``start()``, called at the window's
+    opening, sets the profiler to begin at ``w1 - span_s`` and to stop at
+    ``w1``.  The profiler's stop takes seconds to tens of seconds and
+    slows the steps it overlaps, so it falls after the close, where no
+    metric reads.  ``finish()`` waits for the stop and returns the
+    reduction, with what it cost under ``cost``: the device events and
+    host spans it loaded, and the seconds the profiler's stop, the
+    loading and the reducing took."""
 
-    def __init__(self, trace_dir: str, span_s: float):
-        self.dir, self.span_s = trace_dir, float(span_s)
-        self._timer = None
-        self._t0 = self._t1 = None
+    STOP_WAIT_S = 300.0
+
+    def __init__(self, trace_dir: str, span_s: float, w0: float, w1: float):
+        self.dir = trace_dir
+        self.span_s = min(float(span_s), w1 - w0)
+        self.begin_at = w1 - self.span_s
+        self._t0 = self._t1 = self._stop_s = None
         self._err = None
+        self._stopped = threading.Event()
 
     def start(self):
+        self._after(self.begin_at - time.monotonic(), self._begin)
+
+    @staticmethod
+    def _after(seconds, fn):
+        if seconds <= 0:
+            return fn()
+        t = threading.Timer(seconds, fn)
+        t.daemon = True
+        t.start()
+
+    def _begin(self):
         import jax
 
-        shutil.rmtree(self.dir, ignore_errors=True)
-        kw = {}
         try:
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0       # our spans only
-            kw["profiler_options"] = opts
-        except AttributeError:
-            pass
-        jax.profiler.start_trace(self.dir, **kw)
+            shutil.rmtree(self.dir, ignore_errors=True)
+            kw = {}
+            try:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0       # our spans only
+                kw["profiler_options"] = opts
+            except AttributeError:
+                pass
+            jax.profiler.start_trace(self.dir, **kw)
+        except Exception as e:          # reported by finish()
+            self._err = e
+            self._stopped.set()
+            return
         self._t0 = time.monotonic()
-        self._timer = threading.Timer(self.span_s, self._stop)
-        self._timer.daemon = True
-        self._timer.start()
+        # the stop is due span_s after the trace was due to begin: a
+        # start that took its time shortens the trace and moves no end
+        self._after(self.begin_at + self.span_s - self._t0, self._stop)
 
     def _stop(self):
         import jax
@@ -255,20 +311,22 @@ class TraceWindow:
             jax.profiler.stop_trace()
         except Exception as e:          # reported by finish()
             self._err = e
+        self._stop_s = time.monotonic() - self._t1
+        self._stopped.set()
 
     def finish(self) -> dict:
-        self._timer.join(120.0)
+        if not self._stopped.wait(self.STOP_WAIT_S):
+            raise RuntimeError("the trace was never stopped")
         if self._err is not None:
             raise self._err
-        if self._t1 is None:
-            raise RuntimeError("the trace was never stopped")
         began = time.monotonic()
         device_ops, host_spans = load(self.dir)
         loaded = time.monotonic()
         out = reduce_events(device_ops, host_spans, self._t1 - self._t0)
         out["cost"] = {
             "device_events": sum(len(v) for v in device_ops.values()),
-            "host_spans": len(host_spans), "load_s": loaded - began,
+            "host_spans": len(host_spans), "stop_s": self._stop_s,
+            "load_s": loaded - began,
             "reduce_s": time.monotonic() - loaded}
         out["t0"], out["t1"] = self._t0, self._t1      # monotonic clock
         shutil.rmtree(self.dir, ignore_errors=True)
@@ -279,6 +337,7 @@ def cost_line(trace: dict) -> str:
     """What a runner says once ``TraceWindow.finish`` has returned: a
     traced run that is slow to close shows here where the time went."""
     c = trace["cost"]
-    return ("trace: %d device events, %d host spans, loaded in %.2f s, "
-            "reduced in %.2f s" % (c["device_events"], c["host_spans"],
-                                   c["load_s"], c["reduce_s"]))
+    return ("trace: %d device events, %d host spans, stopped in %.2f s, "
+            "loaded in %.2f s, reduced in %.2f s" % (
+                c["device_events"], c["host_spans"], c["stop_s"],
+                c["load_s"], c["reduce_s"]))

@@ -47,6 +47,77 @@ def test_served_control_fails_where_the_program_passes(seed):
         longest, spec["max_tokens_per_request"])
 
 
+# ------------------------------------ a second number beside the widest
+
+def _gaps(widest, p99_level, n=600):
+    """n gaps: nought but for the top hundredth at ``p99_level`` and one
+    at ``widest``."""
+    g = np.zeros(n)
+    g[:n // 100 + 1] = p99_level
+    g[0] = widest
+    return g
+
+
+@pytest.mark.parametrize("g,spec,want,names", [
+    # one near-tie flips a token far down: the maximum's business alone
+    (_gaps(1.34, 0.05), {"limit_logit_gap": 2.7,
+                         "limit_logit_gap_p99": 0.55}, True,
+     ["widest_logit_gap", "logit_gap_p99"]),
+    (_gaps(1.34, 0.05), {"limit_logit_gap": 1.15,
+                         "limit_logit_gap_p99": 0.55}, False,
+     ["widest_logit_gap", "logit_gap_p99"]),
+    # a lower precision moves the hundredth and stays under the maximum
+    (_gaps(1.7, 1.2), {"limit_logit_gap": 2.7,
+                       "limit_logit_gap_p99": 0.55}, False,
+     ["widest_logit_gap", "logit_gap_p99"]),
+    # one altered token among six hundred: the widest's to catch
+    (_gaps(6.8, 0.05), {"limit_logit_gap": 2.7,
+                        "limit_logit_gap_p99": 0.55}, False,
+     ["widest_logit_gap", "logit_gap_p99"]),
+    # a configuration that states no second limit compares one number
+    (_gaps(1.7, 1.2), {"limit_logit_gap": 2.7}, True,
+     ["widest_logit_gap"]),
+    (np.zeros((0,)), {"limit_logit_gap": 2.7,
+                      "limit_logit_gap_p99": 0.55}, False,
+     ["widest_logit_gap", "logit_gap_p99"]),
+], ids=["one_near_tie", "one_near_tie_old_limit", "lower_precision",
+        "one_altered_token", "one_limit_stated", "nothing_served"])
+def test_the_served_check_compares_the_limits_its_configuration_states(
+        monkeypatch, g, spec, want, names):
+    monkeypatch.setattr(check_served, "sample", lambda *a: ["case"])
+    monkeypatch.setattr(check_served, "gaps", lambda *a: g)
+    said = []
+    correct, compared = check_served.check(
+        {"check": dict(spec, sample_requests=4, max_tokens_per_request=256)},
+        7, [], said.append)
+    assert correct is want and list(compared) == names
+    for name, (value, limit) in compared.items():
+        assert limit == spec["limit_" + name.replace(
+            "widest_logit_gap", "logit_gap")]
+        if g.size:
+            assert f"{name} {value:.6f} (limit {limit})" in said[0]
+    if g.size:
+        assert compared["widest_logit_gap"][0] == g.max()
+        if "logit_gap_p99" in compared:
+            assert compared["logit_gap_p99"][0] == check_served.gap_quantile(
+                g, 0.99) == np.sort(g)[int(0.99 * (g.size - 1))]
+
+
+def test_the_committed_latent_cell_states_both_limits_and_chat_one(
+        benchmark_json):
+    checks = {}
+    for c in benchmark_json["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            checks[c["name"]] = json.load(f)["check"]
+    latent = checks["a.x-k1-ep16-d7"]
+    # PERF.md section 2: between the sound runs' largest and the
+    # control's smallest, at the cell's own load
+    assert 0.208 < latent["limit_logit_gap_p99"] < 1.15
+    assert 1.3427 < latent["limit_logit_gap"] < 3.44
+    assert "limit_logit_gap_p99" not in checks["mistral-7b-v0.1-d12"]
+    assert checks["mistral-7b-v0.1-d12"]["limit_logit_gap"] == 0.7
+
+
 def test_fp8_roundings():
     x = np.linspace(-1e-6, 1e-6, 257).astype(np.float32)
     # the plain cast flushes what is small to zero, gradients above all

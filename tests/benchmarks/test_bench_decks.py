@@ -13,6 +13,10 @@ from benchmarks.rng import SplitMix
 from conftest import ROOT
 
 CHAT = json.load(open(os.path.join(ROOT, "benchmarks", "traffic", "chat.json")))
+# each open-loop cell: its traffic file, the two distributions and the
+# pairing its deck has had since the cell was added
+CELLS = {"mistral-d12.chat": ("chat", (32, 512), (32, 256), 20260927),
+         "axk1-ep16.ragchat": ("ragchat", (512, 3072), (64, 256), 20260928)}
 UNIFORM = dict(CHAT, prompt_len={"kind": "uniform", "lo": 512, "hi": 1536},
                output_len={"kind": "uniform", "lo": 32, "hi": 128})
 
@@ -103,3 +107,72 @@ def test_token_ids_are_seeded_and_in_range():
     assert a.dtype == np.int32 and a.min() >= 3 and a.max() < 32000
     assert (a == decks.token_ids(2 ** 31 + 9, 3, 100, 32000)).all()
     assert (a != decks.token_ids(2 ** 31 + 9, 4, 100, 32000)).any()
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+def test_a_cell_s_deck_at_its_rate_is_the_same_two_distributions(workload):
+    """A cell's ``rate_rps`` may be swept again; its deck stays the
+    quantile mid-points of the same two log-uniform distributions, paired
+    under the same ``pairing_seed``, only more of them."""
+    name, prompts, outputs, pairing = CELLS[workload]
+    here = os.path.join(ROOT, "benchmarks")
+    traffic = json.load(open(os.path.join(here, "traffic", name + ".json")))
+    cell = json.load(open(os.path.join(here, "cells", workload + ".json")))
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    assert traffic["prompt_len"] == {"kind": "loguniform", "lo": prompts[0],
+                                     "hi": prompts[1]}
+    assert traffic["output_len"] == {"kind": "loguniform", "lo": outputs[0],
+                                     "hi": outputs[1]}
+    assert traffic["pairing_seed"] == pairing
+    assert traffic["drain_s"] == 30.0
+    n = int(cell["rate_rps"] * bench["run_seconds"] + 1e-9)
+    assert n >= 50             # four times the requests of the old rates
+    want_p = decks.quantile_midpoints(traffic["prompt_len"], n)
+    want_o = decks.quantile_midpoints(traffic["output_len"], n)
+    perm = SplitMix(pairing).permutation(n)
+    for seed in (5, 2 ** 31 + 11):
+        win = [r for r in open_deck.plan(traffic, cell, seed,
+                                         float(bench["run_seconds"]), 20480)
+               if r.phase == "window"]
+        assert len(win) == n
+        assert sorted(r.prompt_len for r in win) == want_p
+        assert sorted(r.max_new for r in win) == want_o
+        assert collections.Counter((r.prompt_len, r.max_new) for r in win) \
+            == collections.Counter((want_p[i], want_o[perm[i]])
+                                   for i in range(n))
+    # the geometric mean of the ends is the median of either
+    assert abs(want_p[n // 2] - math.sqrt(prompts[0] * prompts[1])) \
+        < 0.02 * prompts[1]
+    assert abs(want_o[n // 2] - math.sqrt(outputs[0] * outputs[1])) \
+        < 0.02 * outputs[1]
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+def test_a_cell_s_rate_is_a_stated_share_of_a_knee_it_shows_the_sweep_of(
+        workload):
+    cell = json.load(open(os.path.join(ROOT, "benchmarks", "cells",
+                                       workload + ".json")))
+    assert 0.7 <= cell["share_of_knee"] <= 0.85
+    assert cell["rate_rps"] == pytest.approx(
+        cell["share_of_knee"] * cell["knee_rps"], rel=0.02)
+    rows = cell["sweep"]
+    assert len(rows) >= 6 and all(r["seconds"] == 40 for r in rows)
+    rates = sorted({r["rate_rps"] for r in rows})
+    assert cell["knee_rps"] in rates and max(rates) > cell["knee_rps"]
+
+    def sustained(rate):
+        """The rule the cells have stated since PR 23, over every window
+        the sweep made at ``rate``: the mean TTFT of the second halves not
+        above the first halves', and at most one request a window without
+        its first token at the close (the one that fell due in the
+        window's last moments)."""
+        at = [r for r in rows if r["rate_rps"] == rate]
+        first = sum(r["ttft_mean_first_half_ms"] for r in at)
+        second = sum(r["ttft_mean_second_half_ms"] or float("inf")
+                     for r in at)
+        return second <= first and sum(
+            r["no_first_token_at_close"] for r in at) <= len(at)
+
+    # the knee is the highest rate sustained
+    assert sustained(cell["knee_rps"])
+    assert not any(sustained(r) for r in rates if r > cell["knee_rps"])

@@ -55,7 +55,10 @@ def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
     assert "device_idle_share.chat" not in layer
     assert "step_roofline_share_counted.chat" not in layer
     assert layer["compiles_in_window.chat"]["value"] == 0
-    assert 0 < layer["padded_slot_share.chat"]["value"] < 100
+    assert 0 < layer["token_slot_fill_share.chat"]["value"] < 100
+    assert layer["padded_slot_share.chat"]["value"] == pytest.approx(
+        100 - layer["token_slot_fill_share.chat"]["value"])
+    assert 0 <= layer["chunk_step_gap_share.chat"]["value"] <= 100
     # a loaded test machine runs late; the chip run reads 1.6 ms
     assert 0 <= layer["gen_lateness_p99_ms"]["value"] < 2000
     assert {"ttft_p50_ms", "ttft_mean_ms", "ttft_p90_ms", "itl_mean_ms",
@@ -97,6 +100,7 @@ def _said_what_the_trace_cost(said, ev):
     cost = ev.trace["cost"]
     assert cost["device_events"] > 0 and cost["host_spans"] > 0
     assert 0 < cost["load_s"] < 60 and 0 <= cost["reduce_s"] < 60
+    assert 0 < cost["stop_s"] < 60
     assert 0 < ev.trace["busy_s"] <= ev.trace["window_s"]
     assert ev.trace["device_ops"] and ev.trace["idle_gaps"]
 
@@ -115,6 +119,56 @@ def test_a_traced_serving_run_says_what_its_trace_cost(tmp_path,
     _said_what_the_trace_cost(said, ev)
     gaps = dict(ev.trace["idle_gaps"])
     assert gaps.get("engine.launch", 0) + gaps.get("engine.wait", 0) > 0
+
+
+def test_a_traced_run_whose_trace_is_empty_still_prints_its_line(
+        tmp_path, monkeypatch, benchmark_json, capsys):
+    """No request was alive while the profiler ran, so no operation is in
+    the trace: the run still gives its line, the device all idle, the
+    breakdown empty, the metrics that divide by a kernel's seconds left
+    out, and the command's exit code is 0."""
+    def load(trace_dir):
+        return {}, [("engine.step", 10.0, 0.25), ("engine.admit", 10.0, 0.05)]
+
+    monkeypatch.setattr(xplane, "load", load)
+    said = []
+    ctx = _ctx(load_data("tiny-llama.json"), load_data("tiny-chat.json"),
+               {"rate_rps": 4.0}, 2 ** 31 + 33, 1.0, tmp_path, trace=1,
+               say=said.append)
+    res = run.run_cell(ctx)
+    assert res["correct"] is True and res["failed"] == 0
+    tr = res["evidence"].trace
+    assert tr["devices"] == 0 and tr["busy_s"] == 0.0
+    # traced: the window's last second (here all of it), stopped at its end
+    assert res["evidence"].w1 - 1.0 <= tr["t0"] < tr["t1"]
+    assert tr["t1"] == pytest.approx(res["evidence"].w1, abs=0.25)
+    line = run.result_line(res, benchmark_json, "mistral-d12.chat", 1,
+                           "cpu", 1)
+    assert line["metrics"]["device_idle_share.chat"] == {"value": 100.0,
+                                                         "unit": "%"}
+    assert "step_roofline_share_counted.chat" not in line["metrics"]
+    assert "paged_attention_roofline_share.chat" not in line["metrics"]
+    assert line["device"]["busy_s"] == 0.0
+    assert line["device"]["window_s"] == pytest.approx(1.0, abs=0.25)
+    gaps = dict(line["breakdown"]["idle_gaps"])
+    assert line["breakdown"]["device_ops"] == []
+    assert gaps["engine.admit"] == pytest.approx(0.05)
+    assert gaps["engine.step"] == pytest.approx(0.20)
+    assert sum(gaps.values()) == pytest.approx(line["device"]["window_s"])
+    assert [s for s in said if s.startswith("trace: 0 device events, 2 ")]
+    json.dumps(line)
+
+    # and through the command itself, the look for a chip stepped over
+    monkeypatch.setattr(run, "run_cell", lambda ctx: res)
+    monkeypatch.setattr(run, "configure_cache", lambda: None)
+    tpu = type("D", (), {"platform": "tpu", "device_kind": "TPU v5 lite"})()
+    monkeypatch.setattr(jax, "devices", lambda *a: [tpu])
+    assert run.main(["--workload", "mistral-d12.chat", "--seed", "1",
+                     "--seconds", "1", "--trace", "1"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["breakdown"] == {"device_ops": [],
+                                "idle_gaps": line["breakdown"]["idle_gaps"]}
+    assert out["metrics"]["device_idle_share.chat"]["value"] == 100.0
 
 
 class _AlteredStream:

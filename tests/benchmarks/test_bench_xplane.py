@@ -1,6 +1,7 @@
 import json
 import os
 import random
+import threading
 import time
 
 import pytest
@@ -72,8 +73,41 @@ def test_reduction_averages_over_devices():
     assert r["busy_s"] == pytest.approx(2.0) and r["devices"] == 2
 
 
-def test_no_device_events_reads_as_nothing_busy():
-    assert xplane.reduce_events({}, [], 2.0)["busy_s"] == 0.0
+_SOME_TRACE = ({"/device:TPU:0": [("fusion.1", 0.0, 1.0)]},
+               [("engine.step", 0.0, 2.0)], 2.0)
+
+
+@pytest.mark.parametrize("ops", [{}, {"/device:TPU:0": []},
+                                 {"/device:TPU:0": [], "/device:TPU:1": []}],
+                         ids=["no_plane", "one_empty_plane", "two"])
+def test_an_empty_trace_gives_every_key_a_trace_with_operations_does(ops):
+    """The engine had no request alive while it was traced: all of the
+    window is idle, under the host span it falls in, innermost first,
+    else ``between_steps``; nothing else differs from any other trace."""
+    host = [("engine.step", 1.0, 2.0), ("engine.step", 4.0, 0.5),
+            ("engine.admit", 1.0, 0.25), ("engine.wait", 1.5, 1.0),
+            ("engine.admit", 2.0, 0.25),       # inside the wait: innermost
+            ("engine.retire", 0.0, 5.0)]       # no label
+    r = xplane.reduce_events(ops, host, 6.0)
+    assert list(r) == list(xplane.reduce_events(*_SOME_TRACE))
+    assert r["window_s"] == 6.0 and r["busy_s"] == 0.0
+    assert r["devices"] == len(ops)
+    assert r["idle_share"] == 1.0 and r["collective_s"] == 0.0
+    assert r["op_seconds"] == {} and r["device_ops"] == []
+    assert r["idle_gaps"] == [[spans.OUTSIDE, pytest.approx(3.5)],
+                              ["engine.step", pytest.approx(1.25)],
+                              ["engine.wait", pytest.approx(0.75)],
+                              ["engine.admit", pytest.approx(0.5)]]
+    assert sum(v for _, v in r["idle_gaps"]) == pytest.approx(r["window_s"])
+
+
+def test_an_empty_trace_with_no_span_is_all_between_steps():
+    r = xplane.reduce_events({}, [], 2.0)
+    assert r["busy_s"] == 0.0 and r["idle_share"] == 1.0
+    assert r["idle_gaps"] == [[spans.OUTSIDE, 2.0]]
+    # spans past the host's own window cannot make the rest negative
+    r = xplane.reduce_events({}, [("engine.step", 0.0, 3.0)], 2.0)
+    assert r["idle_gaps"] == [["engine.step", 3.0]]
 
 
 def test_recorded_trace_sample():
@@ -128,7 +162,8 @@ def test_loader_reads_a_trace_the_profiler_just_wrote(tmp_path):
     import jax
     import jax.numpy as jnp
 
-    win = xplane.TraceWindow(str(tmp_path / "tr"), 0.2)
+    now = time.monotonic()
+    win = xplane.TraceWindow(str(tmp_path / "tr"), 0.2, now, now + 0.2)
     win.start()
     # as the program's StepClock writes them: a step span that carries its
     # number, a phase span inside it
@@ -136,7 +171,7 @@ def test_loader_reads_a_trace_the_profiler_just_wrote(tmp_path):
         x = jnp.ones((256, 256))
         with jax.profiler.TraceAnnotation("engine.launch"):
             (x @ x).block_until_ready()
-    win._timer.join(60)
+    assert win._stopped.wait(60)
     # on the CPU the operations sit on the host plane's XLA threads
     ops, host = xplane.load(str(tmp_path / "tr"), device_prefix="/host:CPU",
                             op_line="tf_XLAPjRtCpuClient")
@@ -163,8 +198,22 @@ def _oracle_subtract(xs, ys):
 
 
 def _oracle_reduce_events(device_ops, host_spans, window_s):
-    if not device_ops:
-        return {"window_s": window_s, "busy_s": 0.0, "devices": 0}
+    if not any(device_ops.values()):
+        # nothing ran: the whole window idle, by label, innermost first
+        gaps_by, seen = {}, []
+        for label in spans.GAP_SPANS:
+            iv = _oracle_subtract(xplane.union(
+                (s, s + d) for n, s, d in host_spans if n == label), seen)
+            if iv:
+                gaps_by[label] = xplane.total(iv)
+                seen = xplane.union(seen + iv)
+        if window_s > xplane.total(seen):
+            gaps_by[spans.OUTSIDE] = window_s - xplane.total(seen)
+        return {"window_s": window_s, "busy_s": 0.0,
+                "devices": len(device_ops), "idle_share": 1.0,
+                "collective_s": 0.0, "op_seconds": {}, "device_ops": [],
+                "idle_gaps": [[k, v] for k, v in sorted(
+                    gaps_by.items(), key=lambda kv: -kv[1])][:10]}
     busy, ops, coll = [], {}, 0.0
     gaps_by = {}
     labelled = {label: xplane.union((s, s + d) for n, s, d in host_spans
@@ -306,7 +355,7 @@ def test_reduction_equals_the_oracle_with_no_spans_at_all(seed):
 
 
 def test_reduction_of_devices_without_events_equals_the_oracle():
-    for ops in ({"/device:TPU:0": []},
+    for ops in ({}, {"/device:TPU:0": []},
                 {"/device:TPU:0": [], "/device:TPU:1": [("a.1", 1.0, 0.0)]},
                 {"/device:TPU:0": [("a.1", 1.0, 0.5)], "/device:TPU:1": []}):
         _same(xplane.reduce_events(ops, [("engine.step", 0.0, 2.0)], 3.0),
@@ -392,7 +441,8 @@ def test_a_finished_window_says_what_its_trace_cost(tmp_path,
     import jax
     import jax.numpy as jnp
 
-    win = xplane.TraceWindow(str(tmp_path / "tr"), 0.2)
+    now = time.monotonic()
+    win = xplane.TraceWindow(str(tmp_path / "tr"), 0.2, now, now + 0.2)
     win.start()
     with jax.profiler.StepTraceAnnotation("engine.step", step_num=3):
         x = jnp.ones((256, 256))
@@ -400,14 +450,118 @@ def test_a_finished_window_says_what_its_trace_cost(tmp_path,
             (x @ x).block_until_ready()
     out = win.finish()
     cost = out["cost"]
-    assert set(cost) == {"device_events", "host_spans", "load_s", "reduce_s"}
+    assert set(cost) == {"device_events", "host_spans", "stop_s", "load_s",
+                         "reduce_s"}
     assert cost["host_spans"] == 2
-    assert cost["load_s"] > 0 and cost["reduce_s"] >= 0
+    assert cost["stop_s"] > 0 and cost["load_s"] > 0
+    assert cost["reduce_s"] >= 0
     if not cost["device_events"]:
         pytest.skip("the CPU profiler wrote no operations to read")
     assert out["busy_s"] > 0 and out["t1"] > out["t0"]
     assert xplane.cost_line(out) == (
-        "trace: %d device events, 2 host spans, loaded in %.2f s, "
-        "reduced in %.2f s" % (cost["device_events"], cost["load_s"],
-                               cost["reduce_s"]))
+        "trace: %d device events, 2 host spans, stopped in %.2f s, "
+        "loaded in %.2f s, reduced in %.2f s" % (
+            cost["device_events"], cost["stop_s"], cost["load_s"],
+            cost["reduce_s"]))
     assert not os.path.exists(str(tmp_path / "tr"))
+
+
+# ------------------------------------------- the window's last seconds
+
+class _FakeHost:
+    """What ``xplane`` reaches the host through, as one fake: a clock the
+    test moves (``time.monotonic``), timers it fires (``threading.Timer``)
+    and a profiler that only writes down when it was started and stopped
+    (``jax.profiler``)."""
+
+    def __init__(self, monkeypatch, now):
+        import jax
+
+        self.now, self.timers, self.calls = now, [], []
+        monkeypatch.setattr(xplane, "time", self)
+        monkeypatch.setattr(xplane, "threading", self)
+        monkeypatch.setattr(jax.profiler, "start_trace", self.start_trace)
+        monkeypatch.setattr(jax.profiler, "stop_trace", self.stop_trace)
+
+    Event = threading.Event
+
+    def monotonic(self):
+        return self.now
+
+    def Timer(self, seconds, fn):
+        fake = self
+
+        class T:
+            daemon = False
+
+            def start(self):
+                fake.timers.append((fake.now + seconds, fn))
+        return T()
+
+    def fire_next(self):
+        at, fn = self.timers.pop(0)
+        self.now = max(self.now, at)
+        fn()
+
+    def start_trace(self, trace_dir, **kw):
+        self.calls.append(("start", self.now))
+        self.now += 0.3                     # a start takes its time
+
+    def stop_trace(self):
+        self.calls.append(("stop", self.now))
+        self.now += 20.0                    # and a stop far more
+
+
+@pytest.mark.parametrize("span_s,begins", [(2.0, 149.0), (6.0, 145.0),
+                                           (80.0, 100.0)],
+                         ids=["2s", "6s", "longer_than_the_window"])
+def test_the_last_seconds_of_a_window_are_what_is_traced(
+        tmp_path, monkeypatch, span_s, begins):
+    """Asked at the window's opening for its last ``s`` seconds, the trace
+    starts at ``w1 - s`` and its stop is called at ``w1``: the stop's own
+    seconds fall after the close."""
+    w0, w1 = 100.0, 151.0
+    fake = _FakeHost(monkeypatch, now=w0)
+    win = xplane.TraceWindow(str(tmp_path / "tr"), span_s, w0, w1)
+    assert win.span_s == min(span_s, w1 - w0) and win.begin_at == begins
+    win.start()
+    if begins > w0:
+        assert fake.calls == [] and [t for t, _ in fake.timers] == [begins]
+        fake.fire_next()
+    assert fake.calls == [("start", begins)]
+    assert not win._stopped.is_set()
+    assert [t for t, _ in fake.timers] == [pytest.approx(w1)]
+    fake.fire_next()
+    assert fake.calls == [("start", begins), ("stop", pytest.approx(w1))]
+    assert win._stopped.is_set()
+    assert win._t0 == pytest.approx(begins + 0.3) and win._t1 \
+        == pytest.approx(w1)
+    assert win._stop_s == pytest.approx(20.0)
+
+
+def test_there_is_one_way_to_place_a_trace():
+    """Both runners hand the window's ends to the same constructor; none
+    can ask for a trace from its opening."""
+    import inspect
+
+    assert list(inspect.signature(xplane.TraceWindow).parameters) == [
+        "trace_dir", "span_s", "w0", "w1"]
+    assert not hasattr(xplane.TraceWindow, "last_of")
+
+
+def test_a_trace_that_cannot_begin_is_reported_at_the_finish(tmp_path,
+                                                             monkeypatch):
+    fake = _FakeHost(monkeypatch, now=0.0)
+
+    def refuses(trace_dir, **kw):
+        raise RuntimeError("another trace is running")
+
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuses)
+    win = xplane.TraceWindow(str(tmp_path / "tr"), 2.0, 0.0, 10.0)
+    win.start()
+    fake.fire_next()
+    assert win._stopped.is_set() and fake.timers == []
+    with pytest.raises(RuntimeError, match="another trace"):
+        win.finish()
