@@ -33,7 +33,21 @@ scale by ``routed_scaling_factor`` (serving/moe/dropless.py), and add a
 shared expert.  ``n_routed_experts`` is the number of experts HELD here
 (``experts_held_first`` on); ``n_routed_experts_published`` the router's
 width.  ``topk_method`` "none" is read as no group restriction and no
-score-correction bias; any other value is refused.
+score-correction bias; "noaux_tc" with ``n_group == topk_group == 1``
+(the group step keeps everything) adds the score-correction bias that
+decides which experts are chosen and not their weights; grouped routing
+is refused.
+
+Residual path.  ``hc_mult = n > 1`` (``model_type`` ``xing4_0``) carries
+``n`` residual streams ``[.., n, hidden]`` mixed by manifold-constrained
+hyper-connections (nn/hyper_connections.py): the embedding fans out to
+``n`` equal streams, each layer's two sub-layers read a learned mix of
+them and write back through a doubly-stochastic carry, and the final
+norm reads their sum.  With ``hc_mult`` absent or 1 the residual is the
+plain ``x + F(norm(x))`` and the program built is the same as before the
+key existed.  ``num_nextn_predict_layers`` (a multi-token-prediction
+module behind the last layer) is carried and not built: the main model's
+logits do not depend on it.
 """
 from __future__ import annotations
 
@@ -43,6 +57,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..nn.hyper_connections import (HyperConnection, expand_streams,
+                                    merge_streams)
 from ..nn.layer import Layer
 from ..nn.layers_common import LayerList, RMSNorm
 from ..parallel.mp_layers import (ColumnParallelLinear, RowParallelLinear,
@@ -65,17 +81,27 @@ class LatentMoEConfig:
                  norm_topk_prob=True, topk_method="none",
                  max_position_embeddings=131072, rms_norm_eps=1e-6,
                  rope_theta=10000.0, rope_scaling=None,
-                 initializer_range=0.02, **extra):
+                 initializer_range=0.02, n_group=None, topk_group=None,
+                 hc_mult=1, hc_sinkhorn_iters=20, hc_eps=1e-6,
+                 mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
+                 **extra):
         if scoring_func != "sigmoid" or not norm_topk_prob:
             raise NotImplementedError(
                 "the expert layer scores by sigmoid and renormalises the "
                 f"chosen; got scoring_func={scoring_func!r}, "
                 f"norm_topk_prob={norm_topk_prob!r}")
-        if topk_method != "none":
+        if topk_method not in ("none", "noaux_tc"):
             raise NotImplementedError(
-                f"topk_method={topk_method!r}: grouped routing with a "
-                "score-correction bias is not built; only plain top-k "
-                "over all sigmoid scores (\"none\") is")
+                f"topk_method={topk_method!r}: only plain top-k over all "
+                "sigmoid scores (\"none\") and the score-correction bias "
+                "of one group (\"noaux_tc\") are built")
+        if topk_method == "noaux_tc" and not (
+                (n_group or 1) == 1 and (topk_group or 1) == 1):
+            raise NotImplementedError(
+                f"topk_method='noaux_tc' with n_group={n_group!r}, "
+                f"topk_group={topk_group!r}: grouped routing is not built; "
+                "the score-correction bias is, for n_group == topk_group "
+                "== 1")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -103,6 +129,12 @@ class LatentMoEConfig:
         self.rope_theta = rope_theta
         self.rope_scaling = rope_scaling
         self.initializer_range = initializer_range
+        self.n_group, self.topk_group = n_group, topk_group
+        self.hc_mult = int(hc_mult or 1)
+        self.hc_sinkhorn_iters = hc_sinkhorn_iters
+        self.hc_eps = hc_eps
+        self.mhc_h_res_clamp_min = mhc_h_res_clamp_min
+        self.mhc_h_res_clamp_max = mhc_h_res_clamp_max
         for k, v in extra.items():
             setattr(self, k, v)
 
@@ -302,7 +334,8 @@ class SharedExpertMoE(Layer):
             held_first=cfg.experts_held_first,
             held_count=cfg.n_routed_experts,
             routed_scale=cfg.routed_scaling_factor,
-            init_std=cfg.initializer_range)
+            init_std=cfg.initializer_range,
+            score_bias=cfg.topk_method == "noaux_tc")
         self.shared_experts = LlamaMLP(
             cfg.hidden_size,
             cfg.moe_intermediate_size * cfg.n_shared_experts)
@@ -324,8 +357,18 @@ class LatentMoEDecoderLayer(Layer):
         self.dense = index < cfg.first_k_dense_replace
         self.mlp = (LlamaMLP(cfg.hidden_size, cfg.intermediate_size)
                     if self.dense else SharedExpertMoE(cfg))
+        # hc_mult streams: one hyper-connection a sub-layer
+        self.hc_attn = self.hc_ffn = None
+        if cfg.hc_mult > 1:
+            hc = lambda: HyperConnection(
+                cfg.hidden_size, cfg.hc_mult, cfg.hc_sinkhorn_iters,
+                cfg.hc_eps, cfg.rms_norm_eps, cfg.mhc_h_res_clamp_min,
+                cfg.mhc_h_res_clamp_max, cfg.initializer_range)
+            self.hc_attn, self.hc_ffn = hc(), hc()
 
     def forward(self, x, cache=None, position_ids=None):
+        if self.hc_attn is not None:
+            return self._forward_streams(x, cache, position_ids)
         h = self.self_attn(self.input_layernorm(x), cache=cache,
                            position_ids=position_ids)
         if cache is not None:
@@ -337,6 +380,25 @@ class LatentMoEDecoderLayer(Layer):
                 x = x + self.mlp(y)
         else:
             x = x + self.mlp(y)
+        return (x, new_cache) if cache is not None else x
+
+    def _forward_streams(self, x, cache, position_ids):
+        """x [b, s, hc_mult, hidden]: each sub-layer reads a mix of the
+        streams and writes back through its carry."""
+        u, carry = self.hc_attn.read(x)
+        h = self.self_attn(self.input_layernorm(u), cache=cache,
+                           position_ids=position_ids)
+        if cache is not None:
+            h, new_cache = h
+        x = self.hc_attn.write(carry, h)
+        u, carry = self.hc_ffn.read(x)
+        y = self.post_attention_layernorm(u)
+        if self.dense:
+            with jax.named_scope("ffn"):
+                y = self.mlp(y)
+        else:
+            y = self.mlp(y)
+        x = self.hc_ffn.write(carry, y)
         return (x, new_cache) if cache is not None else x
 
 
@@ -353,6 +415,9 @@ class LatentMoEModel(Layer):
     def forward(self, input_ids, position_ids=None, caches=None,
                 head_rows=None):
         x = self.embed_tokens(input_ids)
+        streams = self.config.hc_mult
+        if streams > 1:
+            x = Tensor(expand_streams(x._data, streams))
         new_caches = [] if caches is not None else None
         for i, layer in enumerate(self.layers):
             if caches is not None:
@@ -360,7 +425,10 @@ class LatentMoEModel(Layer):
                 new_caches.append(c)
             else:
                 x = layer(x, position_ids=position_ids)
-        x = self.norm(take_head_rows(x, head_rows))
+        x = take_head_rows(x, head_rows)
+        if streams > 1:
+            x = Tensor(merge_streams(x._data))
+        x = self.norm(x)
         return (x, new_caches) if caches is not None else x
 
 

@@ -41,6 +41,7 @@ class AutoModel:
     # "architecture"; its "model_type" names the family
     _BY_MODEL_TYPE = {
         "axk1": ("latent_moe", "LatentMoEForCausalLM"),
+        "xing4_0": ("latent_moe", "LatentMoEForCausalLM"),
     }
 
     @classmethod

@@ -142,6 +142,16 @@ _SCHEMA = (
                                  # any layer this step
     ("moe_experts_touched", 0),  # held experts with at least one token,
                                  # summed over expert layers
+    # hyper-connected residual streams (nn/hyper_connections.py); 0 on a
+    # model with the plain residual
+    ("mhc_col_sum_gap_max", 0.0),  # largest |colsum(H_res) - 1| over the
+                                   # step's valid slots and every
+                                   # sub-layer: what Sinkhorn's rounds
+                                   # leave of the constraint
+    ("residual_streams", 0),     # streams the residual carries (hc_mult)
+    ("residual_stream_bytes", 0),  # bytes a token of the streams as
+                                   # stored (n x hidden x itemsize), read
+                                   # from the array
     ("cache_bytes_per_token", 0),  # the allocated pools' bytes over their
                                    # token capacity, all layers, scales
                                    # and a latent row's lane padding

@@ -56,7 +56,8 @@ from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
-from .programs import (DROPLESS_COUNTERS, build_mixed_step, build_page_copy,
+from .programs import (DROPLESS_COUNTERS, RESIDUAL_COUNTERS, build_mixed_step,
+                       build_page_copy,
                        step_input_layout, step_output_layout)
 from .request import (DeadlineExceededError, GrammarError,
                       GrammarIncompleteError, HandoffError, LoadShedError,
@@ -143,6 +144,11 @@ class EngineCore:
         # conversion and no capacity: the mixed step only threads them
         # its valid mask and returns their counters
         self._dropless = dropless_moe_info(engine._model)
+        # hyper-connected residual streams (nn/hyper_connections.py):
+        # likewise only their counters ride out of the step
+        from ..nn.hyper_connections import hyper_connection_info
+
+        self._residual = hyper_connection_info(engine._model)
 
         # KV-pool quantization rides in on the ENGINE (it owns the
         # pools); the kwarg here is a config affordance that must agree
@@ -385,7 +391,8 @@ class EngineCore:
         self._step_out = step_output_layout(
             self._max_batch, self._spec_window,
             moe=(self._moe["num_experts"] if self._moe is not None
-                 else "dropless" if self._dropless is not None else None))
+                 else "dropless" if self._dropless is not None else None),
+            residual=self._residual is not None)
 
         # step-level flight recorder: every scheduler step event
         # (admission / mixed step / page copy / evict) appends one
@@ -1579,6 +1586,7 @@ class EngineCore:
         # one switch: the step threads its valid mask to whatever expert
         # layers the model has and returns what they counted
         moe_stats = moe is not None or self._dropless is not None
+        residual = self._residual is not None
         if moe is not None:
             # the [E, C_cap] routing buffers are deployment config, so
             # they join the key — routing changes data, never shapes
@@ -1756,7 +1764,8 @@ class EngineCore:
                 mkey, lambda: build_mixed_step(eng, b, C, self._max_pages,
                                                spec_window=W,
                                                moe_stats=moe_stats,
-                                               grammar=grammar_on),
+                                               grammar=grammar_on,
+                                               residual_stats=residual),
                 *step_args)
         except Exception as e:
             self._metrics.on_failed(0)
@@ -1825,6 +1834,10 @@ class EngineCore:
                           moe_aux_loss=m_aux)
             self._metrics.on_moe([int(x) for x in m_routed],
                                  m_dropped, m_aux)
+        if residual:
+            moe_kw.update(
+                (name, (float if dtype == "float32" else int)(out[name]))
+                for name, dtype in RESIDUAL_COUNTERS)
         t_sync = clock.phase("emit")
         # the step as the device ran it, launch to read-back: what the
         # server's step-time and ITL histograms report (the launch alone

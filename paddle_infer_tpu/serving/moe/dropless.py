@@ -37,14 +37,25 @@ from . import stats as moe_stats
 ROW_TILE = 128
 
 
-def route(x, gate_weight, top_k: int, routed_scale: float):
+def route(x, gate_weight, top_k: int, routed_scale: float,
+          score_bias=None):
     """Sigmoid scores over all experts in float32, the ``top_k`` largest,
     renormalised over the chosen and scaled.  x [N, h] -> (expert ids
-    [N, k] int32, weights [N, k] float32)."""
+    [N, k] int32, weights [N, k] float32).
+
+    ``score_bias`` [E] float32 (``topk_method: "noaux_tc"``, one group):
+    the experts chosen are the ``top_k`` of ``scores + score_bias``; the
+    bias decides WHICH experts and nothing else — their weights are the
+    uncorrected scores, renormalised over the chosen."""
     scores = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), gate_weight.astype(jnp.float32),
         precision="highest"))
-    top, ids = jax.lax.top_k(scores, top_k)
+    if score_bias is None:
+        top, ids = jax.lax.top_k(scores, top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + score_bias.astype(jnp.float32),
+                               top_k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
     w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * routed_scale
     return ids.astype(jnp.int32), w
 
@@ -96,12 +107,14 @@ def dropless_experts(x, ids, w, valid, w_gate, w_up, w_down, held_first,
 
 class DroplessMoE(Layer):
     """Router over ``n_published`` experts + the stacked SwiGLU experts
-    ``held_first .. held_first + held_count - 1``."""
+    ``held_first .. held_first + held_count - 1``; with ``score_bias`` a
+    score-correction bias a published expert beside the router."""
 
     def __init__(self, hidden: int, ffn_hidden: int, n_published: int,
                  top_k: int, held_first: int = 0,
                  held_count: Optional[int] = None,
-                 routed_scale: float = 1.0, init_std: float = 0.02):
+                 routed_scale: float = 1.0, init_std: float = 0.02,
+                 score_bias: bool = False):
         super().__init__()
         held_count = n_published if held_count is None else held_count
         if not (0 <= held_first
@@ -122,6 +135,11 @@ class DroplessMoE(Layer):
             (held_count, hidden, ffn_hidden), default_initializer=init)
         self.w_down = self.create_parameter(
             (held_count, ffn_hidden, hidden), default_initializer=init)
+        # the score-correction bias of ``noaux_tc`` routing: float32
+        # whatever the model is served in
+        self.e_score_correction_bias = self.create_parameter(
+            (n_published,), dtype="float32",
+            default_initializer=I.Constant(0.0)) if score_bias else None
 
     def forward(self, x):
         b, s, h = x.shape
@@ -130,8 +148,10 @@ class DroplessMoE(Layer):
         valid = col.valid if col is not None \
             else jnp.ones((b * s,), jnp.bool_)
         with jax.named_scope("moe_router"):
+            bias = self.e_score_correction_bias
             ids, w = route(xf, self.gate_weight._data, self.top_k,
-                           self.routed_scale)
+                           self.routed_scale,
+                           None if bias is None else bias._data)
         y, counts = dropless_experts(
             xf, ids, w, valid, self.w_gate._data, self.w_up._data,
             self.w_down._data, self.held_first,

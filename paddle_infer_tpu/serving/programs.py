@@ -59,6 +59,11 @@ DROPLESS_COUNTERS = ("moe_assignments_total", "moe_assignments_held",
                      "moe_held_expert_max", "moe_experts_touched")
 # ... and the capacity path's three
 CAPACITY_COUNTERS = ("moe_routed", "moe_dropped", "moe_aux")
+# what a model of hyper-connected residual streams notes a step
+# (nn/hyper_connections.py ``ResidualStatsCollector.totals``)
+RESIDUAL_COUNTERS = (("mhc_col_sum_gap_max", "float32"),
+                     ("residual_streams", "int32"),
+                     ("residual_stream_bytes", "int32"))
 
 
 class StepLayout:
@@ -136,13 +141,14 @@ def step_input_layout(max_batch, token_budget, max_pages, spec_window=1):
     return StepLayout(rows)
 
 
-def step_output_layout(max_batch, spec_window=1, moe=None):
+def step_output_layout(max_batch, spec_window=1, moe=None, residual=False):
     """THE layout of the mixed step's packed output: the sampled tokens,
     the finished flags, the emit counts of a speculating step, then the
     expert counters — ``moe`` is None (no expert layer counted),
     ``"dropless"`` (the four ``DROPLESS_COUNTERS``) or the capacity
     path's expert count ``E`` (``moe_routed[E]``, ``moe_dropped``,
-    ``moe_aux`` float32 by bit pattern)."""
+    ``moe_aux`` float32 by bit pattern) — and, with ``residual``, the
+    three ``RESIDUAL_COUNTERS`` of a model of hyper-connected streams."""
     b, W = int(max_batch), int(spec_window)
     rows = [("tok", (b,) if W <= 1 else (b, W), "int32"),
             ("fin", (b,), "bool")]
@@ -153,6 +159,8 @@ def step_output_layout(max_batch, spec_window=1, moe=None):
     elif moe is not None:
         rows += [("moe_routed", (int(moe),), "int32"),
                  ("moe_dropped", (), "int32"), ("moe_aux", (), "float32")]
+    if residual:
+        rows.extend((name, (), dtype) for name, dtype in RESIDUAL_COUNTERS)
     return StepLayout(rows)
 
 
@@ -217,7 +225,8 @@ def _layer_pools(engine, caches):
 
 
 def build_mixed_step(engine, max_batch, token_budget, max_pages,
-                     spec_window=1, moe_stats=False, grammar=False):
+                     spec_window=1, moe_stats=False, grammar=False,
+                     residual_stats=False):
     """THE serving step executable: one launch per scheduler step,
     whatever the batch composition.  Row ``b`` carries ``qlens[b]``
     query tokens starting at absolute position ``ctx[b]`` — 1 for a
@@ -309,6 +318,11 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
     ride the same trace and the same read-back (data, no shape
     impact), so the one-executable invariant is untouched.
 
+    ``residual_stats = True`` (EngineCore sets it when the model carries
+    hyper-connected residual streams, nn/hyper_connections.py) opens
+    that module's side-channel around the forward the same way and
+    appends its three ``RESIDUAL_COUNTERS`` behind the expert counters.
+
     ``grammar = True`` (EngineCore sets it when constructed with a
     ``grammar_vocab``) threads ONE extra input behind ``packed`` —
     ``run(params, packed, gmask, k_pages, v_pages)`` — an additive
@@ -360,8 +374,9 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             return engine._model_step(params, ids[None], pos[None], None,
                                       caches, head_rows=head_rows)
 
-        # LoRA slots follow the axis: one per token, its row's
-        with lora_slots_mod.activate(adapter_slots[row]):
+        def counted_model():
+            """-> (logits, caches, (moe, counters)) under whichever
+            side-channels this deployment's model feeds."""
             if not moe_stats:
                 return (*model(), (None, {}))
             from .moe import stats as moe_stats_mod
@@ -375,6 +390,18 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
             return logits, caches, (totals[0].shape[0],
                                     dict(zip(CAPACITY_COUNTERS, totals)))
 
+        # LoRA slots follow the axis: one per token, its row's
+        with lora_slots_mod.activate(adapter_slots[row]):
+            if not residual_stats:
+                return counted_model()
+            from ..nn import hyper_connections
+
+            with hyper_connections.collect_stats(valid) as res:
+                logits, caches, (moe, counters) = counted_model()
+            counters = dict(counters, **dict(zip(
+                (name for name, _ in RESIDUAL_COUNTERS), res.totals())))
+            return logits, caches, (moe, counters)
+
     W = int(spec_window)
     in_layout = step_input_layout(max_batch, T, max_pages, W)
 
@@ -383,7 +410,7 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         whatever the expert layers counted (``_model_step``'s ``(moe,
         counters)``), by ``step_output_layout``."""
         moe, counters = counted
-        return step_output_layout(max_batch, W, moe).pack(
+        return step_output_layout(max_batch, W, moe, residual_stats).pack(
             {**fields, **counters})
 
     def run(params, packed, *mask_and_pools):

@@ -203,10 +203,11 @@ def not_interpreted():
     steps below, every kernel module answers no."""
     from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
     from paddle_infer_tpu.ops.pallas import latent_attention as LA
+    from paddle_infer_tpu.ops.pallas import mhc_maps as MM
     from paddle_infer_tpu.ops.pallas import paged_attention as PA
     from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
 
-    mods = (PA, RPA, LA, GM)
+    mods = (PA, RPA, LA, GM, MM)
     prev = [m._interpret for m in mods]
     for m in mods:
         m._interpret = lambda: False
@@ -417,6 +418,75 @@ def test_latent_decode_and_grouped_matmul_compile(spec, monkeypatch):
     for k, n in ((7168, 2048), (2048, 7168)):
         _compile(GM.grouped_matmul, spec((512, k), jnp.bfloat16),
                  spec((12, k, n), jnp.bfloat16), spec((12,), i32))
+
+
+@pytest.mark.parametrize("tokens", [64, 1024])
+def test_mhc_maps_compiles_as_one_call(spec, tokens):
+    """The three maps of a hyper-connected sub-layer, Sinkhorn's 20 rounds
+    included, at the reasoning cell's width: ONE Mosaic call under its own
+    name over the step's flat token axis (and over a longer one)."""
+    from paddle_infer_tpu.ops.pallas.mhc_maps import mhc_maps
+
+    text = _compile(
+        lambda z, s, b: mhc_maps(z, s, b, 4, 20, 1e-6, -30.0, 30.0,
+                                 interpret=False),
+        spec((tokens, 24), jnp.float32), spec((24,), jnp.float32),
+        spec((24,), jnp.float32))
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%mhc_maps" in calls[0]
+
+
+@pytest.fixture(scope="module")
+def streams_step(one_chip, not_interpreted):
+    """A dense and an expert layer of the served mixed step at the
+    reasoning cell's widths (four residual streams, all 64 experts, the
+    whole vocabulary), pools donated, compiled for the chip."""
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.latent_moe import (LatentMoEConfig,
+                                                    LatentMoEForCausalLM)
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.serving.programs import build_mixed_step
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    cfg = LatentMoEConfig(
+        vocab_size=131072, hidden_size=3584, num_hidden_layers=2,
+        num_attention_heads=32, q_lora_rank=768, intermediate_size=9216,
+        moe_intermediate_size=1024, n_routed_experts=64,
+        num_experts_per_tok=4, routed_scaling_factor=2,
+        topk_method="noaux_tc", n_group=1, topk_group=1, hc_mult=4,
+        rope_scaling=dict(
+            beta_fast=32, beta_slow=1, factor=64, mscale=1, mscale_all_dim=1,
+            original_max_position_embeddings=4096, type="yarn"))
+    with abstract_parameters():
+        model = LatentMoEForCausalLM(cfg)
+    engine = PagedGenerationEngine(model, page_size=PAGE,
+                                   cache_dtype=jnp.bfloat16)
+    batch = 32
+    run = build_mixed_step(engine, batch, CELL_CHUNK, LAT_PAGES,
+                           moe_stats=True, residual_stats=True)
+    small = ("alpha", "bias", "e_score_correction_bias")
+    params = {n: spec(a.shape, jnp.float32 if n.rsplit(".", 1)[-1] in small
+                      else jnp.bfloat16)
+              for n, a in engine._params.items()}
+    pools = [spec((batch * LAT_PAGES + 1,) + LAT_POOL[1:], jnp.bfloat16)] * 2
+    return run.lower(*_step_args(spec, params, batch, CELL_CHUNK, LAT_PAGES,
+                                 pools, [None, None])).compile()
+
+
+def test_streams_step_holds_one_map_call_a_sublayer(streams_step):
+    """Four sub-layers: four ``mhc_maps`` calls beside the latent decode
+    kernel and the grouped matmul, the streams on the flat token axis in
+    bfloat16, and no temporary near a layer's weights."""
+    text = streams_step.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sum("%mhc_maps" in ln for ln in calls) == 4
+    assert "latent_paged_decode" in text and "moe_grouped_matmul" in text
+    assert f"bf16[{CELL_CHUNK},4,3584]" in text
+    assert streams_step.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert _entry_io(streams_step)[1] == 3      # packed output + 2 pools
 
 
 @pytest.fixture(scope="module")

@@ -424,8 +424,11 @@ def test_auto_model_builds_it_from_the_sources_config_keys(tmp_path):
     ids = Tensor(jnp.arange(7, dtype=jnp.int32)[None])
     np.testing.assert_array_equal(np.asarray(loaded(ids)._data),
                                   np.asarray(model(ids)._data))
-    with pytest.raises(NotImplementedError, match="correction bias"):
-        latent_moe.LatentMoEConfig(topk_method="noaux_tc")
+    # the score-correction bias is built for one group; grouped routing
+    # is refused by name (tests/test_hyper_connections.py has the rest)
+    with pytest.raises(NotImplementedError, match="grouped routing"):
+        latent_moe.LatentMoEConfig(topk_method="noaux_tc", n_group=8,
+                                   topk_group=4)
 
 
 def test_earlier_per_layer_entries_did_not_move():
