@@ -86,6 +86,12 @@ _SCHEMA = (
     ("decode_keys", 0),          # of attended_keys, those of the decode
                                  # rows (sum over rows with qlen == 1 of
                                  # ctx + 1)
+    ("draw_rows", 0),            # rows that drew their token this step
+                                 # (sample_now and do_sample); a step
+                                 # with none ran no categorical draw
+    ("filter_rows", 0),          # of those, the rows with top_k set or
+                                 # top_p under 1; a step with none ran
+                                 # no vocabulary-wide sort
     ("h2d_bytes", 0),            # bytes of the host arrays handed to the
                                  # step program this step
     ("h2d_arrays", 0),           # how many host arrays that was: 1, the

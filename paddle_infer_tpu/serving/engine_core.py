@@ -57,7 +57,7 @@ from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
 from .programs import (DROPLESS_COUNTERS, RESIDUAL_COUNTERS, build_mixed_step,
-                       build_page_copy,
+                       build_page_copy, sampling_rows,
                        step_input_layout, step_output_layout)
 from .request import (DeadlineExceededError, GrammarError,
                       GrammarIncompleteError, HandoffError, LoadShedError,
@@ -1746,6 +1746,11 @@ class EngineCore:
         attended_keys_step = int((ql * cx + ql * (ql + 1) // 2).sum())
         resident_tokens_step = int((cx + ql).sum())
         decode_keys_step = int((cx[ql == 1] + 1).sum())
+        # what the sampling tail will do, by the rule the traced step
+        # branches on: no row that filters, no sort; no row that draws,
+        # no draw
+        draw_rows_step, filter_rows_step = (
+            int(np.count_nonzero(m)) for m in sampling_rows(f, sample_now))
         h2d_bytes_step = h2d_arrays_step = 0
         clog = get_compile_log()
         c0 = clog.count()
@@ -1784,6 +1789,7 @@ class EngineCore:
                 attended_keys=attended_keys_step,
                 resident_tokens=resident_tokens_step,
                 h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
+                draw_rows=draw_rows_step, filter_rows=filter_rows_step,
                 **self._phase_fields(clock, end),
                 active_rows=len(active), decode_rows=n_decode,
                 chunk_steps=1, prefill_tokens=prefill_tokens_step,
@@ -1983,6 +1989,7 @@ class EngineCore:
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
             decode_keys=decode_keys_step,
+            draw_rows=draw_rows_step, filter_rows=filter_rows_step,
             h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
             d2h_arrays=len(host_outs),
             program_temp_bytes=self._program_temp_bytes(mkey),

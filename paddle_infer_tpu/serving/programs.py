@@ -13,7 +13,13 @@ executable per
 (batch, token-budget, table-width, pool-size) and heterogeneous requests
 share it.  Greedy rows stay argmax-exact with ``GenerationEngine``
 output: temperature scaling, top-k and top-p masking never change the
-argmax (the top token always survives every filter).
+argmax (the top token always survives every filter).  Within that one
+executable the sampling tail does what the step's rows ask for
+(``sampling_rows``): the vocabulary-wide sort behind top-k and the
+nucleus runs only on a step in which a row draws through a filter, the
+categorical draw only on a step in which a row draws — two
+``lax.cond`` on fields the packed input already carries, no second
+executable.
 
 Layout contract with ``EngineCore``:
 
@@ -164,10 +170,59 @@ def step_output_layout(max_batch, spec_window=1, moe=None, residual=False):
     return StepLayout(rows)
 
 
-def _process_rows(logits, samp, steps):
+def sampling_rows(samp, sample_now):
+    """``(draws, filters)``, ``[b]`` bools: the rows that draw their token
+    this step (they sample now and ask for a sample), and of those the
+    ones that draw through a filter (``top_k`` set or ``top_p`` under 1).
+    The traced tail branches on them and the packer counts them (StepLog
+    ``draw_rows``, ``filter_rows``) by this one rule, on ``jnp`` arrays
+    and on the host's views alike (bools there are 0 / 1 words)."""
+    draws = (sample_now != 0) & (samp["do_sample"] != 0)
+    filters = draws & ((samp["top_k"] > 0) | (samp["top_p"] < 1.0))
+    return draws, filters
+
+
+def _filter_thresholds(logits, samp, filters):
+    """``[b]`` thresholds ``t``: a filtering row's chain (top-k, then the
+    nucleus over what top-k kept) sets to ``NEG_INF`` exactly its entries
+    below ``t``.  ONE sort: top-k's mask is monotone, so the masked
+    row's descending order is the sorted row with the entries under its
+    k-th masked, ties at the k-th included.  A row outside ``filters``
+    comes back with a threshold that masks nothing."""
+    vocab = logits.shape[-1]
+    # top-k off: k widens to the whole vocabulary, the k-th entry is the
+    # row's minimum and nothing lies below it
+    k = jnp.where(jnp.logical_and(filters, samp["top_k"] > 0),
+                  jnp.clip(samp["top_k"], 1, vocab), vocab)
+    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    sorted_desc = jnp.where(sorted_desc < kth, sampling.NEG_INF, sorted_desc)
+
+    # the nucleus over the post-top-k row (its top token is always kept)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < samp["top_p"][:, None]
+    keep = keep.at[..., 0].set(True)
+    thresh = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1)
+    # nucleus off is OFF: the cumulative sum rounds to 1.0 before the
+    # row's end, so at ``top_p`` 1.0 the comparison above would still
+    # cut a tail (of some 1e-7 of the mass) that the row did not ask for
+    thresh = jnp.where(jnp.logical_and(filters, samp["top_p"] < 1.0),
+                       thresh, -jnp.inf)
+    return jnp.maximum(kth[:, 0], thresh)
+
+
+def _process_rows(logits, samp, steps, filters):
     """Per-row logits-processor chain (min-length eos ban → temperature
     → top-k → top-p), vectorized over rows with heterogeneous knobs.
-    Same order as ``sampling.process_logits``."""
+    Same order as ``sampling.process_logits``.  The ban and the
+    temperature are one elementwise pass on every step; the sort behind
+    both filters runs only on a step in which a row draws through one
+    (``filters`` of ``sampling_rows``, unbatched under a ``vmap``: a
+    batched predicate would turn the conditional into a select that runs
+    both sides).  What a row gets depends on its own fields alone: a
+    greedy row's argmax survives every filter, so it is never filtered,
+    whatever its neighbours ask for."""
     logits = logits.astype(jnp.float32)
     vocab = logits.shape[-1]
 
@@ -180,33 +235,28 @@ def _process_rows(logits, samp, steps):
     t = jnp.maximum(samp["temperature"].astype(jnp.float32), 1e-6)
     logits = logits / t[:, None]
 
-    # per-row top-k: k=0 disables by widening to the full vocab, so the
-    # kth threshold is the row minimum and the mask keeps everything
-    k = jnp.where(samp["top_k"] > 0,
-                  jnp.clip(samp["top_k"], 1, vocab), vocab)
-    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
-    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    logits = jnp.where(logits < kth, sampling.NEG_INF, logits)
-
-    # per-row nucleus filter over the post-top-k logits (top token is
-    # always kept, so p=1.0 rows pass through unchanged)
-    sorted2 = jnp.sort(logits, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted2, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < samp["top_p"][:, None]
-    keep = keep.at[..., 0].set(True)
-    thresh = jnp.min(jnp.where(keep, sorted2, jnp.inf), axis=-1,
-                     keepdims=True)
-    return jnp.where(logits < thresh, sampling.NEG_INF, logits)
+    # only the [b] thresholds cross the conditional; both filters are
+    # ``logits < t -> NEG_INF``, so the mask is one pass out here
+    thresh = jax.lax.cond(
+        jnp.any(filters),
+        lambda: _filter_thresholds(logits, samp, filters),
+        lambda: jnp.full(logits.shape[:1], -jnp.inf, jnp.float32))
+    return jnp.where(logits < thresh[:, None], sampling.NEG_INF, logits)
 
 
-def _pick_rows(proc, samp, steps, keys):
-    """Sample (per-row fold_in stream) or argmax, selected per row."""
-    step_keys = jax.vmap(jax.random.fold_in)(keys, steps)
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row))(step_keys, proc)
-    greedy = jnp.argmax(proc, axis=-1)
-    return jnp.where(samp["do_sample"], sampled, greedy).astype(jnp.int32)
+def _pick_rows(proc, samp, steps, keys, draws):
+    """Argmax, and on a step in which a row draws (``draws`` of
+    ``sampling_rows``, unbatched under a ``vmap``) a sample for each
+    such row from its own ``fold_in`` stream."""
+    greedy = jnp.argmax(proc, axis=-1).astype(jnp.int32)
+
+    def drawn():
+        step_keys = jax.vmap(jax.random.fold_in)(keys, steps)
+        sampled = jax.vmap(
+            lambda k, row: jax.random.categorical(k, row))(step_keys, proc)
+        return jnp.where(draws, sampled, greedy).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(draws), drawn, lambda: greedy)
 
 
 def _layer_caches(engine, k_pages, v_pages, *rest):
@@ -429,8 +479,9 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         with jax.named_scope("lm_head_sample"):
             if grammar:
                 last = last + gmask[0]
-            proc = _process_rows(last, samp, steps0)
-            tok = _pick_rows(proc, samp, steps0, f["keys"])
+            draws, filters = sampling_rows(samp, sample_now)
+            proc = _process_rows(last, samp, steps0, filters)
+            tok = _pick_rows(proc, samp, steps0, f["keys"], draws)
             tok = jnp.where(sample_now, tok, samp["pad"])
             fin = jnp.logical_and(
                 sample_now,
@@ -463,10 +514,14 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
         if grammar:
             lg_w = lg_w + gmask[0]
         steps_w = steps0[:, None] + jnp.where(spec[:, None], j, 0)
-        proc_w = jax.vmap(_process_rows, in_axes=(1, None, 1),
-                          out_axes=1)(lg_w, samp, steps_w)     # [b, W, V]
+        # the tail's two predicates are the step's, not a lane's: taken
+        # once out here, the conditionals stay conditionals under the vmap
+        draws, filters = sampling_rows(samp, sample_now)
+        proc_w = jax.vmap(
+            lambda lg, st: _process_rows(lg, samp, st, filters),
+            in_axes=(1, 1), out_axes=1)(lg_w, steps_w)         # [b, W, V]
         chosen_w = jax.vmap(
-            lambda p, st: _pick_rows(p, samp, st, keys),
+            lambda p, st: _pick_rows(p, samp, st, keys, draws),
             in_axes=(1, 1), out_axes=1)(proc_w, steps_w)       # [b, W]
 
         # drafts ride behind the row's first token, at ids[starts + 1

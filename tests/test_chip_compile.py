@@ -216,13 +216,14 @@ def not_interpreted():
         m._interpret = f
 
 
-def _step_args(spec, params, b, tokens, max_pages, k_pools, v_pools):
+def _step_args(spec, params, b, tokens, max_pages, k_pools, v_pools,
+               spec_window=1):
     """The mixed step's arguments as ``EngineCore`` hands them over: the
     parameters, the ONE packed ``int32`` buffer of ``step_input_layout``,
     the pools."""
     from paddle_infer_tpu.serving.programs import step_input_layout
 
-    size = step_input_layout(b, tokens, max_pages).size
+    size = step_input_layout(b, tokens, max_pages, spec_window).size
     return (params, spec((size,), jnp.int32), k_pools, v_pools)
 
 
@@ -240,10 +241,11 @@ def _entry_io(compiled):
     return n_in, n_out
 
 
-def _llama_step(one_chip, grammar=False, **widths):
+def _llama_step(one_chip, grammar=False, spec_window=1, **widths):
     """One layer of the served mixed step of a LLaMA-block model, as
-    ``EngineCore`` builds it (pools donated), compiled for the chip; with
-    it, how many parameters the step was handed (it has two pools)."""
+    ``EngineCore`` builds it (pools donated; ``spec_window`` > 1: the
+    speculating program), compiled for the chip; with it, how many
+    parameters the step was handed (it has two pools)."""
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_infer_tpu.nn.initializer import abstract_parameters
@@ -259,13 +261,14 @@ def _llama_step(one_chip, grammar=False, **widths):
     engine = PagedGenerationEngine(model, page_size=PAGE,
                                    cache_dtype=jnp.bfloat16)
     run = build_mixed_step(engine, CELL_B, CELL_CHUNK, MAX_PAGES,
-                           grammar=grammar)
+                           spec_window=spec_window, grammar=grammar)
     params = {n: spec(a.shape, jnp.bfloat16)
               for n, a in engine._params.items()}
     pools = [spec((CELL_POOL[0], cfg.num_attention_heads) + CELL_POOL[2:],
                   jnp.bfloat16)]
     _, packed, k_pools, v_pools = _step_args(
-        spec, params, CELL_B, CELL_CHUNK, MAX_PAGES, pools, pools)
+        spec, params, CELL_B, CELL_CHUNK, MAX_PAGES, pools, pools,
+        spec_window)
     # a grammar deployment's mask rides behind the packed buffer
     mask = (spec((CELL_B, 32000), jnp.float32),) if grammar else ()
     return run.lower(params, packed, *mask, k_pools,
@@ -560,3 +563,79 @@ def test_latent_step_runs_its_token_wise_layers_over_the_flat_axis(
     assert _shaped(text, "64,18432") and _shaped(text, "64,7168")
     # the head's product has max_batch rows
     assert _shaped(text, "16,20480") and not _shaped(text, "64,20480")
+
+
+# ---------------------------------------------------------- the sampling tail
+
+@pytest.fixture(scope="module")
+def spec_step_text(one_chip, not_interpreted):
+    """The speculating (``W`` = 4) program of a small LLaMA-block model
+    over the chat cell's vocabulary."""
+    return _llama_step(one_chip, spec_window=WINDOW, hidden_size=1024,
+                       num_attention_heads=8, num_key_value_heads=8,
+                       intermediate_size=2048)[0].as_text()
+
+
+def _sides_of_the_conditionals(text):
+    """The compiled module's instruction lines in two lists: those the
+    entry computation reaches without entering a conditional's branch
+    (what every step runs), and those it reaches only through one."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    always, branched = set(), set()
+
+    def walk(comp, seen):
+        if comp in seen:
+            return
+        seen.add(comp)
+        for line in comps[comp]:
+            for ref in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.-]+)", line):
+                walk(ref, seen)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                for ref in re.findall(r"%([\w.-]+)", group):
+                    walk(ref, branched)
+
+    walk(entry, always)
+    lines = lambda names: [ln for c in sorted(names) for ln in comps[c]]
+    return lines(always), lines(branched - always)
+
+
+@pytest.mark.parametrize("step, rows", [
+    ("chat_step_text", "16,32000"), ("latent_step", "16,20480"),
+    ("streams_step", "32,131072"),
+    ("spec_step_text", "16,4,32000|4,16,32000|64,32000")])
+def test_step_sorts_the_vocabulary_once_and_inside_a_conditional(
+        request, step, rows):
+    """The sampling tail of the compiled step (``_process_rows``,
+    ``_pick_rows``): ONE sort over ``[max_batch, vocab]`` where there
+    were two, and it, the nucleus's cumulative sum and the draw's random
+    bits lie in branches of conditionals, so a step whose rows are all
+    greedy runs none of them.  The speculating program's tail runs under
+    a ``vmap`` over its window: its conditionals are conditionals too,
+    not selects over both sides."""
+    text = request.getfixturevalue(step)
+    text = text if isinstance(text, str) else text.as_text()
+    always, branched = _sides_of_the_conditionals(text)
+    wide_sort = r"= \(?f32\[(%s)\][^=]* sort\(" % rows
+    assert len([ln for ln in branched if re.search(wide_sort, ln)]) == 1
+    # (the experts' router sorts its [tokens, experts] scores on every step)
+    assert not [ln for ln in always if re.search(wide_sort, ln)]
+    assert len(re.findall(r" conditional\(", text)) >= 2
+    for what, mark in (("the nucleus's cumulative sum",
+                        r"= f32\[\S* reduce-window\("),
+                       ("the draw's random bits", "threefry")):
+        assert [ln for ln in branched if re.search(mark, ln)], what
+        # the speculating program's accept tail draws on every step (W6)
+        if step != "spec_step_text":
+            assert not [ln for ln in always if "lm_head_sample" in ln
+                        and re.search(mark, ln)], what

@@ -7,7 +7,7 @@ buffer to the device and one back, a serving step.
   program variants at two deployment shapes;
 * streams served through ``EngineCore`` over the packed interface equal
   the offline oracle token for token: greedy against ``generate()``,
-  sampled against the step's own sampler on the eager model's logits
+  sampled against the whole sampling chain on the eager model's logits
   under the request's fixed key, plain and speculating;
 * a serving step's StepLog record counts ONE array each way (two in with a
   grammar mask), ``h2d_bytes`` is what was put, and no step after the
@@ -22,13 +22,14 @@ import numpy as np
 import pytest
 
 import paddle_infer_tpu as pit
+import sampler_oracle
 from paddle_infer_tpu.core.tensor import Tensor
 from paddle_infer_tpu.inference.generation import (GenerationConfig,
                                                    PagedGenerationEngine)
 from paddle_infer_tpu.models import (GPTConfig, GPTForCausalLM,
                                      GPTMoEForCausalLM, MoEConfig)
-from paddle_infer_tpu.serving import EngineCore, RequestState
-from paddle_infer_tpu.serving import programs
+from paddle_infer_tpu.serving import (EngineCore, EngineSupervisor,
+                                      FaultPlane, FaultSpec, RequestState)
 from paddle_infer_tpu.serving import request as request_mod
 from paddle_infer_tpu.serving.programs import (CAPACITY_COUNTERS,
                                                DROPLESS_COUNTERS,
@@ -225,9 +226,11 @@ def test_greedy_streams_equal_generate(engine, ref, kw):
 
 
 def _sampled_oracle(model, ids, g, rid):
-    """The stream the step's own sampler draws from the EAGER model's
-    logits under the request's key: ``fold_in(PRNGKey(seed), rid)``, then
-    the generation step folded in, a token at a time."""
+    """The stream the whole sampling chain, run for the one row alone as
+    every step ran it before PR 39 (tests/sampler_oracle.py), draws from
+    the EAGER model's logits under the request's key:
+    ``fold_in(PRNGKey(seed), rid)``, then the generation step folded in,
+    a token at a time."""
     key = jax.random.fold_in(jax.random.PRNGKey(g.seed), rid)[None]
     samp = {"temperature": jnp.asarray([g.temperature], jnp.float32),
             "top_k": jnp.asarray([g.top_k or 0], jnp.int32),
@@ -241,8 +244,8 @@ def _sampled_oracle(model, ids, g, rid):
     for step in range(g.max_new_tokens):
         logits = model(Tensor(jnp.asarray(seq, jnp.int32)[None]))._data[:, -1]
         steps = jnp.asarray([step], jnp.int32)
-        proc = programs._process_rows(logits, samp, steps)
-        tok = int(programs._pick_rows(proc, samp, steps, key)[0])
+        proc = sampler_oracle.process_rows(logits, samp, steps)
+        tok = int(sampler_oracle.pick_rows(proc, samp, steps, key)[0])
         out.append(tok)
         seq.append(tok)
     return np.asarray(out, np.int32)
@@ -269,6 +272,51 @@ def test_sampled_streams_equal_the_oracle_under_a_fixed_key(engine, model,
     for ids, g, req in zip(prompts, cfgs, reqs):
         np.testing.assert_array_equal(
             req.result(), _sampled_oracle(model, ids, g, req.rid))
+
+
+def test_steplog_counts_the_rows_that_draw_and_filter(engine):
+    """Two greedy requests and one sampled through ``top_p`` 0.9 whose
+    prompt takes four steps: ``draw_rows`` / ``filter_rows`` read 0 / 0
+    until the step that samples its first token, 1 / 1 on that step and
+    its decode steps, 0 / 0 again when only the greedy rows are left."""
+    prompts = [_prompt(91, 6), _prompt(92, 9), _prompt(93, 30)]
+    cfgs = [GenerationConfig(max_new_tokens=12),
+            GenerationConfig(max_new_tokens=12),
+            GenerationConfig(max_new_tokens=5, do_sample=True, top_p=0.9,
+                             seed=3)]
+    reqs, steps = _serve(engine, prompts, cfgs, 8500)
+    counts = [(r["draw_rows"], r["filter_rows"]) for r in steps]
+    first = counts.index((1, 1))
+    n = len(reqs[2].result())
+    assert first >= 3 and n == 5
+    assert counts == ([(0, 0)] * first + [(1, 1)] * n
+                      + [(0, 0)] * (len(counts) - first - n))
+    assert len(counts) > first + n
+
+
+def test_a_failed_step_records_what_its_tail_was_asked_for(engine):
+    """The record of a step that raised in its launch carries the two
+    counts too: here a sampled row drawing through ``top_k`` beside a
+    sampled row with no filter."""
+    request_mod._rid_counter = itertools.count(8600)
+    plane = FaultPlane([FaultSpec("decode.step", at=3)])
+    core = EngineCore(engine, fault_plane=plane, **CORE_SHAPE)
+    sup = EngineSupervisor(core)
+    try:
+        reqs = [core.submit(_prompt(94, 5), GenerationConfig(
+                    max_new_tokens=6, do_sample=True, top_k=8, seed=1))[0],
+                core.submit(_prompt(95, 7), GenerationConfig(
+                    max_new_tokens=6, do_sample=True, temperature=1.2,
+                    seed=2))[0]]
+        for _ in range(400):
+            if all(r.done for r in reqs):
+                break
+            sup.run_once()
+        assert all(r.state is RequestState.DONE for r in reqs)
+        (failed,) = [r for r in core.steplog.records() if r["failed"]]
+        assert (failed["draw_rows"], failed["filter_rows"]) == (2, 1)
+    finally:
+        sup.close()
 
 
 def _watch_puts(engine, monkeypatch):
