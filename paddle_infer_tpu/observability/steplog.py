@@ -79,6 +79,54 @@ _SCHEMA = (
     ("launch_s", 0.0),           # signature, host-to-device puts, enqueue
     ("wait_s", 0.0),             # blocked in the read-back: the device's
                                  # run and the device-to-host copy
+    # parts of the phases above, timed by StepClock.child and by the
+    # prefix cache on the same two reads as the engine.ready,
+    # engine.emit_rows, engine.release, prefix.insert, prefix.evict spans
+    # of the trace (children of wait_s and host_s, not added to them)
+    ("ready_s", 0.0),            # of wait_s, until the device's result
+                                 # was ready (block_until_ready); the rest
+                                 # is the copy to the host and whatever
+                                 # kept the thread from it
+    ("emit_rows_s", 0.0),        # the per-row loop after the read-back
+                                 # (tokens to their streams, spans, the
+                                 # grammar), less the release_s inside it
+    ("release_s", 0.0),          # step record: the iteration's
+                                 # _release_slot_kv calls of rows leaving
+                                 # the batch, summed (each is its evict
+                                 # record's wall_s)
+    ("finished_rows", 0),        # step record: how many rows left the
+                                 # batch this iteration (its evict records)
+    ("insert_s", 0.0),           # PrefixCache.insert of finished
+                                 # sequences' pages: evict record, this
+                                 # release's; step record, the sum since
+                                 # the previous step's record
+    ("evict_s", 0.0),            # the prefix cache's loops over
+                                 # _evict_one (enforce_watermark at a
+                                 # release, ensure_free wherever it is
+                                 # called): evict record, this release's;
+                                 # step record, all since the previous
+                                 # step's record, admission's included
+    ("evicted_blocks", 0),       # blocks those loops evicted (same deltas)
+    ("evict_scanned_nodes", 0),  # tree entries their searches for a
+                                 # victim examined (same deltas)
+    ("retained_blocks", 0),      # evict record: blocks the prefix cache
+                                 # holds after the release
+    ("gc_s", 0.0),               # step record: seconds of the
+                                 # interpreter's collections of generation
+                                 # 1 or 2, on any thread, that passed
+                                 # between the previous step record's end
+                                 # and this one's (one still running at
+                                 # the record is split between the two)
+    ("gc_gen2", 0),              # generation-2 collections that began in
+                                 # that interval
+    ("cpu_s", 0.0),              # step record: the engine thread's own
+                                 # CPU seconds (time.thread_time) from
+                                 # t_begin to the record; where the kernel
+                                 # accounts by the tick, in steps of 10 ms
+    ("off_cpu_s", 0.0),          # step record: the iteration's wall less
+                                 # wait_s less cpu_s, not under 0: seconds
+                                 # of the host phases in which the thread
+                                 # did not run (the GIL, the scheduler)
     ("attended_keys", 0),        # query-key pairs the step's attention
                                  # must compute (sum qlen*ctx + tri(qlen))
     ("resident_tokens", 0),      # cached tokens the step reads (sum over
@@ -181,6 +229,7 @@ _SCHEMA = (
                                  # across those rows this step
 )
 SCHEMA_KEYS = tuple(k for k, _ in _SCHEMA)
+_SCHEMA_KEYSET = frozenset(SCHEMA_KEYS)   # built once: record() checks every call
 
 
 class StepCostModel:
@@ -509,7 +558,7 @@ class StepLog:
         """Append one record; unknown fields are a programming error
         (the schema is a contract with /steps consumers and the docs
         table), missing fields take their schema defaults."""
-        unknown = set(fields) - set(SCHEMA_KEYS)
+        unknown = fields.keys() - _SCHEMA_KEYSET
         if unknown:
             raise ValueError(f"unknown StepLog fields: {sorted(unknown)}")
         rec = dict(_SCHEMA)

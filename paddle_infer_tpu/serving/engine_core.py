@@ -50,7 +50,7 @@ from ..inference.generation import (GenerationConfig, PagedGenerationEngine,
                                     _round_up)
 from ..observability import Tracer, get_compile_log
 from ..observability.journey import JourneyStore
-from ..observability.stepclock import StepClock
+from ..observability.stepclock import GcWatch, Span, StepClock
 from ..observability.steplog import StepCostModel, StepLog
 from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
@@ -456,6 +456,12 @@ class EngineCore:
         # the previous serving step's end, for the next record's gap_s
         self._clock: Optional[StepClock] = None
         self._last_step_end: Optional[float] = None
+        # the interpreter's collections (on the list of gc's callbacks
+        # from the first iteration to close()), and what the last step
+        # record had seen of the prefix cache's eviction counters: a step
+        # record carries the deltas
+        self._gc = GcWatch()
+        self._cache_seen = self._cache_counters()
         # chunk-boundary notification (fleet handoff): called with the
         # Request, by the stepping thread under the step lock, the step
         # its prompt finishes prefilling.  Must be fast and reentrant-
@@ -970,6 +976,8 @@ class EngineCore:
         # one clock for the iteration: each phase boundary is read once,
         # written into the step's StepLog record and mirrored as an
         # engine.* span into the profiler's trace (no-op without one)
+        if not self._closed:
+            self._gc.install()
         clock = self._clock = StepClock("engine", self._step_idx + 1,
                                         "admit")
         try:
@@ -977,20 +985,54 @@ class EngineCore:
         finally:
             clock.close()
 
-    def _phase_fields(self, clock: StepClock, end: float) -> dict:
-        """The iteration's phases as fields of this step's one StepLog
-        record: ``t_begin`` and the durations tile the iteration up to
-        ``end``, ``gap_s`` reaches back to the previous step's end.  A
+    def _iteration_fields(self, clock: StepClock, end: float) -> dict:
+        """The iteration as fields of this step's one StepLog record.
+        ``t_begin`` and the phases' durations tile the iteration up to
+        ``end``, ``gap_s`` reaches back to the previous step's end; a
         step that failed in its launch never waited: it records what it
-        reached."""
+        reached.  The clock's children are parts of those phases; the
+        thread's own CPU seconds say how much of the host phases it ran.
+        The prefix cache's eviction loops are deltas since the previous
+        step's record, the interpreter's collections what passed between
+        that record's ``end`` and this one's."""
         d = clock.durations(end)
         last, self._last_step_end = self._last_step_end, end
+        wait_s = d.get("wait", 0.0)
+        cpu_s = time.thread_time() - clock.cpu_begin
+        seen, self._cache_seen = self._cache_seen, self._cache_counters()
+        evict_s, blocks, scanned, insert_s = (
+            b - a for a, b in zip(seen, self._cache_seen))
+        gc_s, gc_gen2 = self._gc.book(
+            clock.t_begin if last is None else last, end)
         return dict(
             t_begin=clock.t_begin, step=clock.step_num,
             gap_s=clock.t_begin - last if last is not None else 0.0,
             admit_s=d.get("admit", 0.0), pack_s=d.get("pack", 0.0),
-            launch_s=d.get("launch", 0.0), wait_s=d.get("wait", 0.0),
-            host_s=d.get("emit", 0.0))
+            launch_s=d.get("launch", 0.0), wait_s=wait_s,
+            host_s=d.get("emit", 0.0),
+            ready_s=clock.child_seconds("ready"),
+            cpu_s=cpu_s,
+            off_cpu_s=max(0.0, end - clock.t_begin - wait_s - cpu_s),
+            release_s=clock.child_seconds("release"),
+            finished_rows=clock.child_count("release"),
+            insert_s=insert_s, evict_s=evict_s, evicted_blocks=blocks,
+            evict_scanned_nodes=scanned, gc_s=gc_s, gc_gen2=gc_gen2)
+
+    def _child(self, name: str) -> Span:
+        """A timed part of the running phase: a child span of the
+        iteration's clock; without one alive (``close()``) the same two
+        reads and no span."""
+        clock = self._clock
+        return clock.child(name) if clock is not None else Span()
+
+    def _cache_counters(self):
+        """The prefix cache's cumulative eviction seconds, evicted blocks,
+        scanned entries and insert seconds (zeros without a cache)."""
+        cache = self._prefix_cache
+        if cache is None:
+            return (0.0, 0, 0, 0.0)
+        return (cache.evict_seconds, cache.evicted_blocks,
+                cache.evict_scanned_nodes, cache.insert_seconds)
 
     def _program_temp_bytes(self, key) -> int:
         """The compiled step's temporaries, from the memory analysis the
@@ -1790,7 +1832,7 @@ class EngineCore:
                 resident_tokens=resident_tokens_step,
                 h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
                 draw_rows=draw_rows_step, filter_rows=filter_rows_step,
-                **self._phase_fields(clock, end),
+                **self._iteration_fields(clock, end),
                 active_rows=len(active), decode_rows=n_decode,
                 chunk_steps=1, prefill_tokens=prefill_tokens_step,
                 prefill_chunk_tokens=prefill_tokens_step, token_slots=C,
@@ -1823,7 +1865,18 @@ class EngineCore:
             self._decode_warm = True
         # the one designed sync per step: every host-bound output of the
         # program is read here (ONE array, step_output_layout), the
-        # fields sliced out of it as views
+        # fields sliced out of it as views.  The wait is read in two
+        # halves: engine.ready until the device's result is there, the
+        # rest of the phase until it is on the host.  The copy is asked
+        # for BEFORE the first half's wait, as np.asarray alone would:
+        # asked for after it, every step pays the host's wake-up and the
+        # copy's own latency one after the other (0.1-0.35 ms a step on
+        # the chip, PERF.md section 6)
+        with clock.child("ready"):
+            for o in step_outs:
+                o.copy_to_host_async()
+            # tpulint: disable-next-line=host-sync -- the first half of the one per-step sync point: the array the next line reads anyway
+            jax.block_until_ready(step_outs)
         # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
         host_outs = [np.asarray(o) for o in step_outs]
         out = self._step_out.views(host_outs[0])
@@ -1867,99 +1920,105 @@ class EngineCore:
         now = time.monotonic()
         span_name = ("prefill" if self._prefix_cache is None
                      else "suffix_prefill")
-        for s in active:
-            i = s["sid"]
-            req = s["req"]
-            if qlens[i] == 0:
-                continue            # starved chunk row: untouched
-            was_chunk = i in chunk_taken
-            if was_chunk:
-                n = chunk_taken[i]
-                s["pending"] = s["pending"][n:]
-                s["ctx"] += n
-            sampled = bool(sample_now[i])
-            if n_emit is None:
-                t_row = (np.asarray([int(tok[i])], np.int32) if sampled
-                         else np.zeros((0,), np.int32))
-            else:
-                # speculative step: row i emits its accepted window
-                # prefix (always >= 1 token when it sampled) — the one
-                # intended host readback of this step's tokens
-                # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
-                t_row = np.asarray(tok[i, :int(n_emit[i])], np.int32)
-            bad = t_row.size > 0 and int(t_row.min()) < 0
-            if req.rid in poisoned or (sampled and bad):
-                self._metrics.on_quarantined()
-                self._evict(s, RequestState.FAILED, QuarantinedError(
-                    f"request {req.rid} quarantined: non-finite logits "
-                    f"in mixed step {self._step_idx}"))
-                evicted.append(req.rid)
-                continue
-            if was_chunk:
-                self.tracer.add_span(
-                    req.rid, span_name, s.get("span_end", t0), now,
-                    slot=i, plen=chunk_taken[i],
-                    cached_tokens=int(s["ctx"]) - chunk_taken[i],
-                    replay=req.retries)
-                s["span_end"] = now
-                if sampled:
-                    # prefill complete: this chunk held the prompt's
-                    # last token and sampled the row's next token
-                    if s["steps_base"] == 0:
-                        self._metrics.on_prefill(now - req.arrival)
+        # the per-row loop is one child span; a row that leaves the batch
+        # inside it (engine.release, nested) delays the rows behind it
+        released_before = clock.child_seconds("release")
+        with clock.child("emit_rows") as rows_span:
+            for s in active:
+                i = s["sid"]
+                req = s["req"]
+                if qlens[i] == 0:
+                    continue            # starved chunk row: untouched
+                was_chunk = i in chunk_taken
+                if was_chunk:
+                    n = chunk_taken[i]
+                    s["pending"] = s["pending"][n:]
+                    s["ctx"] += n
+                sampled = bool(sample_now[i])
+                if n_emit is None:
+                    t_row = (np.asarray([int(tok[i])], np.int32) if sampled
+                             else np.zeros((0,), np.int32))
+                else:
+                    # speculative step: row i emits its accepted window
+                    # prefix (always >= 1 token when it sampled) — the one
+                    # intended host readback of this step's tokens
+                    # tpulint: disable-next-line=host-sync -- the sampled step output must reach Python for emission; this is the deliberate per-step sync point
+                    t_row = np.asarray(tok[i, :int(n_emit[i])], np.int32)
+                bad = t_row.size > 0 and int(t_row.min()) < 0
+                if req.rid in poisoned or (sampled and bad):
+                    self._metrics.on_quarantined()
+                    self._evict(s, RequestState.FAILED, QuarantinedError(
+                        f"request {req.rid} quarantined: non-finite logits "
+                        f"in mixed step {self._step_idx}"))
+                    evicted.append(req.rid)
+                    continue
+                if was_chunk:
+                    self.tracer.add_span(
+                        req.rid, span_name, s.get("span_end", t0), now,
+                        slot=i, plen=chunk_taken[i],
+                        cached_tokens=int(s["ctx"]) - chunk_taken[i],
+                        replay=req.retries)
+                    s["span_end"] = now
+                    if sampled:
+                        # prefill complete: this chunk held the prompt's
+                        # last token and sampled the row's next token
+                        if s["steps_base"] == 0:
+                            self._metrics.on_prefill(now - req.arrival)
+                        # tpulint: disable-next-line=determinism -- container-coarse slot read: t_row is the device step output; the slot dict's wall-clock bookkeeping (last_emit, span ends) is sibling metadata
+                        req._emit(t_row)
+                        self._metrics.on_tokens(int(t_row.size))
+                        s["emitted"] += int(t_row.size)
+                        s["last_tok"] = int(t_row[-1])
+                        s["last_emit"] = now
+                        emitted_prefill += int(t_row.size)
+                        prefill_done.append(req)
+                else:
                     # tpulint: disable-next-line=determinism -- container-coarse slot read: t_row is the device step output; the slot dict's wall-clock bookkeeping (last_emit, span ends) is sibling metadata
                     req._emit(t_row)
-                    self._metrics.on_tokens(int(t_row.size))
                     s["emitted"] += int(t_row.size)
                     s["last_tok"] = int(t_row[-1])
                     s["last_emit"] = now
-                    emitted_prefill += int(t_row.size)
-                    prefill_done.append(req)
-            else:
-                # tpulint: disable-next-line=determinism -- container-coarse slot read: t_row is the device step output; the slot dict's wall-clock bookkeeping (last_emit, span ends) is sibling metadata
-                req._emit(t_row)
-                s["emitted"] += int(t_row.size)
-                s["last_tok"] = int(t_row[-1])
-                s["last_emit"] = now
-                emitted_decode += int(t_row.size)
-                if i in drafted:
-                    draft_accepted_step += max(int(t_row.size) - 1, 0)
-                self.tracer.add_span(req.rid, "decode",
-                                     s.get("span_end", t0), now,
-                                     step=self._step_idx, chunk_steps=1,
-                                     tokens=int(t_row.size))
-                s["span_end"] = now
-            if sampled and s.get("fsm") is not None:
-                gf = req.grammar_fsm
-                if t_row.size:
-                    # FSM state stays a pure function of emitted tokens:
-                    # re-fold the accepted row output (masking makes
-                    # violations impossible; count defensively anyway)
-                    s["fsm"], viol = grammar_rt.advance_many(
-                        gf, s["fsm"], t_row, s["g"].eos_token_id)
-                    self._grammar_violations += viol
-                if bool(fin_out[i]) or gf.complete(s["fsm"]):
-                    # EOS (mask-legal only in accept states) or the
-                    # grammar has no continuation: stream is complete
+                    emitted_decode += int(t_row.size)
+                    if i in drafted:
+                        draft_accepted_step += max(int(t_row.size) - 1, 0)
+                    self.tracer.add_span(req.rid, "decode",
+                                         s.get("span_end", t0), now,
+                                         step=self._step_idx, chunk_steps=1,
+                                         tokens=int(t_row.size))
+                    s["span_end"] = now
+                if sampled and s.get("fsm") is not None:
+                    gf = req.grammar_fsm
+                    if t_row.size:
+                        # FSM state stays a pure function of emitted tokens:
+                        # re-fold the accepted row output (masking makes
+                        # violations impossible; count defensively anyway)
+                        s["fsm"], viol = grammar_rt.advance_many(
+                            gf, s["fsm"], t_row, s["g"].eos_token_id)
+                        self._grammar_violations += viol
+                    if bool(fin_out[i]) or gf.complete(s["fsm"]):
+                        # EOS (mask-legal only in accept states) or the
+                        # grammar has no continuation: stream is complete
+                        self._evict(s, RequestState.DONE)
+                        evicted.append(req.rid)
+                    elif s["emitted"] >= s["g"].max_new_tokens:
+                        if gf.accepting(s["fsm"]):
+                            self._evict(s, RequestState.DONE)
+                        else:
+                            self._grammar_incomplete += 1
+                            self._evict(s, RequestState.FAILED,
+                                        GrammarIncompleteError(
+                                            f"request {req.rid} exhausted "
+                                            f"max_new_tokens="
+                                            f"{s['g'].max_new_tokens} in "
+                                            f"non-accepting FSM state "
+                                            f"{int(s['fsm'])}"))
+                        evicted.append(req.rid)
+                elif sampled and (bool(fin_out[i])
+                                  or s["emitted"] >= s["g"].max_new_tokens):
                     self._evict(s, RequestState.DONE)
                     evicted.append(req.rid)
-                elif s["emitted"] >= s["g"].max_new_tokens:
-                    if gf.accepting(s["fsm"]):
-                        self._evict(s, RequestState.DONE)
-                    else:
-                        self._grammar_incomplete += 1
-                        self._evict(s, RequestState.FAILED,
-                                    GrammarIncompleteError(
-                                        f"request {req.rid} exhausted "
-                                        f"max_new_tokens="
-                                        f"{s['g'].max_new_tokens} in "
-                                        f"non-accepting FSM state "
-                                        f"{int(s['fsm'])}"))
-                    evicted.append(req.rid)
-            elif sampled and (bool(fin_out[i])
-                              or s["emitted"] >= s["g"].max_new_tokens):
-                self._evict(s, RequestState.DONE)
-                evicted.append(req.rid)
+        emit_rows_s = rows_span.seconds - (
+            clock.child_seconds("release") - released_before)
         if emitted_decode:
             self._metrics.on_tokens(emitted_decode, itl_s=synced)
         self._metrics.on_step(synced * 1e3, len(active), b)
@@ -1985,7 +2044,8 @@ class EngineCore:
         end = time.monotonic()
         self.steplog.record(
             kind, wall_s=end - t0, dispatch_s=t_sync - t0,
-            **self._phase_fields(clock, end),
+            **self._iteration_fields(clock, end),
+            emit_rows_s=emit_rows_s,
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
             decode_keys=decode_keys_step,
@@ -2062,15 +2122,23 @@ class EngineCore:
             pages = len(self._pool.block_table(slot["sid"]))
         except Exception:
             pages = 0
-        t0 = time.monotonic()
-        self._release_slot_kv(slot["sid"], slot.get("match"),
-                              retain_tokens=retain,
-                              salt=req.route_salt())
-        wall = time.monotonic() - t0
+        before = self._cache_counters()
+        with self._child("release") as release:
+            self._release_slot_kv(slot["sid"], slot.get("match"),
+                                  retain_tokens=retain,
+                                  salt=req.route_salt())
+        wall = release.seconds
+        evict_s, blocks, scanned, insert_s = (
+            b - a for a, b in zip(before, self._cache_counters()))
+        cache = self._prefix_cache
         bts, fl, src_tag = self._cost_model.estimate("evict",
                                                      pages_touched=pages)
         self.steplog.record(
             "evict", wall_s=wall, host_s=wall,
+            insert_s=insert_s, evict_s=evict_s, evicted_blocks=blocks,
+            evict_scanned_nodes=scanned,
+            retained_blocks=(cache.cached_blocks
+                             if cache is not None else 0),
             active_rows=self.active_count, pages_freed=pages,
             resident_kv_pages=self._used_pages(),
             bytes_est=bts, flops_est=fl, cost_source=src_tag,
@@ -2815,6 +2883,7 @@ class EngineCore:
         if self._closed:
             return
         self._closed = True
+        self._gc.remove()
         stopped = self.stop(timeout)
         # the loop thread is joined, but callers driving run_once()
         # from their own threads may still be mid-step — hold the step

@@ -40,6 +40,11 @@ Eviction is leaf-first LRU over entries with ``pins == 0``: partial
 entries and childless nodes.  It runs on demand (``ensure_free``) when
 admission needs blocks, and after every release (``enforce_watermark``)
 to keep the cache under ``watermark × pool_blocks`` retained blocks.
+Both time their loop over ``_evict_one`` as one ``prefix.evict`` span
+(``insert`` is a ``prefix.insert`` span) whose seconds also add up in
+``evict_seconds`` (``insert_seconds``), beside ``evicted_blocks`` and
+``evict_scanned_nodes``, the entries the searches for a victim examined:
+the engine writes the deltas into its StepLog records.
 
 Host-tier demotion (serving/kv_tier/): when a demote hook is wired
 onto ``_tier_demote``, evicting a FULL node hands ``(salt, token path,
@@ -53,6 +58,8 @@ from __future__ import annotations
 import logging
 import threading
 from typing import Dict, List, Optional, Tuple
+
+from ...observability.stepclock import Span
 
 _log = logging.getLogger(__name__)
 
@@ -140,6 +147,12 @@ class PrefixCache:
         self.prompt_tokens_total = 0
         self.inserts = 0
         self.evicted_blocks = 0
+        # entries (block-holding nodes and partial tails) the searches for
+        # a victim examined, summed over the calls of ``_evict_one``
+        self.evict_scanned_nodes = 0
+        # [seconds, count] of the prefix.evict and prefix.insert spans
+        self._evict_spans = [0.0, 0]
+        self._insert_spans = [0.0, 0]
         self.cow_copies = 0
         self.cached_blocks = 0      # gauge: blocks the tree holds refs on
         self.node_count = 0         # gauge: full-page nodes
@@ -331,6 +344,14 @@ class PrefixCache:
         Existing entries win dedup — the duplicate block stays owned by
         the sequence and returns to the pool when the sequence is freed.
         Returns the number of newly retained blocks."""
+        with Span("prefix.insert", self._insert_spans):
+            return self._insert(tokens, blocks, salt)
+
+    @property
+    def insert_seconds(self) -> float:
+        return self._insert_spans[0]
+
+    def _insert(self, tokens, blocks, salt) -> int:
         toks = [int(t) for t in tokens]
         with self._lock:
             self._clock += 1
@@ -438,6 +459,10 @@ class PrefixCache:
         return out
 
     def _evict_one(self, demote: bool = True) -> bool:
+        # the walk examines every entry that holds a block, whatever it
+        # finds: one addition a call (a search that pops a heap or an LRU
+        # list counts the entries it popped here instead)
+        self.evict_scanned_nodes += self.cached_blocks
         cands = self._candidates()
         if not cands:
             return False
@@ -465,22 +490,34 @@ class PrefixCache:
         self.evicted_blocks += 1
         return True
 
+    @property
+    def evict_seconds(self) -> float:
+        return self._evict_spans[0]
+
     def ensure_free(self, need_free: int) -> bool:
         """Evict LRU entries until the pool has ``need_free`` free blocks
-        (or nothing more is evictable).  Returns success."""
+        (or nothing more is evictable).  Returns success.  A call that
+        has anything to evict is one ``prefix.evict`` span."""
         with self._lock:
-            while self._pool.free_blocks < need_free:
-                if not self._evict_one():
-                    return False
+            if self._pool.free_blocks >= need_free:
+                return True
+            with Span("prefix.evict", self._evict_spans):
+                while self._pool.free_blocks < need_free:
+                    if not self._evict_one():
+                        return False
             return True
 
     def enforce_watermark(self):
-        """Evict down to ``watermark × pool_blocks`` retained blocks."""
+        """Evict down to ``watermark × pool_blocks`` retained blocks
+        (one ``prefix.evict`` span where there is anything to evict)."""
         cap = int(self.watermark * self._pool.num_blocks)
         with self._lock:
-            while self.cached_blocks > cap:
-                if not self._evict_one():
-                    break
+            if self.cached_blocks <= cap:
+                return
+            with Span("prefix.evict", self._evict_spans):
+                while self.cached_blocks > cap:
+                    if not self._evict_one():
+                        break
 
     def clear(self):
         """Drop every unpinned entry (engine close / restart).  Never
@@ -508,6 +545,7 @@ class PrefixCache:
                                 if self.prompt_tokens_total else 0.0),
                 "inserts": self.inserts,
                 "evicted_blocks": self.evicted_blocks,
+                "evict_scanned_nodes": self.evict_scanned_nodes,
                 "cow_copies": self.cow_copies,
                 "cached_blocks": self.cached_blocks,
                 "nodes": self.node_count,

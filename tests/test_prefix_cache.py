@@ -230,6 +230,76 @@ def test_prefix_cache_fuzz():
     assert snap["cached_blocks"] == 0 and snap["nodes"] == 0
 
 
+def test_scanned_nodes_equal_a_brute_force_count_of_the_walk():
+    """With N retained blocks, evicting k: ``evict_scanned_nodes`` rises
+    by what the k searches for a victim examined, each the whole tree as
+    it then stood; the loop's seconds add up beside it."""
+    page = 4
+    pool = native.KVBlockPool(64, page)
+    cache = PrefixCache(pool, page, watermark=1.0)
+    rng = random.Random(3)
+    for seq in range(8):                 # branching prefixes and tails
+        tokens = [rng.randrange(3) for _ in range(rng.randrange(5, 23))]
+        pool.reserve(seq, len(tokens))
+        cache.insert(tokens, pool.block_table(seq))
+        pool.free(seq)
+    n = cache.cached_blocks
+    assert n == len(_tree_blocks(cache)) >= 12
+    assert cache.insert_seconds > 0.0 and cache.evict_seconds == 0.0
+    walked = []
+    real = cache._candidates
+
+    def counting():
+        walked.append(len(_tree_blocks(cache)))      # the walk, by hand
+        return real()
+
+    cache._candidates = counting
+    k = 5
+    assert cache.ensure_free(pool.free_blocks + k)
+    assert cache.evicted_blocks == k
+    assert walked == [n - i for i in range(k)]
+    assert cache.evict_scanned_nodes == sum(walked)
+    snap = cache.stats_snapshot()
+    assert snap["evict_scanned_nodes"] == sum(walked)
+    assert snap["evicted_blocks"] == k and snap["cached_blocks"] == n - k
+    # a call with nothing to evict walks nothing and times nothing
+    seconds = cache.evict_seconds
+    assert seconds > 0.0 and cache.ensure_free(0)
+    cache.enforce_watermark()
+    assert cache.evict_seconds == seconds and len(walked) == k
+
+
+def test_admission_eviction_lands_in_the_step_record(make_core):
+    """Watermark 1.0 evicts nothing at a release, so what the pool lacks
+    is evicted by ``ensure_free`` at admission: the step record of that
+    iteration carries the walk, and no evict record does."""
+    core = make_core(prefix_cache_watermark=1.0)
+    cache = core.prefix_cache
+    g = GenerationConfig(max_new_tokens=4)
+    for seed in range(30, 42):
+        (r,) = core.submit(_prompt(seed, 20), g)
+        _drive(core, [r])
+    assert cache.evicted_blocks > 0
+    recs = core.steplog.records()
+    steps = [r for r in recs if r["t_begin"] > 0.0]
+    evicts = [r for r in recs if r["kind"] == "evict"]
+    assert len(evicts) == 12
+    assert all(e["evicted_blocks"] == 0 and e["evict_s"] == 0.0
+               and e["insert_s"] > 0.0 for e in evicts)
+    walked = [r for r in steps if r["evicted_blocks"]]
+    assert walked and all(
+        r["finished_rows"] == 0 and r["release_s"] == 0.0
+        and 0.0 < r["evict_s"] <= r["admit_s"]
+        and r["evict_scanned_nodes"] >= r["evicted_blocks"] for r in walked)
+    assert sum(r["evicted_blocks"] for r in steps) == cache.evicted_blocks
+    assert sum(r["evict_scanned_nodes"] for r in steps) == \
+        cache.evict_scanned_nodes
+    assert sum(r["evict_s"] for r in steps) == pytest.approx(
+        cache.evict_seconds, rel=1e-9)
+    assert core.metrics_snapshot()["prefix_cache"]["evict_scanned_nodes"] \
+        == cache.evict_scanned_nodes
+
+
 # --------------------------------------------------------------- parity
 def test_chunk_logits_bitwise_equal_warm_and_cold(model):
     """Cold full prefill vs warm suffix prefill over shared blocks,
