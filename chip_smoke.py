@@ -84,7 +84,7 @@ TINY = dict(
                window=3, chunk=16),
     flash=[dict(b=1, s=128, h=2, d=64, causal=False),
            dict(b=1, s=256, h=2, d=64, causal=True)],
-    latent=dict(b=3, h=4, rank=16, rope=8, page=16, max_pages=6, pool=19,
+    latent=dict(b=4, h=4, rank=16, rope=8, page=16, max_pages=20, pool=24,
                 chunk=16),
     grouped=dict(rows=32, hidden=64, ffn=32, experts=4),
 )
@@ -791,17 +791,39 @@ def child_kernels(args):
     # queries scaled so the softmax is neither flat nor one-hot
     lq = jax.random.normal(lk[1], (b, h, width), jnp.bfloat16)
     scale = 1.0 / float(np.sqrt(width))
-    got = compiled(lambda q, pool, t, n: LA.latent_paged_decode(
-        q, pool, t, n, scale, rank), lq, lpool, ltab, lctx + 1)
-    with jax.default_matmul_precision("highest"):
-        # each query as the first of a chunk of two, the chunks end to
-        # end on the composition's flat token axis
-        want = jax.jit(lambda q, pool, t, c: LA.latent_chunk_attention(
-            jnp.stack([q, jnp.zeros_like(q)], 1).reshape(
-                (2 * b,) + q.shape[1:]).astype(jnp.float32),
-            pool.astype(jnp.float32), t, c, jnp.full((b,), 2, jnp.int32),
-            scale, rank)[0::2])(lq, lpool, ltab, lctx)
-    close("latent_paged_decode", got, want)
+    # each query as the first of a chunk of two, the chunks end to end
+    # on the composition's flat token axis
+    composed = jax.jit(lambda q, pool, t, c: LA.latent_chunk_attention(
+        jnp.stack([q, jnp.zeros_like(q)], 1).reshape(
+            (2 * b,) + q.shape[1:]).astype(jnp.float32),
+        pool.astype(jnp.float32), t, c, jnp.full((b,), 2, jnp.int32),
+        scale, rank)[0::2])
+    # the served step's mix beside the whole table: every third row dead
+    # among live ones, the longest live row a walk of three grid steps of
+    # the table's many, lengths on a grid step's border and one past it.
+    # ONE executable: the grid's bounds are traced values
+    _, span, table_steps = LA.walk_geometry(page, mp)
+    mixed_np = rs.randint(1, 2 * span + 2, (b,))
+    mixed_np[0], mixed_np[-1] = span, 2 * span + 1
+    mixed_np[1::3] = 0
+    decode = jax.jit(lambda q, pool, t, n: LA.latent_paged_decode(
+        q, pool, t, n, scale, rank)).lower(
+            lq, lpool, ltab, lctx + 1).compile()
+    if not args.rehearse_cpu:
+        assert "tpu_custom_call" in decode.as_text()
+    for name, n_np in (("latent_paged_decode", lctx_np + 1),
+                       ("latent_paged_decode[live rows]", mixed_np)):
+        n = jnp.asarray(n_np, jnp.int32)
+        got = decode(lq, lpool, ltab, n)
+        alive = (n > 0)[:, None, None]
+        assert not np.asarray(jnp.where(alive, 0, got).astype(
+            jnp.float32)).any(), f"{name}: a dead row does not read zero"
+        with jax.default_matmul_precision("highest"):
+            want = composed(lq, lpool, ltab, jnp.maximum(n - 1, 0))
+        close(name, got, jnp.where(alive, want, 0))
+        rows, walk = (int(x) for x in LA.decode_grid(n, page, mp)[1:])
+        print(f"{name}: grid {rows} x {walk} of {b} x {table_steps}",
+              flush=True)
 
     # ---- grouped matmul: sorted rows, uneven groups, an untouched expert
     g = spec["grouped"]

@@ -134,6 +134,14 @@ _SCHEMA = (
     ("decode_keys", 0),          # of attended_keys, those of the decode
                                  # rows (sum over rows with qlen == 1 of
                                  # ctx + 1)
+    ("decode_grid_steps", 0),    # grid steps of one layer's latent decode
+                                 # launch: live decode rows x the walk
+                                 # of the longest one (ceil((ctx + 1) /
+                                 # keys a grid step), the kernel's own
+                                 # decode_grid); 0 with no decode row or
+                                 # no latent pages; at most max_batch x
+                                 # ceil(max_pages / pages a grid step),
+                                 # a full batch of full-length rows
     ("draw_rows", 0),            # rows that drew their token this step
                                  # (sample_now and do_sample); a step
                                  # with none ran no categorical draw

@@ -20,8 +20,14 @@ its own:
   heads of a row against its pages, ``pages_per_step`` pages a grid step
   (the same pool passed that many times, each with its own page index
   from the scalar-prefetched table), both contractions on the MXU,
-  online softmax in VMEM scratch.  Pages past a row's length repeat the
-  row's last valid block index, so the pipeline issues no copy for them.
+  online softmax in VMEM scratch.  The grid is the step's live work
+  (``decode_grid``), both dimensions traced values of the one compiled
+  step: the live rows, compacted to the front of a scalar-prefetched
+  list the index maps read through, by the walk of the longest live
+  row; a grid step costs its index arithmetic live or skipped, so a
+  dead row gets none and no row walks the table past the longest.
+  Inside the walk, pages past a row's length repeat the row's last
+  valid block index, so the pipeline issues no copy for them.
 * ``latent_chunk_attention`` — chunk rows (``query_len > 1``) against
   their gathered window, one row at a time in a loop over the chunk rows
   alone: a row that carries no chunk costs nothing, and the widest
@@ -39,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -68,11 +75,46 @@ def write_latent_pages(pages, block_tables, rows, context_lens, query_lens):
 
 # ------------------------------------------------------------------ decode
 
-def _decode_kernel(lengths_ref, tables_ref, q_ref, *rest, scale, page_size,
-                   pages_per_step, value_width, steps):
+def walk_geometry(page_size, max_pages, pages_per_step=PAGES_PER_STEP):
+    """``(pages a grid step, keys a grid step, grid steps of a whole
+    table)`` of the decode kernel's walk."""
+    g = max(1, min(int(pages_per_step), int(max_pages)))
+    return g, g * int(page_size), -(-int(max_pages) // g)
+
+
+def decode_grid(lengths, page_size, max_pages,
+                pages_per_step=PAGES_PER_STEP):
+    """The decode kernel's grid for one step's ``lengths`` [B]:
+    ``(live, rows, walk)`` — the rows with ``lengths > 0`` compacted to
+    the front of ``live`` [B] (the tail repeats row 0), their count and
+    the grid steps of the longest one's walk; both at least 1, so a step
+    with no decode row launches one step over a dead row."""
+    _, span, steps = walk_geometry(page_size, max_pages, pages_per_step)
+    alive = lengths > 0
+    live = jnp.nonzero(alive, size=lengths.shape[0], fill_value=0)[0]
+    rows = jnp.maximum(jnp.sum(alive.astype(jnp.int32)), 1)
+    walk = jnp.clip(-(-jnp.max(lengths) // span), 1, steps)
+    return live.astype(jnp.int32), rows, walk.astype(jnp.int32)
+
+
+def decode_grid_steps(decode_lengths, page_size, max_pages,
+                      pages_per_step=PAGES_PER_STEP) -> int:
+    """``rows x walk`` of ``decode_grid`` for a step's decode rows'
+    lengths (host integers, every one > 0), 0 for none: what the packer
+    books as StepLog ``decode_grid_steps``."""
+    decode_lengths = np.asarray(decode_lengths)
+    if not decode_lengths.size:
+        return 0
+    _, span, steps = walk_geometry(page_size, max_pages, pages_per_step)
+    return decode_lengths.size * min(
+        max(-(-int(decode_lengths.max()) // span), 1), steps)
+
+
+def _decode_kernel(lengths_ref, tables_ref, live_ref, q_ref, *rest, scale,
+                   page_size, pages_per_step, value_width):
     page_refs = rest[:pages_per_step]
     o_ref, m_ref, l_ref, acc_ref = rest[pages_per_step:]
-    b = pl.program_id(0)
+    b = live_ref[pl.program_id(0)]
     j = pl.program_id(1)
     span = pages_per_step * page_size
 
@@ -104,7 +146,7 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, *rest, scale, page_size,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    @pl.when(j == steps - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-20)
                     ).astype(o_ref.dtype)
@@ -123,6 +165,10 @@ def latent_paged_decode(q, pages, block_tables, lengths, scale,
     lengths      [B] int32       — tokens in cache, the current included;
                                    0 skips the row (its output is zero)
     → [B, H, value_width] in q's dtype
+
+    The grid is ``decode_grid(lengths)``: live rows x the longest live
+    row's walk, traced bounds of one executable.  A dead row's output
+    block is never visited; the mask after the launch makes it zero.
     """
     interpret = _interpret() if interpret is None else interpret
     b, h, width = q.shape
@@ -130,16 +176,17 @@ def latent_paged_decode(q, pages, block_tables, lengths, scale,
     assert 0 < value_width < width <= lanes, (q.shape, pages.shape)
     q = pad_lanes(q, lanes)
     max_pages = block_tables.shape[1]
-    g = max(1, min(int(pages_per_step), max_pages))
-    steps = -(-max_pages // g)
+    g = walk_geometry(page_size, max_pages, pages_per_step)[0]
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
+    live, rows, walk = decode_grid(lengths, page_size, max_pages, g)
 
-    def q_map(b_, j_, lengths_s, tables_s):
-        return (b_, 0, 0)
+    def q_map(i_, j_, lengths_s, tables_s, live_s):
+        return (live_s[i_], 0, 0)
 
     def page_map(i):
-        def index(b_, j_, lengths_s, tables_s):
+        def index(i_, j_, lengths_s, tables_s, live_s):
+            b_ = live_s[i_]
             last = jnp.clip(lengths_s[b_] - 1, 0,
                             max_pages * page_size - 1) // page_size
             return (tables_s[b_, jnp.minimum(j_ * g + i, last)], 0, 0)
@@ -147,10 +194,10 @@ def latent_paged_decode(q, pages, block_tables, lengths, scale,
 
     kernel = functools.partial(
         _decode_kernel, scale=float(scale), page_size=page_size,
-        pages_per_step=g, value_width=int(value_width), steps=steps)
+        pages_per_step=g, value_width=int(value_width))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, steps),
+        num_scalar_prefetch=3,
+        grid=(rows, walk),
         in_specs=[pl.BlockSpec((1, h, lanes), q_map)] + [
             pl.BlockSpec((1, page_size, lanes), page_map(i))
             for i in range(g)],
@@ -167,7 +214,9 @@ def latent_paged_decode(q, pages, block_tables, lengths, scale,
             dimension_semantics=("parallel", "arbitrary")),
         name="latent_paged_decode",
     )
-    return fn(lengths, block_tables, q.astype(pages.dtype), *([pages] * g))
+    out = fn(lengths, block_tables, live, q.astype(pages.dtype),
+             *([pages] * g))
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
 # ------------------------------------------------------------------- chunk

@@ -46,12 +46,14 @@ from typing import List, Optional
 import jax
 import numpy as np
 
+from ..inference.cache_layout import has_latent
 from ..inference.generation import (GenerationConfig, PagedGenerationEngine,
                                     _round_up)
 from ..observability import Tracer, get_compile_log
 from ..observability.journey import JourneyStore
 from ..observability.stepclock import GcWatch, Span, StepClock
 from ..observability.steplog import StepCostModel, StepLog
+from ..ops.pallas.latent_attention import decode_grid_steps
 from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
@@ -242,6 +244,10 @@ class EngineCore:
         # worst-case reservation (page-padded prompt or prompt+max_new)
         self._max_pages = _round_up(self._max_model_len, page) // page
         window = self._max_pages * page
+        # whether the step launches the latent decode kernel, whose grid
+        # the packer books as StepLog ``decode_grid_steps``
+        self._latent_pages = has_latent(
+            getattr(engine, "_cache_layout", None) or ())
 
         # mixed-step scheduling: ONE executable keyed by (max_batch,
         # token_budget, max_pages) serves every batch composition — each
@@ -1787,7 +1793,12 @@ class EngineCore:
         cx = np.where(ql > 0, ctx, 0).astype(np.int64)
         attended_keys_step = int((ql * cx + ql * (ql + 1) // 2).sum())
         resident_tokens_step = int((cx + ql).sum())
-        decode_keys_step = int((cx[ql == 1] + 1).sum())
+        decode_lengths = cx[ql == 1] + 1
+        decode_keys_step = int(decode_lengths.sum())
+        # the grid one layer's latent decode launch runs over them
+        decode_grid_steps_step = decode_grid_steps(
+            decode_lengths, self._page,
+            self._max_pages) if self._latent_pages else 0
         # what the sampling tail will do, by the rule the traced step
         # branches on: no row that filters, no sort; no row that draws,
         # no draw
@@ -2049,6 +2060,7 @@ class EngineCore:
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
             decode_keys=decode_keys_step,
+            decode_grid_steps=decode_grid_steps_step,
             draw_rows=draw_rows_step, filter_rows=filter_rows_step,
             h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
             d2h_arrays=len(host_outs),

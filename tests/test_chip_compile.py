@@ -408,16 +408,32 @@ def test_a_latent_pool_at_its_cached_width_would_not_be_row_major(spec):
 
 
 def test_latent_decode_and_grouped_matmul_compile(spec, monkeypatch):
+    """The decode kernel with its lengths as the served step makes them
+    (traced: dead rows where a row carries no decode token), so BOTH
+    bounds of its grid are traced values Mosaic has to take, the outer
+    ``parallel`` one too; and with a step's mix as constants, dead rows
+    between live ones and a longest row of three grid steps of 32."""
+    import numpy as np
     from paddle_infer_tpu.ops.pallas import grouped_matmul as GM
     from paddle_infer_tpu.ops.pallas import latent_attention as LA
 
     monkeypatch.setattr(LA, "_interpret", lambda: False)
     monkeypatch.setattr(GM, "_interpret", lambda: False)
     i32 = jnp.int32
-    _compile(lambda q, pool, t, n: LA.latent_paged_decode(
-        q, pool, t, n, 0.13, 512),
-        spec((LAT_B, 64, 576), jnp.bfloat16), spec(LAT_POOL, jnp.bfloat16),
-        spec((LAT_B, LAT_PAGES), i32), spec((LAT_B,), i32))
+    served = lambda q, pool, t, ctx, qlens: LA.latent_paged_decode(
+        q, pool, t, jnp.where(qlens == 1, ctx + 1, 0), 0.13, 512)
+    args = (spec((LAT_B, 64, 576), jnp.bfloat16),
+            spec(LAT_POOL, jnp.bfloat16), spec((LAT_B, LAT_PAGES), i32),
+            spec((LAT_B,), i32), spec((LAT_B,), i32))
+    calls = [e for e in jax.make_jaxpr(served)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert [e.params["grid_mapping"].num_dynamic_grid_bounds
+            for e in calls] == [2]
+    assert "latent_paged_decode" in _compile(served, *args)
+    mixed = np.zeros((LAT_B,), np.int32)
+    mixed[[0, 2, 3, 7, LAT_B - 1]] = 128, 1, 129, 300, 17
+    _compile(lambda q, pool, t: LA.latent_paged_decode(
+        q, pool, t, jnp.asarray(mixed), 0.13, 512), *args[:3])
     for k, n in ((7168, 2048), (2048, 7168)):
         _compile(GM.grouped_matmul, spec((512, k), jnp.bfloat16),
                  spec((12, k, n), jnp.bfloat16), spec((12,), i32))

@@ -155,6 +155,60 @@ def test_served_tokens_through_engine_core_are_the_references_best(system):
             assert s["latent_cache_bytes_per_token"] == 3 * 24 * 4
 
 
+def test_steplog_books_the_grid_the_decode_kernel_runs(system, monkeypatch):
+    """StepLog ``decode_grid_steps``: live decode rows x the walk of the
+    longest one, from the packer's own arrays and the kernel's geometry;
+    0 on a step with no decode row; what ``decode_grid`` makes of the
+    lengths the step hands the kernel."""
+    core, eng, log = system.core, system.engine, system.steplog
+    _, span, steps = LA.walk_geometry(core._page, core._max_pages)
+    assert (span, steps) == (128, 2)
+    serving = ("mixed", "decode", "prefill")
+    rng = np.random.default_rng(7)
+    prompt = lambda n: rng.integers(0, system.config["vocab_size"],
+                                    n).astype(np.int32)
+    # one row alone, of known contexts: its decode steps read 125..131
+    # keys, one grid step up to 128 and two past it
+    before = len(log.records())
+    system.submit(prompt(span - 4), 8).result(timeout=600)
+    alone = [s for s in log.records()[before:] if s["kind"] in serving]
+    assert [s["decode_grid_steps"] for s in alone if s["decode_rows"]] == [
+        1, 1, 1, 1, 2, 2, 2]
+    chunks = [s for s in alone if not s["decode_rows"]]
+    assert chunks and all(s["decode_grid_steps"] == 0 for s in chunks)
+    # rows of different lengths together, beside what the kernel computes
+    # from the lengths the same step hands it
+    seen = []
+    launch = eng.run_paged_program
+
+    def spy(key, build, *args):
+        if key[0] == "serve-step":
+            f = core._step_fields
+            seen.append((f["qlens"].copy(), f["ctx"].copy()))
+        return launch(key, build, *args)
+
+    monkeypatch.setattr(eng, "run_paged_program", spy)
+    before = len(log.records())
+    for r in [system.submit(prompt(n), 10) for n in (span - 6, 5, 40)]:
+        r.result(timeout=600)
+    together = [s for s in log.records()[before:] if s["kind"] in serving]
+    assert len(together) == len(seen)
+    booked = set()
+    for s, (qlens, ctx) in zip(together, seen):
+        n = np.where(qlens == 1, ctx + 1, 0)
+        _, rows, walk = LA.decode_grid(jnp.asarray(n), core._page,
+                                       core._max_pages)
+        assert s["decode_grid_steps"] == (
+            int(rows) * int(walk) if n.any() else 0)
+        # a chunk of one token is a decode row to the kernel too
+        assert s["decode_rows"] <= int((qlens == 1).sum())
+        assert s["decode_grid_steps"] <= int((qlens == 1).sum()) * steps
+        booked.add((s["decode_rows"], s["decode_grid_steps"]))
+    # three rows at one grid step each, then at two: the longest row's
+    # walk is every row's
+    assert {(3, 3), (3, 6)} <= booked
+
+
 def test_cache_layout_is_one_description_for_both_kinds(system):
     from paddle_infer_tpu.inference.generation import PagedGenerationEngine
     from paddle_infer_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -218,26 +272,99 @@ def _latent_case(rng, b, h, width, page, max_pages, dtype=jnp.float32):
     return pages, jnp.asarray(tables)
 
 
+def _static_grid_decode(q, pages, block_tables, lengths, scale, value_width,
+                        pages_per_step):
+    """The launch ``latent_paged_decode`` had until its grid followed the
+    step's live rows: every row by the whole table's walk, both static
+    (the row list is the identity).  Kept here as the reference the live
+    grid must equal bit for bit."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, _ = q.shape
+    _, page_size, lanes = pages.shape
+    max_pages = block_tables.shape[1]
+    g, _, steps = LA.walk_geometry(page_size, max_pages, pages_per_step)
+
+    def q_map(b_, j_, lengths_s, tables_s, live_s):
+        return (b_, 0, 0)
+
+    def page_map(i):
+        def index(b_, j_, lengths_s, tables_s, live_s):
+            last = jnp.clip(lengths_s[b_] - 1, 0,
+                            max_pages * page_size - 1) // page_size
+            return (tables_s[b_, jnp.minimum(j_ * g + i, last)], 0, 0)
+        return index
+
+    return pl.pallas_call(
+        functools.partial(LA._decode_kernel, scale=float(scale),
+                          page_size=page_size, pages_per_step=g,
+                          value_width=value_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, steps),
+            in_specs=[pl.BlockSpec((1, h, lanes), q_map)] + [
+                pl.BlockSpec((1, page_size, lanes), page_map(i))
+                for i in range(g)],
+            out_specs=pl.BlockSpec((1, h, value_width), q_map),
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, value_width), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
+        interpret=True,
+    )(lengths, block_tables, jnp.arange(b, dtype=jnp.int32),
+      LA.pad_lanes(q, lanes), *([pages] * g))
+
+
+# lengths of five rows over a table of 96 keys, as functions of the keys
+# a grid step walks (8, 32 or 64 here; 128 at the served sizes)
+LENGTH_SETS = {
+    "mixed": lambda span: [1, 8, 9, 51, 96],
+    "dead_between_live": lambda span: [5, 0, 33, 0, 70],
+    "last_row_only": lambda span: [0, 0, 0, 0, 17],
+    "every_row_dead": lambda span: [0, 0, 0, 0, 0],
+    "every_row_full": lambda span: [96, 96, 96, 96, 96],
+    "on_a_step_border_and_one_past": lambda span: [span, 0, span + 1, 1, 0],
+    "longest_on_a_step_border": lambda span: [0, span, 3, 0, span - 1],
+}
+
+
+@pytest.mark.parametrize("lengths", sorted(LENGTH_SETS))
 @pytest.mark.parametrize("pages_per_step", [1, 4, 8])
-def test_latent_decode_kernel_equals_the_chunk_composition(pages_per_step):
+def test_latent_decode_kernel_equals_the_chunk_composition(pages_per_step,
+                                                           lengths):
     rng = np.random.default_rng(11)
     b, h, width, value, page, max_pages = 5, 4, 24, 16, 8, 12
     pages, tables = _latent_case(rng, b, h, width, page, max_pages)
     q = jnp.asarray(rng.normal(size=(b, h, width)), jnp.float32)
-    ctx = jnp.asarray([0, 7, 8, 50, 95], jnp.int32)
-    dec = LA.latent_paged_decode(q, pages, tables, ctx + 1, 0.3, value,
-                                 pages_per_step=pages_per_step)
+    _, span, steps = LA.walk_geometry(page, max_pages, pages_per_step)
+    n = jnp.asarray(LENGTH_SETS[lengths](span), jnp.int32)
+    alive = np.asarray(n) > 0
+    # both bounds of the grid are traced values of one executable
+    dec = np.asarray(jax.jit(
+        lambda n: LA.latent_paged_decode(q, pages, tables, n, 0.3, value,
+                                         pages_per_step=pages_per_step))(n))
     # the composition treats the same query as the first of a chunk of
     # two, the chunks end to end on its flat token axis
     q2 = jnp.stack([q, jnp.zeros_like(q)], axis=1).reshape(2 * b, h, width)
-    comp = LA.latent_chunk_attention(q2, pages, tables, ctx,
+    comp = LA.latent_chunk_attention(q2, pages, tables, jnp.maximum(n - 1, 0),
                                      jnp.full((b,), 2, jnp.int32), 0.3,
                                      value)[0::2]
-    np.testing.assert_allclose(np.asarray(dec), np.asarray(comp), atol=1e-5)
+    np.testing.assert_allclose(dec[alive], np.asarray(comp)[alive],
+                               atol=1e-5)
     # a row of length zero is skipped and reads zero
-    none = LA.latent_paged_decode(q, pages, tables, jnp.zeros_like(ctx), 0.3,
-                                  value, pages_per_step=pages_per_step)
-    assert not np.asarray(none).any()
+    assert not dec[~alive].any()
+    # the grid is the live rows by the longest one's walk, and what it
+    # leaves out never added anything: bit for bit the table-wide grid
+    _, rows, walk = LA.decode_grid(n, page, max_pages, pages_per_step)
+    assert int(rows) == max(int(alive.sum()), 1)
+    assert int(walk) == min(max(-(-int(n.max()) // span), 1), steps)
+    assert LA.decode_grid_steps(np.asarray(n)[alive], page, max_pages,
+                                pages_per_step) == (
+        int(rows) * int(walk) if alive.any() else 0)
+    np.testing.assert_array_equal(dec, np.asarray(_static_grid_decode(
+        q, pages, tables, n, 0.3, value, pages_per_step)))
 
 
 def test_latent_writer_touches_the_rows_own_slots_only():
