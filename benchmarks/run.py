@@ -136,8 +136,11 @@ def result_line(result, bench, workload, trace, platform, chips) -> dict:
     if trace and ev.trace is not None:
         device["busy_s"] = ev.trace["busy_s"]
         device["window_s"] = ev.trace["window_s"]
-        line["breakdown"] = {"device_ops": ev.trace["device_ops"],
-                             "idle_gaps": ev.trace["idle_gaps"]}
+        # the ten largest of each: the gaps have a label a span of
+        # ``spans.GAP_SPANS`` and one more, and sum to the idle seconds
+        # only in ``ev.trace``
+        line["breakdown"] = {"device_ops": ev.trace["device_ops"][:10],
+                             "idle_gaps": ev.trace["idle_gaps"][:10]}
     # last: each number compared beside its limit
     line["check"] = result["check"]
     return line

@@ -171,7 +171,7 @@ def reduce_events(device_ops: Dict[str, List[tuple]],
         "op_seconds": op_seconds,
         "device_ops": [[k, v] for k, v in list(op_seconds.items())[:10]],
         "idle_gaps": [[k, v / n] for k, v in
-                      sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
+                      sorted(gaps_by.items(), key=lambda kv: -kv[1])]}
 
 
 def _nothing_ran(devices: int, host_spans: List[tuple],
@@ -198,7 +198,7 @@ def _nothing_ran(devices: int, host_spans: List[tuple],
         "idle_share": 1.0, "collective_s": 0.0, "op_seconds": {},
         "device_ops": [],
         "idle_gaps": [[k, v] for k, v in
-                      sorted(gaps_by.items(), key=lambda kv: -kv[1])][:10]}
+                      sorted(gaps_by.items(), key=lambda kv: -kv[1])]}
 
 
 def _subtract(xs: Sequence[Interval], ys: Sequence[Interval]) -> List[Interval]:

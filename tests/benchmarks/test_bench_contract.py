@@ -1,50 +1,86 @@
 """BENCHMARK.json against the harness: every name resolves to a file,
-every file to a reader, as data alone."""
+every file to a reader, as data alone.  The rules themselves are in
+``contract_rules.py``, stated once for every cell; here each cell's
+reading of each metric is a case of its own, found by name."""
 import importlib
 import json
 import os
-import re
 
 import pytest
 
+import contract_rules as rules
 from conftest import ROOT
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-
-
-def _metric_files(folder, entries):
-    return [(folder, m["name"]) for m in entries]
+PATH = os.path.join(ROOT, "BENCHMARK.json")
+BENCH = json.load(open(PATH))
+E2E, LAYER = "end_to_end", "per_layer"
+PAIRS = [(kind, m, cell) for kind in (E2E, LAYER)
+         for m, cell in rules.pairs(BENCH, kind)]
+PAIR_IDS = rules.pair_ids(BENCH, E2E) + rules.pair_ids(BENCH, LAYER)
 
 
 def test_top_level_keys_and_limits():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert BENCH["paths"] == ["benchmarks", "tests/benchmarks"]
-    n = len(BENCH["workloads"])
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(1, n // 4)
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    rules.check_top_level(BENCH, os.path.getsize(PATH))
+    rules.check_names_are_unique(BENCH)
 
 
-@pytest.mark.parametrize("name", sorted(
-    {m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]}
-    | {w["name"] for w in BENCH["workloads"]}
-    | {c["name"] for c in BENCH["configs"]}
-    | {w["traffic"] for w in BENCH["workloads"]}))
-def test_names_are_names(name):
-    assert NAME.match(name), name
+@pytest.mark.parametrize("kind,metric,cell", PAIRS, ids=PAIR_IDS)
+def test_names_are_names(kind, metric, cell):
+    rules.check_metric(BENCH, kind, metric)
+    assert rules.NAME.match(cell)
 
 
-@pytest.mark.parametrize("folder,name", _metric_files(
-    "e2e_metrics", BENCH["end_to_end"]) + _metric_files(
-    "layer_metrics", BENCH["per_layer"]))
-def test_every_metric_has_a_file_and_a_reader(folder, name):
-    with open(os.path.join(ROOT, "benchmarks", folder, name + ".json")) as f:
-        spec = json.load(f)
-    reader = importlib.import_module("benchmarks.readers." + spec["reader"])
-    assert callable(reader.read)
-    assert set(spec) <= {"reader", "args"}
+# a traced window in which no operation ran: what ``xplane._nothing_ran``
+# gives a reader
+NOTHING_RAN = {"busy_s": 0.0, "window_s": 2.0, "idle_share": 1.0,
+               "collective_s": 0.0, "op_seconds": {}, "device_ops": [],
+               "idle_gaps": [], "t0": 0.0, "t1": 2.0}
+
+
+@pytest.mark.parametrize("kind,metric,cell", PAIRS, ids=PAIR_IDS)
+def test_every_metric_has_a_file_and_a_reader(kind, metric, cell):
+    """What ``run.read_metrics`` does for this cell's line: the file names
+    a reader, its arguments are the reader's, and on the evidence of a
+    window of this cell in which nothing happened, traced or not, the
+    reader finds nothing to read and says so: it never raises, and only
+    the set-up time and an idle device are numbers then."""
+    from benchmarks import run
+    from benchmarks.evidence import Evidence
+
+    rules.metric_spec(rules.FOLDER[kind], metric["name"])
+    _, config, traffic, params, _ = run.load_cell(cell)
+    for trace in (None, NOTHING_RAN):
+        ev = Evidence(config=config, traffic=traffic, cell=params,
+                      device_kind="TPU v5 lite", chips=1, setup_s=1.0,
+                      w0=0.0, w1=2.0, trace=trace)
+        got = run.read_metrics([metric], rules.FOLDER[kind], ev, cell)
+        assert not [name for name in got if name != "setup_s"
+                    and not name.startswith("device_idle_share")], got
+
+
+@pytest.mark.parametrize("metric,cell", rules.pairs(BENCH, LAYER),
+                         ids=rules.pair_ids(BENCH, LAYER))
+def test_every_per_layer_metric_moves_what_its_cell_reports(metric, cell):
+    rules.check_pair_moves(BENCH, metric, cell)
+
+
+def test_no_two_entries_measure_the_same():
+    rules.check_no_two_entries_measure_the_same(BENCH)
+
+
+def test_every_metric_file_has_an_entry_and_every_reader_a_file():
+    """Nothing lies about under the benchmark's folders that no entry
+    names: a file left behind by a merged or retired entry shows here."""
+    for kind, folder in rules.FOLDER.items():
+        files = {f[:-len(".json")] for f in os.listdir(
+            os.path.join(ROOT, "benchmarks", folder))}
+        assert files == {m["name"] for m in BENCH[kind]}
+    named = {rules.metric_spec(rules.FOLDER[kind], m["name"])[0]["reader"]
+             for kind in (E2E, LAYER) for m in BENCH[kind]}
+    readers = {f[:-len(".py")] for f in os.listdir(
+        os.path.join(ROOT, "benchmarks", "readers"))
+        if f.endswith(".py") and f != "__init__.py"}
+    assert readers == named
 
 
 @pytest.mark.parametrize("entry", BENCH["workloads"],
@@ -55,14 +91,7 @@ def test_every_cell_resolves_and_reports(entry):
     e, config, traffic, cell, _ = run.load_cell(entry["name"])
     assert callable(run.runner_for(config["kind"]).run)
     importlib.import_module("benchmarks.systems." + config["system"])
-    assert len(entry["why"]) <= 200 and "\n" not in entry["why"]
-    reports = lambda m: "workloads" not in m or entry["name"] in m["workloads"]
-    e2e = [m["name"] for m in BENCH["end_to_end"] if reports(m)]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    layer = [m for m in BENCH["per_layer"] if reports(m)]
-    assert layer
-    for m in layer:
-        assert m["moves"] in e2e, (m["name"], m["moves"])
+    rules.check_cell(BENCH, entry)
 
 
 @pytest.mark.parametrize("entry", BENCH["configs"],
@@ -90,32 +119,10 @@ def test_the_harness_names_no_architecture(name):
         assert word not in text, (name, word)
 
 
-def test_per_layer_entries_are_well_formed():
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    cells = {w["name"] for w in BENCH["workloads"]}
-    for m in BENCH["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["moves"] in e2e and m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-        assert set(m["workloads"]) <= cells and m["workloads"]
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-    for m in BENCH["end_to_end"]:
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-
-
 def test_configs_state_their_cuts():
     for c in BENCH["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
+        rules.check_config(BENCH, c)
         assert c["file"].startswith("benchmarks/configs/")
-        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-        for key in c["reduced"]:
-            assert not re.search(r"(_dim|_rank|hidden_size|intermediate_size"
-                                 r"|head_dim)$", key)
-        assert cfg["source"].startswith("http")
     mistral = json.load(open(os.path.join(
         ROOT, "benchmarks/configs/mistral-7b-v0.1-d12.json")))
     published = dict(hidden_size=4096, intermediate_size=14336,

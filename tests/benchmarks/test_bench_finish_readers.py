@@ -1,20 +1,10 @@
 """The per-layer metrics of a request's finish, of the emit phase's row
 loop, of the longest read-back's two halves, of the interpreter's
 collections and of the engine thread's time off the CPU: four reducers on
-made-up records, the 27 metric files (nine a serving cell), and the
-entries that name them.
-
-The entries stand HERE and not yet in ``BENCHMARK.json``: the end of
-``per_layer`` is pinned by ``test_bench_xing4_cpu.py`` (exactly 25
-entries from ``step_ms_p50.xing4`` to the end) and an entry put anywhere
-else reads as a change to what was there.  The ``benchmark`` issue that
-loosens that pin (ROADMAP D12 (j)) appends ``ENTRIES`` as they are; until
-then ``run.read_metrics`` reads them from here, on the CPU rehearsals of
-the three cells below and on the chip by whoever lays them over a copy."""
-import importlib
-import json
-import os
-import re
+made-up records, the nine metric files, and the nine entries of
+``BENCHMARK.json`` that name them, each read by the serving cells its
+``workloads`` lists (PR 41 brought files, readers and tests; the entries
+waited for PR 43)."""
 import time
 
 import jax
@@ -25,7 +15,7 @@ from benchmarks.evidence import Evidence
 from benchmarks.readers import (record_quantile, step_gap_share,
                                 steplog_ratio, steplog_window_share)
 
-from conftest import ROOT, load_data
+from conftest import load_data
 
 CHAT, RAGCHAT, REASONING = ("mistral-d12.chat", "axk1-ep16.ragchat",
                             "xing4-d7.reasoning")
@@ -45,12 +35,17 @@ METRICS = (
     ("gc_pause_ms_max", SCHED, SPAN, "ms"),
     ("host_off_cpu_ms_max", SCHED, COUNTER, "ms"),
 )
-ENTRIES = [
-    {"name": f"{name}.{suffix}", "unit": unit, "better": "lower",
-     "source": source, "layer": layer, "moves": "itl_p95_ms",
-     "workloads": [cell]}
-    for suffix, cell, _ in CELLS
-    for name, layer, source, unit in METRICS]
+NINE = tuple(name for name, *_ in METRICS)
+# no window of ``xing4-d7.reasoning`` evicts (its pool outlasts 51 s), so
+# that cell does not list the ratio: a listed cell has to report
+NEVER_EVICTS = {REASONING: {"evict_scanned_nodes_per_block"}}
+
+
+def _entries(bench):
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    return [by_name[name] for name in NINE]
+
+
 # what a program older than the spans writes (the parent commit, which
 # the driver runs under these files): these two and no more
 ON_THE_PARENT = ("finish_stall_ms_p50", "readback_wait_ms_max")
@@ -129,45 +124,19 @@ def test_steplog_ratio_is_a_sum_over_a_sum():
     assert read(_ev([]), "evict_scanned_nodes", "evicted_blocks") is None
 
 
-def test_the_entries_are_well_formed_and_name_one_cell_each(benchmark_json):
+def test_the_nine_are_entries_by_name(benchmark_json):
     bench = benchmark_json
-    assert len(ENTRIES) == 27 and len({m["name"] for m in ENTRIES}) == 27
-    taken = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
-    cells = {w["name"]: w for w in bench["workloads"]}
     itl = next(m for m in bench["end_to_end"] if m["name"] == "itl_p95_ms")
-    for m in ENTRIES:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", m["name"])
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", m["unit"])
-        assert m["name"] not in taken
-        (cell,) = m["workloads"]
-        assert cell in cells and cell in itl["workloads"]
-        (suffix,) = (s for s, c, _ in CELLS if c == cell)
-        assert m["name"].endswith("." + suffix)
-        assert m["better"] == "lower" and m["moves"] == "itl_p95_ms"
-        assert len(m["layer"]) <= 200 and "\t" not in m["layer"]
-        assert m["source"] in (SPAN, COUNTER)
-        with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
-                               m["name"] + ".json")) as f:
-            spec = json.load(f)
-        assert set(spec) == {"reader", "args"}
-        reader = importlib.import_module(
-            "benchmarks.readers." + spec["reader"])
-        assert callable(reader.read)
-    # the cells read the same nine through the same files
-    for name, *_ in METRICS:
-        a, b, c = (json.load(open(os.path.join(
-            ROOT, "benchmarks", "layer_metrics", f"{name}.{s}.json")))
-            for s, *_ in CELLS)
-        assert a == b == c
-    # the layers the benchmark names keep their names; the new one is one
-    assert {m["layer"] for m in bench["per_layer"]} >= {SCHED, STEP}
-    assert KV not in {m["layer"] for m in bench["per_layer"]}
-    # appended, the list and the file stay inside the contract's limits
-    assert len(bench["per_layer"]) + len(ENTRIES) <= 128
-    grown = dict(bench, per_layer=bench["per_layer"] + ENTRIES)
-    assert len(json.dumps(grown, indent=1)) < 64 * 1024
+    for m, (name, layer, source, unit) in zip(_entries(bench), METRICS):
+        assert {k: v for k, v in m.items() if k != "workloads"} == {
+            "name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": layer, "moves": "itl_p95_ms"}
+        for _, cell, _ in CELLS:
+            assert cell in itl["workloads"]
+            assert (cell in m["workloads"]) == (
+                name not in NEVER_EVICTS.get(cell, ()))
+    # the layers the benchmark named keep their names; the new one is one
+    assert {m["layer"] for m in bench["per_layer"]} >= {SCHED, STEP, KV}
 
 
 def _rehearse(config, seed, tmp_path):
@@ -181,17 +150,16 @@ def _rehearse(config, seed, tmp_path):
 @pytest.mark.parametrize("suffix,cell,config", CELLS,
                          ids=[c[0] for c in CELLS])
 def test_a_rehearsal_of_the_cell_reads_its_nine(suffix, cell, config,
-                                                tmp_path):
-    suffix = "." + suffix
+                                                tmp_path, benchmark_json):
     ev = _rehearse(config, 2 ** 31 + 41, tmp_path)
-    got = run.read_metrics(ENTRIES, "layer_metrics", ev, cell)
-    assert all(name.endswith(suffix) for name in got)
-    names = {name[:-len(suffix)] for name in got}
+    entries = _entries(benchmark_json)
+    got = run.read_metrics(entries, "layer_metrics", ev, cell)
+    listed = {m["name"] for m in entries if cell in m["workloads"]}
+    assert listed == set(NINE) - NEVER_EVICTS.get(cell, set())
     # requests finished inside the window; whether the cache had to evict
     # is the pool's to say, and the ratio is left out where it did not
-    assert names | {"evict_scanned_nodes_per_block"} \
-        == {name for name, *_ in METRICS}
-    value = lambda name: got[name + suffix]["value"]
+    assert listed - {"evict_scanned_nodes_per_block"} <= set(got) <= listed
+    value = lambda name: got[name]["value"]
     assert 0 < value("finish_stall_ms_p50") < 1000
     assert 0 < value("finish_stall_wall_share") < 100
     assert 0 < value("finish_step_gap_share") <= 100
@@ -209,6 +177,6 @@ def test_a_rehearsal_of_the_cell_reads_its_nine(suffix, cell, config,
            "retained_blocks", "gc_s", "gc_gen2", "cpu_s", "off_cpu_s"}
     ev.steps = [{k: v for k, v in s.items() if k not in new}
                 for s in ev.steps]
-    old = run.read_metrics(ENTRIES, "layer_metrics", ev, cell)
-    assert set(old) == {name + suffix for name in ON_THE_PARENT}
+    old = run.read_metrics(entries, "layer_metrics", ev, cell)
+    assert set(old) == set(ON_THE_PARENT)
     assert old == {name: got[name] for name in old}

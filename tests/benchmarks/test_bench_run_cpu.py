@@ -55,10 +55,10 @@ def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
     assert "device_idle_share.chat" not in layer
     assert "step_roofline_share_counted.chat" not in layer
     assert layer["compiles_in_window.chat"]["value"] == 0
-    assert 0 < layer["token_slot_fill_share.chat"]["value"] < 100
+    assert 0 < layer["token_slot_fill_share"]["value"] < 100
     assert layer["padded_slot_share.chat"]["value"] == pytest.approx(
-        100 - layer["token_slot_fill_share.chat"]["value"])
-    assert 0 <= layer["chunk_step_gap_share.chat"]["value"] <= 100
+        100 - layer["token_slot_fill_share"]["value"])
+    assert 0 <= layer["chunk_step_gap_share"]["value"] <= 100
     # a loaded test machine runs late; the chip run reads 1.6 ms
     assert 0 <= layer["gen_lateness_p99_ms"]["value"] < 2000
     assert {"ttft_p50_ms", "ttft_mean_ms", "ttft_p90_ms", "itl_mean_ms",
@@ -157,6 +157,16 @@ def test_a_traced_run_whose_trace_is_empty_still_prints_its_line(
     assert sum(gaps.values()) == pytest.approx(line["device"]["window_s"])
     assert [s for s in said if s.startswith("trace: 0 device events, 2 ")]
     json.dumps(line)
+    # the line prints the ten largest of each; the reduction keeps every
+    # label, so that its gaps sum to the idle seconds
+    from benchmarks import spans
+    labels = spans.GAP_SPANS + (spans.OUTSIDE,)
+    every = [[name, 1.0 / (i + 1)] for i, name in enumerate(labels)]
+    tr["idle_gaps"], kept = every, tr["idle_gaps"]
+    cut = run.result_line(res, benchmark_json, "mistral-d12.chat", 1, "cpu",
+                          1)["breakdown"]["idle_gaps"]
+    assert len(labels) > 10 and cut == every[:10]
+    tr["idle_gaps"] = kept
 
     # and through the command itself, the look for a chip stepped over
     monkeypatch.setattr(run, "run_cell", lambda ctx: res)
