@@ -827,15 +827,18 @@ class PagedGenerationEngine(GenerationEngine):
             self._v_pages = [alloc(c, s[1]) for c, s in zip(layout, shapes)]
         return self._k_pages, self._v_pages
 
-    def cache_bytes_per_token(self, kind=None, padding: bool = True) -> int:
+    def cache_bytes_per_token(self, kind=None, padding: bool = True,
+                              index_only: bool = False) -> int:
         """Bytes of the allocated pools per token of their capacity, over
         the layers of cache ``kind`` (all layers when None): the arrays'
         own sizes, a quantized pool's scales and a latent row's lane
         padding included.  ``padding=False`` takes the lanes past a
         latent layer's stated ``width`` off again: what is cached.
-        Measured once per engine (a token's bytes do not depend on how
+        ``index_only`` counts the second pools alone (call it with
+        ``kind="latent"``: the indexer's keys; 0 for a model without an
+        indexer).  Measured once per engine (a token's bytes do not depend on how
         many pages the pool has); allocates the pools if nothing has."""
-        key = (kind, padding)
+        key = (kind, padding, index_only)
         if key not in self._cache_bytes_measured:
             k_pages, v_pages = self._ensure_pages()
             total = 0
@@ -843,8 +846,8 @@ class PagedGenerationEngine(GenerationEngine):
                                             v_pages):
                 if kind not in (None, cache.kind):
                     continue
-                nbytes = sum(int(a.nbytes) for a in
-                             jax.tree_util.tree_leaves((first, second)))
+                nbytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
+                    second if index_only else (first, second)))
                 if not padding:
                     nbytes = nbytes * cache.values_per_token() \
                         // cache.stored_per_token()

@@ -48,6 +48,26 @@ plain ``x + F(norm(x))`` and the program built is the same as before the
 key existed.  ``num_nextn_predict_layers`` (a multi-token-prediction
 module behind the last layer) is carried and not built: the main model's
 logits do not depend on it.
+
+Indexer.  ``index_topk`` (``model_type`` ``glm_moe_dsa``; the sparse
+attention published with DeepSeek-V3.2-Exp) gives every layer a learned
+indexer beside its attention: ``index_n_heads`` queries of
+``index_head_dim`` from the normed query latent (``wq_b``), ONE key a
+token for all of them from the layer's normed input (``wk`` and a
+LayerNorm with weight and bias), the layer's rotary embedding on the
+first ``qk_rope_head_dim`` lanes of both, and a weight a head from the
+input (``weights_proj``).  A query's score for a cached token is ``sum_j
+w_j relu(q_j . k) / sqrt(heads x dim)``; attention reads only the
+``index_topk`` best-scored tokens at or before the query, ties to the
+earlier token (ops/pallas/sparse_latent_attention.py), so with
+``position < index_topk`` the layer is the dense one.  The index keys
+are cached beside the latent rows, in a second pool under the same
+block table (inference/cache_layout.py).  A model without the key
+builds none of this and compiles what it compiled.  The deployment's
+Hadamard rotation of index queries and keys (which no dot product sees)
+and their fp8 storage are not built.  ``rope_parameters`` (plain rotary:
+``rope_theta`` and no scaling) is read beside ``rope_scaling``;
+``qk_nope_head_dim`` and ``v_head_dim`` may differ.
 """
 from __future__ import annotations
 
@@ -84,6 +104,8 @@ class LatentMoEConfig:
                  initializer_range=0.02, n_group=None, topk_group=None,
                  hc_mult=1, hc_sinkhorn_iters=20, hc_eps=1e-6,
                  mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0,
+                 rope_parameters=None, index_topk=None, index_n_heads=None,
+                 index_head_dim=None, indexer_rope_interleave=True,
                  **extra):
         if scoring_func != "sigmoid" or not norm_topk_prob:
             raise NotImplementedError(
@@ -102,6 +124,30 @@ class LatentMoEConfig:
                 f"topk_group={topk_group!r}: grouped routing is not built; "
                 "the score-correction bias is, for n_group == topk_group "
                 "== 1")
+        if rope_parameters:
+            kind = rope_parameters.get("rope_type", "default")
+            if kind != "default":
+                raise NotImplementedError(
+                    f"rope_parameters.rope_type={kind!r}: plain rotary "
+                    "embedding (\"default\") is read from rope_parameters; "
+                    "YaRN is stated through rope_scaling")
+            rope_theta = rope_parameters.get("rope_theta", rope_theta)
+        if index_topk and not (index_n_heads and index_head_dim):
+            raise ValueError(
+                "index_topk needs index_n_heads and index_head_dim: the "
+                "indexer's own widths")
+        if index_topk and not indexer_rope_interleave:
+            raise NotImplementedError(
+                "indexer_rope_interleave=False: the indexer's rotary "
+                "lanes are paired as the attention's are (interleaved)")
+        if index_topk and int(hc_mult or 1) > 1:
+            raise NotImplementedError(
+                "an indexer beside hyper-connected residual streams is "
+                "not built")
+        if index_topk and index_head_dim < qk_rope_head_dim:
+            raise ValueError(
+                f"index_head_dim={index_head_dim} is narrower than the "
+                f"{qk_rope_head_dim} rotary lanes it carries")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -128,6 +174,11 @@ class LatentMoEConfig:
         self.rms_norm_eps = rms_norm_eps
         self.rope_theta = rope_theta
         self.rope_scaling = rope_scaling
+        self.rope_parameters = rope_parameters
+        self.index_topk = int(index_topk or 0)
+        self.index_n_heads = int(index_n_heads or 0)
+        self.index_head_dim = int(index_head_dim or 0)
+        self.indexer_rope_interleave = indexer_rope_interleave
         self.initializer_range = initializer_range
         self.n_group, self.topk_group = n_group, topk_group
         self.hc_mult = int(hc_mult or 1)
@@ -194,6 +245,48 @@ def _rope(x, positions, inv_freq, mscale):
 
 # --------------------------------------------------------------- attention
 
+class Indexer(Layer):
+    """The learned index of one layer: which cached tokens a query's
+    attention reads.  Parameter names are the published module's."""
+
+    LN_EPS = 1e-6
+
+    def __init__(self, cfg: LatentMoEConfig):
+        super().__init__()
+        from ..nn.layers_common import LayerNorm
+
+        self.heads, self.dim = cfg.index_n_heads, cfg.index_head_dim
+        self.rope_dim, self.topk = cfg.qk_rope_head_dim, cfg.index_topk
+        lin = lambda i, o: ColumnParallelLinear(i, o, has_bias=False,
+                                                gather_output=True)
+        self.wq_b = lin(cfg.q_lora_rank, self.heads * self.dim)
+        self.wk = lin(cfg.hidden_size, self.dim)
+        self.k_norm = LayerNorm(self.dim, epsilon=self.LN_EPS)
+        self.weights_proj = lin(cfg.hidden_size, self.heads)
+        # the published constants: positive, so no order depends on them
+        self.scale = self.heads ** -0.5 * self.dim ** -0.5
+
+    def _rotate(self, v, positions, rope):
+        """Rotary embedding on the first ``rope_dim`` lanes."""
+        inv_freq, mscale = rope
+        r = _rope(v[..., :self.rope_dim], positions, inv_freq, mscale)
+        return jnp.concatenate([r.astype(v.dtype), v[..., self.rope_dim:]],
+                               axis=-1)
+
+    def queries(self, x, c_q, positions, rope):
+        """-> q_idx [b, s, Hi, dim] (rotated), w_idx [b, s, Hi] float32
+        with the constants folded in."""
+        b, s = x.shape[0], x.shape[1]
+        q = self.wq_b(c_q)._data.reshape(b, s, self.heads, self.dim)
+        q = self._rotate(q, positions[:, :, None], rope)
+        w = self.weights_proj(x)._data.astype(jnp.float32) * self.scale
+        return q, w
+
+    def keys(self, x, positions, rope):
+        """-> k_idx [b, s, dim] (normed, rotated), as cached."""
+        return self._rotate(self.k_norm(self.wk(x))._data, positions, rope)
+
+
 class LatentAttention(Layer):
     def __init__(self, cfg: LatentMoEConfig):
         super().__init__()
@@ -222,11 +315,16 @@ class LatentAttention(Layer):
             yarn_mscale(float(sc["factor"]), float(sc.get("mscale", 1)))
             / yarn_mscale(float(sc["factor"]),
                           float(sc.get("mscale_all_dim", 0))))
+        self.indexer = Indexer(cfg) if cfg.index_topk else None
 
-    def _queries(self, x, positions):
-        """-> q_nope [b, s, H, nope], q_pe [b, s, H, rope] (rotated)."""
-        b, s = x.shape[0], x.shape[1]
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))._data
+    def _query_latent(self, x):
+        return self.q_a_layernorm(self.q_a_proj(x))
+
+    def _queries(self, c_q, positions):
+        """The normed query latent -> q_nope [b, s, H, nope], q_pe
+        [b, s, H, rope] (rotated)."""
+        b, s = c_q.shape[0], c_q.shape[1]
+        q = self.q_b_proj(c_q)._data
         # keep the head split out of the projection: over the mixed
         # step's few flat tokens the TPU compiler otherwise computes the
         # product head-major and transposes the whole weight every step
@@ -235,6 +333,14 @@ class LatentAttention(Layer):
         q_pe = _rope(q[..., self.nope:], positions[:, :, None],
                      self.inv_freq, self.rope_mscale).astype(q.dtype)
         return q[..., :self.nope], q_pe
+
+    def _absorbed_queries(self, c_q, positions, wk):
+        """-> [b, s, H, rank + rope]: queries in the latent space (the
+        no-position part through ``W^K``) ‖ their rotated position part."""
+        q_nope, q_pe = self._queries(c_q, positions)
+        q_lat = jnp.einsum("bshd,chd->bshc", q_nope, wk,
+                           preferred_element_type=jnp.float32)
+        return jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], -1)
 
     def _latent_rows(self, x, positions):
         """-> [b, s, rank + rope]: the normed latent ‖ the rotated key
@@ -267,13 +373,13 @@ class LatentAttention(Layer):
 
         # the mixed step's flat token axis: x [1, T, hidden], rows end to
         # end; tables, ctx and qlens stay per row
+        if self.indexer is not None:
+            return self._forward_selected(x, positions, cache)
         pages, tables, ctx, qlens, scratch = cache
         wk, wv = self._w_kvb()
         with jax.named_scope("mla_q_proj"):
-            q_nope, q_pe = self._queries(x, positions)
-            q_lat = jnp.einsum("bshd,chd->bshc", q_nope, wk,
-                               preferred_element_type=jnp.float32)
-            q_abs = jnp.concatenate([q_lat.astype(q_pe.dtype), q_pe], -1)
+            q_abs = self._absorbed_queries(self._query_latent(x), positions,
+                                           wk)
         with jax.named_scope("mla_kv_proj"):
             rows = self._latent_rows(x, positions)
         with jax.named_scope("latent_write"):
@@ -285,20 +391,63 @@ class LatentAttention(Layer):
         o_lat = LA.latent_ragged_attention(
             q_abs[0], pool, tables._data, ctx._data, qlens._data,
             self.scale, self.rank)[None]
+        return self._project_out(x, o_lat, wv), (
+            Tensor(pool), tables, Tensor(ctx._data + qlens._data), qlens,
+            scratch)
+
+    def _project_out(self, x, o_lat, wv):
+        b, s = x.shape[0], x.shape[1]
         with jax.named_scope("attn_out"):
             o = jnp.einsum("bshc,chd->bshd", o_lat, wv,
                            preferred_element_type=jnp.float32)
             o = Tensor(o.astype(x._data.dtype).reshape(
                 b, s, self.heads * self.v_dim))
-            out = self.o_proj(o)
-        return out, (Tensor(pool), tables, Tensor(ctx._data + qlens._data),
-                     qlens, scratch)
+            return self.o_proj(o)
+
+    def _forward_selected(self, x, positions, cache):
+        """The serving path of a layer with an indexer: the cache tuple
+        carries the index-key pool behind the latent one, both are
+        written, and attention reads each query's selection."""
+        from ..ops.pallas import latent_attention as LA
+        from ..ops.pallas import sparse_latent_attention as SA
+        from ..ops.pallas.ragged_paged_attention import (ragged_rows,
+                                                         rows_from_flat)
+
+        s = x.shape[1]
+        pages, index_pages, tables, ctx, qlens, scratch = cache
+        wk, wv = self._w_kvb()
+        rope = (self.inv_freq, self.rope_mscale)
+        with jax.named_scope("mla_q_proj"):
+            c_q = self._query_latent(x)
+            q_abs = self._absorbed_queries(c_q, positions, wk)
+        with jax.named_scope("dsa_index_proj"):
+            q_idx, w_idx = self.indexer.queries(x, c_q, positions, rope)
+            k_idx = self.indexer.keys(x, positions, rope)
+        with jax.named_scope("mla_kv_proj"):
+            rows = self._latent_rows(x, positions)
+        with jax.named_scope("latent_write"):
+            starts = ragged_rows(qlens._data, s)[0]
+            pool = LA.write_latent_pages(
+                pages._data, tables._data,
+                rows_from_flat(rows[0], starts, s), ctx._data, qlens._data)
+            index_pool = LA.write_latent_pages(
+                index_pages._data, tables._data,
+                rows_from_flat(k_idx[0], starts, s), ctx._data, qlens._data)
+        o_lat = SA.dsa_ragged_attention(
+            q_abs[0], q_idx[0], w_idx[0], pool, index_pool, tables._data,
+            ctx._data, qlens._data, self.scale, self.rank,
+            self.indexer.topk)[None]
+        return self._project_out(x, o_lat, wv), (
+            Tensor(pool), Tensor(index_pool), tables,
+            Tensor(ctx._data + qlens._data), qlens, scratch)
 
     def _forward_expanded(self, x, positions):
         """Causal self-attention over the sequence with per-head keys and
-        values (no cache)."""
+        values (no cache); with an indexer, each query over its selection
+        (a dense mask: the eager forward is for small sizes)."""
         b, s = x.shape[0], x.shape[1]
-        q_nope, q_pe = self._queries(x, positions)
+        c_q = self._query_latent(x)
+        q_nope, q_pe = self._queries(c_q, positions)
         rows = self._latent_rows(x, positions)
         kv = self.kv_b_proj(Tensor(rows[..., :self.rank]))._data.reshape(
             b, s, self.heads, self.nope + self.v_dim)
@@ -308,8 +457,20 @@ class LatentAttention(Layer):
         k = jnp.concatenate([kv[..., :self.nope], k_pe], -1)
         sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * self.scale
-        causal = positions[:, None, :, None] >= positions[:, None, None, :]
-        p = jax.nn.softmax(jnp.where(causal, sc, -1e30), axis=-1)
+        keep = positions[:, :, None] >= positions[:, None, :]    # [b, q, k]
+        if self.indexer is not None:
+            from ..ops.pallas.sparse_latent_attention import selection_mask
+
+            rope = (self.inv_freq, self.rope_mscale)
+            q_idx, w_idx = self.indexer.queries(x, c_q, positions, rope)
+            k_idx = self.indexer.keys(x, positions, rope)
+            isc = jnp.einsum("bqhd,bkd->bqhk", q_idx, k_idx,
+                             preferred_element_type=jnp.float32)
+            isc = jnp.sum(jnp.maximum(isc, 0.0) * w_idx[..., None], axis=2)
+            isc = jnp.where(keep, isc, -jnp.inf)
+            keep = jax.vmap(lambda a, v: selection_mask(
+                a, v, self.indexer.topk))(isc, keep)
+        p = jax.nn.softmax(jnp.where(keep[:, None], sc, -1e30), axis=-1)
         o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(kv.dtype),
                        kv[..., self.nope:],
                        preferred_element_type=jnp.float32)
@@ -436,7 +597,8 @@ class LatentMoEForCausalLM(PretrainedMixin, Layer):
     """Untied head.  Served through ``serving.EngineCore``'s mixed step:
     each layer's cache is the ``latent`` kind, a five-element tuple
     ``(pages [P, page, lanes], tables, context_lens, query_lens,
-    scratch_page)`` per layer."""
+    scratch_page)`` per layer; a layer with an indexer carries its
+    index-key pool ``[P, page, index_lanes]`` right behind ``pages``."""
 
     config_class = LatentMoEConfig
 
@@ -452,7 +614,9 @@ class LatentMoEForCausalLM(PretrainedMixin, Layer):
         from ..inference.cache_layout import LayerCache
 
         cfg = self.config
-        return [LayerCache.latent(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        return [LayerCache.latent(
+            cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+            index_width=cfg.index_head_dim if cfg.index_topk else 0)
                 ] * cfg.num_hidden_layers
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,

@@ -42,6 +42,7 @@ class AutoModel:
     _BY_MODEL_TYPE = {
         "axk1": ("latent_moe", "LatentMoEForCausalLM"),
         "xing4_0": ("latent_moe", "LatentMoEForCausalLM"),
+        "glm_moe_dsa": ("latent_moe", "LatentMoEForCausalLM"),
     }
 
     @classmethod

@@ -142,6 +142,19 @@ _SCHEMA = (
                                  # no latent pages; at most max_batch x
                                  # ceil(max_pages / pages a grid step),
                                  # a full batch of full-length rows
+    # a layer whose attention reads an indexer's selection (models/
+    # latent_moe.py ``index_topk``); 0 on a model without one.  Counted
+    # for ONE layer: every layer of the step does the same
+    ("index_scored_keys", 0),    # query-key pairs the index scores: all
+                                 # of attended_keys (the indexer scores
+                                 # every cached token a query may read)
+    ("index_selected_keys", 0),  # of those, the pairs attention then
+                                 # reads: sum over query tokens of
+                                 # min(index_topk, position + 1)
+    ("index_decode_scored_keys", 0),    # the same two over the decode
+    ("index_decode_selected_keys", 0),  # rows alone (what the kernels
+                                        # dsa_index_scores and
+                                        # dsa_sparse_decode work on)
     ("draw_rows", 0),            # rows that drew their token this step
                                  # (sample_now and do_sample); a step
                                  # with none ran no categorical draw
@@ -223,6 +236,10 @@ _SCHEMA = (
                                           # "latent", the same with the
                                           # lanes past their stated width
                                           # taken off: what is cached
+    ("index_cache_bytes_per_token", 0),   # of those pools, the latent
+                                          # layers' index-key pools (the
+                                          # arrays' own bytes; 0 without
+                                          # an indexer)
     ("planned_tokens", 0),       # tokens the StepPlanner chose to pack
     ("planned_chunk_cap", 0),    # per-row prompt-chunk cap this step
     ("predicted_wall_s", 0.0),   # planner's predicted step wall (0.0

@@ -46,7 +46,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from ..inference.cache_layout import has_latent
+from ..inference.cache_layout import has_index, has_latent
 from ..inference.generation import (GenerationConfig, PagedGenerationEngine,
                                     _round_up)
 from ..observability import Tracer, get_compile_log
@@ -246,8 +246,13 @@ class EngineCore:
         window = self._max_pages * page
         # whether the step launches the latent decode kernel, whose grid
         # the packer books as StepLog ``decode_grid_steps``
-        self._latent_pages = has_latent(
-            getattr(engine, "_cache_layout", None) or ())
+        layout = getattr(engine, "_cache_layout", None) or ()
+        # a layer with an indexer runs its own kernels in that one's
+        # place; the packer books what they score and select
+        self._index_topk = int(getattr(
+            engine._model.config, "index_topk", 0) or 0) \
+            if has_index(layout) else 0
+        self._latent_pages = has_latent(layout) and not self._index_topk
 
         # mixed-step scheduling: ONE executable keyed by (max_batch,
         # token_budget, max_pages) serves every batch composition — each
@@ -1047,16 +1052,40 @@ class EngineCore:
         return int(mem["temp"]) if mem else 0
 
     def _cache_bytes_fields(self) -> dict:
-        """The allocated pools' bytes per token of capacity, and of those
-        what the ``latent`` layers cache (lane padding taken off): the
-        engine reads them from the arrays once — on the step record
-        because that is what a reader of the StepLog is handed, and under
-        ``kv_pool`` in the snapshot."""
+        """The allocated pools' bytes per token of capacity, of those
+        what the ``latent`` layers cache (lane padding taken off) and
+        what their index-key pools hold: the engine reads them from the
+        arrays once — on the step record because that is what a reader
+        of the StepLog is handed, and under ``kv_pool`` in the
+        snapshot."""
         eng = self._engine
         return dict(
             cache_bytes_per_token=eng.cache_bytes_per_token(),
             latent_cache_bytes_per_token=eng.cache_bytes_per_token(
-                "latent", padding=False))
+                "latent", padding=False),
+            index_cache_bytes_per_token=eng.cache_bytes_per_token(
+                "latent", index_only=True))
+
+    def _index_fields(self, ql, cx, attended_keys: int,
+                      decode_lengths) -> dict:
+        """What one layer's indexer scores and its attention then reads
+        this step, from the packer's arrays (``ql`` query tokens a row
+        from position ``cx`` on); nothing on a model without one."""
+        k = self._index_topk
+        if not k:
+            return {}
+        # a row's queries at positions cx .. cx + ql - 1 keep
+        # min(k, position + 1) keys each
+        last = cx + ql
+        tri = lambda n: n * (n + 1) // 2
+        below = tri(np.minimum(last, k)) - tri(np.minimum(cx, k))
+        selected = int((below + k * (np.maximum(last, k)
+                                     - np.maximum(cx, k))).sum())
+        return dict(
+            index_scored_keys=attended_keys, index_selected_keys=selected,
+            index_decode_scored_keys=int(decode_lengths.sum()),
+            index_decode_selected_keys=int(
+                np.minimum(decode_lengths, k).sum()))
 
     def _iteration(self, now: float) -> bool:
         progressed = False
@@ -1799,6 +1828,8 @@ class EngineCore:
         decode_grid_steps_step = decode_grid_steps(
             decode_lengths, self._page,
             self._max_pages) if self._latent_pages else 0
+        index_fields = self._index_fields(ql, cx, attended_keys_step,
+                                          decode_lengths)
         # what the sampling tail will do, by the rule the traced step
         # branches on: no row that filters, no sort; no row that draws,
         # no draw
@@ -2033,6 +2064,9 @@ class EngineCore:
         if emitted_decode:
             self._metrics.on_tokens(emitted_decode, itl_s=synced)
         self._metrics.on_step(synced * 1e3, len(active), b)
+        if index_fields:
+            self._metrics.on_index(index_fields["index_scored_keys"],
+                                   index_fields["index_selected_keys"])
         self.step_trace.append({
             "step": self._step_idx, "batch_steps": 1,
             "active": [s["req"].rid for s in active],
@@ -2060,7 +2094,7 @@ class EngineCore:
             attended_keys=attended_keys_step,
             resident_tokens=resident_tokens_step,
             decode_keys=decode_keys_step,
-            decode_grid_steps=decode_grid_steps_step,
+            decode_grid_steps=decode_grid_steps_step, **index_fields,
             draw_rows=draw_rows_step, filter_rows=filter_rows_step,
             h2d_bytes=h2d_bytes_step, h2d_arrays=h2d_arrays_step,
             d2h_arrays=len(host_outs),

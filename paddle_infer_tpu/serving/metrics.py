@@ -109,6 +109,11 @@ class ServingMetrics:
             self.moe_expert_tokens: list = []
             self.moe_tokens_dropped = 0
             self.moe_aux_loss_last = 0.0
+            # a model whose attention reads an indexer's selection:
+            # query-key pairs one layer's index scored, and of those
+            # the pairs its attention then read
+            self.index_scored_keys = 0
+            self.index_selected_keys = 0
             # resilience counters (serving/resilience/) — rendered as
             # their own Prometheus families (engine_restarts_total, …),
             # NOT through the auto-named serving_*_total counters block
@@ -211,6 +216,13 @@ class ServingMetrics:
                 self.moe_expert_tokens[e] += int(n)
             self.moe_tokens_dropped += int(dropped)
             self.moe_aux_loss_last = float(aux_loss)
+
+    def on_index(self, scored: int, selected: int):
+        """One mixed step's indexer scored ``scored`` query-key pairs a
+        layer and its attention read ``selected`` of them."""
+        with self._lock:
+            self.index_scored_keys += int(scored)
+            self.index_selected_keys += int(selected)
 
     def on_queue_wait(self, wait_s: float):
         """One request left the admission queue after ``wait_s``."""
@@ -425,6 +437,12 @@ class ServingMetrics:
                                          if util and routed else 0.0),
                     "gate_aux_loss": self.moe_aux_loss_last,
                 })
+            if self.index_scored_keys:
+                out["indexer"] = {
+                    "scored_keys": self.index_scored_keys,
+                    "selected_keys": self.index_selected_keys,
+                    "keep_share": (self.index_selected_keys
+                                   / self.index_scored_keys)}
             if adapters is not None:
                 out["adapters"] = dict(adapters)
             if kv_tier is not None:

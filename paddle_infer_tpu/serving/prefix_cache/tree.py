@@ -40,11 +40,19 @@ Eviction is leaf-first LRU over entries with ``pins == 0``: partial
 entries and childless nodes.  It runs on demand (``ensure_free``) when
 admission needs blocks, and after every release (``enforce_watermark``)
 to keep the cache under ``watermark × pool_blocks`` retained blocks.
-Both time their loop over ``_evict_one`` as one ``prefix.evict`` span
+The victim comes off a heap keyed on ``last_used`` (``_evictable``): an
+entry is pushed whenever it may have become evictable or its clock
+moved while it was (an insert's deepest node and tail, an unpin that
+reaches zero, the parent of an evicted entry) and is checked against
+the tree when popped, so a stale push costs one pop and a search never
+walks the tree: a finished 12k-token sequence retains and then evicts
+some 750 blocks, and a walk of the whole tree for each of them (8,000
+retained entries: 1.8 s a finish, measured) stalled every row.
+Both loops over ``_evict_one`` are timed as one ``prefix.evict`` span
 (``insert`` is a ``prefix.insert`` span) whose seconds also add up in
 ``evict_seconds`` (``insert_seconds``), beside ``evicted_blocks`` and
-``evict_scanned_nodes``, the entries the searches for a victim examined:
-the engine writes the deltas into its StepLog records.
+``evict_scanned_nodes``, the heap entries the searches for a victim
+popped: the engine writes the deltas into its StepLog records.
 
 Host-tier demotion (serving/kv_tier/): when a demote hook is wired
 onto ``_tier_demote``, evicting a FULL node hands ``(salt, token path,
@@ -55,6 +63,8 @@ capacity becomes host-RAM-sized.
 """
 from __future__ import annotations
 
+import heapq
+import itertools
 import logging
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -95,16 +105,20 @@ class _Node:
 class PrefixMatch:
     """The result of ``PrefixCache.match`` — pinned until ``release``."""
     __slots__ = ("nodes", "blocks", "partial_block", "partial_len",
-                 "partial_node", "partial_entry", "salt", "_page")
+                 "partial_node", "partial_entry", "partial_owner", "salt",
+                 "_page")
 
     def __init__(self, nodes, blocks, partial_block, partial_len,
-                 partial_node, partial_entry, salt, page):
+                 partial_node, partial_entry, salt, page,
+                 partial_owner=None):
         self.nodes: List[_Node] = nodes
         self.blocks: List[int] = blocks        # full shared blocks
         self.partial_block = partial_block     # tail block to CoW, or None
         self.partial_len = partial_len         # valid tokens in the tail
         self.partial_node = partial_node       # pinned source node, if any
         self.partial_entry = partial_entry     # pinned partials entry, if any
+        # (node, tokens) the pinned entry is stored under, if any
+        self.partial_owner = partial_owner
         self.salt = salt
         self._page = page
 
@@ -131,6 +145,10 @@ class PrefixCache:
         self._roots: Dict[object, _Node] = {}
         self._clock = 0
         self._lock = threading.Lock()
+        # (last_used, push order, kind, node, key) of entries that were
+        # evictable when pushed; checked against the tree when popped
+        self._evictable: list = []
+        self._pushes = itertools.count()
         # host-tier demotion hook, wired by the engine as a direct
         # ``cache._tier_demote = core._demote_block`` assignment (the
         # binding form the static lock analyzer follows); called as
@@ -187,7 +205,7 @@ class PrefixCache:
                 node = child
                 depth += 1
             partial_block, partial_len, partial_node = None, 0, None
-            best_entry = None
+            best_entry = owner = None
             if node is not None:
                 rem = toks[depth * self.page:usable]
                 best = 0
@@ -200,6 +218,7 @@ class PrefixCache:
                     if k > best:
                         best, partial_block = k, entry[0]
                         best_entry, partial_node = entry, None
+                        owner = (node, ptoks)
                 for chunk, child in node.children.items():
                     k = _common(chunk, rem)
                     if k > best:
@@ -218,7 +237,8 @@ class PrefixCache:
                     best_entry[1] = self._clock
                     best_entry[2] += 1
             m = PrefixMatch(nodes, blocks, partial_block, partial_len,
-                            partial_node, best_entry, salt, self.page)
+                            partial_node, best_entry, salt, self.page,
+                            owner if best_entry is not None else None)
             if m.cached_tokens > 0:
                 self.hits += 1
                 self.cached_tokens_total += m.cached_tokens
@@ -306,21 +326,27 @@ class PrefixCache:
         """Unpin a match's nodes (request left its slot)."""
         with self._lock:
             for node in match.nodes:
-                if node.pins > 0:
-                    node.pins -= 1
+                self._unpin(node)
             match.nodes = []
             match.blocks = []
             self._drop_partial(match)
 
-    @staticmethod
-    def _drop_partial(match: PrefixMatch):
-        if match.partial_node is not None and match.partial_node.pins > 0:
-            match.partial_node.pins -= 1
-        if match.partial_entry is not None and match.partial_entry[2] > 0:
-            match.partial_entry[2] -= 1
+    def _unpin(self, node: _Node):
+        if node.pins > 0:
+            node.pins -= 1
+            self._offer(node)
+
+    def _drop_partial(self, match: PrefixMatch):
+        if match.partial_node is not None:
+            self._unpin(match.partial_node)
+        entry = match.partial_entry
+        if entry is not None and entry[2] > 0:
+            entry[2] -= 1
+            if entry[2] == 0:
+                self._push(entry[1], "partial", *match.partial_owner)
         match.partial_block, match.partial_len = None, 0
         match.partial_node = None
-        match.partial_entry = None
+        match.partial_entry = match.partial_owner = None
 
     def trim(self, match: PrefixMatch, max_tokens: int):
         """Shrink a match to at most ``max_tokens`` cached tokens
@@ -333,8 +359,7 @@ class PrefixCache:
             while match.cached_tokens > max_tokens and match.nodes:
                 node = match.nodes.pop()
                 match.blocks.pop()
-                if node.pins > 0:
-                    node.pins -= 1
+                self._unpin(node)
 
     # ------------------------------------------------------------ insert
     def insert(self, tokens, blocks, salt=None) -> int:
@@ -364,6 +389,8 @@ class PrefixCache:
             n_full = len(toks) // self.page
             for i in range(n_full):
                 if i >= len(blocks):
+                    # fewer blocks than pages: no tail either
+                    self._offer(node)
                     return retained
                 chunk = tuple(toks[i * self.page:(i + 1) * self.page])
                 child = node.children.get(chunk)
@@ -383,11 +410,15 @@ class PrefixCache:
                 if entry is None:
                     blk = int(blocks[n_full])
                     self._pool.ref_block(blk)
-                    node.partials[rem] = [blk, self._clock, 0]
+                    entry = node.partials[rem] = [blk, self._clock, 0]
                     self.cached_blocks += 1
                     retained += 1
                 else:
                     entry[1] = self._clock
+                if entry[2] == 0:
+                    self._push(entry[1], "partial", node, rem)
+            # the deepest node walked: the one that may be a leaf
+            self._offer(node)
             return retained
 
     def on_cow(self, n: int = 1):
@@ -444,7 +475,8 @@ class PrefixCache:
     def _candidates(self):
         """(last_used, kind, node, key) for every evictable entry:
         unpinned partial entries, and unpinned childless partial-less
-        nodes."""
+        nodes.  A walk of the whole tree: what the heap is rebuilt from,
+        and what its choice is held to in the tests."""
         out = []
         stack = list(self._roots.values())
         while stack:
@@ -453,22 +485,57 @@ class PrefixCache:
             for ptoks, entry in node.partials.items():
                 if entry[2] == 0:
                     out.append((entry[1], "partial", node, ptoks))
-            if (node.block is not None and not node.children
-                    and not node.partials and node.pins == 0):
+            if self._is_evictable(node):
                 out.append((node.last_used, "node", node, node.chunk))
         return out
 
+    @staticmethod
+    def _is_evictable(node: _Node) -> bool:
+        return (node.block is not None and not node.children
+                and not node.partials and node.pins == 0)
+
+    def _push(self, last_used, kind, node, key):
+        heapq.heappush(self._evictable,
+                       (last_used, next(self._pushes), kind, node, key))
+        # stale pushes (an entry touched again, pinned again or gone) are
+        # dropped when popped; a cache that seldom evicts drops them here
+        if len(self._evictable) > 4 * self.cached_blocks + 64:
+            self._evictable = [
+                (lu, next(self._pushes), kind, node, key)
+                for lu, kind, node, key in self._candidates()]
+            heapq.heapify(self._evictable)
+
+    def _offer(self, node: Optional[_Node]):
+        """Push ``node`` if it is evictable as it stands."""
+        if node is not None and self._is_evictable(node):
+            self._push(node.last_used, "node", node, node.chunk)
+
+    def _pop_victim(self):
+        """The least recently used evictable entry, or None: pops until
+        an entry still stands in the tree as it was pushed."""
+        while self._evictable:
+            last_used, _, kind, node, key = heapq.heappop(self._evictable)
+            self.evict_scanned_nodes += 1
+            if kind == "partial":
+                entry = node.partials.get(key)
+                if (entry is not None and entry[2] == 0
+                        and entry[1] == last_used):
+                    return kind, node, key
+            elif (self._is_evictable(node) and node.last_used == last_used
+                  and node.parent is not None
+                  and node.parent.children.get(key) is node):
+                return kind, node, key
+        return None
+
     def _evict_one(self, demote: bool = True) -> bool:
-        # the walk examines every entry that holds a block, whatever it
-        # finds: one addition a call (a search that pops a heap or an LRU
-        # list counts the entries it popped here instead)
-        self.evict_scanned_nodes += self.cached_blocks
-        cands = self._candidates()
-        if not cands:
+        victim = self._pop_victim()
+        if victim is None:
             return False
-        _, kind, node, key = min(cands, key=lambda c: c[0])
+        kind, node, key = victim
         if kind == "partial":
             blk = node.partials.pop(key)[0]
+            # its node may have been waiting for this tail to go
+            self._offer(node)
         else:
             blk = node.block
             if demote and self._tier_demote is not None:
@@ -482,9 +549,11 @@ class PrefixCache:
                     self._tier_demote(salt, path, blk)
                 except Exception:       # pragma: no cover - hook safety
                     _log.exception("host-tier demote hook failed")
-            if node.parent is not None:
-                node.parent.children.pop(key, None)
+            node.parent.children.pop(key, None)
             self.node_count -= 1
+            # leaf-first: the parent may be a leaf now
+            self._offer(node.parent)
+            node.parent = None
         self._pool.unref_block(blk)
         self.cached_blocks -= 1
         self.evicted_blocks += 1
@@ -528,6 +597,7 @@ class PrefixCache:
                 pass
             self._roots = {r: n for r, n in self._roots.items()
                            if n.children or n.partials}
+            self._evictable = []
 
     # ------------------------------------------------------------- stats
     def stats_snapshot(self) -> dict:

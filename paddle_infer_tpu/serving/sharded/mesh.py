@@ -149,35 +149,46 @@ def validate_cache_layout(cache_layout, *, mp: int = 1,
                           speculate: bool = False,
                           kv_host_pages: int = 0, handoff: bool = False):
     """What a ``latent`` cache layer (inference/cache_layout.py: one
-    ``[P, page, lanes]`` pool, no head axis, no V pool) cannot do yet —
-    refused here, at start-up, one mechanism a sentence.  Silent for
-    layouts of ``kv`` layers only."""
+    ``[P, page, lanes]`` pool, no head axis, no V pool — or, with an
+    ``index_width``, that pool and the indexer's ``[P, page, 128]`` keys
+    beside it) cannot do yet — refused here, at start-up, one mechanism a
+    sentence.  Silent for layouts of ``kv`` layers only."""
     if not cache_layout or all(c.kind != "latent" for c in cache_layout):
         return
+    # the pair of a layer with an indexer: two pools of UNEQUAL shape
+    pair = any(c.index_width for c in cache_layout if c.kind == "latent")
     if mp > 1:
         raise ShardedConfigError(
             f"mp={mp} splits the page pool over its head axis; a latent "
-            "cache layer has no head axis to split — serve it with mp=1")
+            "cache layer has no head axis to split" + (
+                ", and its index keys are one vector a token for every "
+                "index head" if pair else "") + " — serve it with mp=1")
     if kv_dtype is not None:
         raise ShardedConfigError(
             f"kv_dtype={kv_dtype!r} scales each page per head; a latent "
-            "cache layer has no heads to scale over — serve it with "
-            "full-precision pages")
+            "cache layer has no heads to scale over" + (
+                ", and its index-key pool is stored in the served type"
+                if pair else "") + " — serve it with full-precision pages")
     if speculate:
         raise ShardedConfigError(
             "speculative decoding verifies drafts through the per-head "
-            "decode kernel's verify lanes; the latent decode kernel has "
-            "none — drop speculate")
+            "decode kernel's verify lanes; the latent decode kernel" + (
+                "s (index scores, sparse decode) have" if pair else " has")
+            + " none — drop speculate")
     if int(kv_host_pages) > 0:
         raise ShardedConfigError(
             "the host KV tier parks and resumes a row through its key "
-            "and value pools; a latent cache layer has one pool — drop "
-            "kv_host_pages")
+            "and value pools, a pair of equal shape; a latent cache "
+            "layer has " + ("a latent pool and an index-key pool of "
+                            "another width" if pair else "one pool")
+            + " — drop kv_host_pages")
     if handoff:
         raise ShardedConfigError(
             "KV handoff between replicas serialises a row's key and "
-            "value pools; a latent cache layer has one pool — serve it "
-            "without a dedicated prefill role")
+            "value pools, a pair of equal shape; a latent cache layer "
+            "has " + ("a latent pool and an index-key pool of another "
+                      "width" if pair else "one pool")
+            + " — serve it without a dedicated prefill role")
 
 
 def validate_serving_config(cfg: ServingMesh, *, speculate: bool = False,

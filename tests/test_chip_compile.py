@@ -206,8 +206,9 @@ def not_interpreted():
     from paddle_infer_tpu.ops.pallas import mhc_maps as MM
     from paddle_infer_tpu.ops.pallas import paged_attention as PA
     from paddle_infer_tpu.ops.pallas import ragged_paged_attention as RPA
+    from paddle_infer_tpu.ops.pallas import sparse_latent_attention as SA
 
-    mods = (PA, RPA, LA, GM, MM)
+    mods = (PA, RPA, LA, GM, MM, SA)
     prev = [m._interpret for m in mods]
     for m in mods:
         m._interpret = lambda: False
@@ -579,6 +580,92 @@ def test_latent_step_runs_its_token_wise_layers_over_the_flat_axis(
     assert _shaped(text, "64,18432") and _shaped(text, "64,7168")
     # the head's product has max_batch rows
     assert _shaped(text, "16,20480") and not _shaped(text, "64,20480")
+
+
+# ------------------------------- a latent layer with an indexer's selection
+
+DSA_PAGES, DSA_CHUNK = 1024, 256       # max_model_len 16384, token_budget
+DSA_POOL = (16 * DSA_PAGES + 1, PAGE)
+
+
+def test_index_scores_and_sparse_decode_compile(spec, not_interpreted):
+    """The two kernels of a decode row's selection at the long-document
+    cell's widths: 32 index heads of 128 over 32 pages a grid step, then
+    64 heads over 2,048 gathered rows of 640 lanes, both grids traced."""
+    from paddle_infer_tpu.ops.pallas import sparse_latent_attention as SA
+
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    text = _compile(
+        lambda q, w, pool, t, n: SA.dsa_index_scores(q, w, pool, t, n),
+        spec((LAT_B, 32, 128), bf16), spec((LAT_B, 32), jnp.float32),
+        spec(DSA_POOL + (128,), bf16), spec((LAT_B, DSA_PAGES), i32),
+        spec((LAT_B,), i32))
+    assert "dsa_index_scores" in text
+    text = _compile(
+        lambda q, rows, n: SA.dsa_sparse_decode(q, rows, n, 0.0625, 512),
+        spec((LAT_B, 64, 576), bf16), spec((LAT_B, 2048, 640), bf16),
+        spec((LAT_B,), i32))
+    assert "dsa_sparse_decode" in text
+
+
+@pytest.fixture(scope="module")
+def selecting_step(one_chip, not_interpreted):
+    """A dense and an expert layer of the served mixed step at the
+    long-document cell's widths and deployment (16 rows of 16384, 256
+    token slots), both pools of each layer donated, compiled for the
+    chip."""
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.latent_moe import (LatentMoEConfig,
+                                                    LatentMoEForCausalLM)
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.serving.programs import build_mixed_step
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    cfg = LatentMoEConfig(
+        vocab_size=19456, hidden_size=6144, num_hidden_layers=2,
+        q_lora_rank=2048, qk_nope_head_dim=192, v_head_dim=256,
+        intermediate_size=12288, n_routed_experts=16,
+        n_routed_experts_published=256, topk_method="noaux_tc", n_group=1,
+        topk_group=1, rms_norm_eps=1e-5, max_position_embeddings=202752,
+        rope_parameters=dict(rope_theta=1000000, rope_type="default"),
+        index_topk=2048, index_n_heads=32, index_head_dim=128,
+        model_type="glm_moe_dsa")
+    with abstract_parameters():
+        model = LatentMoEForCausalLM(cfg)
+    engine = PagedGenerationEngine(model, page_size=PAGE,
+                                   cache_dtype=jnp.bfloat16)
+    run = build_mixed_step(engine, LAT_B, DSA_CHUNK, DSA_PAGES,
+                           moe_stats=True)
+    params = {n: spec(a.shape, a.dtype if a.dtype == jnp.float32
+                      else jnp.bfloat16) for n, a in engine._params.items()}
+    return run.lower(*_step_args(
+        spec, params, LAT_B, DSA_CHUNK, DSA_PAGES,
+        [spec(DSA_POOL + (640,), jnp.bfloat16)] * 2,
+        [spec(DSA_POOL + (128,), jnp.bfloat16)] * 2)).compile()
+
+
+def test_selecting_step_holds_its_kernels_and_fits(selecting_step):
+    """Both selecting kernels under their own names and none of the dense
+    decode kernel; neither pool copied or transposed; the widest
+    temporary a tile of one chunk row's scores, not [heads, chunk,
+    window] (1 GB at 256 x 16384) nor a row's gathered window."""
+    text = selecting_step.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    # two layers: each kernel once a layer
+    for kernel, n in (("%dsa_index_scores", 2), ("%dsa_sparse_decode", 2),
+                      ("%latent_paged_decode", 0)):
+        assert sum(kernel in ln for ln in calls) == n, kernel
+    assert "moe_grouped_matmul" in text
+    pool = r"bf16\[%d,16,(640|128)\]" % DSA_POOL[0]
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* (copy|transpose)\(" % pool, ln)]
+    assert selecting_step.memory_analysis().temp_size_in_bytes < 0.6e9
+    n_params = len(selecting_step.args_info[0][0])
+    # parameters, the packed input, two pools a layer; the packed output
+    # and the pools back
+    assert _entry_io(selecting_step) == (n_params + 1 + 4, 1 + 4)
 
 
 # ---------------------------------------------------------- the sampling tail

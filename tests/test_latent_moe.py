@@ -559,35 +559,63 @@ def test_auto_model_builds_it_from_the_sources_config_keys(tmp_path):
 
 
 def test_earlier_per_layer_entries_did_not_move():
-    """BENCHMARK.json is append-only: every per-layer entry the parent
-    commit had sits at the index it had there (PR 24's nine phase metrics
-    among them, together and in order), and this PR's entries name the
-    new cell alone.  Nothing here pins what comes last: the next PR
-    appends too."""
+    """BENCHMARK.json is append-only, and what this file owns of it is
+    found BY NAME: the per-layer entries the first two cells were entered
+    with (PR 24's nine phase metrics among them) and PR 27's ``.axk1``
+    block are there, each lists its own cell, and the cell and its
+    configuration are there.  No place, no length and no list is held to a
+    literal: later PRs append cells, entries and names to lists, and a
+    ``benchmark`` PR may fold an entry under a suffix into the plain entry
+    of the same name (then the plain entry lists the cell)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    names = [m["name"] for m in bench["per_layer"]]
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert len(by_name) == len(bench["per_layer"])
+
+    def reads(name, cell):
+        """The entry ``name``, or once folded the plain entry of that
+        name, lists ``cell``."""
+        m = by_name.get(name) or by_name.get(name.rsplit(".", 1)[0])
+        return m is not None and cell in m["workloads"]
+
     nine = ["loop_gap_ms_per_step.chat", "admit_ms_per_step.chat",
             "pack_ms_per_step.chat", "launch_ms_per_step.chat",
             "readback_wait_ms_p50.chat", "host_serial_ms_per_step.chat",
             "h2d_kb_per_step.chat", "step_roofline_share_counted.chat",
             "step_temp_share.chat"]
-    parent = ["gen_lateness_p99_ms", "queue_wait_mean_ms", "ttft_p50_ms",
-              "ttft_mean_ms", "itl_mean_ms", "itl_p99_ms", "ttft_p90_ms",
-              "batch_rows_mean.chat", "padded_slot_share.chat",
-              "step_ms_p50.chat", "compiles_in_window.chat",
-              "compiles_in_window.train", "step_ms_p50.train",
-              "mfu_share.train", "device_idle_share.chat",
-              "hbm_peak_share.chat", "device_idle_share.train",
-              "hbm_peak_share.train", "step_temp_share.train"] + nine
-    assert names[:len(parent)] == parent
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".axk1")]
-    assert len(mine) >= 21
-    assert all(m["workloads"] == ["axk1-ep16.ragchat"]
-               and m["moves"] == "itl_p95_ms" for m in mine)
-    assert all("axk1-ep16.ragchat" not in m["workloads"]
-               for m in bench["per_layer"][:len(parent)])
-    assert [w["name"] for w in bench["workloads"]][:3] == [
-        "ernie-base.pretrain", "mistral-d12.chat", "axk1-ep16.ragchat"]
-    assert [c["name"] for c in bench["configs"]][:3] == [
-        "mistral-7b-v0.1-d12", "ernie-3.0-base-pretrain", "a.x-k1-ep16-d7"]
+    chat = ["gen_lateness_p99_ms", "queue_wait_mean_ms", "ttft_p50_ms",
+            "ttft_mean_ms", "itl_mean_ms", "itl_p99_ms", "ttft_p90_ms",
+            "batch_rows_mean.chat", "padded_slot_share.chat",
+            "step_ms_p50.chat", "compiles_in_window.chat",
+            "device_idle_share.chat", "hbm_peak_share.chat"] + nine
+    train = ["compiles_in_window.train", "step_ms_p50.train",
+             "mfu_share.train", "device_idle_share.train",
+             "hbm_peak_share.train", "step_temp_share.train"]
+    for name in chat:
+        assert reads(name, "mistral-d12.chat"), name
+    for name in train:
+        assert reads(name, "ernie-base.pretrain"), name
+    axk1 = ["step_ms_p50", "batch_rows_mean", "chunk_step_gap_share",
+            "compiles_in_window", "host_serial_ms_per_step",
+            "readback_wait_ms_p50", "device_idle_share", "hbm_peak_share",
+            "step_temp_share", "ttft_mean_ms", "ttft_p90_ms", "itl_mean_ms",
+            "queue_wait_mean_ms", "gen_lateness_p99_ms",
+            "moe_assignments_held_mean", "moe_held_expert_max_p95",
+            "moe_experts_touched_mean", "latent_cache_bytes_per_token",
+            "latent_decode_roofline_share",
+            "moe_grouped_matmul_roofline_share",
+            "step_roofline_share_counted"]
+    for name in axk1:
+        assert reads(name + ".axk1", "axk1-ep16.ragchat"), name
+    # an entry still under this file's suffix moves the judged metric and
+    # reads its own cell
+    for m in bench["per_layer"]:
+        if m["name"].endswith(".axk1"):
+            assert "axk1-ep16.ragchat" in m["workloads"], m["name"]
+            assert m["moves"] == "itl_p95_ms", m["name"]
+    cells = {w["name"]: w for w in bench["workloads"]}
+    configs = {c["name"] for c in bench["configs"]}
+    for cell, config in (("ernie-base.pretrain", "ernie-3.0-base-pretrain"),
+                         ("mistral-d12.chat", "mistral-7b-v0.1-d12"),
+                         ("axk1-ep16.ragchat", "a.x-k1-ep16-d7")):
+        assert cells[cell]["config"] == config and config in configs

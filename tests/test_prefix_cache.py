@@ -230,43 +230,71 @@ def test_prefix_cache_fuzz():
     assert snap["cached_blocks"] == 0 and snap["nodes"] == 0
 
 
-def test_scanned_nodes_equal_a_brute_force_count_of_the_walk():
-    """With N retained blocks, evicting k: ``evict_scanned_nodes`` rises
-    by what the k searches for a victim examined, each the whole tree as
-    it then stood; the loop's seconds add up beside it."""
+def test_evictions_follow_the_brute_force_lru_and_count_their_pops():
+    """The victim comes off a heap, never a walk of the tree: under
+    random inserts, matches, trims and releases every eviction takes an
+    entry of the least ``last_used`` a walk of the whole tree
+    (``_candidates``) finds evictable at that moment, leaf first;
+    ``evict_scanned_nodes`` counts the heap entries popped, a few a
+    victim and not the tree's size; the loop's seconds add up beside
+    it; ``clear`` leaves nothing behind."""
     page = 4
-    pool = native.KVBlockPool(64, page)
+    pool = native.KVBlockPool(96, page)
     cache = PrefixCache(pool, page, watermark=1.0)
     rng = random.Random(3)
-    for seq in range(8):                 # branching prefixes and tails
+    real = cache._pop_victim
+    checked = []
+
+    def checking():
+        want = min((c[0] for c in cache._candidates()), default=None)
+        victim = real()
+        if victim is None:
+            assert want is None
+            return None
+        kind, node, key = victim
+        got = node.partials[key][1] if kind == "partial" else node.last_used
+        assert got == want
+        checked.append(kind)
+        return victim
+
+    cache._pop_victim = checking
+    held = []
+    for seq in range(60):                # branching prefixes and tails
         tokens = [rng.randrange(3) for _ in range(rng.randrange(5, 23))]
+        m = cache.match(tokens)
+        if rng.random() < 0.3:
+            cache.trim(m, rng.randrange(0, 12))
+        held.append(m)
+        need = -(-len(tokens) // page)
+        assert cache.ensure_free(need) or pool.free_blocks >= need
         pool.reserve(seq, len(tokens))
         cache.insert(tokens, pool.block_table(seq))
         pool.free(seq)
-    n = cache.cached_blocks
-    assert n == len(_tree_blocks(cache)) >= 12
-    assert cache.insert_seconds > 0.0 and cache.evict_seconds == 0.0
-    walked = []
-    real = cache._candidates
-
-    def counting():
-        walked.append(len(_tree_blocks(cache)))      # the walk, by hand
-        return real()
-
-    cache._candidates = counting
-    k = 5
-    assert cache.ensure_free(pool.free_blocks + k)
-    assert cache.evicted_blocks == k
-    assert walked == [n - i for i in range(k)]
-    assert cache.evict_scanned_nodes == sum(walked)
+        while len(held) > 3 or (held and rng.random() < 0.5):
+            cache.release(held.pop(rng.randrange(len(held))))
+        if rng.random() < 0.4:
+            cache.ensure_free(pool.free_blocks + rng.randrange(1, 4))
+        assert cache.cached_blocks == len(_tree_blocks(cache))
+    assert cache.insert_seconds > 0.0
+    assert cache.evicted_blocks == len(checked) >= 30
+    assert {"node", "partial"} <= set(checked)
+    # a pop a victim and the stale pushes between: not the tree's size
+    assert cache.evicted_blocks <= cache.evict_scanned_nodes \
+        <= 6 * cache.evicted_blocks
     snap = cache.stats_snapshot()
-    assert snap["evict_scanned_nodes"] == sum(walked)
-    assert snap["evicted_blocks"] == k and snap["cached_blocks"] == n - k
-    # a call with nothing to evict walks nothing and times nothing
-    seconds = cache.evict_seconds
+    assert snap["evict_scanned_nodes"] == cache.evict_scanned_nodes
+    assert snap["evicted_blocks"] == cache.evicted_blocks
+    # a call with nothing to evict pops nothing and times nothing
+    seconds, popped = cache.evict_seconds, cache.evict_scanned_nodes
     assert seconds > 0.0 and cache.ensure_free(0)
     cache.enforce_watermark()
-    assert cache.evict_seconds == seconds and len(walked) == k
+    assert (cache.evict_seconds, cache.evict_scanned_nodes) == (seconds,
+                                                                popped)
+    for m in held:
+        cache.release(m)
+    cache.clear()
+    assert pool.free_blocks == pool.num_blocks and not cache._evictable
+    assert cache.cached_blocks == 0 and not _tree_blocks(cache)
 
 
 def test_admission_eviction_lands_in_the_step_record(make_core):
