@@ -155,6 +155,13 @@ _SCHEMA = (
     ("index_decode_selected_keys", 0),  # rows alone (what the kernels
                                         # dsa_index_scores and
                                         # dsa_sparse_decode work on)
+    ("index_gathered_rows", 0),  # latent rows the selection copies out
+                                 # of the pool: live decode rows x
+                                 # index_topk rounded up to the sparse
+                                 # decode's blocks (select_rows' loop,
+                                 # one trip a live row); over max_batch
+                                 # x that width, the share of a full
+                                 # batch's gather the step paid
     ("draw_rows", 0),            # rows that drew their token this step
                                  # (sample_now and do_sample); a step
                                  # with none ran no categorical draw

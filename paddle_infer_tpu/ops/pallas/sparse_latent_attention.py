@@ -21,14 +21,18 @@ index heads.  The pieces, composed by ``dsa_ragged_attention``:
   out.  The grid is ``latent_attention.decode_grid``: the live rows by
   the walk of the longest, so its time follows the context.
 * ``select_rows`` (scope ``dsa_select``) — ``lax.top_k`` of each row's
-  scores (lower index first among equals: the tie rule), and the gather
-  of the chosen tokens' latent rows by (page, slot) into
-  ``[B, topk, lanes]``.  Its time follows ``topk`` and the window's
-  width, not the context.
+  scores (lower index first among equals: the tie rule; the TPU's
+  compiler sorts the whole window for it), then a loop of one trip a
+  LIVE row, ``decode_grid``'s compaction: the row's chosen positions
+  looked up in its table and their latent rows gathered by (page, slot)
+  into slot ``n`` of ``[B, topk, lanes]``.  The sort's time follows
+  ``B`` and the window's width, the rest ``topk`` by the step's live
+  decode rows, none of it the context; a dead row costs its place in
+  the sort and its slot's share of the buffer's zero fill.
 * ``dsa_sparse_decode`` — ``latent_attention``'s decode kernel body over
-  the gathered rows, ``SELECT_BLOCK`` of them a grid step; the grid is
-  the live rows by the largest selection's blocks, so its time follows
-  ``min(context, topk)``.
+  the gathered rows, slot ``n`` for the ``n``-th live row,
+  ``SELECT_BLOCK`` of them a grid step; the grid is the live rows by the
+  largest selection's blocks, so its time follows ``min(context, topk)``.
 * ``dsa_chunk_attention`` — chunk rows, one row at a time: index scores
   and then attention in tiles of ``CHUNK_TILE`` keys over the row's own
   context only (``ceil((ctx + qlen) / tile)`` tiles, a traced bound),
@@ -43,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -140,25 +145,54 @@ def dsa_index_scores(q_idx, w_idx, index_pages, block_tables, lengths,
 
 # --------------------------------------------------------------- selection
 
+def selection_width(topk, window):
+    """``(k, padded k)``: the tokens a row keeps at most, and that
+    rounded up to whole ``SELECT_BLOCK``s (the sparse decode's grid)."""
+    k = min(int(topk), int(window))
+    return k, k + -k % min(SELECT_BLOCK, k)
+
+
+def gathered_rows(decode_lengths, topk, window) -> int:
+    """Latent rows ``select_rows`` copies out of the pool for a step's
+    decode rows' lengths (host integers): its loop's trips, one a live
+    row, by the padded ``k``; what the packer books as StepLog
+    ``index_gathered_rows``."""
+    return (int(np.count_nonzero(np.asarray(decode_lengths) > 0))
+            * selection_width(topk, window)[1])
+
+
 def select_rows(scores, lengths, pages, block_tables, topk):
     """The ``topk`` best-scored tokens of each row (all of a shorter
-    row), and their latent rows gathered by (page, slot).
+    row), and the live rows' latent rows gathered from the pool.
 
     scores [B, window] (``-inf`` past ``lengths``) → ``(rows [B, k,
-    lanes], counts [B], positions [B, k])`` with ``k = min(topk,
-    window)`` rounded up to whole ``SELECT_BLOCK``s; entries past a
-    row's ``counts`` are other tokens' rows and are never read."""
-    page = pages.shape[1]
-    window = scores.shape[1]
-    k = min(int(topk), window)
+    lanes], counts [B], positions [B, k])`` with ``k`` the padded
+    ``selection_width``.  ``rows`` is compacted as ``decode_grid``
+    compacts: slot ``n`` holds the chosen rows of the ``n``-th row with
+    ``lengths > 0``, best first, and the slots past the live rows hold
+    zeros; entries past a row's ``counts`` are other tokens' rows and
+    are never read."""
+    b, window = scores.shape
+    page, lanes = pages.shape[1:]
+    k, padded = selection_width(topk, window)
     # equal scores keep the lower index first: the tie rule
     _, pos = jax.lax.top_k(scores, k)
-    counts = jnp.minimum(lengths.astype(jnp.int32), k)
-    block = min(SELECT_BLOCK, k)
-    pos = jnp.pad(pos, ((0, 0), (0, -k % block)))
-    flat = (jnp.take_along_axis(block_tables, pos // page, axis=1) * page
-            + pos % page)
-    rows = pages.reshape(-1, pages.shape[-1])[flat]
+    pos = jnp.pad(pos, ((0, 0), (0, padded - k)))
+    lengths = lengths.astype(jnp.int32)
+    counts = jnp.minimum(lengths, k)
+    live, _, _ = decode_grid(lengths, page, block_tables.shape[1], 1)
+    pool = pages.reshape(-1, lanes)
+
+    def gather(n, rows):
+        r = live[n]
+        p = pos[r]
+        flat = block_tables[r][p // page] * page + p % page
+        return jax.lax.dynamic_update_index_in_dim(rows, pool[flat], n, 0)
+
+    # one trip a live row: a dead row's selection is never looked up
+    rows = jax.lax.fori_loop(
+        0, jnp.count_nonzero(lengths > 0), gather,
+        jnp.zeros((b, padded, lanes), pages.dtype))
     return rows, counts, pos
 
 
@@ -168,7 +202,9 @@ def dsa_sparse_decode(q, rows, counts, scale, value_width,
     selection.
 
     q      [B, H, width]   queries in the latent space, ``width <= lanes``
-    rows   [B, K, lanes]   the chosen tokens' cached rows
+    rows   [B, K, lanes]   the chosen tokens' cached rows as ``select_rows``
+                           compacts them: slot ``n`` is the ``n``-th row
+                           with ``counts > 0``
     counts [B] int32       how many of them are the row's; 0 skips it
     → [B, H, value_width] in q's dtype, zero for a skipped row.
     """
@@ -186,9 +222,8 @@ def dsa_sparse_decode(q, rows, counts, scale, value_width,
         return (live_s[i_], 0, 0)
 
     def rows_map(i_, j_, counts_s, unused_s, live_s):
-        b_ = live_s[i_]
-        last = jnp.clip(counts_s[b_] - 1, 0, k - 1) // block
-        return (b_, jnp.minimum(j_, last), 0)
+        last = jnp.clip(counts_s[live_s[i_]] - 1, 0, k - 1) // block
+        return (i_, jnp.minimum(j_, last), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

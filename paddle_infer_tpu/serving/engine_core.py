@@ -54,6 +54,7 @@ from ..observability.journey import JourneyStore
 from ..observability.stepclock import GcWatch, Span, StepClock
 from ..observability.steplog import StepCostModel, StepLog
 from ..ops.pallas.latent_attention import decode_grid_steps
+from ..ops.pallas.sparse_latent_attention import gathered_rows
 from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
@@ -1085,7 +1086,9 @@ class EngineCore:
             index_scored_keys=attended_keys, index_selected_keys=selected,
             index_decode_scored_keys=int(decode_lengths.sum()),
             index_decode_selected_keys=int(
-                np.minimum(decode_lengths, k).sum()))
+                np.minimum(decode_lengths, k).sum()),
+            index_gathered_rows=gathered_rows(
+                decode_lengths, k, self._max_pages * self._page))
 
     def _iteration(self, now: float) -> bool:
         progressed = False

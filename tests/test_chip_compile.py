@@ -668,6 +668,33 @@ def test_selecting_step_holds_its_kernels_and_fits(selecting_step):
     assert _entry_io(selecting_step) == (n_params + 1 + 4, 1 + 4)
 
 
+def test_selecting_step_gathers_inside_the_live_rows_loop(selecting_step):
+    """The selection's plumbing follows the live decode rows: one sort a
+    layer (the top-k's, scores and positions: a third operand costs it
+    half as much again on the chip), the chosen positions looked up and
+    their rows gathered a live row at a time inside the loop, and nothing
+    gathered for the whole batch (32,768 table entries and 32,768 latent
+    rows a layer before PR 45)."""
+    text = selecting_step.as_text()
+    lines = [ln for ln in text.splitlines() if re.search(
+        r'op_name="[^"]*/dsa_select/[^"]*"', ln)]
+    sorts = [ln for ln in lines if " sort(" in ln]
+    assert len(sorts) == 2, sorts
+    for ln in sorts:
+        assert re.search(r"= \(f32\[16,16384\]\S*, s32\[16,16384\]\S*\) sort\(",
+                         ln), ln[:200]
+    gathers = [ln for ln in lines if re.search(r'/gather"', ln)
+               and re.search(r"= \S+ (gather|fusion)\(", ln)]
+    assert gathers
+    for ln in gathers:
+        assert "/while/body/" in ln, ln[:200]
+        assert not re.search(
+            r"= (s32\[32768\]|bf16\[32768,640\]|bf16\[16,2048,640\])", ln)
+    assert sum(bool(re.search(r"= bf16\[2048,640\]\S* fusion\(", ln))
+               for ln in gathers) == 2
+    assert not re.search(r"= s32\[32768\]", text)
+
+
 # ---------------------------------------------------------- the sampling tail
 
 @pytest.fixture(scope="module")

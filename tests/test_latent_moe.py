@@ -176,6 +176,8 @@ def test_steplog_books_the_grid_the_decode_kernel_runs(system, monkeypatch):
         1, 1, 1, 1, 2, 2, 2]
     chunks = [s for s in alone if not s["decode_rows"]]
     assert chunks and all(s["decode_grid_steps"] == 0 for s in chunks)
+    # no indexer, no selection: nothing gathered for one
+    assert all(s["index_gathered_rows"] == 0 for s in alone)
     # rows of different lengths together, beside what the kernel computes
     # from the lengths the same step hands it
     seen = []

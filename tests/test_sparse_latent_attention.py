@@ -89,10 +89,15 @@ def _plain_scores(q_idx, w_idx, keys):
     return (np.maximum(keys @ q_idx.T, 0.0) * w_idx[None]).sum(-1)
 
 
+def _plain_order(scores, k):
+    """Positions of the k largest, best first, ties to the earlier
+    position."""
+    return sorted(range(len(scores)), key=lambda s: (-scores[s], s))[:k]
+
+
 def _plain_topk(scores, k):
-    """Positions of the k largest, ties to the earlier position."""
-    order = sorted(range(len(scores)), key=lambda s: (-scores[s], s))
-    return sorted(order[:k])
+    """The same positions, in position order."""
+    return sorted(_plain_order(scores, k))
 
 
 def _plain_attention(q, rows, scale, value_width):
@@ -105,6 +110,10 @@ def _plain_attention(q, rows, scale, value_width):
 # contexts below, at and above the top-k of 8; a row ending mid-page (21,
 # 47) and one on a page's edge (8, 48); a dead row
 LENGTHS = (5, 8, 21, 0, 47, 48)
+# dead rows BETWEEN live ones, as a step's decode rows lie among chunk and
+# idle rows; a step with no decode row at all
+SCATTERED = (47, 0, 5, 0, 0, 30, 0, 12)
+NO_DECODE_ROW = (0, 0, 0, 0)
 
 
 def test_index_scores_kernel_against_plain_numpy():
@@ -128,42 +137,53 @@ def test_index_scores_kernel_against_plain_numpy():
     np.testing.assert_allclose(whole, got, atol=1e-5)
 
 
-def test_selection_and_sparse_decode_against_plain_numpy():
+@pytest.mark.parametrize("lens", [LENGTHS, SCATTERED, NO_DECODE_ROW],
+                         ids=["live-first", "dead-between", "no-decode-row"])
+def test_selection_and_sparse_decode_against_plain_numpy(lens):
     rng = np.random.default_rng(2)
-    b, h, width, vw, k = len(LENGTHS), 4, 24, 16, 8
+    b, h, width, vw, k = len(lens), 4, 24, 16, 8
     pool, _, tables = _pools(rng, b, width, 16)
     q = jnp.asarray(rng.normal(size=(b, h, width)), jnp.float32)
     scores = rng.normal(size=(b, MAX_PAGES * PAGE)).astype(np.float32)
-    # tied scores across the k-th place: positions 3, 9, 10 and 30 of the
-    # 47-token row share the value that ranks 7th to 10th, so 3 and 9 are
-    # kept and 10 and 30 are not
-    row = LENGTHS.index(47)
-    top = np.sort(scores[row, :47])[::-1]
-    scores[row, [3, 9, 10, 30]] = (top[5] + top[6]) / 2
-    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    if 47 in lens:
+        # tied scores across the k-th place: positions 3, 9, 10 and 30 of
+        # the 47-token row share the value that ranks 7th to 10th, so 3
+        # and 9 are kept and 10 and 30 are not
+        row = lens.index(47)
+        top = np.sort(scores[row, :47])[::-1]
+        scores[row, [3, 9, 10, 30]] = (top[5] + top[6]) / 2
+    lengths = jnp.asarray(lens, jnp.int32)
     masked = jnp.where(jnp.arange(scores.shape[1])[None] < lengths[:, None],
                        jnp.asarray(scores), -jnp.inf)
     rows, counts, pos = SA.select_rows(masked, lengths, pool, tables, k)
-    assert rows.shape == (b, k, LANES)
-    assert list(np.asarray(counts)) == [min(n, k) for n in LENGTHS]
+    assert rows.shape == (b, k, LANES) and pos.shape == (b, k)
+    assert list(np.asarray(counts)) == [min(n, k) for n in lens]
     out = np.asarray(SA.dsa_sparse_decode(q, rows, counts, 0.3, vw))
-    for r, n in enumerate(LENGTHS):
+    rows, pos = np.asarray(rows), np.asarray(pos)
+    alive = [r for r, n in enumerate(lens) if n]
+    # the gathered buffer holds the live rows at the front, in row order,
+    # and nothing behind them: the loop made one trip a live row
+    assert not rows[len(alive):].any()
+    for r, n in enumerate(lens):
         if not n:
             assert not out[r].any()
             continue
-        want = _plain_topk(scores[r, :n], k)
-        assert sorted(np.asarray(pos)[r, :min(n, k)]) == want
+        kept = min(n, k)
+        want = _plain_order(scores[r, :n], k)
+        assert list(pos[r, :kept]) == want
         chosen = _row_tokens(pool, tables, r, n)[want]
+        np.testing.assert_array_equal(rows[alive.index(r), :kept], chosen)
         np.testing.assert_allclose(
             out[r], _plain_attention(np.asarray(q[r]), chosen[:, :width],
                                      0.3, vw), atol=1e-5)
-    kept = set(np.asarray(pos)[row, :k])
-    assert {3, 9} <= kept and not {10, 30} & kept
+    if 47 in lens:
+        kept = set(pos[row, :k])
+        assert {3, 9} <= kept and not {10, 30} & kept
     # against dense attention: the same where the context fits the top-k,
     # another answer where it does not
     dense = np.asarray(LA.latent_paged_decode(q, pool, tables, lengths, 0.3,
                                               vw))
-    for r, n in enumerate(LENGTHS):
+    for r, n in enumerate(lens):
         if 0 < n <= k:
             np.testing.assert_allclose(out[r], dense[r], atol=1e-5)
         elif n > k:
@@ -183,18 +203,24 @@ def test_selection_mask_keeps_exactly_k_and_the_earlier_of_equals():
         [True, True, True, False, False, False, False, False]]
 
 
-def test_chunk_rows_select_per_query_token_and_tile_the_window():
-    """The chunk composition against plain numpy: two chunk rows beside a
-    decode row and a dead one, contexts that start below the top-k and
+@pytest.mark.parametrize("ctx, qlens", [
+    ([3, 30, 0, 19], [9, 1, 0, 11]),
+    # decode rows among idle ones and a chunk row, one of them under the
+    # top-k: the gathered buffer's slots are not the rows' numbers
+    ([0, 40, 0, 3, 4, 0, 20], [0, 1, 0, 9, 1, 0, 1]),
+], ids=["two-chunks", "scattered-decode-rows"])
+def test_chunk_rows_select_per_query_token_and_tile_the_window(ctx, qlens):
+    """The mixed step's composition against plain numpy: chunk rows beside
+    decode rows and dead ones, contexts that start below the top-k and
     end above it, tiles narrower than the context."""
     rng = np.random.default_rng(3)
-    b, h, width, vw, hi, di, k, t = 4, 4, 24, 16, 4, 16, 8, 24
+    b, h, width, vw, hi, di, k, t = len(ctx), 4, 24, 16, 4, 16, 8, 24
     pool, ipool, tables = _pools(rng, b, width, di)
     q = jnp.asarray(rng.normal(size=(t, h, width)), jnp.float32)
     qi = jnp.asarray(rng.normal(size=(t, hi, di)), jnp.float32)
     wi = jnp.asarray(rng.normal(size=(t, hi)), jnp.float32)
-    ctx = jnp.asarray([3, 30, 0, 19], jnp.int32)
-    qlens = jnp.asarray([9, 1, 0, 11], jnp.int32)
+    ctx = jnp.asarray(ctx, jnp.int32)
+    qlens = jnp.asarray(qlens, jnp.int32)
     got = np.asarray(SA.dsa_ragged_attention(
         q, qi, wi, pool, ipool, tables, ctx, qlens, 0.3, vw, k))
     tiled = np.asarray(SA.dsa_chunk_attention(
@@ -484,6 +510,62 @@ def test_served_tokens_through_engine_core_are_the_references_best(system):
     snap = system.core.metrics_snapshot()
     assert snap["indexer"]["scored_keys"] >= snap["indexer"]["selected_keys"]
     assert snap["kv_pool"]["index_cache_bytes_per_token"] == 3 * 128 * 4
+
+
+def test_steplog_books_the_rows_the_selection_gathers(system, monkeypatch):
+    """StepLog ``index_gathered_rows``: the step's live decode rows by
+    the padded ``index_topk``, idle and chunk rows beside them; the trips
+    ``select_rows``' loop makes of the lengths the same step hands the
+    program, by the same width, through the function they share."""
+    core, eng, log = system.core, system.engine, system.steplog
+    k, page, max_pages = core._index_topk, core._page, core._max_pages
+    window = page * max_pages
+    width = SA.selection_width(k, window)[1]
+    assert (k, width) == (12, 12)
+    assert SA.selection_width(2048, 16384) == (2048, 2048)
+    assert SA.selection_width(700, 16384) == (700, 1024)
+    assert SA.gathered_rows([], k, window) == 0
+    seen = []
+    launch = eng.run_paged_program
+
+    def spy(key, build, *args):
+        if key[0] == "serve-step":
+            f = core._step_fields
+            seen.append((f["qlens"].copy(), f["ctx"].copy()))
+        return launch(key, build, *args)
+
+    monkeypatch.setattr(eng, "run_paged_program", spy)
+    rng = np.random.default_rng(21)
+    before = len(log.records())
+    # four slots: three requests that end at different steps, so decode
+    # rows lie beside a chunk row first and idle rows later
+    reqs = [system.submit(rng.integers(0, system.config["vocab_size"],
+                                       n).astype(np.int32), new)
+            for n, new in ((70, 9), (5, 3), (40, 6))]
+    for r in reqs:
+        r.result(timeout=600)
+    steps = [s for s in log.records()[before:]
+             if s["kind"] in ("mixed", "decode", "prefill")]
+    assert len(steps) == len(seen)
+    pool = jnp.asarray(1.0 + rng.random((4 * max_pages + 1, page, LANES)),
+                       jnp.float32)
+    tables = jnp.asarray(1 + np.arange(4 * max_pages).reshape(4, max_pages),
+                         jnp.int32)
+    scores = jnp.asarray(rng.normal(size=(4, window)), jnp.float32)
+    select = jax.jit(lambda sc, n: SA.select_rows(sc, n, pool, tables, k)[0])
+    booked = set()
+    for s, (qlens, ctx) in zip(steps, seen):
+        n = np.where(qlens == 1, ctx + 1, 0)
+        trips = int(np.asarray(select(scores, jnp.asarray(n))).any(
+            axis=(1, 2)).sum())
+        assert trips == int((n > 0).sum())
+        assert s["index_gathered_rows"] == trips * width
+        assert s["index_gathered_rows"] == SA.gathered_rows(n[n > 0], k,
+                                                            window)
+        booked.add((int((qlens > 0).sum()), s["index_gathered_rows"] // width))
+    # steps with fewer decode rows than occupied rows (a chunk beside
+    # them), with idle rows beside the decode rows, and with none
+    assert {(1, 0), (3, 2), (2, 2), (1, 1)} <= booked, booked
 
 
 def test_a_prefix_cache_hit_serves_both_pools(system):
