@@ -144,42 +144,18 @@ def check_config(bench, entry):
     assert cfg["source"].startswith("http") and entry["source"]
 
 
-# PR 27 and the PRs before it entered each shared metric once a cell, under
-# the cell's suffix; ``tests/test_latent_moe.py`` (outside the benchmark's
-# paths, so no ``benchmark`` PR may edit it) holds those entries by name and
-# by place, so they stay as they were entered until a PR that may edit that
-# file lets them go.  No later cell takes such a suffix.
-HELD_SUFFIX = {".chat": "mistral-d12.chat", ".axk1": "axk1-ep16.ragchat"}
-
-
-def held_suffix(name):
-    return next((s for s in HELD_SUFFIX if name.endswith(s)), None)
-
-
-def base_name(name):
-    suffix = held_suffix(name)
-    return name[:-len(suffix)] if suffix else name
-
-
 def check_no_two_entries_measure_the_same(bench, load=None):
     """One measurement is one entry, and the cells that report it are its
     ``workloads``: no two per-layer entries have the same metric file and
-    the same fields but for ``name`` and ``workloads``.  The one exception
-    is an entry under a held suffix, which names that suffix's cell alone
-    and is the same measurement as the entry without the suffix."""
+    the same fields but for ``name`` and ``workloads``, whatever their
+    names."""
     seen = {}
     for m in bench["per_layer"]:
         spec, _ = metric_spec("layer_metrics", m["name"], load)
-        suffix = held_suffix(m["name"])
-        if suffix:
-            assert m["workloads"] == [HELD_SUFFIX[suffix]], m["name"]
         key = (json.dumps(spec, sort_keys=True),) + tuple(
             m[k] for k in ("unit", "better", "source", "layer", "moves"))
-        for other in seen.get(key, ()):
-            assert suffix or held_suffix(other), (m["name"], other)
-            assert base_name(m["name"]) == base_name(other), (m["name"],
-                                                              other)
-        seen.setdefault(key, []).append(m["name"])
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
 
 
 def check_all(bench, size, load=None):

@@ -23,8 +23,7 @@ import sys
 def shared_with(bench, kin):
     """The per-layer entries a cell of ``kin``'s family joins: those that
     list ``kin`` under no suffix (a suffix says that the reader or its
-    cost file is one configuration's own, or that the entry is held as an
-    earlier PR entered it)."""
+    cost file is one configuration's own)."""
     return [m for m in bench["per_layer"]
             if kin in m["workloads"] and "." not in m["name"]]
 

@@ -1,6 +1,5 @@
 """``chunk_step_gap_share``: the reader, on made-up step records, and the
-entries with the cells that read them (``.axk1`` is held as PR 27 entered
-it: see ``contract_rules.HELD_SUFFIX``)."""
+entry with the cells that read it."""
 import json
 import os
 
@@ -14,9 +13,8 @@ from benchmarks.readers import (chunk_step_gap_share, padded_slot_share,
 from conftest import ROOT
 
 NAME = "chunk_step_gap_share"
-# the cell, and the entry it reads the measurement under
-CELLS = {"mistral-d12.chat": NAME, "axk1-ep16.ragchat": NAME + ".axk1",
-         "xing4-d7.reasoning": NAME}
+CELLS = ("mistral-d12.chat", "axk1-ep16.ragchat", "xing4-d7.reasoning",
+         "glm5-ep16.longdoc")
 
 
 def _evidence(steps):
@@ -64,27 +62,25 @@ def test_nothing_to_read_is_none(steps):
 def test_the_entry_names_the_cell(cell):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    name = CELLS[cell]
-    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == NAME]
     assert cell in entry.pop("workloads")
-    assert entry == {"name": name, "unit": "%", "better": "lower",
+    assert entry == {"name": NAME, "unit": "%", "better": "lower",
                      "source": "program_counter", "layer": "scheduler",
                      "moves": "itl_p95_ms"}
-    assert run.load_json("layer_metrics", name + ".json") == {
+    assert run.load_json("layer_metrics", NAME + ".json") == {
         "reader": "chunk_step_gap_share", "args": {}}
 
 
 def test_the_stale_metric_is_retired_or_mended():
-    """``padded_slot_share.axk1`` is gone.  ``.chat`` stays, because
-    ``tests/test_latent_moe.py`` pins its place among the entries, and
-    reads the real axis now: 100 less ``token_slot_fill_share``."""
+    """``padded_slot_share`` stays for the chat cell alone, because
+    ``tests/test_latent_moe.py`` asserts that chat reads it, and reads the
+    real axis: 100 less ``token_slot_fill_share``."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"]
-            if m["name"].startswith("padded_slot_share")] == [
-                "padded_slot_share.chat"]
-    assert not os.path.exists(os.path.join(
-        ROOT, "benchmarks", "layer_metrics", "padded_slot_share.axk1.json"))
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"].startswith("padded_slot_share")]
+    assert entry["name"] == "padded_slot_share"
+    assert "mistral-d12.chat" in entry["workloads"]
     steps = [_step(kind="decode", decode_rows=10, token_slots=64),
              _step(decode_rows=4, prefill_chunk_tokens=50, token_slots=64)]
     ev = _evidence(steps)

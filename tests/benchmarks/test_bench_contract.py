@@ -9,7 +9,8 @@ import os
 import pytest
 
 import contract_rules as rules
-from conftest import ROOT
+import readings_diff
+from conftest import ROOT, load_data
 
 PATH = os.path.join(ROOT, "BENCHMARK.json")
 BENCH = json.load(open(PATH))
@@ -66,6 +67,42 @@ def test_every_per_layer_metric_moves_what_its_cell_reports(metric, cell):
 
 def test_no_two_entries_measure_the_same():
     rules.check_no_two_entries_measure_the_same(BENCH)
+
+
+@pytest.mark.parametrize("name", [
+    "step_ms_p50.chat", "step_ms_p50.axk1", "step_p50"])
+def test_two_equal_entries_are_refused_whatever_their_names(name):
+    """Until PR 46 an entry under ``.chat`` or ``.axk1`` could stand beside
+    the plain entry it duplicated.  No name may now: the same file and
+    fields a second time are refused, under a cell's suffix too."""
+    from benchmarks import run
+
+    plain = next(m for m in BENCH["per_layer"] if m["name"] == "step_ms_p50")
+    twice = dict(BENCH, per_layer=BENCH["per_layer"] + [
+        dict(plain, name=name, workloads=plain["workloads"][:1])])
+    load = lambda *parts: run.load_json(*parts[:-1], (
+        "step_ms_p50.json" if parts[-1] == name + ".json" else parts[-1]))
+    with pytest.raises(AssertionError, match="step_ms_p50"):
+        rules.check_no_two_entries_measure_the_same(twice, load)
+    # the same entry over another file is another measurement
+    other = lambda *parts: (
+        {"reader": "steplog_quantile",
+         "args": {"field": "wall_s", "q": 0.9, "scale": 1000.0}}
+        if parts[-1] == name + ".json" else run.load_json(*parts))
+    rules.check_no_two_entries_measure_the_same(twice, other)
+
+
+PR45 = load_data("per_layer_pr45.json")
+
+
+@pytest.mark.parametrize("cell", sorted(readings_diff.readings(PR45)))
+def test_no_cell_lost_a_reading_by_the_fold(cell):
+    """PR 46 folded the entries held under ``.chat`` and ``.axk1`` into
+    their plain ones: each measurement a cell's line held at PR 45 it holds
+    now, under the name ``readings_diff.new_name`` maps it to."""
+    was = readings_diff.readings(PR45, readings_diff.new_name)[cell]
+    now = readings_diff.readings(readings_diff.table(BENCH))[cell]
+    assert was <= now, sorted(was - now)
 
 
 def test_every_metric_file_has_an_entry_and_every_reader_a_file():

@@ -114,8 +114,10 @@ BREAKS = [
     (lambda b: b["per_layer"].append(dict(
         b["per_layer"][0], name="gen_lateness_p99_ms.tiny",
         workloads=[CELL])), "a second entry over the same file and fields"),
-    (lambda b: b["per_layer"][-1].update(name="step_ms_p90.axk1"),
-     "a held suffix on another cell's entry"),
+    (lambda b: b["per_layer"].append(dict(
+        next(m for m in b["per_layer"] if m["name"] == "step_ms_p50"),
+        name="step_ms_p50.chat", workloads=["mistral-d12.chat"])),
+     "a shared measurement entered again under a cell's suffix"),
     (lambda b: b["workloads"].append(dict(b["workloads"][-1])),
      "a name twice"),
     (lambda b: b["workloads"][-1].update(chips=4) or b["workloads"][0].update(
@@ -135,7 +137,9 @@ def test_the_rules_refuse_a_copy_that_breaks_one(fifth, break_it):
         {"reader": "client_quantile",
          "args": {"series_name": "lateness", "q": 0.99, "scale": 1000.0}}
         if parts[-1] == "gen_lateness_p99_ms.tiny.json"
-        else OWN["step_ms_p90.tiny"][1] if re.match(r"(x\d+|step_ms_p90\.axk1)\.json", parts[-1])
+        else _load_json("layer_metrics", "step_ms_p50.json")
+        if parts[-1] == "step_ms_p50.chat.json"
+        else OWN["step_ms_p90.tiny"][1] if re.match(r"x\d+\.json", parts[-1])
         else load_with_the_new_files(*parts))
     with pytest.raises((AssertionError, KeyError)):
         rules.check_all(broken, len(json.dumps(broken, indent=1)), load=load)

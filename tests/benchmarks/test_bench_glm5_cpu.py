@@ -6,6 +6,7 @@ import collections
 import json
 import math
 import os
+import re
 import time
 import types
 
@@ -76,9 +77,14 @@ def test_new_cell_metrics_read_from_data_files(longdoc_result,
     assert 0 < layer["index_keep_share.glm5"]["value"] < 35
     assert layer["compiles_in_window"]["value"] == 0
     assert layer["moe_assignments_held_mean"]["value"] > 0
-    for name in ("pack_ms_per_step", "h2d_kb_per_step",
+    for name in ("loop_gap_ms_per_step", "admit_ms_per_step",
+                 "pack_ms_per_step", "launch_ms_per_step", "h2d_kb_per_step",
                  "host_serial_ms_per_step"):
         assert layer[name]["value"] >= 0
+    # both pools as stored: 3 layers x (128 + 128) lanes x 2 bytes
+    assert layer["cache_bytes_per_token"]["value"] == 3 * 256 * 2
+    # the rows the selection gathered, of max_batch x index_topk
+    assert 0 < layer["index_gather_share.glm5"]["value"] <= 100
     assert 0 < layer["token_slot_fill_share"]["value"] <= 100
     # not traced: what reads the trace found nothing to read
     assert not [n for n in layer if "roofline" in n or "dsa_select" in n
@@ -386,6 +392,113 @@ def test_readers_from_counters_and_kernel_seconds(benchmark_json):
                               "dsa_sparse_decode") is None
 
 
+def _selection_equations():
+    """(primitive, shapes read, shapes written) of every equation the tiny
+    configuration's served step traces under the scope ``dsa_select``,
+    whatever loop or branch it lies in; shapes as the trace's operation
+    keys write them (``bf16[12,128]``).  The kernels' bodies are not the
+    scope's."""
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.latent_moe import (LatentMoEConfig,
+                                                    LatentMoEForCausalLM)
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.serving.programs import (build_mixed_step,
+                                                   step_input_layout)
+
+    cfg, spec = load_data("tiny-glm5.json"), jax.ShapeDtypeStruct
+    dep = cfg["deployment"]
+    with abstract_parameters():
+        model = LatentMoEForCausalLM(LatentMoEConfig(**{
+            k: v for k, v in cfg.items()
+            if k not in glm5_serving.NOT_MODEL_KEYS}))
+    page = dep["page_size"]
+    engine = PagedGenerationEngine(model, page_size=page,
+                                   cache_dtype=jnp.bfloat16)
+    b, pages = dep["max_batch"], dep["max_model_len"] // page
+    step = build_mixed_step(engine, b, dep["token_budget"], pages,
+                            moe_stats=True)
+    (layer,) = set(model.cache_layout())
+    latent, index = (spec(shape, jnp.bfloat16)
+                     for shape in layer.pool_shapes(b * pages + 1, page))
+    n = cfg["num_hidden_layers"]
+    traced = step.trace(
+        {name: spec(a.shape, a.dtype) for name, a in engine._params.items()},
+        spec((step_input_layout(b, dep["token_budget"], pages, 1).size,),
+             jnp.int32), [latent] * n, [index] * n)
+    short = {"float32": "f32", "bfloat16": "bf16", "int32": "s32",
+             "uint32": "u32", "bool": "pred"}
+    shapes = lambda vs: tuple(
+        "%s[%s]" % (short.get(str(v.aval.dtype), str(v.aval.dtype)),
+                    ",".join(map(str, v.aval.shape)))
+        for v in vs if hasattr(v.aval, "shape"))
+    found = []
+
+    def walk(jaxpr, scope):
+        for eqn in jaxpr.eqns:
+            here = scope + "/" + str(eqn.source_info.name_stack)
+            inner = [getattr(x, "jaxpr", x) for v in eqn.params.values()
+                     for x in (v if isinstance(v, (tuple, list)) else (v,))
+                     if hasattr(getattr(x, "jaxpr", x), "eqns")]
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for sub in inner:
+                walk(sub, here)
+            if not inner and "dsa_select" in here:
+                found.append((eqn.primitive.name, shapes(eqn.invars),
+                              shapes(eqn.outvars)))
+
+    walk(traced.jaxpr.jaxpr, "")
+    return found
+
+
+def test_the_selection_s_keys_name_what_its_scope_runs():
+    """``dsa_select_ms_per_step.glm5`` sums device seconds by operation
+    key, opcode and shape, because the scope's XLA operations keep their
+    own names (PR 45 rearranged the selection and the metric read half of
+    it).  So the keys are held to the program here, at the tiny
+    configuration's counterparts of the cell's sizes and before any
+    compiler: every key names a shape an equation under ``dsa_select``
+    reads or writes, and every equation there that sorts, gathers or
+    updates ``index_topk`` elements or more is named by a key.  A PR that
+    rearranges the selection fails this test, not the metric."""
+    real, tiny = _published(), load_data("tiny-glm5.json")
+    lanes = lambda c: -(-(c["kv_lora_rank"] + c["qk_rope_head_dim"]) // 128) \
+        * 128
+    sizes = lambda c: (c["deployment"]["max_batch"],
+                       c["deployment"]["max_model_len"], c["index_topk"],
+                       lanes(c), c["deployment"]["token_budget"])
+    to_tiny = dict(zip(sizes(real), sizes(tiny)))
+    assert len(to_tiny) == 5
+
+    def counterpart(key):
+        kind, dims = re.search(r"(\w+)\[([\d,]*)\]", key).groups()
+        return "%s[%s]" % (kind, ",".join(
+            str(to_tiny[int(d)]) for d in dims.split(",")))
+
+    kernels = run.load_json(
+        "layer_metrics", "dsa_select_ms_per_step.glm5.json")["args"]["kernels"]
+    # the chip's compiler stages the gathered buffer to the next loop in
+    # four parts: no equation of the program has that shape
+    staged = {"bf16[4,2048,640]"}
+    assert staged < set(kernels)
+    named = {counterpart(k) for k in kernels if k not in staged}
+    eqns = _selection_equations()
+    touched = {s for _, read, written in eqns for s in read + written}
+    assert named <= touched, sorted(named - touched)
+    # what moves the data: a sort reads its scores, the others write
+    moved = {(read if prim in ("top_k", "sort") else written)[0]
+             for prim, read, written in eqns
+             if prim in ("top_k", "sort", "gather", "dynamic_update_slice",
+                         "scatter", "scatter-add", "cumsum")}
+    elements = lambda s: math.prod(
+        int(d) for d in re.search(r"\[([\d,]*)\]", s).group(1).split(",")
+        if d)
+    moved = {s for s in moved if elements(s) >= tiny["index_topk"]}
+    # at least the scores sorted, the ids looked up, the rows gathered, the
+    # buffer they are put in and the chunk rows' running count
+    assert len(moved) >= 5 and moved <= named, sorted(moved - named)
+
+
 def test_the_cell_s_deck_is_two_log_uniform_distributions(benchmark_json):
     here = os.path.join(ROOT, "benchmarks")
     traffic = json.load(open(os.path.join(here, "traffic", "longdoc.json")))
@@ -446,10 +559,9 @@ def test_the_cell_s_rate_is_a_stated_share_of_a_knee_it_shows_the_sweep_of():
     assert not any(sustained(r) for r in rates if r > cell["knee_rps"])
 
 
-# the family's shared metrics under their plain names (the packer's phase
-# and the step's host-to-device bytes entered by this cell's PR for the
-# cells after it: what the 96 entries test_bench_fifth_cell.py leaves a
-# PR had room for), and what only this configuration has under its suffix
+# the family's shared metrics under their plain names (the last four since
+# PR 46: the table had no room for them when the cell came), and what only
+# this configuration has under its suffix
 SHARED = ("step_ms_p50", "batch_rows_mean", "chunk_step_gap_share",
           "token_slot_fill_share", "compiles_in_window",
           "host_serial_ms_per_step", "readback_wait_ms_p50",
@@ -462,12 +574,13 @@ SHARED = ("step_ms_p50", "batch_rows_mean", "chunk_step_gap_share",
           "finish_stall_wall_share", "finish_step_gap_share",
           "emit_rows_ms_per_step", "readback_wait_ms_max",
           "readback_ready_ms_max", "gc_pause_ms_max", "host_off_cpu_ms_max",
-          "pack_ms_per_step", "h2d_kb_per_step")
+          "pack_ms_per_step", "h2d_kb_per_step", "loop_gap_ms_per_step",
+          "admit_ms_per_step", "launch_ms_per_step", "cache_bytes_per_token")
 OWN = ("dsa_index_roofline_share.glm5",
        "dsa_sparse_decode_roofline_share.glm5",
        "dsa_select_ms_per_step.glm5", "index_keep_share.glm5",
        "index_cache_bytes_per_token.glm5",
-       "step_roofline_share_counted.glm5")
+       "step_roofline_share_counted.glm5", "index_gather_share.glm5")
 
 
 def test_the_cell_and_its_entries_are_there_by_name(benchmark_json):

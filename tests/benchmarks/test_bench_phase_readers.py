@@ -39,15 +39,15 @@ def _roofline_share():
 
 # three serving steps; the failed one and the evict are not steps
 EXPECTED = {
-    "loop_gap_ms_per_step.chat": 1.0,
-    "admit_ms_per_step.chat": 2.0,
-    "pack_ms_per_step.chat": 2.0,
-    "launch_ms_per_step.chat": 5.0,
-    "readback_wait_ms_p50.chat": 152.0,
-    "host_serial_ms_per_step.chat": (8.0 + 14.0 + 14.0) / 3,
-    "h2d_kb_per_step.chat": (14.0 + 14.0 + 16.0) / 3,
+    "loop_gap_ms_per_step": 1.0,
+    "admit_ms_per_step": 2.0,
+    "pack_ms_per_step": 2.0,
+    "launch_ms_per_step": 5.0,
+    "readback_wait_ms_p50": 152.0,
+    "host_serial_ms_per_step": (8.0 + 14.0 + 14.0) / 3,
+    "h2d_kb_per_step": (14.0 + 14.0 + 16.0) / 3,
     "step_roofline_share_counted.chat": _roofline_share(),
-    "step_temp_share.chat": 100.0 * 858993459 / (16 * 1024 ** 3),
+    "step_temp_share": 100.0 * 858993459 / (16 * 1024 ** 3),
 }
 NEW = [m for m in BENCH["per_layer"] if m["name"] in EXPECTED]
 
@@ -62,7 +62,7 @@ def _evidence(steps, traced=True):
 # the reader each of the nine is read through
 READERS = dict({name: "steplog_phase" for name in EXPECTED},
                **{"step_roofline_share_counted.chat": "step_roofline_counted",
-                  "step_temp_share.chat": "steplog_hbm_share"})
+                  "step_temp_share": "steplog_hbm_share"})
 
 
 def test_the_issue_s_nine_metrics_are_declared():
@@ -107,10 +107,10 @@ def test_roofline_and_temp_share_need_their_sources():
                             "mistral-d12.chat") == {}
     # a backend with no memory analysis records 0: nothing to report
     zero = [dict(s, program_temp_bytes=0) for s in SAMPLE["steps"]]
-    assert run.read_metrics([by["step_temp_share.chat"]], "layer_metrics",
+    assert run.read_metrics([by["step_temp_share"]], "layer_metrics",
                             _evidence(zero), "mistral-d12.chat") == {}
     # a device without published peaks has no share either
     ev = _evidence(SAMPLE["steps"])
     ev.device_kind = "cpu"
-    assert run.read_metrics([by["step_temp_share.chat"]], "layer_metrics",
+    assert run.read_metrics([by["step_temp_share"]], "layer_metrics",
                             ev, "mistral-d12.chat") == {}

@@ -52,11 +52,11 @@ def test_open_loop_metrics_read_from_data_files(chat_result, benchmark_json):
     layer = run.read_metrics(benchmark_json["per_layer"], "layer_metrics",
                              ev, "mistral-d12.chat")
     # not traced: the readers of the device trace found nothing to read
-    assert "device_idle_share.chat" not in layer
+    assert "device_idle_share" not in layer
     assert "step_roofline_share_counted.chat" not in layer
-    assert layer["compiles_in_window.chat"]["value"] == 0
+    assert layer["compiles_in_window"]["value"] == 0
     assert 0 < layer["token_slot_fill_share"]["value"] < 100
-    assert layer["padded_slot_share.chat"]["value"] == pytest.approx(
+    assert layer["padded_slot_share"]["value"] == pytest.approx(
         100 - layer["token_slot_fill_share"]["value"])
     assert 0 <= layer["chunk_step_gap_share"]["value"] <= 100
     # a loaded test machine runs late; the chip run reads 1.6 ms
@@ -144,8 +144,8 @@ def test_a_traced_run_whose_trace_is_empty_still_prints_its_line(
     assert tr["t1"] == pytest.approx(res["evidence"].w1, abs=0.25)
     line = run.result_line(res, benchmark_json, "mistral-d12.chat", 1,
                            "cpu", 1)
-    assert line["metrics"]["device_idle_share.chat"] == {"value": 100.0,
-                                                         "unit": "%"}
+    assert line["metrics"]["device_idle_share"] == {"value": 100.0,
+                                                    "unit": "%"}
     assert "step_roofline_share_counted.chat" not in line["metrics"]
     assert "paged_attention_roofline_share.chat" not in line["metrics"]
     assert line["device"]["busy_s"] == 0.0
@@ -178,7 +178,7 @@ def test_a_traced_run_whose_trace_is_empty_still_prints_its_line(
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["breakdown"] == {"device_ops": [],
                                 "idle_gaps": line["breakdown"]["idle_gaps"]}
-    assert out["metrics"]["device_idle_share.chat"]["value"] == 100.0
+    assert out["metrics"]["device_idle_share"]["value"] == 100.0
 
 
 class _AlteredStream:

@@ -74,7 +74,14 @@ def test_new_cell_metrics_read_from_data_files(reasoning_result,
     assert layer["compiles_in_window"]["value"] == 0
     assert layer["moe_assignments_held_mean"]["value"] > 0
     assert 0 < layer["moe_experts_touched_mean"]["value"] <= 2 * 8
-    assert layer["host_serial_ms_per_step"]["value"] >= 0
+    # the host's phases of a step, and their sum with the emit's
+    phases = [layer[name + "_ms_per_step"]["value"]
+              for name in ("loop_gap", "admit", "pack", "launch")]
+    assert min(phases) >= 0
+    assert sum(phases) <= layer["host_serial_ms_per_step"]["value"]
+    assert layer["h2d_kb_per_step"]["value"] > 0
+    # as stored: each layer's row in one 128-lane tile
+    assert layer["cache_bytes_per_token"]["value"] == 4 * 128 * 2
     assert 0 < layer["token_slot_fill_share"]["value"] <= 100
     # not traced: what reads the trace found nothing to read
     assert not [n for n in layer if "roofline" in n or "mhc_maps_ms" in n
@@ -380,7 +387,10 @@ SHARED = ("step_ms_p50", "batch_rows_mean", "chunk_step_gap_share",
           "gen_lateness_p99_ms", "moe_assignments_held_mean",
           "moe_held_expert_max_p95", "moe_experts_touched_mean",
           "latent_cache_bytes_per_token", "latent_decode_roofline_share",
-          "moe_grouped_matmul_roofline_share")
+          "moe_grouped_matmul_roofline_share",
+          # since PR 46, once the table had room
+          "loop_gap_ms_per_step", "admit_ms_per_step", "pack_ms_per_step",
+          "launch_ms_per_step", "h2d_kb_per_step", "cache_bytes_per_token")
 OWN = ("step_roofline_share_counted.xing4", "mhc_maps_roofline_share.xing4",
        "mhc_maps_ms_per_step.xing4", "mhc_col_sum_gap_max.xing4",
        "residual_stream_bytes_per_token.xing4")
