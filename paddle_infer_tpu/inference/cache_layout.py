@@ -40,9 +40,19 @@ Two kinds today, the second in two forms:
             is refused at start-up, one sentence each
             (``serving/sharded/mesh.validate_cache_layout``).
 
+            A decoder layer with TWO attention sub-layers
+            (models/longcat_flash.py) states two entries, ``part`` 0 and
+            1, each a one-pool latent layer of its own: the layout's
+            length is the number of CACHE layers (attention sub-layers),
+            not of decoder layers, and everything that walks pools walks
+            the layout.  Block ids are shared as ever, so the page
+            writer, the prefix cache, release and eviction carry all of
+            them under the one table; what a latent pool cannot do yet
+            it cannot do for either part.
+
 A model states its layers' kinds through ``cache_layout()`` (a list, one
-entry a layer); a model without it is the ``kv`` case at its config's
-heads.  ``PagedGenerationEngine._ensure_pages``, ``run_paged_program``
+entry a cache layer); a model without it is the ``kv`` case at its
+config's heads.  ``PagedGenerationEngine._ensure_pages``, ``run_paged_program``
 and the serving programs (``serving/programs.py``) go through this one
 description, so the pools still travel as the ``(k_pages, v_pages)``
 pair of per-layer lists every program donates — a ``latent`` layer's
@@ -62,14 +72,19 @@ class LayerCache:
     dim: int = 0              # kv
     width: int = 0            # latent
     index_width: int = 0      # latent: the indexer's key, second pool
+    part: int = 0             # which attention sub-layer of its decoder
+                              # layer this entry caches (0: the first or
+                              # the only one)
 
     @classmethod
     def kv(cls, heads: int, dim: int) -> "LayerCache":
         return cls("kv", heads=int(heads), dim=int(dim))
 
     @classmethod
-    def latent(cls, width: int, index_width: int = 0) -> "LayerCache":
-        return cls("latent", width=int(width), index_width=int(index_width))
+    def latent(cls, width: int, index_width: int = 0,
+               part: int = 0) -> "LayerCache":
+        return cls("latent", width=int(width), index_width=int(index_width),
+                   part=int(part))
 
     @property
     def lanes(self) -> int:

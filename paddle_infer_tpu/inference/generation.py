@@ -218,7 +218,12 @@ class GenerationEngine:
         self._placed = {}            # name -> (source array, placed array)
         self._shard_record = {}      # name -> sharded|replicated|fallback
         cfg = model.config
-        self._num_layers = cfg.num_hidden_layers
+        # CACHE layers: a decoder layer with two attention sub-layers
+        # states two (inference/cache_layout.py)
+        from .cache_layout import layout_of
+
+        self._cache_layout = layout_of(model)
+        self._num_layers = len(self._cache_layout)
         self._num_heads = cfg.num_attention_heads
         self._head_dim = cfg.hidden_size // cfg.num_attention_heads
         self._max_positions = cfg.max_position_embeddings
@@ -738,9 +743,6 @@ class PagedGenerationEngine(GenerationEngine):
                          prompt_bucket=prompt_bucket,
                          cache_dtype=cache_dtype, mesh=mesh,
                          quantized_allreduce=quantized_allreduce)
-        from .cache_layout import layout_of
-
-        self._cache_layout = layout_of(model)
         self._cache_bytes_measured = {}
         self.page_size = page_size
         self._requested_pages = num_pages

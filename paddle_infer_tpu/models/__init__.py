@@ -22,6 +22,7 @@ __all__ = [
     "LlamaMLP", "LLAMA_PRESETS", "llama_lm_loss",
     "GPTMoEModel", "GPTMoEForCausalLM", "MoEConfig",
     "LatentMoEConfig", "LatentMoEModel", "LatentMoEForCausalLM",
+    "LongcatFlashConfig", "LongcatFlashModel", "LongcatFlashForCausalLM",
     "AutoModel", "AutoConfig", "PretrainedMixin",
 ]
 
@@ -51,6 +52,11 @@ def __getattr__(name):
         from . import latent_moe
 
         return getattr(latent_moe, name)
+    if name in ("LongcatFlashConfig", "LongcatFlashModel",
+                "LongcatFlashForCausalLM"):
+        from . import longcat_flash
+
+        return getattr(longcat_flash, name)
     if name in ("AutoModel", "AutoConfig", "PretrainedMixin"):
         from . import pretrained
 

@@ -109,9 +109,12 @@ class LatentMoEConfig:
                  **extra):
         if scoring_func != "sigmoid" or not norm_topk_prob:
             raise NotImplementedError(
-                "the expert layer scores by sigmoid and renormalises the "
-                f"chosen; got scoring_func={scoring_func!r}, "
-                f"norm_topk_prob={norm_topk_prob!r}")
+                "this family's expert layer scores by sigmoid and "
+                "renormalises the chosen; got scoring_func="
+                f"{scoring_func!r}, norm_topk_prob={norm_topk_prob!r}. "
+                "serving.moe.dropless.DroplessMoE takes scoring="
+                "\"softmax\" and renormalise=False; the decoder built on "
+                "them is models.longcat_flash.LongcatFlashForCausalLM")
         if topk_method not in ("none", "noaux_tc"):
             raise NotImplementedError(
                 f"topk_method={topk_method!r}: only plain top-k over all "
@@ -316,9 +319,19 @@ class LatentAttention(Layer):
             / yarn_mscale(float(sc["factor"]),
                           float(sc.get("mscale_all_dim", 0))))
         self.indexer = Indexer(cfg) if cfg.index_topk else None
+        # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: a latent times
+        # sqrt(hidden / its rank) behind its norm (the rotary key is not
+        # scaled); None where the config has no such key
+        scaled = lambda key, rank: (
+            (h / rank) ** 0.5 if getattr(cfg, key, False) else None)
+        self.q_latent_scale = scaled("mla_scale_q_lora", cfg.q_lora_rank)
+        self.kv_latent_scale = scaled("mla_scale_kv_lora", self.rank)
 
     def _query_latent(self, x):
-        return self.q_a_layernorm(self.q_a_proj(x))
+        c_q = self.q_a_layernorm(self.q_a_proj(x))
+        if self.q_latent_scale is not None:
+            c_q = Tensor(c_q._data * self.q_latent_scale)
+        return c_q
 
     def _queries(self, c_q, positions):
         """The normed query latent -> q_nope [b, s, H, nope], q_pe
@@ -347,6 +360,8 @@ class LatentAttention(Layer):
         position part, as cached."""
         ckv = self.kv_a_proj_with_mqa(x)._data
         c_kv = self.kv_a_layernorm(Tensor(ckv[..., :self.rank]))._data
+        if self.kv_latent_scale is not None:
+            c_kv = c_kv * self.kv_latent_scale
         k_pe = _rope(ckv[..., self.rank:], positions, self.inv_freq,
                      self.rope_mscale).astype(ckv.dtype)
         return jnp.concatenate([c_kv, k_pe], axis=-1)

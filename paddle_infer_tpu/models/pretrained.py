@@ -35,6 +35,8 @@ class AutoModel:
         "ErnieForSequenceClassification": (
             "ernie", "ErnieForSequenceClassification"),
         "LatentMoEForCausalLM": ("latent_moe", "LatentMoEForCausalLM"),
+        "LongcatFlashForCausalLM": ("longcat_flash",
+                                    "LongcatFlashForCausalLM"),
     }
 
     # a directory holding a model's own published config.json carries no
@@ -43,6 +45,7 @@ class AutoModel:
         "axk1": ("latent_moe", "LatentMoEForCausalLM"),
         "xing4_0": ("latent_moe", "LatentMoEForCausalLM"),
         "glm_moe_dsa": ("latent_moe", "LatentMoEForCausalLM"),
+        "longcat_flash": ("longcat_flash", "LongcatFlashForCausalLM"),
     }
 
     @classmethod

@@ -224,6 +224,14 @@ _SCHEMA = (
                                  # any layer this step
     ("moe_experts_touched", 0),  # held experts with at least one token,
                                  # summed over expert layers
+    # ... of a model whose router also scores identity experts (an
+    # assignment to one computes nothing); 0 on a model without them
+    ("moe_assignments_identity", 0),  # valid tokens' assignments to
+                                      # identity experts, summed over
+                                      # expert layers
+    ("moe_real_per_token_max", 0),  # the most computing experts one
+                                    # valid token chose in a layer, the
+                                    # step's largest
     # hyper-connected residual streams (nn/hyper_connections.py); 0 on a
     # model with the plain residual
     ("mhc_col_sum_gap_max", 0.0),  # largest |colsum(H_res) - 1| over the

@@ -59,7 +59,8 @@ from .adapters import UnknownAdapterError
 from .kv_tier import HostKVTier
 from .metrics import ServingMetrics
 from .prefix_cache import PrefixCache
-from .programs import (DROPLESS_COUNTERS, RESIDUAL_COUNTERS, build_mixed_step,
+from .programs import (DROPLESS_COUNTERS, IDENTITY_COUNTERS,
+                       RESIDUAL_COUNTERS, build_mixed_step,
                        build_page_copy, sampling_rows,
                        step_input_layout, step_output_layout)
 from .request import (DeadlineExceededError, GrammarError,
@@ -403,7 +404,9 @@ class EngineCore:
         self._step_out = step_output_layout(
             self._max_batch, self._spec_window,
             moe=(self._moe["num_experts"] if self._moe is not None
-                 else "dropless" if self._dropless is not None else None),
+                 else None if self._dropless is None
+                 else "dropless_identity"
+                 if self._dropless["identity_experts"] else "dropless"),
             residual=self._residual is not None)
 
         # step-level flight recorder: every scheduler step event
@@ -1928,7 +1931,13 @@ class EngineCore:
         tok, fin_out, n_emit = out["tok"], out["fin"], out.get("n_emit")
         moe_kw = {}
         if DROPLESS_COUNTERS[0] in out:
-            moe_kw = {name: int(out[name]) for name in DROPLESS_COUNTERS}
+            moe_kw = {name: int(out[name])
+                      for name in DROPLESS_COUNTERS + IDENTITY_COUNTERS
+                      if name in out}
+            if IDENTITY_COUNTERS[0] in moe_kw:
+                self._metrics.on_identity_experts(
+                    moe_kw[DROPLESS_COUNTERS[0]],
+                    moe_kw[IDENTITY_COUNTERS[0]])
         elif "moe_routed" in out:
             m_routed = out["moe_routed"]
             m_dropped = int(out["moe_dropped"])

@@ -114,6 +114,11 @@ class ServingMetrics:
             # the pairs its attention then read
             self.index_scored_keys = 0
             self.index_selected_keys = 0
+            # a model whose router also scores identity experts: valid
+            # assignments its dropless layers made, and of those the
+            # ones to an identity expert (they compute nothing)
+            self.moe_assignments = 0
+            self.moe_assignments_identity = 0
             # resilience counters (serving/resilience/) — rendered as
             # their own Prometheus families (engine_restarts_total, …),
             # NOT through the auto-named serving_*_total counters block
@@ -223,6 +228,13 @@ class ServingMetrics:
         with self._lock:
             self.index_scored_keys += int(scored)
             self.index_selected_keys += int(selected)
+
+    def on_identity_experts(self, total: int, identity: int):
+        """One mixed step's dropless layers made ``total`` valid
+        assignments, ``identity`` of them to identity experts."""
+        with self._lock:
+            self.moe_assignments += int(total)
+            self.moe_assignments_identity += int(identity)
 
     def on_queue_wait(self, wait_s: float):
         """One request left the admission queue after ``wait_s``."""
@@ -443,6 +455,12 @@ class ServingMetrics:
                     "selected_keys": self.index_selected_keys,
                     "keep_share": (self.index_selected_keys
                                    / self.index_scored_keys)}
+            if self.moe_assignments_identity:
+                out["identity_experts"] = {
+                    "assignments": self.moe_assignments,
+                    "identity_assignments": self.moe_assignments_identity,
+                    "identity_share": (self.moe_assignments_identity
+                                       / self.moe_assignments)}
             if adapters is not None:
                 out["adapters"] = dict(adapters)
             if kv_tier is not None:

@@ -45,6 +45,7 @@ class MoEStatsCollector:
         self.dropped = []
         self.aux = []
         self.dropless = []
+        self.identity = []
 
     def note(self, routed, dropped, aux):
         self.routed.append(_raw(routed))
@@ -57,6 +58,11 @@ class MoEStatsCollector:
         count any held expert got, held experts with at least one."""
         self.dropless.append((total, held, held_max, touched))
 
+    def note_identity(self, identity, real_max):
+        """One dropless layer with identity experts: valid assignments to
+        them, and the most computing experts one valid token chose."""
+        self.identity.append((identity, real_max))
+
     def totals(self):
         """Sum the per-layer notes into the program's outputs.  Capacity
         layers give three: routed [E] i32 (kept expert assignments over
@@ -65,14 +71,21 @@ class MoEStatsCollector:
         (load-balancing loss, averaged across layers — a gauge, not a
         counter).  Dropless layers give four i32: assignments and held
         assignments summed over layers, the largest held-expert count of
-        any layer, touched held experts summed over layers."""
+        any layer, touched held experts summed over layers; layers with
+        identity experts two more: the assignments to those summed over
+        layers, and the most computing experts a token chose in any."""
         import jax.numpy as jnp
 
         if self.dropless:
             total, held, held_max, touched = zip(*self.dropless)
             i32 = lambda x: jnp.asarray(x).astype(jnp.int32)
-            return (i32(sum(total)), i32(sum(held)),
-                    i32(jnp.max(jnp.stack(held_max))), i32(sum(touched)))
+            out = (i32(sum(total)), i32(sum(held)),
+                   i32(jnp.max(jnp.stack(held_max))), i32(sum(touched)))
+            if self.identity:
+                identity, real_max = zip(*self.identity)
+                out += (i32(sum(identity)),
+                        i32(jnp.max(jnp.stack(real_max))))
+            return out
         if not self.routed:
             raise RuntimeError(
                 "moe_stats collection ran but no serving MoE layer "

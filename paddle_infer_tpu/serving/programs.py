@@ -63,6 +63,8 @@ SAMP_FIELDS = (("temperature", "float32"), ("top_k", "int32"),
 # ``MoEStatsCollector.totals``): a dropless model's four, in this order
 DROPLESS_COUNTERS = ("moe_assignments_total", "moe_assignments_held",
                      "moe_held_expert_max", "moe_experts_touched")
+# ... two more where its expert layers have identity experts
+IDENTITY_COUNTERS = ("moe_assignments_identity", "moe_real_per_token_max")
 # ... and the capacity path's three
 CAPACITY_COUNTERS = ("moe_routed", "moe_dropped", "moe_aux")
 # what a model of hyper-connected residual streams notes a step
@@ -151,7 +153,8 @@ def step_output_layout(max_batch, spec_window=1, moe=None, residual=False):
     """THE layout of the mixed step's packed output: the sampled tokens,
     the finished flags, the emit counts of a speculating step, then the
     expert counters — ``moe`` is None (no expert layer counted),
-    ``"dropless"`` (the four ``DROPLESS_COUNTERS``) or the capacity
+    ``"dropless"`` (the four ``DROPLESS_COUNTERS``), ``"dropless_identity"``
+    (those and the two ``IDENTITY_COUNTERS``) or the capacity
     path's expert count ``E`` (``moe_routed[E]``, ``moe_dropped``,
     ``moe_aux`` float32 by bit pattern) — and, with ``residual``, the
     three ``RESIDUAL_COUNTERS`` of a model of hyper-connected streams."""
@@ -160,8 +163,10 @@ def step_output_layout(max_batch, spec_window=1, moe=None, residual=False):
             ("fin", (b,), "bool")]
     if W > 1:
         rows.append(("n_emit", (b,), "int32"))
-    if moe == "dropless":
+    if moe in ("dropless", "dropless_identity"):
         rows.extend((name, (), "int32") for name in DROPLESS_COUNTERS)
+        if moe == "dropless_identity":
+            rows.extend((name, (), "int32") for name in IDENTITY_COUNTERS)
     elif moe is not None:
         rows += [("moe_routed", (int(moe),), "int32"),
                  ("moe_dropped", (), "int32"), ("moe_aux", (), "float32")]
@@ -435,8 +440,9 @@ def build_mixed_step(engine, max_batch, token_budget, max_pages,
                 logits, caches = model()
             totals = col.totals()
             if col.dropless:
-                return logits, caches, ("dropless",
-                                        dict(zip(DROPLESS_COUNTERS, totals)))
+                kind = "dropless_identity" if col.identity else "dropless"
+                return logits, caches, (kind, dict(zip(
+                    DROPLESS_COUNTERS + IDENTITY_COUNTERS, totals)))
             return logits, caches, (totals[0].shape[0],
                                     dict(zip(CAPACITY_COUNTERS, totals)))
 

@@ -151,44 +151,51 @@ def validate_cache_layout(cache_layout, *, mp: int = 1,
     """What a ``latent`` cache layer (inference/cache_layout.py: one
     ``[P, page, lanes]`` pool, no head axis, no V pool — or, with an
     ``index_width``, that pool and the indexer's ``[P, page, 128]`` keys
-    beside it) cannot do yet — refused here, at start-up, one mechanism a
-    sentence.  Silent for layouts of ``kv`` layers only."""
+    beside it; or two such pools a decoder layer, one an attention
+    sub-layer, stated as two entries) cannot do yet — refused here, at
+    start-up, one mechanism a sentence.  Silent for layouts of ``kv``
+    layers only."""
     if not cache_layout or all(c.kind != "latent" for c in cache_layout):
         return
     # the pair of a layer with an indexer: two pools of UNEQUAL shape
     pair = any(c.index_width for c in cache_layout if c.kind == "latent")
+    # a decoder layer of two attention sub-layers: a latent pool each
+    twin = any(c.part for c in cache_layout if c.kind == "latent")
+    either = ", in either of a decoder layer's two pools" if twin else ""
     if mp > 1:
         raise ShardedConfigError(
             f"mp={mp} splits the page pool over its head axis; a latent "
             "cache layer has no head axis to split" + (
                 ", and its index keys are one vector a token for every "
-                "index head" if pair else "") + " — serve it with mp=1")
+                "index head" if pair else "") + either
+            + " — serve it with mp=1")
     if kv_dtype is not None:
         raise ShardedConfigError(
             f"kv_dtype={kv_dtype!r} scales each page per head; a latent "
             "cache layer has no heads to scale over" + (
                 ", and its index-key pool is stored in the served type"
-                if pair else "") + " — serve it with full-precision pages")
+                if pair else "") + either
+            + " — serve it with full-precision pages")
     if speculate:
         raise ShardedConfigError(
             "speculative decoding verifies drafts through the per-head "
             "decode kernel's verify lanes; the latent decode kernel" + (
                 "s (index scores, sparse decode) have" if pair else " has")
-            + " none — drop speculate")
+            + " none" + (", for either of a decoder layer's two attention "
+                         "sub-layers" if twin else "") + " — drop speculate")
+    one = ("a latent pool and an index-key pool of another width" if pair
+           else "two latent pools, one an attention sub-layer, that are "
+           "no key and value of each other" if twin else "one pool")
     if int(kv_host_pages) > 0:
         raise ShardedConfigError(
             "the host KV tier parks and resumes a row through its key "
             "and value pools, a pair of equal shape; a latent cache "
-            "layer has " + ("a latent pool and an index-key pool of "
-                            "another width" if pair else "one pool")
-            + " — drop kv_host_pages")
+            f"layer has {one} — drop kv_host_pages")
     if handoff:
         raise ShardedConfigError(
             "KV handoff between replicas serialises a row's key and "
             "value pools, a pair of equal shape; a latent cache layer "
-            "has " + ("a latent pool and an index-key pool of another "
-                      "width" if pair else "one pool")
-            + " — serve it without a dedicated prefill role")
+            f"has {one} — serve it without a dedicated prefill role")
 
 
 def validate_serving_config(cfg: ServingMesh, *, speculate: bool = False,

@@ -769,3 +769,96 @@ def test_step_sorts_the_vocabulary_once_and_inside_a_conditional(
         if step != "spec_step_text":
             assert not [ln for ln in always if "lm_head_sample" in ln
                         and re.search(mark, ln)], what
+
+
+# one layer of the tool-chat cell's step: two latent attentions, two dense
+# blocks and the shortcut expert block at its widths and deployment (64
+# rows of 4096, 256 token slots)
+SHORTCUT_B, SHORTCUT_CHUNK, SHORTCUT_PAGES = 64, 256, 256
+
+
+@pytest.fixture(scope="module")
+def shortcut_step(one_chip, not_interpreted):
+    from paddle_infer_tpu.inference.generation import PagedGenerationEngine
+    from paddle_infer_tpu.models.longcat_flash import (
+        LongcatFlashConfig, LongcatFlashForCausalLM)
+    from paddle_infer_tpu.nn.initializer import abstract_parameters
+    from paddle_infer_tpu.serving.programs import build_mixed_step
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    cfg = LongcatFlashConfig(vocab_size=16384, num_layers=1,
+                             n_routed_experts=16,
+                             n_routed_experts_published=512)
+    with abstract_parameters():
+        model = LongcatFlashForCausalLM(cfg)
+    engine = PagedGenerationEngine(model, page_size=PAGE,
+                                   cache_dtype=jnp.bfloat16)
+    run = build_mixed_step(engine, SHORTCUT_B, SHORTCUT_CHUNK,
+                           SHORTCUT_PAGES, moe_stats=True)
+    params = {n: spec(a.shape, jnp.float32
+                      if n.endswith("e_score_correction_bias")
+                      else jnp.bfloat16) for n, a in engine._params.items()}
+    pools = [spec((SHORTCUT_B * SHORTCUT_PAGES + 1, PAGE, 640),
+                  jnp.bfloat16)] * 2
+    return run.lower(*_step_args(
+        spec, params, SHORTCUT_B, SHORTCUT_CHUNK, SHORTCUT_PAGES, pools,
+        [None, None])).compile()
+
+
+def test_shortcut_step_holds_two_decode_kernels_a_layer_and_fits(
+        shortcut_step):
+    """One layer: the latent decode kernel once an attention sub-layer,
+    the grouped matmul for the one expert block, neither pool copied or
+    transposed, the packed output and both pools back."""
+    text = shortcut_step.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    entry = text.split("\nENTRY ", 1)[1]
+    assert len(set(re.findall(r"%(latent_paged_decode[\w.]*) = ", entry))) \
+        == 2
+    assert sum("%moe_grouped_matmul" in ln for ln in calls) >= 3
+    pool = r"bf16\[%d,16,640\]" % (SHORTCUT_B * SHORTCUT_PAGES + 1)
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= %s\S* (copy|transpose)\(" % pool, ln)]
+    assert shortcut_step.memory_analysis().temp_size_in_bytes < 0.8e9
+    n_params = len(shortcut_step.args_info[0][0])
+    assert _entry_io(shortcut_step) == (n_params + 1 + 2, 1 + 2)
+
+
+def test_shortcut_step_identity_term_is_two_small_operations_a_block(
+        shortcut_step):
+    """The identity experts' term compiles to one masked sum a token
+    (``select_reduce_fusion f32[256]``) and one multiply into the combine's
+    base (``convert_multiply_fusion f32[256,6144]``): the two operation
+    keys ``moe_identity_ms_per_step.longcat`` sums, each carrying the
+    scope ``moe_identity``, and nothing under the scope or under
+    ``moe_experts`` is as wide as tokens x router outputs but the scores
+    themselves; the rows buffer is tokens x 12 whatever is chosen."""
+    import json
+    import os
+
+    text = shortcut_step.as_text()
+    entry = text.split("\nENTRY ", 1)[1]
+    entry = entry[:entry.index("\n}")]
+    scoped = [ln for ln in entry.splitlines() if re.search(
+        r'op_name="[^"]*/moe_identity/[^"]*"', ln) and " fusion(" in ln]
+    names = sorted(re.match(r"\s*%([a-z_]+)[\w.]* = \(?(\w+\[[\d,]*\])",
+                            ln).groups() for ln in scoped)
+    assert names == [("convert_multiply_fusion", "f32[256,6144]"),
+                     ("select_reduce_fusion", "f32[256]")]
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "layer_metrics",
+                           "moe_identity_ms_per_step.longcat.json")) as f:
+        keys = json.load(f)["args"]["kernels"]
+    assert sorted(keys) == sorted("fusion %s %s" % n for n in names)
+    # no other operation of the step goes by either key
+    for name, shape in names:
+        same = [ln for ln in entry.splitlines() if re.match(
+            r"\s*%%%s[\w.]* = \(?%s" % (name, re.escape(shape)), ln)]
+        assert len(same) == 1, (name, len(same))
+    experts = [ln for ln in text.splitlines() if re.search(
+        r'op_name="[^"]*/(moe_experts|moe_identity)/[^"]*"', ln)]
+    assert experts and not [ln for ln in experts
+                            if re.search(r"\[256,768\]|\[768,256\]", ln)]
+    assert "bf16[3072,2048]" in text and "bf16[3072,6144]" in text
